@@ -1,4 +1,4 @@
-"""LeNet-5 MNIST model builder (BASELINE.md config #1).
+"""LeNet-5 MNIST model builder (BASELINE.json config #1).
 
 The reference has no model zoo at 0.7.3; this mirrors the canonical DL4J
 LeNet example config (conv 5x5x20 -> maxpool -> conv 5x5x50 -> maxpool ->

@@ -1,4 +1,4 @@
-"""ResNet-50 built on the ComputationGraph DSL (BASELINE.md config #2).
+"""ResNet-50 built on the ComputationGraph DSL (BASELINE.json config #2).
 
 The reference has no zoo at 0.7.3; this expresses the canonical ResNet-50
 (bottleneck v1) through the same GraphBuilder API a DL4J user would employ

@@ -55,20 +55,14 @@ def _match_varying(tree, ref: Array):
     Fresh ``jnp.zeros`` carries are unvarying; inside ``shard_map`` (the
     ParallelWrapper step) the scanned inputs are device-varying, and
     ``lax.scan`` requires carry-in and carry-out types to match.  Outside
-    shard_map ``ref`` has no vma and this is a no-op.  On jax versions
-    without ``jax.typeof``/``lax.pcast`` (no vma type system) it is
-    always a no-op."""
-    typeof = getattr(jax, "typeof", None)
-    pcast = getattr(lax, "pcast", None)
-    if typeof is None or pcast is None:
-        return tree
-    ref_vma = getattr(typeof(ref), "vma", frozenset())
+    shard_map ``ref`` has no vma and this is a no-op."""
+    ref_vma = jax.typeof(ref).vma
     if not ref_vma:
         return tree
 
     def cast(leaf):
-        missing = ref_vma - getattr(typeof(leaf), "vma", frozenset())
-        return pcast(leaf, tuple(missing), to="varying") if missing \
+        missing = ref_vma - jax.typeof(leaf).vma
+        return lax.pcast(leaf, tuple(missing), to="varying") if missing \
             else leaf
 
     return jax.tree.map(cast, tree)

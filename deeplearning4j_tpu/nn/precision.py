@@ -93,10 +93,9 @@ _NAMED = {FP32: _FP32_POLICY, BF16: _BF16_POLICY, MIXED_BF16: _MIXED_POLICY}
 
 
 def on_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+    # a backend that fails to initialise raises here: answering False
+    # would turn a broken chip into a quiet fp32 run on the CPU
+    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def env_mode() -> Optional[str]:

@@ -140,8 +140,7 @@ def _pad_to(x: Array, axis: int, multiple: int) -> Array:
 def _sds(shape, dtype, like: Array) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct carrying ``like``'s shard_map varying-axes tag
     (required for pallas_call under shard_map with vma checking)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof else None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)

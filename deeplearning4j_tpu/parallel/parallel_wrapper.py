@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..ops.compat import pcast as _pcast, shard_map as _shard_map
 
 from .. import monitor as _monitor
 from .mesh import MeshRuntime
@@ -169,8 +168,8 @@ class ParallelWrapper:
             # (allreduce-SGD), which is NOT the reference's local-step-then-
             # average semantics.
             for ax in dp:
-                params, net_state = _pcast((params, net_state), ax,
-                                           to="varying")
+                params, net_state = lax.pcast((params, net_state), ax,
+                                              to="varying")
 
             def one_step(carry, batch):
                 from ..nn import ingest
@@ -251,8 +250,8 @@ class ParallelWrapper:
             if avg_updaters:
                 updater_state = lax.pmean(updater_state, dp)
                 for ax in dp:
-                    updater_state = _pcast(updater_state, ax,
-                                           to="varying")
+                    updater_state = lax.pcast(updater_state, ax,
+                                              to="varying")
             net_state = lax.pmean(net_state, dp)
             score = lax.pmean(jnp.mean(scores), dp)
             # Mean across workers: a single worker's NaN poisons the
@@ -268,7 +267,7 @@ class ParallelWrapper:
                     P(None, dp), P(None, dp), P(None, dp), P(),
                     P())
         out_specs = (P(), P(dp), P(), P(), P())
-        fn = _shard_map(local_round, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(local_round, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs)
         return _monitor.watched_jit(fn, name="parallel.step",
                                     donate_argnums=(0, 1, 2))

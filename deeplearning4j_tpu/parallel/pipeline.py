@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ..ops.compat import shard_map as _shard_map
 
 from ..datasets.dataset import DataSet
 from .mesh import MeshRuntime
@@ -254,7 +253,7 @@ class PipelineParallel:
             score = loss + net._reg_score(params)
             return new_params, new_ustate, score
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             train_step, mesh=self.mesh,
             in_specs=(P(),) * 5, out_specs=(P(), P(), P()),
             check_vma=False)

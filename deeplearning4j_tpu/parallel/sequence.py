@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ..ops.compat import axis_size as _axis_size, pcast as _pcast, shard_map as _shard_map
 
 Array = jax.Array
 
@@ -78,7 +77,7 @@ def ring_attention(q: Array, k: Array, v: Array, *, axis_name: str,
 
     Accumulation is float32 regardless of input dtype (bf16-safe).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     t_local = q.shape[1]
     d = q.shape[-1]
@@ -114,7 +113,7 @@ def ring_attention(q: Array, k: Array, v: Array, *, axis_name: str,
 
     # Fresh accumulators are replication-tracked as unvarying; the body
     # mixes in device-varying q/k/v, so the carry must enter varying.
-    o0, m0, l0 = _pcast((o0, m0, l0), axis_name, to="varying")
+    o0, m0, l0 = lax.pcast((o0, m0, l0), axis_name, to="varying")
     (o, _, l, _, _), _ = lax.scan(body, (o0, m0, l0, k, v),
                                   jnp.arange(n))
     out = o / jnp.maximum(l, 1e-30)[..., None]
@@ -190,7 +189,7 @@ def _ring_flash_forward(q, k, v, axis_name, causal, sm_scale, block_q,
                         block_k, interpret, precision):
     from ..ops.attention import flash_attention_partial
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     # axis_index lowers to partition-id; only materialize it when the
     # causal schedule needs it, so the non-causal program stays free of
     # it (older XLA SPMD partitioners reject stray partition-id ops).
@@ -221,7 +220,7 @@ def _ring_flash_forward(q, k, v, axis_name, causal, sm_scale, block_q,
         def masked(_):
             # fresh constants are replication-tracked as unvarying; the
             # kernel branches are varying — align the types for switch
-            return _pcast(
+            return lax.pcast(
                 (jnp.zeros(q.shape, jnp.float32),
                  jnp.full(q.shape[:3], _NEG_INF, jnp.float32),
                  jnp.zeros(q.shape[:3], jnp.float32)),
@@ -241,7 +240,7 @@ def _ring_flash_forward(q, k, v, axis_name, causal, sm_scale, block_q,
     o0 = jnp.zeros(q.shape, jnp.float32)
     m0 = jnp.full(q.shape[:3], _NEG_INF, jnp.float32)
     l0 = jnp.zeros(q.shape[:3], jnp.float32)
-    o0, m0, l0 = _pcast((o0, m0, l0), axis_name, to="varying")
+    o0, m0, l0 = lax.pcast((o0, m0, l0), axis_name, to="varying")
     (o, m, l, _, _), _ = lax.scan(body, (o0, m0, l0, k, v),
                                   jnp.arange(n))
     l_safe = jnp.maximum(l, 1e-30)
@@ -270,7 +269,7 @@ def _ring_flash_bwd(axis_name, causal, sm_scale, block_q, block_k,
     from ..ops.attention import flash_attention_bwd
 
     q, k, v, out, L = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     scale = (float(sm_scale) if sm_scale is not None
              else 1.0 / float(np.sqrt(q.shape[-1])))
@@ -291,7 +290,7 @@ def _ring_flash_bwd(axis_name, causal, sm_scale, block_q, block_k,
 
     def masked(pkg):
         # align vma with the kernel branches (fresh zeros are unvarying)
-        return _pcast(
+        return lax.pcast(
             (jnp.zeros(q.shape, jnp.float32),
              jnp.zeros(k.shape, jnp.float32),
              jnp.zeros(v.shape, jnp.float32)),
@@ -318,7 +317,7 @@ def _ring_flash_bwd(axis_name, causal, sm_scale, block_q, block_k,
     dq0 = jnp.zeros(q.shape, jnp.float32)
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
-    dq0, dk0, dv0 = _pcast((dq0, dk0, dv0), axis_name, to="varying")
+    dq0, dk0, dv0 = lax.pcast((dq0, dk0, dv0), axis_name, to="varying")
     carry0 = ((q, g, L, D_row, dq0), dk0, dv0)
     ((_, _, _, _, dq), dk, dv), _ = lax.scan(body, carry0, jnp.arange(n))
     # after n rotations the package (with its accumulated dq) is home
@@ -340,7 +339,7 @@ def ulysses_attention(q: Array, k: Array, v: Array, *, axis_name: str,
     sequence, and the output is swapped back.  Requires
     ``heads % axis_size == 0``.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n != 0:
         raise ValueError(f"heads={h} not divisible by seq shards={n}")
@@ -389,7 +388,7 @@ def ring_lstm_scan(W: Array, RW: Array, b: Array, x: Array,
     """
     from ..nn.layers.recurrent import lstm_scan_preact
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
 
     # Loop-invariant: project this chip's shard once, not once per round.
@@ -414,7 +413,7 @@ def ring_lstm_scan(W: Array, RW: Array, b: Array, x: Array,
     # The scan carry's dtype must be loop-invariant; mixed-precision inputs
     # (bf16 x, f32 weights) would otherwise promote it after round one.
     carry = jax.tree.map(lambda a: a.astype(res_dtype), carry)
-    carry, ys0 = _pcast((carry, ys0), axis_name, to="varying")
+    carry, ys0 = lax.pcast((carry, ys0), axis_name, to="varying")
     (ring_carry, ys), _ = lax.scan(round_body, (carry, ys0), jnp.arange(n))
     # After the last round chip (n-1)'s final — the global final — was
     # ppermuted onto chip 0; broadcast it everywhere.
@@ -447,7 +446,7 @@ class SequenceParallel:
 
     def _sharded(self, fn, n_args: int):
         spec = P(None, self.axis)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=self.mesh, in_specs=(spec,) * n_args,
             out_specs=spec))
 

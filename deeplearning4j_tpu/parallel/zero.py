@@ -46,15 +46,12 @@ import numpy as np
 from jax import lax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
-from ..ops.compat import pcast as _pcast, shard_map as _shard_map
 
 from ..datasets.dataset import DataSet
 from ..nn import updaters as U
 from .mesh import MeshRuntime
 
 Array = jax.Array
-
-
 
 
 class ZeroShardedParallelWrapper:
@@ -170,12 +167,18 @@ class ZeroShardedParallelWrapper:
             # reg score on the replicated params (stays invariant for the
             # P() out spec)
             reg = net._reg_score(params)
+            # everything after the gradient psum (l1/l2, the slice the
+            # update applies to) reads the REPLICATED copy, so the updated
+            # slice and state vary over ``zero`` only and match their
+            # P("zero") out specs; the copy cast below would leave them
+            # varying over ``data`` as well
+            shared_params = params
             # varying params -> per-replica grads + EXPLICIT pmean below
             # (unvarying params would make shard_map auto-psum the grads,
             # i.e. SUM not MEAN — the ParallelWrapper pattern)
             for ax in dp:
-                params, net_state = _pcast((params, net_state), ax,
-                                           to="varying")
+                params, net_state = lax.pcast((params, net_state), ax,
+                                              to="varying")
             # combined batch-replica index over the flattened data x zero
             # extent (matches the legacy single-axis ordering when data=1)
             widx = lax.axis_index("data") * zero_n + lax.axis_index("zero")
@@ -208,7 +211,7 @@ class ZeroShardedParallelWrapper:
             grads = [
                 U.regularize(g, p, layer.l1_by_param(),
                              layer.l2_by_param())
-                for layer, p, g in zip(net.layers, params, grads)]
+                for layer, p, g in zip(net.layers, shared_params, grads)]
             grads = [
                 U.normalize_gradients(
                     g, layer.gradient_normalization,
@@ -221,7 +224,7 @@ class ZeroShardedParallelWrapper:
                      if getattr(layer, "frozen", False) else g
                      for layer, g in zip(net.layers, grads)]
             flat_g, _ = ravel_pytree(grads)
-            flat_p, _ = ravel_pytree(params)
+            flat_p, _ = ravel_pytree(shared_params)
             flat_g = jnp.pad(flat_g, (0, padded - total))
             flat_p_pad = jnp.pad(flat_p, (0, padded - total))
             start = zidx * shard
@@ -247,7 +250,7 @@ class ZeroShardedParallelWrapper:
             new_state = jax.tree.map(lambda a: a[None], new_state)
             return new_slice, new_state, new_net_state, score
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             zero_step, mesh=self.mesh,
             in_specs=(P(), P("zero"), P(), P(), P(dp), P(dp),
                       P(dp), P(dp), P()),

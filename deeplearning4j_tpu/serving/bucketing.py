@@ -66,9 +66,9 @@ class BucketPolicy:
             raise ValueError("timestep buckets must be >= 1")
 
     def describe(self) -> str:
-        """Stable one-line identity of the ladder — feeds the
-        executable-cache namespace key (``compile_cache.signature``),
-        so two processes agree on a namespace iff their ladders
+        """Stable one-line identity of the ladder — feeds the fleet's
+        model signature (``compile_cache.signature``), so two
+        processes report the same signature iff their ladders
         match."""
         return (f"serving-buckets:b{list(self.batch_buckets)}"
                 f":t{list(self.timestep_buckets)}")

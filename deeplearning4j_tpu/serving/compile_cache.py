@@ -1,47 +1,41 @@
-"""Persistent on-disk XLA executable cache for serving workers.
+"""Persistent on-disk XLA executable cache: one resolver for every
+entry point.
 
-Cold-start is the enemy of elasticity: a respawned fleet worker that
-has to re-AOT-compile its whole bucket ladder (one executable per
-(input combo, batch bucket[, timestep bucket])) spends seconds in XLA
-before its first reply, which turns every health-driven respawn and
-every scale-out decision into a latency cliff.  This module wires the
-engine's bucket compiles through JAX's persistent compilation cache so
-the *second* process to compile any given (model, backend, bucket
-policy) ladder deserializes executables from disk instead of running
-XLA again.
+Compiling is the slow part of a cold start: a respawned fleet worker
+re-AOT-compiles its whole bucket ladder, and a ResNet-50 ``fit`` step
+compiles for minutes before its first dispatch.  JAX's persistent
+compilation cache turns the second compile of any program into a disk
+read.  Its entry key is JAX's own (computation, compile options,
+backend) digest, so unrelated models share one directory safely.
 
-Key discipline — the part JAX does not do for us:
+:func:`enable` is the ONE place the directory is decided, and the entry
+points (``chip_smoke.py``, ``bench.py``, the fleet worker) call it
+first, before anything compiles:
 
-- The cache *entry* key is JAX's own (computation, compile options,
-  backend) digest; nothing to add there.
-- The cache *directory* is namespaced by the autotuner's model
-  signature (:func:`tools.autotune.model_signature` — architecture +
-  backend + policy), so unrelated models never share a namespace and
-  a fleet can prewarm/ship one model's ladder as a unit.
-- JAX's cache key covers the compile options; flipping any
-  cache-relevant knob silently forks the namespace and every lookup
-  misses.  :func:`enable` therefore pins the full knob set
-  (min-entry-size, min-compile-time) to fixed values so every worker
-  process computes identical entry keys.
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
+  sets no directory, so the cache can be placed from outside (a
+  launcher, the fleet router via its workers' environment).
+- unset: the fixed path ``<checkout>/.jax_compile_cache`` (git-ignored).
+  The path is part of JAX's key, so it must not move between runs.
 
-``enable`` is idempotent and process-global (JAX has exactly one cache
-dir per process); workers call it FIRST, before building the model, so
-even the placement/canonicalization compiles hit the cache.
-
-Env: ``DL4J_TPU_FLEET_COMPILE_CACHE`` — cache root directory; the
-no-arg :func:`enable` uses it, and an empty/unset value disables the
-cache (cold compiles, the pre-fleet behavior).
+Either way the cache-relevant knobs are pinned (min-entry-size,
+min-compile-time): they feed JAX's entry key, so every process that
+wants HITS, not just writes, must use the same values.
 """
 
 from __future__ import annotations
 
 import hashlib
 import os
-from typing import Optional
 
 from .. import monitor as _monitor
 
-ENV_CACHE_DIR = "DL4J_TPU_FLEET_COMPILE_CACHE"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the cache lives when the environment does not say
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
 
 #: the knob set pinned by :func:`enable`; every process that wants
 #: cache HITS (not just writes) must use these exact values, because
@@ -51,15 +45,13 @@ _PINNED_CONFIG = {
     "jax_persistent_cache_min_compile_time_secs": 0.0,
 }
 
-_enabled_dir: Optional[str] = None
-
 
 def signature(conf, policy) -> str:
-    """The cache-namespace key for (model conf, bucket policy): the
-    autotuner's model signature when ``tools`` ships alongside the
-    package, else the same recipe computed locally (stripped
-    deployments must produce identical keys or a mixed fleet would
-    never share a namespace)."""
+    """Identity of (model conf, backend, bucket policy) — what a fleet
+    worker reports in its ready line so a router can tell which workers
+    compile identical executables: the autotuner's model signature when
+    ``tools`` ships alongside the package, else the same recipe computed
+    locally (stripped deployments must produce identical values)."""
     try:
         from tools.autotune import model_signature
         return model_signature(conf, policy)
@@ -74,56 +66,29 @@ def signature(conf, policy) -> str:
         return hashlib.sha256(payload.encode()).hexdigest()[:16]
 
 
-def cache_dir_for(root: str, sig: str) -> str:
-    """The per-model-signature namespace directory under ``root``."""
-    return os.path.join(root, f"sig-{sig}")
-
-
-def enable(root: Optional[str] = None,
-           sig: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    ``<root>/sig-<sig>`` (or ``<root>`` when ``sig`` is None) and pin
-    the cache-relevant config knobs.  ``root=None`` reads
-    ``DL4J_TPU_FLEET_COMPILE_CACHE``; unset/empty means "no cache" and
-    returns None.  Idempotent; re-enabling with a different directory
-    repoints the process (JAX holds one cache dir at a time).
-
-    Returns the active cache directory (created if missing)."""
-    global _enabled_dir
-    if root is None:
-        root = os.environ.get(ENV_CACHE_DIR, "").strip() or None
-    if not root:
-        return None
-    path = cache_dir_for(root, sig) if sig else root
+def enable() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return its directory.  Call before the first compile.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already holds that
+    directory and none is set here; otherwise the directory is
+    :data:`DEFAULT_CACHE_DIR`."""
     import jax
+    path = os.environ.get(ENV_CACHE_DIR, "").strip()
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
     for knob, value in _PINNED_CONFIG.items():
         jax.config.update(knob, value)
-    _enabled_dir = path
     _observe(path)
     return path
 
 
-def disable() -> None:
-    """Detach the process from the persistent cache (tests)."""
-    global _enabled_dir
-    import jax
-    jax.config.update("jax_compilation_cache_dir", None)
-    _enabled_dir = None
-
-
-def enabled_dir() -> Optional[str]:
-    """The directory :func:`enable` last activated (None = cold)."""
-    return _enabled_dir
-
-
-def stats(path: Optional[str] = None) -> dict:
-    """``{"dir", "entries", "bytes"}`` for ``path`` (default: the
-    enabled directory).  Entries are JAX ``*-cache`` files — the
-    serialized executables, not the access-time sidecars."""
-    path = path or _enabled_dir
-    if not path or not os.path.isdir(path):
+def stats(path: str) -> dict:
+    """``{"dir", "entries", "bytes"}`` for the cache at ``path``.
+    Entries are JAX ``*-cache`` files — the serialized executables, not
+    the access-time sidecars."""
+    if not os.path.isdir(path):
         return {"dir": path, "entries": 0, "bytes": 0}
     entries = n_bytes = 0
     for base, _dirs, files in os.walk(path):

@@ -60,7 +60,10 @@ swap stay pinned to the version they started on
 
 The ``NativeModelRunner`` PJRT path is available as
 ``backend="native"``: same bucketer (the ladder bounds the runner's
-per-shape executable cache), execution through the C++ PJRT client.
+per-shape executable cache), execution through the C++ PJRT client —
+a second PJRT client, so it raises at once in a process whose JAX
+backend already holds the TPU (one client per chip;
+``nativeops._require_chip_free``).
 
 Everything is instrumented through the ``monitor`` registry:
 ``serving_queue_depth``, ``serving_batch_fill_ratio``,

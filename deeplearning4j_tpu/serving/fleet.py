@@ -172,7 +172,7 @@ def build_fleet_conf(spec: str = "lstm", seed: int = 11):
     """(NeuralNetConfiguration, engine kwargs, warmup shape) for a
     named fleet spec — one deterministic recipe shared by every worker
     and by the bench's baseline, so all processes agree on the model
-    signature (and therefore on the executable-cache namespace)."""
+    signature (and compile identical, cache-shareable executables)."""
     from ..nn.conf import inputs as _inputs
     from ..nn.conf.neural_net_configuration import NeuralNetConfiguration
     from ..nn.layers.core import DenseLayer, OutputLayer
@@ -225,7 +225,8 @@ def spawn_worker(rank: int, *, model: str = "lstm",
     if store_dir:
         cmd += ["--store-dir", store_dir]
     if cache_root:
-        cmd += ["--cache-root", cache_root]
+        # the worker's resolver (compile_cache.enable) reads this
+        env[compile_cache.ENV_CACHE_DIR] = cache_root
     if slo_p99_ms:
         cmd += ["--slo-p99-ms", str(slo_p99_ms)]
     return subprocess.Popen(cmd, env=env, stdin=subprocess.PIPE,
@@ -860,7 +861,6 @@ def fleet_worker_main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--seed", type=int, default=11)
     ap.add_argument("--spawn-ts", type=float, default=None)
     ap.add_argument("--store-dir", default=None)
-    ap.add_argument("--cache-root", default=None)
     ap.add_argument("--slo-p99-ms", type=float, default=None)
     args = ap.parse_args(argv)
 
@@ -873,10 +873,10 @@ def fleet_worker_main(argv: Optional[Sequence[str]] = None) -> int:
     policy = BucketPolicy(engine_kwargs["max_batch_size"],
                           engine_kwargs["timestep_buckets"])
     # cache FIRST: every compile from here on (init, placement,
-    # bucket ladder) reads/writes the persistent namespace
-    sig = compile_cache.signature(conf, policy)
-    cache_dir = compile_cache.enable(args.cache_root, sig)
+    # bucket ladder) reads/writes the persistent cache
+    cache_dir = compile_cache.enable()
     cache_before = compile_cache.stats(cache_dir)
+    sig = compile_cache.signature(conf, policy)
 
     from ..nn.multilayer import MultiLayerNetwork
     from .engine import InferenceEngine

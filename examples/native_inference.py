@@ -2,7 +2,12 @@
 once into persistent device buffers, each request stages only the
 activations, and executables are cached per input shape
 (`nn/native_runtime.NativeModelRunner` — the cuDNN-helper/ND4J-backend
-deployment role, with zero Python/JAX dispatch on the hot path)."""
+deployment role, with zero Python/JAX dispatch on the hot path).
+
+One PJRT client per chip: on a machine with a TPU run this with
+``JAX_PLATFORMS=cpu`` so JAX authors the StableHLO on the host and the
+native client owns the chip; where JAX itself holds the TPU the runner
+refuses at once and the example says why."""
 
 import os
 import sys
@@ -36,7 +41,8 @@ def main():
         from deeplearning4j_tpu.nn.native_runtime import NativeModelRunner
         runner = NativeModelRunner(net)
     except RuntimeError as e:
-        print(f"no PJRT plugin available ({e}); skipping native serve")
+        print(f"native PJRT client not available ({e}); skipping native "
+              f"serve")
         return None
 
     with runner:

@@ -1,13 +1,11 @@
 """Pallas flash-attention kernel tests (interpret mode on the CPU mesh;
-the compiled Mosaic path is validated on the real chip by the bench/
-verify runs — BASELINE.md notes T=8192+ works where XLA full attention
-fails to compile)."""
+the compiled Mosaic path is checked on the chip by ``chip_smoke.py``'s
+``kernels`` phase)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from deeplearning4j_tpu.ops.compat import shard_map as _shard_map
 
 from deeplearning4j_tpu.ops.attention import flash_attention
 from deeplearning4j_tpu.parallel.sequence import (SequenceParallel,
@@ -116,6 +114,46 @@ def test_flash_non_tile_aligned_t_defaults():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_clamp_block_rounds_to_eight_rows_for_every_dtype():
+    """The clamp bounds a block by the sequence and rounds it UP to 8
+    rows; it does not look at the dtype.  A 16-row rule for bf16 was
+    expected to be needed and is not: Mosaic on the v5e (libtpu 0.0.34)
+    compiles (1, 8, 128) and (1, 40, 128) bf16 blocks, whole-sequence or
+    not, and computes them right — ``chip_smoke.py``'s kernels phase
+    checks T=40 and T=8 in bf16 on every chip run."""
+    from deeplearning4j_tpu.ops.attention import _auto_block, _clamp_block
+    assert _clamp_block(_auto_block(40), 40) == 40
+    assert _clamp_block(_auto_block(8), 8) == 8
+    assert _clamp_block(_auto_block(5), 5) == 8       # never below a tile
+    assert _clamp_block(_auto_block(100), 100) == 104
+    assert _clamp_block(24, 96) == 24                  # explicit, kept
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 3e-2),
+                                       (jnp.float32, 1e-4)])
+@pytest.mark.parametrize("t", [40, 8, 5])
+def test_flash_odd_short_t_forward_and_gradient(t, dtype, tol):
+    """Short odd T with default blocks, in both input widths: one block
+    covers the (padded) sequence, padded rows and keys are masked in the
+    forward and in both backward kernels."""
+    q, k, v = (x.astype(dtype) for x in _qkv(t=t, d=16, seed=t))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True).astype(jnp.float32) ** 2)
+
+    lf, gf = jax.value_and_grad(loss(flash_attention),
+                                argnums=(0, 1, 2))(q, k, v)
+    lr, gr = jax.value_and_grad(loss(_full_attention), argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_allclose(float(lf), float(lr), rtol=tol)
+    for a, b in zip(gf, gr):
+        assert a.dtype == dtype
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b), rtol=0, atol=tol * scale)
+
+
 def test_flash_rejects_bad_shapes():
     q, k, v = _qkv()
     with pytest.raises(ValueError, match="shapes differ"):
@@ -160,7 +198,7 @@ def test_ring_flash_attention_matches_full(causal):
     from deeplearning4j_tpu.parallel.sequence import ring_flash_attention
     q, k, v = _qkv(t=32, h=2, d=16)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("seq",))
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_flash_attention, axis_name="seq",
                           causal=causal, block_q=8, block_k=8),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3,
@@ -180,7 +218,7 @@ def test_ring_flash_gradients_match_full(causal):
     from deeplearning4j_tpu.parallel.sequence import ring_flash_attention
     q, k, v = _qkv(t=16, h=2, d=8)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("seq",))
-    rf = _shard_map(
+    rf = jax.shard_map(
         functools.partial(ring_flash_attention, axis_name="seq",
                           causal=causal, block_q=8, block_k=8),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3,

@@ -194,27 +194,61 @@ def test_signature_deterministic_and_policy_sensitive():
                                 okw["timestep_buckets"]))
 
 
-def test_compile_cache_enable_disable_and_stats(tmp_path):
-    root = str(tmp_path / "cache")
-    try:
-        d = compile_cache.enable(root, "abc123")
-        assert d == compile_cache.cache_dir_for(root, "abc123")
-        assert os.path.isdir(d)
-        assert compile_cache.enabled_dir() == d
-        s = compile_cache.stats(d)
-        assert s["entries"] == 0 and s["bytes"] == 0
-        (tmp_path / "cache" / "sig-abc123" / "entry").write_bytes(
-            b"x" * 10)
-        s = compile_cache.stats(d)
-        assert s["entries"] == 1 and s["bytes"] == 10
-    finally:
-        compile_cache.disable()
-    assert compile_cache.enabled_dir() is None
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Record ``jax.config.update`` calls instead of applying them: the
+    resolver's decisions are process-global and must not leak into the
+    rest of the suite."""
+    import jax
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda knob, value: calls.__setitem__(knob, value))
+    return calls
 
 
-def test_compile_cache_enable_unset_env_is_noop(monkeypatch):
-    monkeypatch.delenv(compile_cache.ENV_CACHE_DIR, raising=False)
-    assert compile_cache.enable(None, "sig") is None
+def test_compile_cache_placed_from_outside_sets_no_directory(
+        tmp_path, monkeypatch, config_updates):
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    assert compile_cache.enable() == placed
+    assert os.path.isdir(placed)
+    assert "jax_compilation_cache_dir" not in config_updates
+    # the knobs that feed JAX's entry key are pinned either way
+    assert config_updates == compile_cache._PINNED_CONFIG
+
+
+def test_compile_cache_unset_env_is_fixed_path_in_checkout(
+        monkeypatch, config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.enable() == os.path.join(repo,
+                                                  ".jax_compile_cache")
+    assert config_updates["jax_compilation_cache_dir"] == \
+        compile_cache.DEFAULT_CACHE_DIR == compile_cache.enable()
+
+
+def test_compile_cache_stats_counts_entries_not_atime_sidecars(tmp_path):
+    assert compile_cache.stats(str(tmp_path / "absent"))["entries"] == 0
+    (tmp_path / "k-cache").write_bytes(b"x" * 10)
+    (tmp_path / "k-atime").write_bytes(b"y" * 4)
+    s = compile_cache.stats(str(tmp_path))
+    assert s["entries"] == 1 and s["bytes"] == 10
+
+
+def test_spawn_worker_places_cache_through_the_environment(monkeypatch):
+    """``cache_root`` reaches the worker as JAX_COMPILATION_CACHE_DIR —
+    the variable its resolver honours — not as a flag."""
+    from deeplearning4j_tpu.serving import fleet
+    seen = {}
+
+    def fake_popen(cmd, env, **kw):
+        seen["cmd"], seen["env"] = cmd, env
+        return object()
+
+    monkeypatch.setattr(fleet.subprocess, "Popen", fake_popen)
+    fleet.spawn_worker(0, model="mlp", cache_root="/some/cache")
+    assert seen["env"]["JAX_COMPILATION_CACHE_DIR"] == "/some/cache"
+    assert "--cache-root" not in seen["cmd"]
 
 
 # ---- fleet model spec ----------------------------------------------------

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.ops.compat import shard_map as _shard_map
 
 from deeplearning4j_tpu.nn import activations as _act
 from deeplearning4j_tpu.nn.layers.recurrent import lstm_scan
@@ -45,7 +44,7 @@ def test_ring_attention_odd_shard_counts():
     """Ring correctness must not depend on power-of-two shard counts."""
     q, k, v = _qkv(t=30)
     mesh = _mesh(3)
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_attention, axis_name="seq", causal=True),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq")))
     np.testing.assert_allclose(
@@ -62,7 +61,7 @@ def test_ring_attention_gradients_match_full():
     mesh = _mesh(4)
     spec = (P(None, "seq"),) * 3
 
-    ring = _shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="seq", causal=True),
         mesh=mesh, in_specs=spec, out_specs=P(None, "seq"))
 
@@ -113,7 +112,7 @@ def test_ring_lstm_scan_matches_serial():
                                    gate_fn=gate)
 
     mesh = _mesh(4)
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_lstm_scan, afn=afn, gate_fn=gate,
                           axis_name="seq"),
         mesh=mesh,
@@ -140,7 +139,7 @@ def test_ring_lstm_scan_mixed_precision():
     afn, gate = _act.get("tanh"), _act.get("sigmoid")
 
     mesh = _mesh(4)
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_lstm_scan, afn=afn, gate_fn=gate,
                           axis_name="seq"),
         mesh=mesh,
@@ -176,7 +175,7 @@ def test_ring_lstm_scan_masked():
     ref_out, ref_final = lstm_scan(W, RW, bias, x, carry, afn=afn,
                                    gate_fn=gate, mask=mask)
     mesh = _mesh(4)
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(ring_lstm_scan, afn=afn, gate_fn=gate,
                           axis_name="seq"),
         mesh=mesh,
@@ -202,7 +201,7 @@ def test_ring_lstm_grads_match_serial():
     afn, gate = _act.get("tanh"), _act.get("sigmoid")
 
     mesh = _mesh(4)
-    sp_scan = _shard_map(
+    sp_scan = jax.shard_map(
         functools.partial(ring_lstm_scan, afn=afn, gate_fn=gate,
                           axis_name="seq"),
         mesh=mesh,

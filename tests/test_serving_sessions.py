@@ -111,7 +111,7 @@ def test_session_request_is_exactly_one_dispatch():
 def test_full_sequence_baseline_dispatch_grows_with_history():
     """The naive alternative the cache replaces: re-running output() over
     the growing history costs one FULL-sequence dispatch per request and
-    O(T) device work — the sweep in BASELINE.md quantifies the collapse."""
+    O(T) device work — bench.py --serve sweeps the collapse."""
     model = _rnn_model(seed=17)
     rng = np.random.RandomState(4)
     history = []

@@ -235,7 +235,8 @@ def compiled_step(config: str):
 
 
 # The recorded fp32 LeNet row this campaign is measured against
-# (BENCH_r05.json batch-256 hbm_bytes_per_step; ISSUE 7 acceptance).
+# (round-5 record, deleted in PR 21: batch-256 hbm_bytes_per_step;
+# ISSUE 7 acceptance).
 BENCH_R05_LENET_BYTES = 117_648_384
 
 # configs whose step comes from a network (fp32 twin is comparable)
