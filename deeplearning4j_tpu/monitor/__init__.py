@@ -17,6 +17,10 @@ listener overhead).  This package is the runtime-side answer, three pillars:
   every step-cache call site; counts compiles vs cache hits, times
   compiles, and records the abstract-shape signature that triggered each
   recompile so shape churn is diagnosable.
+- :mod:`.device_trace` — the scope grammar of the step programs
+  (``scope("layer", name)``) and the way back: ``device_trace(log_dir)``
+  profiles a region and reduces the trace to device seconds by scope and
+  pass, with the device's idle time split among the spans above.
 
 Export paths: ``ui/server.py`` serves ``GET /metrics`` (Prometheus text)
 and ``GET /trace`` (Chrome-event JSONL) straight from the globals here, and
@@ -45,6 +49,7 @@ from .alerts import (AlertEngine, Rule, default_rules,
                      status as alert_status)
 from . import attribution
 from .attribution import StepAttributor, breakdown as wall_breakdown
+from .device_trace import DeviceTrace, device_trace, parse_op_name, scope
 from .jit_watch import WatchedJit, publish_cost_analysis, watched_jit
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, registry)
 from .tracing import (TraceContext, Tracer, attach, current_context,
@@ -52,20 +57,20 @@ from .tracing import (TraceContext, Tracer, attach, current_context,
                       parse_traceparent, span, tracer)
 
 __all__ = [
-    "AlertEngine", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Rule", "StepAttributor", "TraceContext", "Tracer",
+    "AlertEngine", "Counter", "DeviceTrace", "Gauge", "Histogram",
+    "MetricsRegistry", "Rule", "StepAttributor", "TraceContext", "Tracer",
     "TrainingDivergedError", "WatchedJit", "alert_status", "alerts",
     "attach", "attribution", "counter", "current_context",
-    "current_trace_hex", "default_rules", "detach", "disable_health",
-    "enable_health",
-    "flight_recorder", "gauge", "health", "health_enabled",
-    "health_snapshot", "histogram", "incident_dir", "new_trace_id",
-    "observe_phase", "parse_traceparent", "phase_breakdown",
-    "post_system_metrics", "prometheus_text", "publish_cost_analysis",
-    "record_incident", "registry", "reset", "sanitize_end_warmup",
-    "sanitize_scenario", "snapshot", "span",
-    "system_metrics_persistable", "trace_chrome_json", "trace_jsonl",
-    "tracer", "wall_breakdown", "watched_jit",
+    "current_trace_hex", "default_rules", "detach", "device_trace",
+    "disable_health", "enable_health", "flight_recorder", "gauge",
+    "health", "health_enabled", "health_snapshot", "histogram",
+    "incident_dir", "new_trace_id", "observe_phase", "parse_op_name",
+    "parse_traceparent", "phase_breakdown", "post_system_metrics",
+    "prometheus_text", "publish_cost_analysis", "record_incident",
+    "registry", "reset", "sanitize_end_warmup", "sanitize_scenario",
+    "scope", "snapshot", "span", "system_metrics_persistable",
+    "trace_chrome_json", "trace_jsonl", "tracer", "wall_breakdown",
+    "watched_jit",
 ]
 
 

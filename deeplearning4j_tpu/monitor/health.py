@@ -217,26 +217,29 @@ def layer_stats(old_params, new_params, grads, loss,
     """
     import jax
     import jax.numpy as jnp
+    from .device_trace import scope
     cfg = config()
     keys = list(order) if order is not None else list(range(len(grads)))
     g_norms, p_norms, ratios = [], [], []
-    finite = jnp.isfinite(jnp.asarray(loss, jnp.float32))
-    explode = jnp.asarray(False)
-    limit = jnp.float32(cfg.grad_norm_limit)
-    for k in keys:
-        g = _l2(grads[k])
-        p = _l2(old_params[k])
-        u = _l2(jax.tree.map(
-            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            old_params[k], new_params[k]))
-        g_norms.append(g)
-        p_norms.append(p)
-        ratios.append(u / (p + _EPS))
-        finite = finite & jnp.isfinite(g) & jnp.isfinite(u)
-        explode = explode | (g > limit)
-    bad = (~finite) | explode
-    vec = jnp.stack([jnp.asarray(loss, jnp.float32),
-                     bad.astype(jnp.float32)] + g_norms + p_norms + ratios)
+    with scope("health"):
+        finite = jnp.isfinite(jnp.asarray(loss, jnp.float32))
+        explode = jnp.asarray(False)
+        limit = jnp.float32(cfg.grad_norm_limit)
+        for k in keys:
+            g = _l2(grads[k])
+            p = _l2(old_params[k])
+            u = _l2(jax.tree.map(
+                lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+                old_params[k], new_params[k]))
+            g_norms.append(g)
+            p_norms.append(p)
+            ratios.append(u / (p + _EPS))
+            finite = finite & jnp.isfinite(g) & jnp.isfinite(u)
+            explode = explode | (g > limit)
+        bad = (~finite) | explode
+        vec = jnp.stack([jnp.asarray(loss, jnp.float32),
+                         bad.astype(jnp.float32)]
+                        + g_norms + p_norms + ratios)
     return vec, bad
 
 
@@ -251,7 +254,9 @@ def guard_select(bad, new, old):
         return new
     import jax
     import jax.numpy as jnp
-    return jax.tree.map(lambda n, o: jnp.where(bad, o, n), new, old)
+    from .device_trace import scope
+    with scope("health"):
+        return jax.tree.map(lambda n, o: jnp.where(bad, o, n), new, old)
 
 
 # ------------------------------------------------------------- host side

@@ -18,6 +18,17 @@ compile from a cache hit *before* dispatching:
   the exact abstract shape that triggered the retrace, so the trace dump
   answers *why* it recompiled.
 
+Where set-up time goes is counted from JAX's own compile events
+(``jax.monitoring``, one listener registered at import): seconds spent
+tracing, lowering to MLIR and in the backend (compiling, or loading
+from the persistent cache) add into
+``jit_trace_seconds_total{fn}`` / ``jit_lower_seconds_total{fn}`` /
+``jit_backend_seconds_total{fn}``, ``fn`` being the watched jit whose
+first call is on the stack (``<name>/cost_analysis`` for the extra
+lowering behind the cost gauges, ``unwatched`` for everything else,
+such as the small programs of ``net.init()``).  They grow on compiles
+only, never on a cached dispatch.
+
 Python scalars are weak-typed under jit — a value change does **not**
 retrace — so they hash as ``int[]``/``float[]``/``bool[]`` rather than
 by value.  ``static_argnums`` values **do** retrace, so they hash by
@@ -29,10 +40,13 @@ are separate.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
 from typing import Any, Callable, Optional, Sequence, Set, Tuple
 
 import jax
+import jax.monitoring
 
 from .metrics import registry
 from .tracing import tracer
@@ -43,6 +57,10 @@ COMPILE_MS = "jit_compile_ms"
 XLA_FLOPS = "xla_cost_flops"
 XLA_BYTES = "xla_cost_bytes_accessed"
 XLA_PEAK_HBM = "xla_cost_peak_hbm_bytes"
+TRACE_SECONDS = "jit_trace_seconds_total"
+LOWER_SECONDS = "jit_lower_seconds_total"
+BACKEND_SECONDS = "jit_backend_seconds_total"
+UNWATCHED = "unwatched"
 
 _SAN = None
 _SAN_TRIED = False
@@ -76,7 +94,70 @@ _HELP = {
                "executable's most recent compile",
     XLA_PEAK_HBM: "compiler memory_analysis peak HBM (args + outputs + "
                   "temps - aliased) of the most recent AOT compile",
+    TRACE_SECONDS: "seconds tracing Python to jaxprs, by the watched jit "
+                   "that was compiling",
+    LOWER_SECONDS: "seconds lowering jaxprs to MLIR modules, by the "
+                   "watched jit that was compiling",
+    BACKEND_SECONDS: "seconds in the backend (compile, or the load from "
+                     "the persistent cache), by the watched jit that was "
+                     "compiling",
 }
+
+# ------------------------------------------------------- set-up counters
+_PRUNE_EVERY = 4096
+_HORIZON_S = 3600.0         # no single trace or compile lasts an hour
+_compiling = threading.local()
+
+
+@contextlib.contextmanager
+def _compiling_as(name: str):
+    """Charge this thread's compile events to ``name`` meanwhile."""
+    names = _compiling.__dict__.setdefault("names", [])
+    names.append(name)
+    try:
+        yield
+    finally:
+        names.pop()
+
+
+def _on_compile_duration(event: str, duration: float, **_) -> None:
+    """Add one of JAX's compile events into its counter.  A jit called
+    inside a trace fires its own event inside the outer one, and a
+    listener sees only ``(event, duration)`` at the end: so each event
+    adds the part of ``[now - duration, now]`` that earlier (inner)
+    events of its kind on this thread have not counted, and the seconds
+    add up to wall time."""
+    reg = registry()
+    # one call per counter, by constant: tools/analyze reads the
+    # registrations from the source
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        counter = reg.counter(TRACE_SECONDS, _HELP[TRACE_SECONDS])
+    elif event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        counter = reg.counter(LOWER_SECONDS, _HELP[LOWER_SECONDS])
+    elif event == "/jax/core/compile/backend_compile_duration":
+        counter = reg.counter(BACKEND_SECONDS, _HELP[BACKEND_SECONDS])
+    else:
+        return
+    start = time.perf_counter() - duration
+    # (start, seconds) of what this thread has counted for this event
+    # and no enclosing event has absorbed yet
+    counted = _compiling.__dict__.setdefault("counted", {}).setdefault(
+        event, [])
+    inner = 0.0
+    while counted and counted[-1][0] >= start:
+        inner += counted.pop()[1]
+    counted.append((start, duration))
+    if len(counted) % _PRUNE_EVERY == 0:
+        # siblings wait here for their parent (7,500 jnp calls inside
+        # ResNet-50's one trace); top-level events would wait for ever,
+        # so now and then forget what no open event can still enclose
+        counted[:] = [c for c in counted if c[0] >= start - _HORIZON_S]
+    names = _compiling.__dict__.get("names")
+    counter.inc(max(0.0, duration - inner),
+                fn=names[-1] if names else UNWATCHED)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
 
 
 def publish_cost_analysis(name: str, obj: Any) -> None:
@@ -204,7 +285,8 @@ class _LoweredProxy:
         reg = registry()
         t0 = time.perf_counter()
         with tracer().span(f"jit/compile/{self._name}", mode="aot",
-                           signature=self._signature):
+                           signature=self._signature), \
+                _compiling_as(self._name):
             compiled = self._lowered.compile(*args, **kwargs)
         elapsed = time.perf_counter() - t0
         reg.counter(COMPILES_TOTAL, _HELP[COMPILES_TOTAL]).inc(
@@ -282,13 +364,15 @@ class WatchedJit:
             # without compiling or consuming donated buffers, and one
             # extra trace per WatchedJit bounds the overhead.
             try:
-                publish_cost_analysis(
-                    self.name, self._jitted.lower(*args, **kwargs))
+                with _compiling_as(f"{self.name}/cost_analysis"):
+                    lowered = self._jitted.lower(*args, **kwargs)
+                publish_cost_analysis(self.name, lowered)
             except Exception:
                 pass
         t0 = time.perf_counter()
         with tracer().span(f"jit/compile/{self.name}",
-                           signature=signature, recompile=recompile):
+                           signature=signature, recompile=recompile), \
+                _compiling_as(self.name):
             out = self._dispatch(args, kwargs, san)
         elapsed = time.perf_counter() - t0
         reg.counter(COMPILES_TOTAL, _HELP[COMPILES_TOTAL]).inc(fn=self.name)
@@ -298,8 +382,9 @@ class WatchedJit:
 
     def lower(self, *args, **kwargs) -> _LoweredProxy:
         signature = abstract_signature(args, kwargs, self._static_argnums)
-        return _LoweredProxy(self._jitted.lower(*args, **kwargs),
-                             self.name, signature)
+        with _compiling_as(self.name):
+            lowered = self._jitted.lower(*args, **kwargs)
+        return _LoweredProxy(lowered, self.name, signature)
 
     @property
     def compile_count(self) -> int:

@@ -146,14 +146,15 @@ class ComputationGraph:
         acts: Dict[str, Array] = {}
         pol = self._pol()
         compute_dtype = jnp.dtype(pol.compute_dtype)
-        for name, x in zip(conf.network_inputs, inputs):
-            if jnp.issubdtype(x.dtype, jnp.floating):
-                x = x.astype(compute_dtype)
-            acts[name] = x
-        if compute_dtype != jnp.dtype(pol.param_dtype):
-            params = jax.tree.map(
-                lambda p: p.astype(compute_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        with _monitor.scope("precision", "cast"):
+            for name, x in zip(conf.network_inputs, inputs):
+                if jnp.issubdtype(x.dtype, jnp.floating):
+                    x = x.astype(compute_dtype)
+                acts[name] = x
+            if compute_dtype != jnp.dtype(pol.param_dtype):
+                params = jax.tree.map(
+                    lambda p: p.astype(compute_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
         new_state = dict(net_state)
         layer_names = self._layer_names()
         keys = (jax.random.split(rng, max(len(layer_names), 1))
@@ -166,62 +167,64 @@ class ComputationGraph:
 
         for name in self.topo:
             v = self.vertices[name]
-            xs = [acts[i] for i in v.inputs]
-            in_masks = [masks.get(i) for i in v.inputs]
-            mask = next((m for m in in_masks if m is not None), None)
-            if isinstance(v, LayerVertex):
-                x = xs[0]
-                if v.preprocessor is not None:
-                    x = v.preprocessor(x)
-                layer = v.layer
-                if preoutput_outputs and name in conf.network_outputs \
-                        and hasattr(layer, "pre_output"):
-                    if layer.dropout and train:
+            with _monitor.scope("layer", name):
+                xs = [acts[i] for i in v.inputs]
+                in_masks = [masks.get(i) for i in v.inputs]
+                mask = next((m for m in in_masks if m is not None), None)
+                if isinstance(v, LayerVertex):
+                    x = xs[0]
+                    if v.preprocessor is not None:
+                        x = v.preprocessor(x)
+                    layer = v.layer
+                    if preoutput_outputs and name in conf.network_outputs \
+                            and hasattr(layer, "pre_output"):
+                        if layer.dropout and train:
+                            x = layer.apply_dropout(x, train, key_of[name])
+                        out = layer.pre_output(params[name], x)
+                    elif (pol.downcasts_output and name in conf.network_outputs
+                          and hasattr(layer, "pre_output")
+                          and hasattr(layer, "_activate")):
+                        # fp32 logits contract, head half: output-head logits
+                        # are cast fp32 BEFORE softmax/sigmoid so serving
+                        # probabilities are fp32-exact, not bf16-rounded.
+                        # Applies even when the vertex is in ``carries``
+                        # (rnn_step / decode_step): the only recurrent head
+                        # with pre_output is RnnOutputLayer, whose carry is
+                        # () — forward_seq would be the same math minus the
+                        # fp32 cast, and skipping it must not change the
+                        # carry.  Without this, N single-token decode calls
+                        # drift from output() under mixed_bf16.
                         x = layer.apply_dropout(x, train, key_of[name])
-                    out = layer.pre_output(params[name], x)
-                elif (pol.downcasts_output and name in conf.network_outputs
-                      and hasattr(layer, "pre_output")
-                      and hasattr(layer, "_activate")):
-                    # fp32 logits contract, head half: output-head logits
-                    # are cast fp32 BEFORE softmax/sigmoid so serving
-                    # probabilities are fp32-exact, not bf16-rounded.
-                    # Applies even when the vertex is in ``carries``
-                    # (rnn_step / decode_step): the only recurrent head
-                    # with pre_output is RnnOutputLayer, whose carry is
-                    # () — forward_seq would be the same math minus the
-                    # fp32 cast, and skipping it must not change the
-                    # carry.  Without this, N single-token decode calls
-                    # drift from output() under mixed_bf16.
-                    x = layer.apply_dropout(x, train, key_of[name])
-                    out = layer._activate(
-                        layer.pre_output(params[name], x)
-                        .astype(jnp.float32))
-                elif carries is not None and name in carries:
-                    out, new_carries[name] = layer.forward_seq(
-                        params[name], x, carries[name], train=train,
-                        rng=key_of[name], mask=mask)
+                        out = layer._activate(
+                            layer.pre_output(params[name], x)
+                            .astype(jnp.float32))
+                    elif carries is not None and name in carries:
+                        out, new_carries[name] = layer.forward_seq(
+                            params[name], x, carries[name], train=train,
+                            rng=key_of[name], mask=mask)
+                    else:
+                        out, new_state[name] = layer.forward(
+                            params[name], net_state[name], x, train=train,
+                            rng=key_of[name], mask=mask)
+                    acts[name] = out
+                    masks[name] = mask
+                elif isinstance(v, DuplicateToTimeSeriesVertex):
+                    ref = v.reference_input
+                    acts[name] = v.apply(*xs, masks=masks,
+                                         timesteps=acts[ref].shape[1])
+                    masks[name] = masks.get(ref)
+                elif isinstance(v, LastTimeStepVertex):
+                    acts[name] = v.apply(*xs, masks=masks)
+                    masks[name] = None
                 else:
-                    out, new_state[name] = layer.forward(
-                        params[name], net_state[name], x, train=train,
-                        rng=key_of[name], mask=mask)
-                acts[name] = out
-                masks[name] = mask
-            elif isinstance(v, DuplicateToTimeSeriesVertex):
-                ref = v.reference_input
-                acts[name] = v.apply(*xs, masks=masks,
-                                     timesteps=acts[ref].shape[1])
-                masks[name] = masks.get(ref)
-            elif isinstance(v, LastTimeStepVertex):
-                acts[name] = v.apply(*xs, masks=masks)
-                masks[name] = None
-            else:
-                acts[name] = v.apply(*xs, masks=masks)
-                masks[name] = mask
+                    acts[name] = v.apply(*xs, masks=masks)
+                    masks[name] = mask
         if pol.downcasts_output:
             # fp32 logits contract: loss/softmax/metrics accumulation and
             # serving all consume fp32 even under bf16 storage.
-            for out in conf.network_outputs:
-                acts[out] = acts[out].astype(jnp.float32)
+            with _monitor.scope("precision", "cast"):
+                for out in conf.network_outputs:
+                    acts[out] = acts[out].astype(jnp.float32)
         return acts, new_state, new_carries
 
     # ------------------------------------------------------------------ loss
@@ -242,45 +245,48 @@ class ComputationGraph:
             carries=carries)
         total = (jnp.zeros((features[0].shape[0],), jnp.float32)
                  if per_example else jnp.asarray(0.0, jnp.float32))
-        for i, out_name in enumerate(self.conf.network_outputs):
-            v = self.vertices[out_name]
-            layer = v.layer
-            lmask = None if labels_masks is None else labels_masks[i]
-            if getattr(layer, "NEEDS_INPUT_FOR_SCORE", False):
-                # Center-loss-style heads score against their input
-                # activations; those are already in the DAG's acts.
-                x = acts[v.inputs[0]]
-                if v.preprocessor is not None:
-                    x = v.preprocessor(x)
-                if layer.dropout and train and rng is not None:
-                    x = layer.apply_dropout(
-                        x, train, jax.random.fold_in(rng, 100_000 + i))
+        with _monitor.scope("loss"):
+            for i, out_name in enumerate(self.conf.network_outputs):
+                v = self.vertices[out_name]
+                layer = v.layer
+                lmask = None if labels_masks is None else labels_masks[i]
+                if getattr(layer, "NEEDS_INPUT_FOR_SCORE", False):
+                    # Center-loss-style heads score against their input
+                    # activations; those are already in the DAG's acts.
+                    x = acts[v.inputs[0]]
+                    if v.preprocessor is not None:
+                        x = v.preprocessor(x)
+                    if layer.dropout and train and rng is not None:
+                        x = layer.apply_dropout(
+                            x, train, jax.random.fold_in(rng, 100_000 + i))
+                    if per_example:
+                        total = total + \
+                            layer.compute_score_examples_with_input(
+                                params[out_name], labels[i], x, lmask)
+                    else:
+                        total = total + layer.compute_score_with_input(
+                            params[out_name], labels[i], x, lmask,
+                            average=self.conf.conf.mini_batch)
+                    continue
+                if not hasattr(layer, "compute_score"):
+                    raise ValueError(
+                        f"Output vertex '{out_name}' is not an output layer")
                 if per_example:
-                    total = total + layer.compute_score_examples_with_input(
-                        params[out_name], labels[i], x, lmask)
+                    total = total + layer.compute_score_examples(
+                        labels[i], acts[out_name], lmask)
                 else:
-                    total = total + layer.compute_score_with_input(
-                        params[out_name], labels[i], x, lmask,
+                    total = total + layer.compute_score(
+                        labels[i], acts[out_name], lmask,
                         average=self.conf.conf.mini_batch)
-                continue
-            if not hasattr(layer, "compute_score"):
-                raise ValueError(
-                    f"Output vertex '{out_name}' is not an output layer")
-            if per_example:
-                total = total + layer.compute_score_examples(
-                    labels[i], acts[out_name], lmask)
-            else:
-                total = total + layer.compute_score(
-                    labels[i], acts[out_name], lmask,
-                    average=self.conf.conf.mini_batch)
         return total, (new_state, new_carries)
 
     def _reg_score(self, params) -> Array:
         total = jnp.asarray(0.0, jnp.float32)
-        for name in self._layer_names():
-            layer = self.vertices[name].layer
-            total = total + _updaters.regularization_score(
-                params[name], layer.l1_by_param(), layer.l2_by_param())
+        with _monitor.scope("reg"):
+            for name in self._layer_names():
+                layer = self.vertices[name].layer
+                total = total + _updaters.regularization_score(
+                    params[name], layer.l1_by_param(), layer.l2_by_param())
         return total
 
     # ------------------------------------------------------------ train step
@@ -290,10 +296,11 @@ class ComputationGraph:
             layer = self.vertices[name].layer
             g = grads[name]
             if g:
-                new_params[name], new_ustate[name] = \
-                    _updaters.apply_layer_updates(
-                        self._updater_conf(name), layer, params[name],
-                        updater_state[name], g, iteration)
+                with _monitor.scope("update", name):
+                    new_params[name], new_ustate[name] = \
+                        _updaters.apply_layer_updates(
+                            self._updater_conf(name), layer, params[name],
+                            updater_state[name], g, iteration)
             else:
                 new_params[name] = params[name]
                 new_ustate[name] = updater_state[name]
@@ -440,9 +447,11 @@ class ComputationGraph:
 
             def body(carry, idx_row):
                 p, u, s, it = carry
-                f = [ingest.device_decode(jnp.take(d, idx_row, axis=0), w)
-                     for d, w in zip(data_fs, wires)]
-                l = [jnp.take(d, idx_row, axis=0) for d in data_ls]
+                with _monitor.scope("ingest", "gather"):
+                    f = [ingest.device_decode(
+                             jnp.take(d, idx_row, axis=0), w)
+                         for d, w in zip(data_fs, wires)]
+                    l = [jnp.take(d, idx_row, axis=0) for d in data_ls]
                 rng = jax.random.fold_in(base_rng, it)
                 (data_loss, (new_s, _)), grads = jax.value_and_grad(
                     self._loss_fn, has_aux=True)(
@@ -1369,7 +1378,10 @@ class ComputationGraph:
 
     def score(self, data=None) -> float:
         if data is None:
-            return float(self._score)
+            from . import ingest
+            # fetched once: the next call finds a host value
+            self._score = float(ingest.fetch_scores(self._score))
+            return self._score
         self.init()
         mds = _as_multi(data)
         fmasks = (None if mds.features_masks is None else tuple(

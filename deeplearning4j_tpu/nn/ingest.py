@@ -44,6 +44,7 @@ import os
 import time
 from typing import List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from .. import monitor as _monitor
@@ -346,6 +347,17 @@ def cast_for_transfer(features: np.ndarray, compute_dtype) -> np.ndarray:
     return features.astype(ml_dtypes.bfloat16)
 
 
+def fetch_scores(scores) -> np.ndarray:
+    """Device scores on the host.  For a device array this is where the
+    host waits for the device (the dispatch that produced it may still
+    be running), so the wait is a ``fit/score_wait`` span; a value that
+    is already on the host is just converted."""
+    if not isinstance(scores, jax.Array):
+        return np.asarray(scores)
+    with _monitor.span("fit/score_wait"):
+        return np.asarray(scores)
+
+
 class ScoreReplayer:
     """Collects (start_iteration, device scores) per dispatch and
     replays listeners with per-step scores.  Fetching a dispatch's
@@ -364,7 +376,7 @@ class ScoreReplayer:
         step (exact per-iteration score; params are end-of-dispatch)."""
         model = self._model
         for start, dev_scores in self._pending:
-            scores = np.asarray(dev_scores)
+            scores = fetch_scores(dev_scores)
             for j, s in enumerate(scores):
                 model._score = s
                 for listener in model.listeners:
@@ -430,6 +442,11 @@ def run_device_cached_fit(model, u, epochs: int, dispatch, *,
             replay.replay()  # flush scores; listeners never trail a save
             ckpt.save(model, step_in_epoch=step_in_epoch)
 
+    # ``fit/dispatch`` is the host's cost of one launch: signature hash,
+    # jit lookup, argument handling, enqueue.  Opened in this frame, not
+    # in a wrapper around ``dispatch``: the first launch traces and
+    # lowers the step under it, and one more Python frame there moved
+    # that by over a second (PERF.md section 6, PR 24).
     done = 0
     while done < epochs:
         fuse = 1
@@ -443,8 +460,9 @@ def run_device_cached_fit(model, u, epochs: int, dispatch, *,
                     if hasattr(listener, "on_epoch_start"):
                         listener.on_epoch_start(model)
             t0 = time.perf_counter()
-            for _ in range(fuse):
-                consume_epoch(u)
+            with _monitor.span("fit/stage"):
+                for _ in range(fuse):
+                    consume_epoch(u)
             _monitor.observe_phase("data", time.perf_counter() - t0)
             t1 = time.perf_counter()
             chunked = bool(steps and (pos or step_cadence is not None))
@@ -465,7 +483,9 @@ def run_device_cached_fit(model, u, epochs: int, dispatch, *,
                         run = steps - pos
                         if step_cadence is not None:
                             run = min(run, ckpt.steps_to_next_save())
-                        scores = dispatch(model.epoch, 1, 0, pos, run)
+                        with _monitor.span("fit/dispatch", steps=run,
+                                           fused=1):
+                            scores = dispatch(model.epoch, 1, 0, pos, run)
                         replay.add(model.iteration, scores)
                         iters.inc(run)
                         model.iteration += run
@@ -477,7 +497,9 @@ def run_device_cached_fit(model, u, epochs: int, dispatch, *,
                             maybe_save(pos)
                             _faults.maybe_die(model.iteration)
                 elif steps:
-                    scores = dispatch(model.epoch, fuse, 0, 0, steps)
+                    with _monitor.span("fit/dispatch", steps=fuse * steps,
+                                       fused=fuse):
+                        scores = dispatch(model.epoch, fuse, 0, 0, steps)
                     replay.add(model.iteration, scores)
                     iters.inc(fuse * steps)
                     model.iteration += fuse * steps
@@ -485,7 +507,8 @@ def run_device_cached_fit(model, u, epochs: int, dispatch, *,
                     if ckpt is not None:
                         ckpt.note_steps(fuse * steps)
                 if tail:
-                    scores = dispatch(model.epoch, 1, tail, 0, 0)
+                    with _monitor.span("fit/dispatch", steps=1, fused=1):
+                        scores = dispatch(model.epoch, 1, tail, 0, 0)
                     replay.add(model.iteration, scores)
                     iters.inc(1)
                     model.iteration += 1

@@ -109,6 +109,11 @@ class MultiLayerNetwork:
     def _updater_conf(self, i: int) -> _updaters.UpdaterConfig:
         return self.layers[i].updater or self.conf.conf.updater
 
+    def _scope_name(self, i: int) -> str:
+        """Layer ``i`` in the step program's scopes (``layer.<name>``,
+        ``update.<name>``; ``monitor/device_trace.py``)."""
+        return f"{i}_{type(self.layers[i]).__name__}"
+
     # --------------------------------------------------------------- forward
     def _forward(self, params, net_state, x, *, train: bool,
                  rng: Optional[jax.Array], mask=None, carries=None,
@@ -133,55 +138,61 @@ class MultiLayerNetwork:
         keys = (jax.random.split(rng, n) if rng is not None else [None] * n)
         pol = self._pol()
         compute_dtype = jnp.dtype(pol.compute_dtype)
-        if jnp.issubdtype(x.dtype, jnp.floating):
-            # Cast inputs to the policy compute dtype (bfloat16 for
-            # MXU-friendly matmuls under the TPU default); integer inputs
-            # (embedding indices) pass through.
-            x = x.astype(compute_dtype)
-        if compute_dtype != jnp.dtype(pol.param_dtype):
-            # Mixed compute: storage params stay in the param dtype; compute
-            # sees a bfloat16 copy (XLA fuses the casts into the matmul/conv).
-            params = jax.tree.map(
-                lambda p: p.astype(compute_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        with _monitor.scope("precision", "cast"):
+            if jnp.issubdtype(x.dtype, jnp.floating):
+                # Cast inputs to the policy compute dtype (bfloat16 for
+                # MXU-friendly matmuls under the TPU default); integer
+                # inputs (embedding indices) pass through.
+                x = x.astype(compute_dtype)
+            if compute_dtype != jnp.dtype(pol.param_dtype):
+                # Mixed compute: storage params stay in the param dtype;
+                # compute sees a bfloat16 copy (XLA fuses the casts into
+                # the matmul/conv).
+                params = jax.tree.map(
+                    lambda p: p.astype(compute_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
         for i in range(from_layer, n):
             layer = self.layers[i]
-            if i in self.conf.input_preprocessors:
-                x = self.conf.input_preprocessors[i](x)
-            if preoutput_last and i == n - 1 and hasattr(layer, "pre_output"):
-                if layer.dropout and train:
+            with _monitor.scope("layer", self._scope_name(i)):
+                if i in self.conf.input_preprocessors:
+                    x = self.conf.input_preprocessors[i](x)
+                if preoutput_last and i == n - 1 \
+                        and hasattr(layer, "pre_output"):
+                    if layer.dropout and train:
+                        x = layer.apply_dropout(x, train, keys[i])
+                    x = layer.pre_output(params[i], x)
+                elif (pol.downcasts_output and i == len(self.layers) - 1
+                      and hasattr(layer, "pre_output")
+                      and hasattr(layer, "_activate")):
+                    # fp32 logits contract, head half: the output head's
+                    # logits are cast to fp32 BEFORE the softmax/sigmoid
+                    # so serving probabilities are fp32-exact, not
+                    # bf16-rounded (bf16 softmax row sums wobble at the
+                    # 1e-3 level).  Checked BEFORE the carries branch: a
+                    # carried step (rnn_step / decode_step) must honor
+                    # the same contract or N single-token calls drift
+                    # from output() under mixed precision.  The only
+                    # recurrent head with pre_output is RnnOutputLayer,
+                    # whose carry is () — so skipping forward_seq leaves
+                    # new_carries[i] correct.
                     x = layer.apply_dropout(x, train, keys[i])
-                x = layer.pre_output(params[i], x)
-            elif (pol.downcasts_output and i == len(self.layers) - 1
-                  and hasattr(layer, "pre_output")
-                  and hasattr(layer, "_activate")):
-                # fp32 logits contract, head half: the output head's
-                # logits are cast to fp32 BEFORE the softmax/sigmoid so
-                # serving probabilities are fp32-exact, not bf16-rounded
-                # (bf16 softmax row sums wobble at the 1e-3 level).
-                # Checked BEFORE the carries branch: a carried step
-                # (rnn_step / decode_step) must honor the same contract
-                # or N single-token calls drift from output() under
-                # mixed precision.  The only recurrent head with
-                # pre_output is RnnOutputLayer, whose carry is () — so
-                # skipping forward_seq leaves new_carries[i] correct.
-                x = layer.apply_dropout(x, train, keys[i])
-                x = layer._activate(
-                    layer.pre_output(params[i], x).astype(jnp.float32))
-            elif (carries is not None
-                  and isinstance(layer, BaseRecurrentLayer)):
-                x, new_carries[i] = layer.forward_seq(
-                    params[i], x, carries[i], train=train, rng=keys[i],
-                    mask=mask)
-            else:
-                x, new_state[i] = layer.forward(
-                    params[i], net_state[i], x, train=train, rng=keys[i],
-                    mask=mask)
+                    x = layer._activate(
+                        layer.pre_output(params[i], x).astype(jnp.float32))
+                elif (carries is not None
+                      and isinstance(layer, BaseRecurrentLayer)):
+                    x, new_carries[i] = layer.forward_seq(
+                        params[i], x, carries[i], train=train, rng=keys[i],
+                        mask=mask)
+                else:
+                    x, new_state[i] = layer.forward(
+                        params[i], net_state[i], x, train=train,
+                        rng=keys[i], mask=mask)
         if pol.downcasts_output:
             # fp32 logits contract: every consumer (loss, softmax, metrics
             # accumulation, serving) sees fp32 even under bf16 storage so
             # Evaluation numbers never drift with the policy.
-            x = x.astype(jnp.float32)
+            with _monitor.scope("precision", "cast"):
+                x = x.astype(jnp.float32)
         return x, new_state, new_carries
 
     # ----------------------------------------------------------------- loss
@@ -210,13 +221,15 @@ class MultiLayerNetwork:
                 x = out_layer.apply_dropout(
                     x, train, jax.random.fold_in(rng, n - 1)
                     if rng is not None else None)
-            if per_example:
-                data_loss = out_layer.compute_score_examples_with_input(
-                    params[n - 1], labels, x, labels_mask)
-            else:
-                data_loss = out_layer.compute_score_with_input(
-                    params[n - 1], labels, x, labels_mask,
-                    average=self.conf.conf.mini_batch)
+            with _monitor.scope("loss"):
+                if per_example:
+                    data_loss = \
+                        out_layer.compute_score_examples_with_input(
+                            params[n - 1], labels, x, labels_mask)
+                else:
+                    data_loss = out_layer.compute_score_with_input(
+                        params[n - 1], labels, x, labels_mask,
+                        average=self.conf.conf.mini_batch)
             return data_loss, (new_state, new_carries)
         preout, new_state, new_carries = self._forward(
             params, net_state, features, train=train, rng=rng,
@@ -230,19 +243,22 @@ class MultiLayerNetwork:
             # Per-timestep output: the features mask doubles as the labels
             # mask (reference feedForwardMaskArray propagation).
             lmask = features_mask
-        if per_example:
-            data_loss = out_layer.compute_score_examples(labels, preout,
-                                                         lmask)
-            return data_loss, (new_state, new_carries)
-        data_loss = out_layer.compute_score(labels, preout, lmask,
-                                            average=self.conf.conf.mini_batch)
+        with _monitor.scope("loss"):
+            if per_example:
+                data_loss = out_layer.compute_score_examples(
+                    labels, preout, lmask)
+            else:
+                data_loss = out_layer.compute_score(
+                    labels, preout, lmask,
+                    average=self.conf.conf.mini_batch)
         return data_loss, (new_state, new_carries)
 
     def _reg_score(self, params) -> Array:
         total = jnp.asarray(0.0, jnp.float32)
-        for i, layer in enumerate(self.layers):
-            total = total + _updaters.regularization_score(
-                params[i], layer.l1_by_param(), layer.l2_by_param())
+        with _monitor.scope("reg"):
+            for i, layer in enumerate(self.layers):
+                total = total + _updaters.regularization_score(
+                    params[i], layer.l1_by_param(), layer.l2_by_param())
         return total
 
     # ------------------------------------------------------------ train step
@@ -253,9 +269,10 @@ class MultiLayerNetwork:
         for i, layer in enumerate(self.layers):
             g = grads[i]
             if g:
-                new_p, ustate = _updaters.apply_layer_updates(
-                    self._updater_conf(i), layer, params[i],
-                    updater_state[i], g, iteration)
+                with _monitor.scope("update", self._scope_name(i)):
+                    new_p, ustate = _updaters.apply_layer_updates(
+                        self._updater_conf(i), layer, params[i],
+                        updater_state[i], g, iteration)
                 new_params.append(new_p)
                 new_updater_state.append(ustate)
             else:
@@ -418,9 +435,10 @@ class MultiLayerNetwork:
 
             def body(carry, idx_row):
                 p, u, s, it = carry
-                f = ingest.device_decode(
-                    jnp.take(data_f, idx_row, axis=0), wire)
-                l = jnp.take(data_l, idx_row, axis=0)
+                with _monitor.scope("ingest", "gather"):
+                    f = ingest.device_decode(
+                        jnp.take(data_f, idx_row, axis=0), wire)
+                    l = jnp.take(data_l, idx_row, axis=0)
                 rng = jax.random.fold_in(base_rng, it)
                 (data_loss, (new_s, _)), grads = jax.value_and_grad(
                     self._loss_fn, has_aux=True)(
@@ -1369,7 +1387,10 @@ class MultiLayerNetwork:
     def score(self, dataset: Optional[DataSet] = None) -> float:
         """Mean loss on a dataset (reference ``score:1705``)."""
         if dataset is None:
-            return float(self._score)
+            from . import ingest
+            # fetched once: the next call finds a host value
+            self._score = float(ingest.fetch_scores(self._score))
+            return self._score
         self.init()
         fmask = (None if dataset.features_mask is None
                  else jnp.asarray(dataset.features_mask))

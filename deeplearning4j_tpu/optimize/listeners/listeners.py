@@ -266,72 +266,47 @@ class ProfilerListener(TrainingListener):
     ``log_dir`` (viewable in TensorBoard/Perfetto), plus host-side phase
     timings per iteration.  The reference exposes runtime timing through
     PerformanceListener; XLA's profiler is the TPU-native deep-dive
-    equivalent."""
+    equivalent.  The capture runs through ``monitor.DeviceTrace``, the
+    program's one way to take a trace, so it is reduced when it closes:
+    :meth:`device_report` has the device seconds by scope.  Like every
+    listener this one breaks ``fit``'s fused dispatch; profile the fused
+    path with ``with monitor.device_trace(log_dir): net.fit(...)``."""
 
     def __init__(self, log_dir: str, start_iteration: int = 2,
                  end_iteration: int = 5):
+        from ... import monitor as _monitor
         self.log_dir = log_dir
         self.start_iteration = start_iteration
         self.end_iteration = end_iteration
-        self._tracing = False
-        self._capture_t0: Optional[float] = None
-        self._capture_ctx = None
+        self._trace = _monitor.DeviceTrace(log_dir)
         self._last_t: Optional[float] = None
         self.iteration_times_ms: List[float] = []
 
     def iteration_done(self, model, iteration: int) -> None:
-        import jax
         now = time.perf_counter()
         if self._last_t is not None:
             self.iteration_times_ms.append((now - self._last_t) * 1e3)
         self._last_t = now
-        if not self._tracing and iteration >= self.start_iteration \
+        if not self._trace.open and iteration >= self.start_iteration \
                 and iteration < self.end_iteration:
-            from ... import monitor as _monitor
-            jax.profiler.start_trace(self.log_dir)
-            self._tracing = True
-            self._capture_t0 = time.time()
-            self._capture_ctx = _monitor.current_context()
-        elif self._tracing and iteration >= self.end_iteration:
-            self._stop_trace()
-
-    def _stop_trace(self) -> None:
-        """Close the capture exactly once.  ``_tracing`` flips before the
-        profiler call and a failed ``stop_trace`` is swallowed: on the
-        error path (e.g. the capture died with the run, or ``stop`` races
-        ``iteration_done``) a second stop must not raise over the
-        original failure.  The capture window is also recorded as a
-        ``profiler/capture`` span so it shows up on the trace timeline
-        next to the work it profiled."""
-        if not self._tracing:
-            return
-        self._tracing = False
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except RuntimeError:
-            pass
-        if self._capture_t0 is not None:
-            from ... import monitor as _monitor
-            ctx = self._capture_ctx
-            _monitor.tracer().record_span(
-                "profiler/capture",
-                trace_id=(ctx.trace_id if ctx is not None
-                          else _monitor.new_trace_id()),
-                parent_id=ctx.span_id if ctx is not None else None,
-                ts=self._capture_t0,
-                dur_ms=(time.time() - self._capture_t0) * 1e3,
-                log_dir=self.log_dir)
-            self._capture_t0 = None
-            self._capture_ctx = None
+            self._trace.start()
+        elif self._trace.open and iteration >= self.end_iteration:
+            self._trace.stop()
 
     def stop(self) -> None:
         """Close a still-open capture (only needed when training ended
         before ``end_iteration``).  Deliberately NOT hooked to epoch
         boundaries — a capture window spanning epochs must stay one
-        contiguous trace.  Idempotent: safe on the error path where the
-        capture was already stopped (or never started)."""
-        self._stop_trace()
+        contiguous trace.  Idempotent: ``DeviceTrace.stop`` closes the
+        capture exactly once, swallows a failed ``stop_trace`` on the
+        error path, and records the ``profiler/capture`` span."""
+        self._trace.stop()
+
+    def device_report(self) -> Optional[dict]:
+        """Device seconds by scope and pass of the closed capture
+        (``monitor.device_trace.reduce``); ``None`` while it is open and
+        where the trace holds no TPU operation (the CPU)."""
+        return self._trace.report
 
     def phase_report(self) -> dict:
         """Host-side phase timing summary (mean/p50/p95 iteration ms)."""

@@ -366,7 +366,9 @@ class ParallelWrapper:
         the staging future, then run the shard_map program."""
         with _monitor.span("parallel/round", workers=self.workers,
                            steps=self.averaging_frequency, prefetched=True):
-            self._dispatch_round(future.result())
+            with _monitor.span("parallel/data_wait"):
+                staged = future.result()
+            self._dispatch_round(staged)
 
     def _stage_round(self, batches: List[DataSet]):
         """Host side of a round: stack the k*w minibatches into the

@@ -1,0 +1,533 @@
+"""The step programs' scopes, the reduction of a device trace by scope
+(``monitor/device_trace.py``), the program's spans on the profiler's
+clock, and the set-up counters of ``monitor/jit_watch.py``."""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import monitor
+from deeplearning4j_tpu.monitor import health
+from deeplearning4j_tpu.monitor.device_trace import (
+    GROUPS, find_trace, hlo_modules, parse_op_name, reduce, table)
+from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
+from deeplearning4j_tpu.nn.conf.neural_net_configuration import \
+    NeuralNetConfiguration
+from deeplearning4j_tpu.nn.layers.core import DenseLayer, OutputLayer
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SMALL_TRACE = os.path.join(HERE, "benchmark", "data",
+                           "small_trace.xplane.pb")
+SCOPE_TRACE = os.path.join(HERE, "data", "scope_trace.xplane.pb.gz")
+
+
+def _builder():
+    return (NeuralNetConfiguration.builder().seed(1).updater("nesterovs")
+            .learning_rate(0.1).weight_init("xavier").activation("tanh")
+            .l2(1e-4))
+
+
+def _mln():
+    conf = (_builder().list()
+            .layer(DenseLayer(n_in=4, n_out=6))
+            .layer(OutputLayer(n_in=6, n_out=3, activation="softmax",
+                               loss="mcxent")))
+    return MultiLayerNetwork(conf.build()).init()
+
+
+def _graph():
+    conf = (_builder().graph_builder().add_inputs("in")
+            .add_layer("d", DenseLayer(n_in=4, n_out=6), "in")
+            .add_layer("o", OutputLayer(n_in=6, n_out=3,
+                                        activation="softmax",
+                                        loss="mcxent"), "d")
+            .set_outputs("o"))
+    return ComputationGraph(conf.build()).init()
+
+
+CONTAINERS = {
+    "mln": (_mln, ["0_DenseLayer", "1_OutputLayer"]),
+    "graph": (_graph, ["d", "o"]),
+}
+
+
+def _op_names(net, program: str, with_health: bool):
+    """The ``op_name`` of every instruction in the compiled HLO text of
+    one of ``net``'s programs."""
+    graph = isinstance(net, ComputationGraph)
+    x = jnp.ones((8, 4), jnp.float32)
+    y = jnp.eye(3, dtype=jnp.float32)[jnp.arange(8) % 3]
+    one = (lambda a: (a,)) if graph else (lambda a: a)
+    if program == "output":
+        lowered = net._output_fn.lower(net.params, net.net_state, one(x),
+                                       None)
+    elif program == "train_step":
+        lowered = net._build_train_step(with_health).lower(
+            net.params, net.updater_state, net.net_state, 0, one(x), one(y),
+            None, None, net._rng_key)
+    else:
+        lowered = net._build_gather_train_step(with_health).lower(
+            net.params, net.updater_state, net.net_state, 0, one(x), one(y),
+            net._rng_key, net._rng_key, 0, 2, 2, 4, True, 0,
+            one(None), 0, 2)
+    return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+
+
+@pytest.mark.parametrize("with_health", [False, True],
+                         ids=["plain", "health"])
+@pytest.mark.parametrize("program", ["train_step", "gather_train_step"])
+@pytest.mark.parametrize("container", sorted(CONTAINERS))
+def test_train_programs_carry_the_scopes(container, program, with_health):
+    build, layers = CONTAINERS[container]
+    parsed = {parse_op_name(n) for n in _op_names(build(), program,
+                                                  with_health)}
+    for name in layers:
+        assert (f"layer.{name}", "forward") in parsed
+        assert (f"layer.{name}", "backward") in parsed
+        assert (f"update.{name}", "other") in parsed
+    assert ("loss", "forward") in parsed and ("loss", "backward") in parsed
+    assert ("reg", "other") in parsed
+    assert (("ingest.gather", "other") in parsed) == \
+        (program == "gather_train_step")
+    assert (("health", "other") in parsed) == with_health
+    assert {scope.split(".")[0] for scope, _ in parsed} <= \
+        set(GROUPS) | {"unscoped"}
+
+
+@pytest.mark.parametrize("container", sorted(CONTAINERS))
+def test_output_program_carries_bare_layer_scopes(container):
+    build, layers = CONTAINERS[container]
+    names = _op_names(build(), "output", False)
+    for name in layers:
+        assert any(f"/layer.{name}/" in n for n in names)
+    assert not any("jvp(" in n or "transpose(" in n for n in names)
+    parsed = {parse_op_name(n) for n in names}
+    assert {(f"layer.{n}", "forward") for n in layers} <= parsed
+    assert not any(scope.startswith(("update.", "loss", "health"))
+                   for scope, _ in parsed)
+
+
+@pytest.mark.parametrize("container", sorted(CONTAINERS))
+def test_scopes_change_no_number(container, monkeypatch):
+    """Scopes are metadata: a step traced without them computes the same
+    bits."""
+    import contextlib
+    build, _ = CONTAINERS[container]
+    x = np.random.default_rng(0).random((8, 4), dtype=np.float32)
+    y = np.eye(3, dtype=np.float32)[np.arange(8) % 3]
+    scoped = build()
+    scoped.fit(x, y)
+    monkeypatch.setattr(monitor, "scope",
+                        lambda *a, **k: contextlib.nullcontext())
+    bare = build()
+    bare.fit(x, y)
+    assert scoped.score() == bare.score()
+    np.testing.assert_array_equal(scoped.get_flat_params(),
+                                  bare.get_flat_params())
+
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(multi)/while/body/closed_call/jvp(layer.res2a_branch2a)"
+     "/dot_general", ("layer.res2a_branch2a", "forward")),
+    ("jit(multi)/while/body/closed_call/transpose(jvp(layer.bn_conv1))"
+     "/reduce_sum", ("layer.bn_conv1", "backward")),
+    ("jit(multi)/while/body/update.res2a_branch2a/mul",
+     ("update.res2a_branch2a", "other")),
+    ("jit(multi)/while/body/ingest.gather/gather",
+     ("ingest.gather", "other")),
+    ("jit(multi)/while/body/health/sqrt", ("health", "other")),
+    ("jit(multi)/while/body/reg/square", ("reg", "other")),
+    ("jit(step)/jvp(loss)/jit(log_softmax)/exp", ("loss", "forward")),
+    ("jit(step)/transpose(jvp(loss))/mul", ("loss", "backward")),
+    ("jit(step)/transpose(jvp(precision.cast))/convert_element_type",
+     ("precision.cast", "backward")),
+    ("jit(run)/layer.1_OutputLayer/dot_general",
+     ("layer.1_OutputLayer", "forward")),
+    # XLA joins the names of merged instructions: the first wins
+    ("jit(multi)/while/body/transpose(jvp(layer.0_Dense))/dot_general;"
+     "jit(multi)/while/body/update.0_Dense/mul",
+     ("layer.0_Dense", "backward")),
+    ("jit(multi)/while/body/add;jit(multi)/while/body/update.0_Dense/mul",
+     ("unscoped", "other")),
+    # nothing of the grammar
+    ("jit(chain)/dot_general", ("unscoped", "other")),
+    ("", ("unscoped", "other")),
+    ("params[0]['W']", ("unscoped", "other")),
+    # a jitted function that happens to be called like a scope
+    ("jit(loss)/mul", ("unscoped", "other")),
+    ("jit(step)/jvp(jit(health))/mul", ("unscoped", "other")),
+    # vmap and other transformations wrap without naming a pass
+    ("jit(f)/vmap(layer.a)/mul", ("layer.a", "forward")),
+    ("jit(f)/vmap(update.a)/mul", ("update.a", "other")),
+])
+def test_parse_op_name(op_name, expected):
+    assert parse_op_name(op_name) == expected
+
+
+def test_scope_refuses_a_group_outside_the_grammar_and_a_slash():
+    with pytest.raises(ValueError):
+        monitor.scope("layers", "a")
+
+    @jax.jit
+    def f(x):
+        with monitor.scope("layer", "block/conv"):
+            return x * 2.0
+    text = f.lower(jnp.ones(3)).compile().as_text()
+    assert "layer.block_conv" in text
+
+
+# ------------------------------------------------- the recorded chip traces
+@pytest.fixture(scope="module")
+def small():
+    return reduce(SMALL_TRACE, window="bench/window")
+
+
+def test_small_trace_busy_seconds_equal_the_benchmarks(small):
+    """Two reductions, one file: the program's and the benchmark's
+    (``benchmark/xplane.py``) agree on the device-busy seconds."""
+    from benchmark import xplane
+    theirs = xplane.reduce_events(xplane.read_events(SMALL_TRACE))
+    assert small["busy_s"] == pytest.approx(theirs["busy_s"], abs=1e-9)
+    assert small["window_s"] == pytest.approx(theirs["window_s"], abs=1e-9)
+    assert small["idle_share"] == pytest.approx(theirs["idle_share"],
+                                                abs=1e-9)
+    assert small["devices"] == 1
+
+
+def test_small_trace_rows_sum_to_busy_and_nothing_is_dropped(small):
+    assert sum(r[2] for r in small["by_scope"]) == \
+        pytest.approx(small["busy_s"], abs=1e-12)
+    assert sum(small["by_pass"].values()) == \
+        pytest.approx(small["busy_s"], abs=1e-12)
+    assert sum(small["by_group"].values()) == \
+        pytest.approx(small["busy_s"], abs=1e-12)
+    # a program without scopes: everything is unscoped, by opcode
+    assert [r[:2] for r in small["by_scope"]] == [["unscoped", "other"]]
+    assert small["by_group"]["unscoped"] == small["by_scope"][0][2]
+    opcodes = {r[0]: r for r in small["unscoped_by_opcode"]}
+    assert set(opcodes) == {"fusion", "copy-start", "copy-done"}
+    # two of the three units lie inside ``bench/window`` on the device's
+    # clock: 8 fusions and one copy pair each
+    assert opcodes["fusion"][2] == 16
+    assert opcodes["copy-start"][2] == opcodes["copy-done"][2] == 2
+    assert sum(r[1] for r in small["unscoped_by_opcode"]) == \
+        pytest.approx(small["busy_s"], abs=1e-12)
+
+
+def test_small_trace_op_names_come_from_the_hlo_module():
+    (name, instructions), = hlo_modules(SMALL_TRACE).items()
+    assert re.fullmatch(r"jit_chain\(\d+\)", name)
+    for i in range(1, 9):
+        assert instructions[f"fusion.{i}"] == (
+            "fusion", "jit(chain)/dot_general", (), False)
+    # XLA gave the prefetch no name: it takes its consumer's
+    assert instructions["copy-start"] == (
+        "copy-start", "jit(chain)/dot_general", (), True)
+    assert instructions["copy-done"] == (
+        "copy-done", "jit(chain)/dot_general", (), True)
+
+
+def test_small_trace_idle_time_by_the_benchmarks_spans(small):
+    """The benchmark's ``bench/...`` annotations have the form of program
+    spans, so the idle split works on them as on ``fit/...``."""
+    idle = dict(small["idle_by_span"])
+    assert idle["bench/sleep"] > 0.058
+    assert sum(idle.values()) == pytest.approx(
+        small["window_s"] - small["busy_s"], rel=1e-9)
+    assert "bench/window" not in idle
+
+
+def test_without_its_window_span_the_device_events_bound_the_session():
+    whole = reduce(SMALL_TRACE)          # no ``profiler/capture`` in it
+    assert whole["by_scope"][0][3] == 30     # all three units
+    assert whole["window_s"] < 0.05
+    assert reduce(os.path.dirname(SMALL_TRACE))["busy_s"] == \
+        whole["busy_s"]
+
+
+def test_nothing_to_read_gives_nothing(tmp_path):
+    assert find_trace(str(tmp_path)) is None
+    assert reduce(str(tmp_path)) is None
+
+
+@pytest.fixture(scope="module")
+def scoped():
+    return reduce(SCOPE_TRACE)
+
+
+def test_scope_trace_is_small_and_says_how_it_was_made():
+    assert os.path.getsize(SCOPE_TRACE) < 100_000
+    assert os.path.isfile(os.path.join(HERE, "data",
+                                       "record_scope_trace.py"))
+
+
+def test_scope_trace_splits_the_gather_step(scoped):
+    """A two-layer net's gather step, 40 fused steps, recorded on the
+    chip (``tests/data/record_scope_trace.py``).  Forward, backward and
+    the gather have kernels of their own.  The updater has none: XLA
+    fuses ``update.*`` into the weight-gradient kernels, whose time goes
+    to the layer's backward pass, so it shows in ``fused_in_by_group``
+    alone."""
+    assert scoped["devices"] == 1 and scoped["busy_s"] > 0
+    assert sum(r[2] for r in scoped["by_scope"]) == \
+        pytest.approx(scoped["busy_s"], rel=1e-9)
+    assert scoped["by_pass"]["forward"] > 0
+    assert scoped["by_pass"]["backward"] > scoped["by_pass"]["forward"]
+    assert scoped["by_group"]["ingest"] > 0
+    assert scoped["by_group"]["layer"] > 0.4 * scoped["busy_s"]
+    assert scoped["by_group"]["health"] > 0
+    assert scoped["by_group"]["update"] == 0
+    assert scoped["fused_in_by_group"]["update"] > 0
+    assert scoped["fused_in_by_group"]["update"] <= \
+        scoped["by_pass"]["backward"] * (1 + 1e-9)
+    assert scoped["by_group"]["unscoped"] < 0.10 * scoped["busy_s"]
+    assert 0 <= scoped["inherited_s"] < scoped["busy_s"]
+    rows = {(r[0], r[1]): r for r in scoped["by_scope"]}
+    assert rows[("ingest.gather", "other")][3] >= 40      # events
+    for layer in ("0_DenseLayer", "1_OutputLayer"):
+        assert rows[(f"layer.{layer}", "forward")][2] > 0
+        assert rows[(f"layer.{layer}", "backward")][2] > 0
+
+
+def test_scope_trace_fusions_say_what_they_hold():
+    modules = hlo_modules(SCOPE_TRACE)
+    (step,) = [m for name, m in modules.items()
+               if name.startswith("jit_multi(")]
+    holding = [ins for ins in step.values()
+               if ins[0] == "fusion" and "update" in ins[2]]
+    owners = {parse_op_name(op_name) for _, op_name, _, _ in holding}
+    assert any(scope.startswith("layer.") and pass_ == "backward"
+               for scope, pass_ in owners)
+    assert not any(scope.startswith("update.") for scope, _ in owners)
+    # nameless copies of a prefetch take their consumer's name
+    assert any(ins[3] for ins in step.values())
+
+
+def test_scope_trace_idle_time_goes_to_the_programs_spans(scoped):
+    idle = dict(scoped["idle_by_span"])
+    assert set(idle) <= {"fit/epoch", "fit/stage", "fit/dispatch",
+                         "fit/score_wait", "no_span"}
+    assert "fit/dispatch" in idle or "fit/score_wait" in idle
+    assert sum(idle.values()) == pytest.approx(
+        scoped["window_s"] - scoped["busy_s"], rel=1e-6)
+
+
+def test_table_prints_every_section(scoped):
+    text = table(scoped, top=5)
+    for heading in ("by pass:", "by group (own", "(scope, pass) rows",
+                    "idle time of the first device"):
+        assert heading in text
+    assert "ingest.gather" in text
+
+
+# --------------------------------------------- spans on the profiler's clock
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_a_span_is_an_event_of_the_profilers_host_plane(tmp_path):
+    monitor.reset()
+    with monitor.span("fit/outside"):
+        pass
+    with monitor.device_trace(str(tmp_path)) as trace:
+        with monitor.span("fit/dispatch", steps=3, fused=1):
+            time.sleep(0.002)
+    assert trace.report is None           # the CPU has no TPU plane
+    assert trace.open is False
+    events = {name: (start, dur) for name, start, dur
+              in _host_events(str(tmp_path))}
+    assert "fit/outside" not in events
+    start, dur = events["fit/dispatch"]
+    assert dur >= 2e6
+    session_start, session_dur = events["profiler/capture"]
+    assert session_start <= start
+    assert start + dur <= session_start + session_dur
+    # the ring still has both, and the capture's window
+    ring = [e["name"] for e in monitor.tracer().events()]
+    assert ring == ["fit/outside", "fit/dispatch", "profiler/capture"]
+    (capture,) = monitor.tracer().events(name="profiler/capture")
+    assert capture["attrs"]["log_dir"] == str(tmp_path)
+
+
+def test_a_span_costs_nothing_observable_without_a_session():
+    def spans(n):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with monitor.span("fit/dispatch", steps=1, fused=1):
+                pass
+        return (time.perf_counter() - t0) / n
+    spans(200)
+    assert min(spans(2000) for _ in range(3)) < 100e-6
+
+
+def test_fit_opens_the_spans_of_the_epoch_cache_path():
+    monitor.reset()
+    net = _mln()
+    x = np.random.default_rng(0).random((16, 4), dtype=np.float32)
+    y = np.eye(3, dtype=np.float32)[np.arange(16) % 3]
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+    net.fit(ListDataSetIterator(DataSet(x, y), 4), epochs=2)
+    assert np.isfinite(net.score())
+    assert net.score() == net.score()     # a host value is not waited for
+    events = monitor.tracer().events()
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    (epoch,) = by_name["fit/epoch"]
+    (stage,) = by_name["fit/stage"]
+    (dispatch,) = by_name["fit/dispatch"]
+    assert stage["parent"] == dispatch["parent"] == epoch["id"]
+    assert dispatch["attrs"] == {"steps": 8, "fused": 2}
+    assert len(by_name["fit/score_wait"]) == 1
+
+
+# ------------------------------------------------------------ set-up counters
+SETUP_COUNTERS = ("jit_trace_seconds_total", "jit_lower_seconds_total",
+                  "jit_backend_seconds_total")
+
+
+def _seconds(name, fn):
+    return monitor.registry().counter(name).value(fn=fn)
+
+
+def test_setup_counters_grow_on_a_first_call_and_not_on_a_second():
+    monitor.reset()
+
+    def f(x):
+        for _ in range(4):
+            x = jnp.tanh(x @ x) + jnp.take(x, jnp.arange(8), axis=0)
+        return x.sum()
+
+    watched = monitor.watched_jit(f, name="test.setup_counters")
+    x = jnp.ones((8, 8), jnp.float32)
+    t0 = time.perf_counter()
+    watched(x).block_until_ready()
+    wall = time.perf_counter() - t0
+    first = {n: _seconds(n, "test.setup_counters")
+             + _seconds(n, "test.setup_counters/cost_analysis")
+             for n in SETUP_COUNTERS}
+    assert all(v > 0 for v in first.values()), first
+    # nested trace events are not counted twice: the stages add up to
+    # less than the wall they ran in
+    assert sum(first.values()) <= wall
+    # the extra lowering behind the cost gauges is charged by name
+    assert _seconds("jit_trace_seconds_total",
+                    "test.setup_counters/cost_analysis") > 0
+    snapshot = monitor.snapshot()
+    watched(x).block_until_ready()
+    assert monitor.snapshot()["jit_trace_seconds_total"] == \
+        snapshot["jit_trace_seconds_total"]
+    for n in SETUP_COUNTERS:
+        assert _seconds(n, "test.setup_counters") + _seconds(
+            n, "test.setup_counters/cost_analysis") == first[n]
+
+
+def test_compiles_outside_a_watched_jit_are_unwatched():
+    monitor.reset()
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(5)).block_until_ready()
+    assert _seconds("jit_backend_seconds_total", "unwatched") > 0
+    assert _seconds("jit_trace_seconds_total", "unwatched") > 0
+
+
+class _Clock:
+    """A clock the test moves: the listener reads ``perf_counter``."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        return self.now
+
+
+def test_nested_compile_events_add_up_to_wall_time(monkeypatch):
+    """The listener sees ``(event, duration)`` at an event's end: an
+    outer event adds only what its inner events have not counted."""
+    from deeplearning4j_tpu.monitor import jit_watch
+    monitor.reset()
+    clock = _Clock()
+    monkeypatch.setattr(jit_watch, "time", clock)
+    jit_watch._compiling.__dict__.clear()   # this thread's earlier events
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    with jit_watch._compiling_as("test.nested"):
+        clock.now += 3.0                    # outer runs 1 s, then an inner
+        jit_watch._on_compile_duration(event, 2.0)
+        clock.now += 1.5                    # 0.5 s of outer, 1 s of inner
+        jit_watch._on_compile_duration(event, 1.0)
+        clock.now += 0.25
+        jit_watch._on_compile_duration(event, 4.75)     # the outer one
+    assert _seconds("jit_trace_seconds_total", "test.nested") == \
+        pytest.approx(4.75)
+    # a sibling after it, on its own, and an event of another kind
+    clock.now += 2.0
+    jit_watch._on_compile_duration(event, 0.5)
+    jit_watch._on_compile_duration("/jax/some/other_event", 5.0)
+    assert _seconds("jit_trace_seconds_total", "unwatched") == \
+        pytest.approx(0.5)
+    assert _seconds("jit_lower_seconds_total", "unwatched") == 0
+    jit_watch._compiling.__dict__.clear()
+
+
+def test_thousands_of_sibling_events_still_add_up(monkeypatch):
+    """ResNet-50's step fires 7,500 trace events inside one: the parent
+    must find what all of them counted, not only the latest few, also
+    when older top-level events lie before it."""
+    from deeplearning4j_tpu.monitor import jit_watch
+    monitor.reset()
+    clock = _Clock()
+    monkeypatch.setattr(jit_watch, "time", clock)
+    jit_watch._compiling.__dict__.clear()   # this thread's earlier events
+    event = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    clock.now += 1.0
+    jit_watch._on_compile_duration(event, 1.0)          # an older one
+    with jit_watch._compiling_as("test.siblings"):
+        clock.now += 0.5
+        siblings = 3 * jit_watch._PRUNE_EVERY
+        for _ in range(siblings):
+            clock.now += 0.002
+            jit_watch._on_compile_duration(event, 0.001)
+        clock.now += 0.5
+        jit_watch._on_compile_duration(event, 1.0 + 0.002 * siblings)
+    assert _seconds("jit_lower_seconds_total", "test.siblings") == \
+        pytest.approx(1.0 + 0.002 * siblings)
+    assert _seconds("jit_lower_seconds_total", "unwatched") == \
+        pytest.approx(1.0)
+    # an hour on, what no open event can enclose is forgotten
+    clock.now += 2 * jit_watch._HORIZON_S
+    for _ in range(jit_watch._PRUNE_EVERY):
+        clock.now += 0.002
+        jit_watch._on_compile_duration(event, 0.001)
+    counted = jit_watch._compiling.counted[event]
+    assert len(counted) <= jit_watch._PRUNE_EVERY
+    jit_watch._compiling.__dict__.clear()   # the fake clock's leave too
+
+
+def test_health_scope_is_written_once_in_the_monitor():
+    """``layer_stats`` and ``guard_select`` carry the ``health`` scope
+    themselves, so every step builder that calls them has it."""
+    health.enable(policy="skip_update")
+    try:
+        def f(p, g):
+            new = jax.tree.map(lambda a, b: a - b, p, g)
+            vec, bad = health.layer_stats([p], [new], [g], 1.0)
+            return health.guard_select(bad, new, p), vec
+        text = jax.jit(f).lower({"W": jnp.ones(3)},
+                                {"W": jnp.ones(3)}).compile().as_text()
+    finally:
+        health.reset()
+    parsed = {parse_op_name(n)
+              for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert ("health", "other") in parsed

@@ -511,7 +511,7 @@ def test_profiler_listener_double_stop_guard(tmp_path, monkeypatch):
     calls = {"start": 0, "stop": 0}
     monkeypatch.setattr(
         jax.profiler, "start_trace",
-        lambda d: calls.__setitem__("start", calls["start"] + 1))
+        lambda d, **kw: calls.__setitem__("start", calls["start"] + 1))
 
     def _stop():
         calls["stop"] += 1
@@ -530,8 +530,8 @@ def test_profiler_listener_double_stop_guard(tmp_path, monkeypatch):
 
     # error path: a stop whose profiler call raises is swallowed and
     # still closes the window
-    pl._tracing = True
-    pl._capture_t0 = time.time()
+    pl._trace.start()
     pl.stop()                            # raises inside, guarded
-    assert pl._tracing is False
-    assert calls["stop"] == 2
+    assert pl._trace.open is False
+    assert calls == {"start": 2, "stop": 2}
+    assert pl.device_report() is None
