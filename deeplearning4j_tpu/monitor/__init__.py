@@ -50,7 +50,8 @@ from .alerts import (AlertEngine, Rule, default_rules,
 from . import attribution
 from .attribution import StepAttributor, breakdown as wall_breakdown
 from .device_trace import DeviceTrace, device_trace, parse_op_name, scope
-from .jit_watch import WatchedJit, publish_cost_analysis, watched_jit
+from .jit_watch import (WatchedJit, program_identity,
+                        publish_cost_analysis, watched_jit)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, registry)
 from .tracing import (TraceContext, Tracer, attach, current_context,
                       current_trace_hex, detach, new_trace_id,
@@ -66,7 +67,7 @@ __all__ = [
     "health", "health_enabled", "health_snapshot", "histogram",
     "incident_dir", "new_trace_id", "observe_phase", "parse_op_name",
     "parse_traceparent", "phase_breakdown", "post_system_metrics",
-    "prometheus_text", "publish_cost_analysis", "record_incident",
+    "program_identity", "prometheus_text", "publish_cost_analysis", "record_incident",
     "registry", "reset", "sanitize_end_warmup", "sanitize_scenario",
     "scope", "snapshot", "span", "system_metrics_persistable",
     "trace_chrome_json", "trace_jsonl", "tracer", "wall_breakdown",

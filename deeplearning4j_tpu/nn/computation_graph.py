@@ -23,6 +23,7 @@ import numpy as np
 
 from . import precision as _precision
 from . import updaters as _updaters
+from . import weights as _weights
 from .. import monitor as _monitor
 from .conf.computation_graph import (ComputationGraphConfiguration,
                                      DuplicateToTimeSeriesVertex,
@@ -98,26 +99,46 @@ class ComputationGraph:
 
     # ------------------------------------------------------------------ init
     def init(self) -> "ComputationGraph":
+        """Initialize params/state from the seed's key, by the staged
+        programs of ``_init_program``."""
         if self._init_done:
             return self
-        pol = self._pol()
-        _precision.publish(pol)
-        dtype = jnp.dtype(pol.param_dtype)
+        _precision.publish(self._pol())
         key = jax.random.PRNGKey(self.conf.conf.seed)
         self._rng_key = key
-        names = [n for n in self.topo
-                 if isinstance(self.vertices[n], LayerVertex)]
-        keys = jax.random.split(key, max(len(names), 1))
-        for n, k in zip(names, keys):
-            layer = self.vertices[n].layer
-            self.params[n] = layer.init_params(k, dtype)
-            self.net_state[n] = layer.init_state(dtype)
-            self.updater_state[n] = _updaters.init_state(
-                self._updater_conf(n),
-                _updaters.updatable_params(layer, self.params[n]),
-                policy=pol)
+        out = self._init_program(key)
+        # a jitted program returns its dicts sorted: back to topo order
+        self.params, self.net_state, self.updater_state = (
+            {n: tree[n] for n in self._layer_names()} for tree in out)
         self._init_done = True
         return self
+
+    @functools.cached_property
+    def _init_program(self):
+        """Graph twin of ``MultiLayerNetwork._init_program``: every
+        layer vertex's parameters, state, updater state and masters, in
+        topological order, as staged jitted programs of the seed's key
+        that the executable store can serve."""
+        pol = self._pol()
+        dtype = jnp.dtype(pol.param_dtype)
+
+        def init(key):
+            names = self._layer_names()
+            keys = jax.random.split(key, max(len(names), 1))
+            params, net_state, updater_state = {}, {}, {}
+            for n, k in zip(names, keys):
+                layer = self.vertices[n].layer
+                params[n] = layer.init_params(k, dtype)
+                net_state[n] = layer.init_state(dtype)
+                updater_state[n] = _updaters.init_state(
+                    self._updater_conf(n),
+                    _updaters.updatable_params(layer, params[n]),
+                    policy=pol)
+            return params, net_state, updater_state
+
+        return _weights.init_programs(
+            init, "cg.init",
+            lambda part: _monitor.program_identity(self, part))
 
     def _updater_conf(self, name: str):
         return (self.vertices[name].layer.updater
@@ -476,10 +497,12 @@ class ComputationGraph:
             scores, hstack = out
             return params, updater_state, net_state, scores, hstack
 
-        return _monitor.watched_jit(multi, name="cg.gather_train_step",
-                                    static_argnums=(9, 10, 11, 12, 13,
-                                                    15, 16),
-                                    donate_argnums=(0, 1, 2))
+        return _monitor.watched_jit(
+            multi, name="cg.gather_train_step",
+            static_argnums=(9, 10, 11, 12, 13, 15, 16),
+            donate_argnums=(0, 1, 2),
+            identity=lambda: _monitor.program_identity(
+                self, "gather_train_step", health))
 
     @functools.cached_property
     def _gather_train_step(self):

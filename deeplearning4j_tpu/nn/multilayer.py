@@ -26,6 +26,7 @@ import numpy as np
 
 from . import precision as _precision
 from . import updaters as _updaters
+from . import weights as _weights
 from .. import monitor as _monitor
 from .conf.neural_net_configuration import MultiLayerConfiguration
 from ..datasets.dataset import DataSet
@@ -82,29 +83,48 @@ class MultiLayerNetwork:
 
     # ------------------------------------------------------------------ init
     def init(self) -> "MultiLayerNetwork":
-        """Initialize params/state (reference ``init():384-470``)."""
+        """Initialize params/state (reference ``init():384-470``) from
+        the seed's key, by the staged programs of ``_init_program``."""
         if self._init_done:
             return self
-        pol = self._pol()
-        _precision.publish(pol)
-        dtype = jnp.dtype(pol.param_dtype)
+        _precision.publish(self._pol())
         key = jax.random.PRNGKey(self.conf.conf.seed)
         self._rng_key = key
-        keys = jax.random.split(key, len(self.layers) + 1)
-        self.params = [
-            layer.init_params(keys[i], dtype)
-            for i, layer in enumerate(self.layers)
-        ]
-        self.net_state = [layer.init_state(dtype) for layer in self.layers]
-        self.updater_state = [
-            _updaters.init_state(
-                self._updater_conf(i),
-                _updaters.updatable_params(self.layers[i], self.params[i]),
-                policy=pol)
-            for i in range(len(self.layers))
-        ]
+        out = self._init_program(key)
+        self.params, self.net_state, self.updater_state = out
         self._init_done = True
         return self
+
+    @functools.cached_property
+    def _init_program(self):
+        """Per-layer keys, parameters, layer state, updater state and
+        masters as jitted programs of the seed's key (two, staged so
+        that the values are the leaf-by-leaf ones bit for bit:
+        ``weights.init_programs``), served by the executable store where
+        one is installed: a warm start loads them and derives nothing.
+        ``_init_program.__wrapped__(key)`` is the leaf-by-leaf init."""
+        pol = self._pol()
+        dtype = jnp.dtype(pol.param_dtype)
+
+        def init(key):
+            keys = jax.random.split(key, len(self.layers) + 1)
+            params = [
+                layer.init_params(keys[i], dtype)
+                for i, layer in enumerate(self.layers)
+            ]
+            net_state = [layer.init_state(dtype) for layer in self.layers]
+            updater_state = [
+                _updaters.init_state(
+                    self._updater_conf(i),
+                    _updaters.updatable_params(self.layers[i], params[i]),
+                    policy=pol)
+                for i in range(len(self.layers))
+            ]
+            return params, net_state, updater_state
+
+        return _weights.init_programs(
+            init, "mln.init",
+            lambda part: _monitor.program_identity(self, part))
 
     def _updater_conf(self, i: int) -> _updaters.UpdaterConfig:
         return self.layers[i].updater or self.conf.conf.updater
@@ -461,10 +481,12 @@ class MultiLayerNetwork:
             scores, hstack = out
             return params, updater_state, net_state, scores, hstack
 
-        return _monitor.watched_jit(multi, name="mln.gather_train_step",
-                                    static_argnums=(9, 10, 11, 12, 13,
-                                                    15, 16),
-                                    donate_argnums=(0, 1, 2))
+        return _monitor.watched_jit(
+            multi, name="mln.gather_train_step",
+            static_argnums=(9, 10, 11, 12, 13, 15, 16),
+            donate_argnums=(0, 1, 2),
+            identity=lambda: _monitor.program_identity(
+                self, "gather_train_step", health))
 
     @functools.cached_property
     def _gather_train_step(self):

@@ -216,9 +216,24 @@ def init_state(conf: UpdaterConfig, params: ParamTree,
             jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating)
             and jnp.asarray(p).dtype.itemsize < 4
             for p in jax.tree_util.tree_leaves(params)):
-        state[MASTER_KEY] = jax.tree.map(
-            lambda p: jnp.asarray(p, jnp.float32), params)
+        state[MASTER_KEY] = jax.tree.map(_master_of, params)
     return state
+
+
+def _master_of(p: Array) -> Array:
+    """The fp32 master of a parameter: exactly the value the parameter
+    holds.  Inside a jitted ``init`` the parameter is ``round(x)`` of an
+    fp32 ``x`` in the same program, and XLA, allowed excess precision,
+    folds ``float32(bfloat16(x))`` to ``x`` (seen on the v5e, PR 28):
+    ``reduce_precision`` says the rounding is meant.  On a value that is
+    already rounded it changes nothing."""
+    p = jnp.asarray(p)
+    if not (jnp.issubdtype(p.dtype, jnp.floating) and p.dtype.itemsize < 4):
+        return jnp.asarray(p, jnp.float32)
+    info = jnp.finfo(p.dtype)
+    return jax.lax.reduce_precision(p.astype(jnp.float32),
+                                    exponent_bits=info.nexp,
+                                    mantissa_bits=info.nmant)
 
 
 def compute_update(conf: UpdaterConfig, grads: ParamTree, state: ParamTree,

@@ -6,6 +6,17 @@ function of a JAX PRNG key, so replica initialization under SPMD is
 deterministic given the seed (the analogue of DL4J's shared ``Nd4j.getRandom``
 seed when ``ParallelWrapper`` clones a model per device).
 
+``init()`` of a container runs every layer's scheme inside jitted
+programs whose values must equal the leaf-by-leaf ones bit for bit.  Run
+one operation at a time, a draw and the arithmetic after it round
+separately; inside one program XLA fuses them (``std * (sqrt(2) *
+erfinv(u))`` reassociates, a division by a constant becomes a product
+with its reciprocal), and it drops ``optimization_barrier`` before it
+fuses.  So init is :func:`staged` into TWO programs with a real buffer
+between them: the first makes what a scheme holds (:func:`_held`: the
+normal draws and the schemes' scalars), the second takes those as
+arguments and does the rest.  Run un-staged, ``_held`` changes nothing.
+
 Shapes follow the JAX convention ``(fan_in, fan_out)`` for dense kernels and
 ``(H, W, C_in, C_out)`` (HWIO) for conv kernels; fan computation mirrors
 ``WeightInitUtil.initWeights``.
@@ -13,12 +24,15 @@ Shapes follow the JAX convention ``(fan_in, fan_out)`` for dense kernels and
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Optional, Sequence
+import threading
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
+from .. import monitor as _monitor
 from .conf import serde as _serde
 
 Array = jax.Array
@@ -49,10 +63,18 @@ class Distribution:
             # exactly at round(fp32 init), matching the fp32 masters).
             return self.sample(rng, shape, jnp.float32).astype(dtype)
         if self.kind == "normal" or self.kind == "gaussian":
-            return self.mean + self.std * jax.random.normal(rng, shape, dtype)
+            if self.mean != 0.0 and getattr(_stage, "mode", None):
+                # product and sum fuse into one rounding in a program
+                raise NotStaged("normal distribution with a mean")
+            return self.mean + self.std * _held(
+                lambda: jax.random.normal(rng, shape, dtype))
         if self.kind == "uniform":
             return jax.random.uniform(rng, shape, dtype, self.lower, self.upper)
         if self.kind == "binomial":
+            if getattr(_stage, "mode", None):
+                # its logarithms of a constant probability are folded by
+                # the compiler's evaluator, not the device's (v5e, PR 28)
+                raise NotStaged("binomial distribution")
             return jax.random.binomial(
                 rng, self.n_trials, self.prob_success, shape).astype(dtype)
         raise ValueError(f"Unknown distribution kind '{self.kind}'")
@@ -63,6 +85,90 @@ class Distribution:
     @staticmethod
     def from_dict(d: dict) -> "Distribution":
         return Distribution(**d)
+
+
+class NotStaged(Exception):
+    """This scheme's values would differ inside a staged program: the
+    net initialises leaf by leaf."""
+
+
+_stage = threading.local()
+
+
+@contextlib.contextmanager
+def _staging(mode: str, items: list):
+    _stage.mode, _stage.items, _stage.taken = mode, items, 0
+    try:
+        yield
+    finally:
+        _stage.mode = None
+
+
+def _held(make: Callable[[], Array]) -> Array:
+    """A value that is finished before anything is computed from it.
+    Un-staged: ``make()``.  While the first program of :func:`staged`
+    is traced, ``make()`` is also one of its outputs; while the second
+    is, the value is its next argument and ``make`` is not called."""
+    mode = getattr(_stage, "mode", None)
+    if mode is None:
+        return make()
+    if mode == "hold":
+        _stage.items.append(make())
+        return _stage.items[-1]
+    _stage.taken += 1
+    return _stage.items[_stage.taken - 1]
+
+
+def _scalar(fn, *args) -> Array:
+    """A scheme's scalar (``sqrt(2 / fan_in)``), held, and computed now
+    even while tracing: by the device's own operation, as leaf by leaf."""
+    def make():
+        with jax.ensure_compile_time_eval():
+            return fn(*args)
+    return _held(make)
+
+
+def staged(init: Callable):
+    """``init(key) -> tree`` as two functions to jit: ``hold(key)``
+    gives the list of what the schemes hold, and ``finish(key, held)``
+    the tree, taking each held value from ``held`` in the same order
+    (the same Python runs both times)."""
+    def hold(key):
+        items: list = []
+        with _staging("hold", items):
+            init(key)
+        return items
+
+    def finish(key, held):
+        with _staging("take", list(held)):
+            return init(key)
+
+    return hold, finish
+
+
+def init_programs(init: Callable, name: str,
+                  identity: Callable[[str], Optional[str]]):
+    """A container's ``init(key) -> (params, state, updater state)`` as
+    its two staged programs, watched under ``<name>_held`` and
+    ``<name>`` and served by the executable store where one is
+    installed (``identity(part)`` says what they close over).  The
+    returned ``run(key)`` falls back to ``init`` itself, leaf by leaf
+    (``run.__wrapped__``), where a scheme cannot be staged or needs
+    concrete values."""
+    hold, finish = staged(init)
+    hold_program = _monitor.watched_jit(
+        hold, name=f"{name}_held", identity=lambda: identity("init_held"))
+    finish_program = _monitor.watched_jit(
+        finish, name=name, identity=lambda: identity("init"))
+
+    def run(key):
+        try:
+            return finish_program(key, hold_program(key))
+        except (jax.errors.JAXTypeError, NotStaged):
+            return init(key)
+
+    run.__wrapped__ = init
+    return run
 
 
 def _is_sub_fp32(dtype) -> bool:
@@ -118,34 +224,36 @@ def init_weights(rng: jax.Array, shape: Sequence[int], scheme: str = "xavier",
         if distribution is None:
             raise ValueError("WeightInit 'distribution' requires a Distribution")
         return distribution.sample(rng, shape, dtype)
+    def normal():
+        return _held(lambda: jax.random.normal(rng, shape, dtype))
+
     if scheme == "xavier":
         # Gaussian with var = 2/(fanIn+fanOut) (WeightInitUtil XAVIER)
-        std = jnp.sqrt(2.0 / (fan_in + fan_out))
-        return std * jax.random.normal(rng, shape, dtype)
+        std = _scalar(jnp.sqrt, 2.0 / (fan_in + fan_out))
+        return std * normal()
     if scheme == "xavier_uniform":
-        a = jnp.sqrt(6.0 / (fan_in + fan_out))
+        a = _scalar(jnp.sqrt, 6.0 / (fan_in + fan_out))
         return jax.random.uniform(rng, shape, dtype, -a, a)
     if scheme == "xavier_fan_in":
-        return jax.random.normal(rng, shape, dtype) / jnp.sqrt(fan_in)
+        return normal() / _scalar(jnp.sqrt, fan_in)
     if scheme == "xavier_legacy":
-        return jax.random.normal(rng, shape, dtype) * jnp.sqrt(
-            1.0 / (fan_in + fan_out))
+        return normal() * _scalar(jnp.sqrt, 1.0 / (fan_in + fan_out))
     if scheme in ("relu", "he_normal"):
-        return jax.random.normal(rng, shape, dtype) * jnp.sqrt(2.0 / fan_in)
+        return normal() * _scalar(jnp.sqrt, 2.0 / fan_in)
     if scheme in ("relu_uniform", "he_uniform"):
-        a = jnp.sqrt(6.0 / fan_in)
+        a = _scalar(jnp.sqrt, 6.0 / fan_in)
         return jax.random.uniform(rng, shape, dtype, -a, a)
     if scheme == "sigmoid_uniform":
-        a = 4.0 * jnp.sqrt(6.0 / (fan_in + fan_out))
+        a = _scalar(lambda v: 4.0 * jnp.sqrt(v), 6.0 / (fan_in + fan_out))
         return jax.random.uniform(rng, shape, dtype, -a, a)
     if scheme == "uniform":
         # DL4J legacy UNIFORM: U(-a, a) with a = 1/sqrt(fanIn)
-        a = 1.0 / jnp.sqrt(fan_in)
+        a = _scalar(lambda v: 1.0 / jnp.sqrt(v), fan_in)
         return jax.random.uniform(rng, shape, dtype, -a, a)
     if scheme == "lecun_normal":
-        return jax.random.normal(rng, shape, dtype) / jnp.sqrt(fan_in)
+        return normal() / _scalar(jnp.sqrt, fan_in)
     if scheme == "lecun_uniform":
-        a = jnp.sqrt(3.0 / fan_in)
+        a = _scalar(jnp.sqrt, 3.0 / fan_in)
         return jax.random.uniform(rng, shape, dtype, -a, a)
     if scheme == "normal":
         return jax.random.normal(rng, shape, dtype)

@@ -34,3 +34,28 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running test (multi-process cluster, "
         "large serde round-trips)")
+
+
+import pytest
+
+
+@pytest.fixture(autouse=True)
+def _executable_store_of_its_own(tmp_path_factory, monkeypatch):
+    """``compile_cache.enable()`` installs the executable store for the
+    process (the benchmark's harness tests call it).  Each test gets a
+    store directory of its own, and no store after it: tests patch the
+    package in memory (an updater that applies nothing), which no digest
+    of files sees, and a later test's nets would be served the patched
+    executable, or any executable, and count no compiles."""
+    from deeplearning4j_tpu.monitor import jit_watch
+    from deeplearning4j_tpu.serving import compile_cache
+    own = []
+
+    def executables_dir(cache_dir):
+        if not own:
+            own.append(str(tmp_path_factory.mktemp("executables")))
+        return own[0]
+
+    monkeypatch.setattr(compile_cache, "_executables_dir", executables_dir)
+    yield
+    jit_watch.set_executable_store(None)

@@ -613,3 +613,69 @@ def test_graph_transfer_pretrain_flag_and_shape_inference():
         MultiDataSet(mds.features,
                      [np.float64(np.eye(2)[rng.randint(0, 2, 16)])]))
     np.testing.assert_array_equal(np.asarray(new.params["ae"]["W"]), w)
+
+
+# ------------------------------------------------ init() as staged programs
+@pytest.mark.parametrize("precision", ["fp32", "mixed_bf16"])
+@pytest.mark.parametrize("scheme", ["xavier", "relu", "lecun_normal",
+                                    "uniform", "dist_normal",
+                                    "dist_normal_mean"])
+def test_graph_init_programs_equal_leaf_by_leaf_bit_for_bit(
+        scheme, precision, monkeypatch):
+    """Graph twin of the ``MultiLayerNetwork`` test (which runs every
+    scheme): convolution, batch-norm state and dense vertices, in
+    topological order, with updater state and masters."""
+    import jax
+    from test_multilayer import (_scheme_builder,
+                                 assert_trees_bit_identical)
+    from deeplearning4j_tpu.nn.layers.convolution import ConvolutionLayer
+    from deeplearning4j_tpu.nn.layers.normalization import (
+        BatchNormalization)
+    monkeypatch.setenv("DL4J_TPU_PRECISION", precision)
+    conf = (_scheme_builder(scheme, "nesterovs").graph_builder()
+            .add_inputs("in")
+            .add_layer("z_conv", ConvolutionLayer(
+                n_out=8, kernel_size=(3, 3)), "in")
+            .add_layer("bn", BatchNormalization(), "z_conv")
+            .add_layer("a_dense", DenseLayer(n_out=10), "bn")
+            .add_layer("out", OutputLayer(n_out=3), "a_dense")
+            .set_outputs("out")
+            .set_input_types(inputs.convolutional(8, 8, 3)).build())
+    g = ComputationGraph(conf).init()
+    assert g._pol().name == precision
+    # topological order, not the sorted order a jitted dict comes in
+    assert list(g.params) == g._layer_names() == list(g.updater_state)
+    assert list(g.params)[0] == "z_conv"
+    ref = g._init_program.__wrapped__(g._rng_key)
+    got = (g.params, g.net_state, g.updater_state)
+    for tree, want in zip(got, ref):
+        assert set(tree) == set(want)
+        for name in tree:
+            assert_trees_bit_identical(tree[name], want[name])
+    assert set(g.net_state["bn"]) == {"mean", "var"}
+
+
+def test_second_process_loads_graph_init_and_the_gather_step(tmp_path):
+    import second_process
+    first = second_process.run("cg", tmp_path)
+    for fn in second_process.PROGRAMS["cg"]:
+        assert first["results"][fn] == ["miss_absent", "written"]
+    second = second_process.run("cg", tmp_path)
+    for fn in second_process.PROGRAMS["cg"]:
+        assert second["results"][fn] == ["hit"]
+        assert second["trace_s"][fn] == 0 and second["lower_s"][fn] == 0
+        assert second["compiles"][fn] == 0
+        assert second["backend_s"][fn] == second["load_s"][fn] > 0
+    assert second["params"] == first["params"]
+    assert second["score"] == first["score"]
+    # the precision policy is part of the identity: other programs
+    third = second_process.run("cg", tmp_path,
+                               DL4J_TPU_PRECISION="mixed_bf16")
+    for fn in second_process.PROGRAMS["cg"]:
+        assert third["results"][fn][0] == "miss_absent"
+        assert third["compiles"][fn] == 1
+    # (the draws' program is the same HLO under either policy: JAX's own
+    # cache reloads it, and XLA:CPU cannot serialize that again)
+    assert third["results"]["cg.init"] == ["miss_absent", "written"]
+    assert third["results"]["cg.gather_train_step"] == [
+        "miss_absent", "written"]

@@ -2,6 +2,8 @@
 (analogue of reference deeplearning4j-core/src/test/.../nn/multilayer/
 MultiLayerTest.java and nn/conf serde tests)."""
 
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -427,3 +429,107 @@ def test_frozen_respected_by_solver_path():
     new.fit(ds, epochs=5)
     np.testing.assert_array_equal(np.asarray(new.params[0]["W"]), w_frozen)
     assert new.score(ds) < s0          # head still optimizes
+
+
+# ------------------------------------------------ init() as staged programs
+from deeplearning4j_tpu.nn.weights import Distribution
+
+INIT_SCHEMES = ["zero", "ones", "xavier", "xavier_uniform", "xavier_fan_in",
+                "xavier_legacy", "relu", "relu_uniform", "sigmoid_uniform",
+                "uniform", "lecun_normal", "lecun_uniform", "normal",
+                "identity"]
+INIT_DISTS = {"dist_normal": Distribution("normal", 0.0, 0.3),
+              "dist_normal_mean": Distribution("normal", 0.25, 0.3),
+              "dist_uniform": Distribution("uniform", lower=-0.2, upper=0.7),
+              "dist_binomial": Distribution("binomial", n_trials=5,
+                                            prob_success=0.3)}
+
+
+def _scheme_builder(scheme, updater):
+    b = (NeuralNetConfiguration.builder().seed(1234).updater(updater)
+         .learning_rate(0.1).activation("tanh"))
+    return b.dist(INIT_DISTS[scheme]) if scheme in INIT_DISTS \
+        else b.weight_init(scheme)
+
+
+def assert_trees_bit_identical(got, ref):
+    import jax
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("precision", ["fp32", "mixed_bf16"])
+@pytest.mark.parametrize("scheme", INIT_SCHEMES + sorted(INIT_DISTS))
+def test_init_programs_equal_leaf_by_leaf_bit_for_bit(scheme, precision,
+                                                      monkeypatch):
+    """``init()`` runs two staged programs; the values are the ones the
+    same Python gives leaf by leaf, one operation at a time."""
+    from deeplearning4j_tpu import monitor
+    monkeypatch.setenv("DL4J_TPU_PRECISION", precision)
+    conf = (_scheme_builder(scheme, "adam").list()
+            .layer(DenseLayer(n_out=12))
+            .layer(DenseLayer(n_out=12))        # square: identity fits
+            .layer(OutputLayer(n_out=12))
+            .set_input_type(inputs.feed_forward(12)).build())
+    before = monitor.snapshot().get("jit_compiles_total", {}).get(
+        "values", {}).get('{fn="mln.init"}', 0)
+    net = MultiLayerNetwork(conf).init()
+    assert net._pol().name == precision
+    ref = net._init_program.__wrapped__(net._rng_key)
+    assert_trees_bit_identical(
+        (net.params, net.net_state, net.updater_state), ref)
+    compiled = monitor.snapshot().get("jit_compiles_total", {}).get(
+        "values", {}).get('{fn="mln.init"}', 0) - before
+    # a normal distribution with a mean cannot be staged (its product
+    # and sum would fuse into one rounding), nor a binomial one (its
+    # constants fold differently on a TPU): they stay leaf by leaf
+    assert compiled == (0 if scheme in ("dist_normal_mean",
+                                        "dist_binomial") else 1)
+    if precision == "mixed_bf16":
+        assert net.params[0]["W"].dtype == jnp.bfloat16
+        master = net.updater_state[0]["_master"]["W"]
+        assert master.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(master), np.asarray(net.params[0]["W"],
+                                           np.float32))
+
+
+def test_second_process_loads_init_and_the_gather_step(tmp_path):
+    """The first process on a conf derives and writes; a second one
+    loads all three programs, traces nothing, and trains to the same
+    bits; another seed is the same program (the key is an argument)."""
+    import second_process
+    first = second_process.run("mln", tmp_path)
+    for fn in second_process.PROGRAMS["mln"]:
+        assert first["results"][fn] == ["miss_absent", "written"]
+        assert first["compiles"][fn] == 1
+    # under <cache dir>/executables, one whole entry a program
+    assert first["stored"]["entries"] == 3 and first["stored"]["bytes"] > 0
+    assert "executables" in os.listdir(tmp_path)
+    second = second_process.run("mln", tmp_path)
+    for fn in second_process.PROGRAMS["mln"]:
+        assert second["results"][fn] == ["hit"]
+        assert second["trace_s"][fn] == 0 and second["lower_s"][fn] == 0
+        assert second["compiles"][fn] == 0
+        assert second["backend_s"][fn] == second["load_s"][fn] > 0
+    assert second["params"] == first["params"]
+    assert second["score"] == first["score"]
+    third = second_process.run("mln", tmp_path, NET_SEED="8")
+    assert all(r == ["hit"] for r in third["results"].values())
+    assert third["params"] != first["params"]
+    # the health configuration is read while tracing: part of the
+    # identity, so another program under another key
+    fourth = second_process.run("mln", tmp_path,
+                                DL4J_TPU_HEALTH_POLICY="skip_update")
+    assert fourth["results"]["mln.gather_train_step"] == [
+        "miss_absent", "written"]
+    # an executable the backend will not serialize (here the CPU's, for
+    # the sort in a shuffled epoch) is compiled and run as ever, and
+    # nothing is written
+    fifth = second_process.run("mln", tmp_path, NET_SHUFFLE="1")
+    assert fifth["results"]["mln.gather_train_step"] == ["miss_absent"]
+    assert fifth["compiles"]["mln.gather_train_step"] == 1
+    assert fifth["results"]["mln.init"] == ["hit"]
+    assert np.isfinite(fifth["score"])

@@ -83,6 +83,55 @@ def test_recompile_before_end_warmup_is_free(armed):
     assert sanitizer.violation_count() == 0
 
 
+# ------------------------------------- a store hit is a cache hit
+
+def test_executable_store_hit_counts_as_cache_hit_not_compile(
+        armed, monkeypatch, tmp_path):
+    """A signature served by the executable store was loaded, not
+    derived: one dispatch against the scenario's budget like any other,
+    no compile counted, and after warmup no ``recompile_after_warmup``
+    (that contract is about shape churn that COMPILES)."""
+    from deeplearning4j_tpu.monitor import jit_watch
+    from deeplearning4j_tpu.serving.compile_cache import ExecutableStore
+    budgets = tmp_path / "budgets.json"
+    budgets.write_text(json.dumps(
+        {"t.store": {"max_dispatches_per_unit": 1}}))
+    monkeypatch.setenv("DL4J_TPU_SANITIZE_BUDGETS", str(budgets))
+
+    def fresh():                    # what a new process builds
+        jit_watch.set_executable_store(
+            ExecutableStore(str(tmp_path / "executables")))
+        return monitor.watched_jit(lambda x: x * 2 + 1, name="san_store",
+                                   donate_argnums=(0,), identity="v1")
+
+    writer = fresh()
+    writer(jnp.ones((2,), jnp.float32))
+    writer(jnp.ones((3,), jnp.float32))
+    assert monitor.counter(jit_watch.COMPILES_TOTAL, "").value(
+        fn="san_store") == 2        # two misses, compiled and written
+
+    loader = fresh()
+    with monitor.sanitize_scenario("t.store"):
+        loader(jnp.ones((2,), jnp.float32))     # warmup occurrence: hit
+    sanitizer.end_warmup()
+    with monitor.sanitize_scenario("t.store"):
+        x = jnp.ones((3,), jnp.float32)
+        out = loader(x)             # a NEW signature after warmup: hit
+    np.testing.assert_array_equal(out, 3.0)
+    assert x.is_deleted()           # the donation audit saw it consumed
+    assert sanitizer.violation_count() == 0
+    assert monitor.counter(jit_watch.COMPILES_TOTAL, "").value(
+        fn="san_store") == 2
+    assert monitor.counter(jit_watch.CACHE_HITS_TOTAL, "").value(
+        fn="san_store") == 2
+    assert monitor.counter(jit_watch.STORE_TOTAL, "").value(
+        fn="san_store", result="hit") == 2
+    with monitor.sanitize_scenario("t.store"):
+        loader(jnp.ones((3,), jnp.float32))
+        loader(jnp.ones((3,), jnp.float32))     # over budget all the same
+    assert _kinds() == ["dispatch_budget"]
+
+
 # ------------------------------------------ seeded over-budget dispatch
 
 def test_dispatch_budget_exceeded_is_caught(armed, monkeypatch,

@@ -236,3 +236,33 @@ def test_lars_network_trains():
         net.fit(DataSet(X, y))
     after = float(net.score(DataSet(X, y)))
     assert after < before * 0.7, (before, after)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "float32"])
+def test_masters_hold_exactly_what_the_parameters_hold(dtype):
+    """``init_state``'s fp32 masters: the parameter's own value, also
+    inside a program that made the parameter by rounding an fp32 value
+    (where XLA may keep the excess precision of a plain cast)."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn import precision, updaters
+    x = jax.random.normal(jax.random.PRNGKey(0), (64, 33), jnp.float32)
+    policy = precision._MIXED_POLICY
+
+    def masters(x):
+        params = {"W": x.astype(dtype), "b": jnp.zeros((33,), dtype)}
+        state = updaters.init_state(
+            updaters.UpdaterConfig(updater="nesterovs"), params,
+            policy=policy)
+        return params, state
+
+    for run in (masters, jax.jit(masters)):
+        params, state = run(x)
+        if dtype == "float32":
+            assert updaters.MASTER_KEY not in state
+            continue
+        master = state[updaters.MASTER_KEY]["W"]
+        assert master.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(master), np.asarray(params["W"]).astype(np.float32))
+        assert state["v"]["W"].dtype == jnp.float32
