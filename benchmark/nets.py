@@ -67,11 +67,14 @@ def _resolve(spec: str):
 
 def build_net(cfg: Dict, seed: int):
     """The configuration's container, its conf built by the builder the
-    file names and seeded from ``seed`` (the built conf's seed is set,
-    so a builder that pins one is not touched), initialised on the
-    device by the conf's own seeded init."""
+    file names, seeded from ``seed`` and trained at the file's
+    ``learning_rate`` (the one the reference reads), both set on the
+    built conf so that a builder that pins them is not touched (its
+    layers share the conf's one updater), initialised on the device by
+    the conf's own seeded init."""
     conf = _resolve(cfg["builder"])(**cfg.get("builder_args", {}))
     conf.conf.seed = int(seed)
+    conf.conf.updater.learning_rate = float(cfg["learning_rate"])
     return _resolve(cfg["container"])(conf).init()
 
 

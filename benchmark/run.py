@@ -9,8 +9,10 @@ reference, and prints ONE JSON object as the last line of stdout:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and,
 with ``--trace 1``, ``breakdown``.  ``--trace 0`` reports the cell's
 end-to-end metrics, ``--trace 1`` its per-layer metrics (a few seconds
-of the window run under ``jax.profiler``).  Earlier lines, prefixed
-``bench:``, carry what else is worth reading.
+of the window run under ``jax.profiler``; ``breakdown`` names the
+device's seconds by the program's scopes and its idle time by the
+program's spans, ``scopes.py``).  Earlier lines, prefixed ``bench:``,
+carry what else is worth reading.
 
 It finds everything by name (``BENCHMARK.json`` names the cell):
 ``workloads/<cell>.json`` -> ``configs/<config>.json``,
@@ -34,6 +36,7 @@ import argparse
 import contextlib
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
@@ -233,7 +236,7 @@ def main(argv: Optional[Sequence[str]] = None, *,
     record.setdefault("monitor_after", after)
     record.setdefault("spans", monitor.tracer().events())
     record["phase"] = monitor.phase_breakdown(since=before)
-    from benchmark import flops, xplane
+    from benchmark import flops, scopes, xplane
     if device["platform"] == "tpu":
         record["peaks"] = flops.chip_peaks(device["kind"])
     # the peak on the fullest chip: live buffers plus what the runtime
@@ -252,8 +255,9 @@ def main(argv: Optional[Sequence[str]] = None, *,
     breakdown = None
     if args.trace:
         t0 = time.perf_counter()
-        reduced = xplane.reduce_trace(trace_dir)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        path = xplane.find_trace(trace_dir)
+        traced = xplane.read_events(path) if path else None
+        reduced = xplane.reduce_events(traced) if traced else None
         if reduced is not None:
             record["trace"] = reduced
             device["busy_s"] = reduced["busy_s"]
@@ -265,17 +269,40 @@ def main(argv: Optional[Sequence[str]] = None, *,
                                         "busy_s_by_device", "idle_share",
                                         "idle_share_worst")}),
                 f"reduced in {time.perf_counter() - t0:.1f}s")
+            # the same seconds by the program's names: for readers and
+            # for ``breakdown``, for no number above
+            try:
+                named = scopes.view(path, traced)
+            except Exception as exc:
+                named = None
+                say(f"trace: the program's reduction by scope failed "
+                    f"({type(exc).__name__}: {exc})")
+            if named is None:
+                say("trace: no rows by scope; breakdown keeps the "
+                    "compiler's names")
+            else:
+                reduced.update(named, path=path)
+                breakdown = scopes.breakdown(reduced)
+                say("trace by scope", json.dumps({
+                    "rows": len(named["by_scope"]),
+                    "unscoped_ops": len(named["unscoped_ops"]),
+                    "unscoped_s": sum(r[1] for r in named["unscoped_ops"]),
+                    "reduce_s": named["by_scope_reduce_s"]}))
         else:
             say("trace: no device operation or no window span was read")
 
     group, kind = (("per_layer", "layer_metrics") if args.trace
                    else ("end_to_end", "end_to_end"))
     metrics = {}
-    for entry in _metric_entries(manifest, group, args.workload):
-        value = lookup.module(kind, entry["name"]).read(record)
-        if value is not None:
-            metrics[entry["name"]] = {"value": float(value),
-                                      "unit": entry["unit"]}
+    try:
+        for entry in _metric_entries(manifest, group, args.workload):
+            value = lookup.module(kind, entry["name"]).read(record)
+            if value is not None:
+                metrics[entry["name"]] = {"value": float(value),
+                                          "unit": entry["unit"]}
+    finally:
+        # a reader may open the trace itself (``record["trace"]["path"]``)
+        shutil.rmtree(trace_dir, ignore_errors=True)
     say("setup", json.dumps({
         "setup_s": setup_s, "cache_hits": events["hits"],
         "cache_misses": events["misses"],
@@ -289,6 +316,16 @@ def main(argv: Optional[Sequence[str]] = None, *,
               "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    if record.get("checks"):
+        # each number ``correct`` compared, beside its limit: last on
+        # stderr and last in the line, for whoever reads a refusal
+        # (a number that is not finite goes in as text: strict JSON)
+        result["checks"] = {
+            name: [value if math.isfinite(value) else str(value), limit]
+            for name, (value, limit) in record["checks"].items()}
+        for name, (value, limit) in record["checks"].items():
+            print(f"check {name} {value} limit {limit}", file=sys.stderr)
+        sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

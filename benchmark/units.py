@@ -79,7 +79,18 @@ def record(run, state, walls, items_per_unit, steps_per_unit,
     scores = state["scores"]
     window_scores = np.asarray(scores[len(scores) - len(walls):])
     dtype = nets.compute_dtype(state["net"])
+    finite = np.asarray(scores, np.float64)
+    finite = finite[np.isfinite(finite)]
+    checks = {f"{k}_rel_err": [state["errors"][k], f"<={limit}"]
+              for k, limit in nets.BOUNDS[dtype].items()
+              if k in state["errors"]}
+    checks["nonfinite_scores"] = [
+        int((~np.isfinite(window_scores)).sum()), "<=0"]
+    checks["score_range_rel"] = [
+        float(np.ptp(finite) / np.abs(finite).max()) if finite.size
+        else float("nan"), ">1e-06"]
     return {
+        "checks": checks,
         "correct": bool(nets.verdict(state["errors"], dtype)
                         and np.isfinite(window_scores).all()
                         and scores_move(scores)

@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
+MODULES_LINE = "XLA Modules"
 WINDOW_SPAN = "bench/window"
 SPAN_PREFIX = "bench/"
 COLLECTIVE = re.compile(
@@ -108,19 +109,27 @@ def leaves(ops: List[Tuple[str, float, float]]) -> List:
 
 def read_events(path: str) -> Dict:
     """``{"devices": {index: [(name, start_s, end_s)]}, "async":
-    {index: [...]}, "spans": [(name, start_s, end_s)]}`` from one
-    ``.xplane.pb``: the leaf device operations of every TPU plane, its
-    asynchronous operations while in flight, and the benchmark's host
-    spans."""
+    {index: [...]}, "modules": {index: [(start_s, end_s, name)]},
+    "spans": [(name, start_s, end_s)]}`` from one ``.xplane.pb``: the
+    leaf device operations of every TPU plane, its asynchronous
+    operations while in flight, the programs it ran (``scopes.py`` looks
+    an operation's name up in the one that contains it; no number of
+    this file reads them), and the benchmark's host spans."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices: Dict[int, List] = {}
     in_flight: Dict[int, List] = {}
+    modules: Dict[int, List] = {}
     spans: List = []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             for line in plane.lines:
+                if line.name == MODULES_LINE:
+                    modules.setdefault(int(m.group(1)), []).extend(
+                        (ev.start_ns * 1e-9,
+                         (ev.start_ns + ev.duration_ns) * 1e-9, ev.name)
+                        for ev in line.events)
                 if line.name not in (OPS_LINE, ASYNC_LINE):
                     continue
                 ops = []
@@ -140,7 +149,8 @@ def read_events(path: str) -> Dict:
                         s = ev.start_ns * 1e-9
                         spans.append((ev.name, s,
                                       s + ev.duration_ns * 1e-9))
-    return {"devices": devices, "async": in_flight, "spans": spans}
+    return {"devices": devices, "async": in_flight, "modules": modules,
+            "spans": spans}
 
 
 def _top(named_seconds: Dict[str, float], n: int) -> List[List]:

@@ -23,7 +23,15 @@ RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 def test_end_to_end_line(cell, expected, tmp_path):
     rc, result, _ = run_toy(cell, trace=0, out_dir=tmp_path)
     assert rc == 0
-    assert set(result) == RESULT_KEYS
+    assert set(result) - {"checks"} == RESULT_KEYS
+    if "fit" in cell or "dp" in cell:
+        # each number ``correct`` compared, beside its limit, comes last
+        assert list(result)[-1] == "checks"
+        assert set(result["checks"]) == {
+            "output_rel_err", "score_rel_err", "nonfinite_scores",
+            "score_range_rel"}
+        for value, limit in result["checks"].values():
+            assert isinstance(value, (int, float)) and limit[0] in "<>"
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert set(result["metrics"]) == expected

@@ -91,7 +91,14 @@ def _fit_scores(cfg, steps=4, seed=5, **wrong):
     return np.asarray(got), np.asarray(want)
 
 
-@pytest.mark.parametrize("make", [_toy_vgg, _toy_vgg_l2, _small_vgg16])
+def _toy_vgg_slower():
+    """The file's learning rate, not the builder's 0.01, is what both
+    sides train at (``nets.build_net`` sets it on the built conf)."""
+    return dict(_toy_vgg(), learning_rate=0.003)
+
+
+@pytest.mark.parametrize("make", [_toy_vgg, _toy_vgg_l2, _small_vgg16,
+                                  _toy_vgg_slower])
 def test_fit_follows_the_references_nesterov_steps(make):
     """The backward pass and the updater (learning rate, Nesterov
     momentum, l2 in the update) against the plain reference: four train

@@ -2,9 +2,8 @@
 on a record of a program that has no such counter (it reports nothing
 and raises nothing), and through ``benchmark.run.main`` on the toy
 ``fit`` cell, twice in one checkout's cache: the second run loads what
-the first wrote.  ``BENCHMARK.json`` has no line for it yet (PERF.md
-section 7 says which accepted test stands in the way), so the toy run
-brings a manifest of its own."""
+the first wrote.  ``BENCHMARK.json`` has its line since PR 29, appended
+after the accepted ones; the toy run brings a manifest of its own."""
 
 import io
 import json
@@ -13,7 +12,8 @@ from contextlib import redirect_stdout
 
 import pytest
 
-from benchtools import ROOT, TOY
+import benchtools
+from benchtools import ACCEPTED_PER_LAYER, FIT_CELLS, ROOT, TOY
 from benchmark import run
 
 BENCH = os.path.join(ROOT, "benchmark")
@@ -55,6 +55,19 @@ def test_reader_says_what_it_is():
     assert (reader.UNIT, reader.BETTER, reader.SOURCE, reader.LAYER) == \
         ("count", "higher", "program_counter", "compile cache")
     assert "``executable_store_total``" in reader.__doc__
+
+
+def test_manifest_entry_says_what_the_reader_says():
+    entries = benchtools.manifest()["per_layer"]
+    (entry,) = [e for e in entries if e["name"] == "executable_store_hits"]
+    reader = _reader()
+    assert (entry["unit"], entry["better"], entry["source"],
+            entry["layer"]) == (reader.UNIT, reader.BETTER, reader.SOURCE,
+                                reader.LAYER)
+    assert entry["moves"] == "setup_s"
+    assert set(entry["workloads"]) >= set(FIT_CELLS)
+    # appended: it comes after every accepted metric
+    assert entries.index(entry) >= len(ACCEPTED_PER_LAYER)
 
 
 def _run(tmp_path, manifest):
