@@ -1,0 +1,16 @@
+"""The latent attention over the ring (absorbed query, scores, softmax,
+latent context) as a share of its roofline: the least time the chip
+could take (the larger of bytes over the HBM peak and operations over
+the bf16 peak, ``record["kernels"]["mla_decode"]``, counted from shapes
+at the ring's capacity by ``families/<family>.py`` for the traced
+units) over the device seconds of the ``layer.<vertex>.latent_attention``
+scopes in the traced window.  Nothing to read is ``None``."""
+
+from benchmark import kernel_roofline
+
+LAYER = "step program"
+UNIT, BETTER, SOURCE = "%", "higher", "device_trace"
+
+
+def read(record):
+    return kernel_roofline.share(record, "mla_decode", ".latent_attention")

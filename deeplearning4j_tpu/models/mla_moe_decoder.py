@@ -1,0 +1,110 @@
+"""A decoder of latent-attention blocks with routed experts on a
+hyper-connected residual path, built on the ComputationGraph DSL.
+
+One builder for the family whose published ``config.json`` carries
+DeepSeek-V3's keys (``kv_lora_rank``, ``n_routed_experts``,
+``first_k_dense_replace``, ...) plus the hyper-connection keys
+(``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+``mhc_h_res_clamp_min/max``): the benchmark's ``xing4_29b_a4b``
+configuration is one such file, the CPU tests run a small one.
+
+Graph, per token: ``embed`` -> ``streams`` (``hc_mult`` copies) ->
+for each layer ``L<i>`` two sublayers, attention then feed-forward,
+each wrapped as ``_read`` (the streams' part the sublayer sees) ->
+``_norm`` -> the sublayer (``L<i>_attn``; ``L<i>_ffn`` dense for the
+first ``first_k_dense_replace`` layers, ``L<i>_moe`` after) ->
+``_write`` (streams mixed, the output added) -> ``stream_sum`` ->
+``final_norm`` -> ``head`` (float32 logits).  The multi-token-
+prediction module of the published model is not built: it is not part
+of the served forward pass.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, List, Optional
+
+from ..nn.conf.computation_graph import StreamExpandVertex, StreamSumVertex
+from ..nn.conf.neural_net_configuration import NeuralNetConfiguration
+from ..nn.layers.decoder import (GatedFeedForward, HyperConnectionRead,
+                                 HyperConnectionWrite, LatentAttention,
+                                 LMHead, MixtureOfExperts, RMSNorm,
+                                 TokenEmbedding)
+from ..nn.weights import Distribution
+
+
+def from_config(cfg: Dict, *, cache_len: int = 4096,
+                experts_held: Optional[List[int]] = None,
+                init_std: float = 0.02, hc_alpha_init: float = 0.01,
+                hc_bias_std: float = 0.0, router_bias_std: float = 0.0,
+                dtype: Optional[str] = None, seed: int = 0):
+    """The graph configuration for the published keys in ``cfg``.
+    ``cache_len`` is the latent ring's default capacity;
+    ``experts_held`` the experts every expert layer holds (default:
+    all); ``init_std`` the normal deviation of every matrix;
+    ``hc_alpha_init``, ``hc_bias_std`` and ``router_bias_std`` the
+    initial values the source does not give (a hyper-connection's
+    ``alpha`` and the deviation of its ``b``; the deviation of the
+    router's selection bias); ``dtype`` the parameter dtype (default:
+    the backend's policy)."""
+    b = (NeuralNetConfiguration.builder().seed(seed).updater("sgd")
+         .weight_init("distribution")
+         .dist(Distribution(kind="normal", std=float(init_std)))
+         .activation("identity"))
+    if dtype:
+        b = b.dtype(dtype)
+    g = b.graph_builder()
+    c, n = int(cfg["hidden_size"]), int(cfg["hc_mult"])
+    hc = dict(n_in=c, n_streams=n, eps=float(cfg["hc_eps"]),
+              alpha_init=hc_alpha_init, bias_std=hc_bias_std)
+
+    def sublayer(prefix: str, layer, stream: str, kind: str) -> str:
+        g.add_layer(f"{prefix}_read", HyperConnectionRead(**hc), stream)
+        g.add_layer(f"{prefix}_norm",
+                    RMSNorm(n_out=c, eps=float(cfg["rms_norm_eps"])),
+                    f"{prefix}_read")
+        name = f"{prefix.split('_')[0]}_{kind}"
+        g.add_layer(name, layer, f"{prefix}_norm")
+        g.add_layer(f"{prefix}_write", HyperConnectionWrite(
+            sinkhorn_iters=int(cfg["hc_sinkhorn_iters"]),
+            clamp_min=float(cfg["mhc_h_res_clamp_min"]),
+            clamp_max=float(cfg["mhc_h_res_clamp_max"]), **hc),
+            stream, name)
+        return f"{prefix}_write"
+
+    g.add_inputs("ids")
+    g.add_layer("embed", TokenEmbedding(n_in=int(cfg["vocab_size"]),
+                                        n_out=c), "ids")
+    g.add_vertex("streams", StreamExpandVertex(n_streams=n), "embed")
+    x = "streams"
+    for i in range(int(cfg["num_hidden_layers"])):
+        x = sublayer(f"L{i}_attn", LatentAttention(
+            n_in=c, n_out=c, n_heads=int(cfg["num_attention_heads"]),
+            q_rank=int(cfg["q_lora_rank"]), kv_rank=int(cfg["kv_lora_rank"]),
+            d_nope=int(cfg["qk_nope_head_dim"]),
+            d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
+            eps=float(cfg["rms_norm_eps"]),
+            rope_theta=float(cfg["rope_theta"]),
+            rope_scaling=cfg.get("rope_scaling"), cache_len=int(cache_len)),
+            x, "attn")
+        if i < int(cfg["first_k_dense_replace"]):
+            x = sublayer(f"L{i}_ffn", GatedFeedForward(
+                n_in=c, n_out=c, width=int(cfg["intermediate_size"])),
+                x, "ffn")
+        else:
+            x = sublayer(f"L{i}_ffn", MixtureOfExperts(
+                n_in=c, n_out=c, n_experts=int(cfg["n_routed_experts"]),
+                top_k=int(cfg["num_experts_per_tok"]),
+                width=int(cfg["moe_intermediate_size"]),
+                n_shared=int(cfg["n_shared_experts"]),
+                routed_scaling=float(cfg["routed_scaling_factor"]),
+                norm_topk=bool(cfg["norm_topk_prob"]),
+                router_bias_std=router_bias_std,
+                experts_held=experts_held), x, "moe")
+    g.add_vertex("stream_sum", StreamSumVertex(), x)
+    g.add_layer("final_norm", RMSNorm(n_out=c,
+                                      eps=float(cfg["rms_norm_eps"])),
+                "stream_sum")
+    g.add_layer("head", LMHead(n_in=c, n_out=int(cfg["vocab_size"])),
+                "final_norm")
+    g.set_outputs("head")
+    return g.build()

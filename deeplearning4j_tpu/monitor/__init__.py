@@ -49,7 +49,8 @@ from .alerts import (AlertEngine, Rule, default_rules,
                      status as alert_status)
 from . import attribution
 from .attribution import StepAttributor, breakdown as wall_breakdown
-from .device_trace import DeviceTrace, device_trace, parse_op_name, scope
+from .device_trace import (DeviceTrace, device_trace, parse_op_name, scope,
+                           subscope)
 from .jit_watch import (WatchedJit, program_identity,
                         publish_cost_analysis, watched_jit)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, registry)
@@ -69,7 +70,7 @@ __all__ = [
     "parse_traceparent", "phase_breakdown", "post_system_metrics",
     "program_identity", "prometheus_text", "publish_cost_analysis", "record_incident",
     "registry", "reset", "sanitize_end_warmup", "sanitize_scenario",
-    "scope", "snapshot", "span", "system_metrics_persistable",
+    "scope", "snapshot", "span", "subscope", "system_metrics_persistable",
     "trace_chrome_json", "trace_jsonl", "tracer", "wall_breakdown",
     "watched_jit",
 ]

@@ -53,10 +53,12 @@ with ``from deeplearning4j_tpu.monitor.device_trace import reduce``.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import glob
 import gzip
 import os
 import re
+import threading
 import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -83,14 +85,40 @@ _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 Interval = Tuple[float, float]
 
 
+_open_scopes = threading.local()
+
+
 def scope(group: str, name: Optional[str] = None):
     """``jax.named_scope`` of the grammar: ``<group>`` or
     ``<group>.<name>``, one path component."""
-    import jax
     if group not in GROUPS:
         raise ValueError(f"scope group {group!r} is not one of {GROUPS}")
-    label = group if name is None else f"{group}.{name}"
-    return jax.named_scope(label.replace("/", "_"))
+    return _named(
+        (group if name is None else f"{group}.{name}").replace("/", "_"))
+
+
+@contextlib.contextmanager
+def _named(label: str):
+    import jax
+    stack = _open_scopes.__dict__.setdefault("stack", [])
+    stack.append(label)
+    try:
+        with jax.named_scope(label):
+            yield
+    finally:
+        stack.pop()
+
+
+def subscope(part: str):
+    """A named part of the scope that is open on this thread:
+    ``<group>.<name>.<part>``, again one path component, so the
+    innermost rule of :func:`parse_op_name` gives the part a row of its
+    own (``layer.L3_moe.experts``).  A layer calls it without knowing
+    the name its container gave it; with no scope open it is the bare
+    ``layer.<part>``."""
+    stack = getattr(_open_scopes, "stack", None)
+    outer = stack[-1] if stack else "layer"
+    return scope(*f"{outer}.{part}".split(".", 1))
 
 
 def parse_op_name(op_name: str) -> Tuple[str, str]:

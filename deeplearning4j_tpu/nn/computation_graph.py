@@ -14,6 +14,7 @@ Multi-input/multi-output batches are :class:`MultiDataSet` pytrees.
 from __future__ import annotations
 
 import functools
+import json
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -24,6 +25,7 @@ import numpy as np
 from . import precision as _precision
 from . import updaters as _updaters
 from . import weights as _weights
+from .conf import serde
 from .. import monitor as _monitor
 from .conf.computation_graph import (ComputationGraphConfiguration,
                                      DuplicateToTimeSeriesVertex,
@@ -52,6 +54,17 @@ def _as_multi(data) -> MultiDataSet:
     raise TypeError(f"Expected DataSet/MultiDataSet, got {type(data)}")
 
 
+def _layer_init_programs(layer, dtype):
+    """``(init, hold, finish)`` for one layer: its parameters and state
+    from a key leaf by leaf, and the same as the two staged programs of
+    ``weights.staged``, jitted."""
+    def init(key):
+        return layer.init_params(key, dtype), layer.init_state(dtype)
+
+    hold, finish = _weights.staged(init)
+    return init, jax.jit(hold), jax.jit(finish)
+
+
 class ComputationGraph:
     """DAG network with named vertices (reference ``ComputationGraph``)."""
 
@@ -74,6 +87,7 @@ class ComputationGraph:
         self._rnn_carry_batch = -1
         self._decode_grow_cache: Dict[int, Any] = {}
         self._precision: Optional[_precision.PrecisionPolicy] = None
+        self._inference_only = False
 
     def _pol(self) -> _precision.PrecisionPolicy:
         """The precision policy, resolved once per network instance
@@ -98,20 +112,61 @@ class ComputationGraph:
         return Solver(self, algo)
 
     # ------------------------------------------------------------------ init
-    def init(self) -> "ComputationGraph":
+    def init(self, for_inference: bool = False) -> "ComputationGraph":
         """Initialize params/state from the seed's key, by the staged
-        programs of ``_init_program``."""
+        programs of ``_init_program``.
+
+        ``for_inference`` makes the net a served one: the same
+        parameters (the staged programs and leaf by leaf give the same
+        values) and nothing a ``fit`` would need beside them, so it
+        holds the parameter dtype's bytes a parameter.  The staged
+        programs hand every normal draw from one program to the next in
+        float32, with float32 masters and the updater's moments beside
+        them: some 14 bytes a parameter at the peak, which a net sized
+        to fill the chip in bf16 cannot pay.  Such a net is drawn leaf
+        by leaf (one leaf in float32 at a time) with an empty updater
+        state; ``fit`` refuses it."""
         if self._init_done:
             return self
         _precision.publish(self._pol())
         key = jax.random.PRNGKey(self.conf.conf.seed)
         self._rng_key = key
-        out = self._init_program(key)
+        self._inference_only = bool(for_inference)
+        out = (self._init_for_inference(key) if for_inference
+               else self._init_program(key))
         # a jitted program returns its dicts sorted: back to topo order
         self.params, self.net_state, self.updater_state = (
             {n: tree[n] for n in self._layer_names()} for tree in out)
         self._init_done = True
         return self
+
+    def _init_for_inference(self, key):
+        """Parameters and state, a layer at a time: each layer's own two
+        staged programs (the values of ``_init_program``; equal layers
+        share theirs), so that what is live beside the finished layers
+        is one layer's draws in float32, not the net's; leaf by leaf
+        where a scheme cannot be staged."""
+        dtype = jnp.dtype(self._pol().param_dtype)
+        names = self._layer_names()
+        keys = jax.random.split(key, max(len(names), 1))
+        programs, params, net_state = {}, {}, {}
+        for n, k in zip(names, keys):
+            layer = self.vertices[n].layer
+            same = json.dumps(serde.to_dict(layer), sort_keys=True,
+                              default=str)
+            if same not in programs:
+                programs[same] = _layer_init_programs(layer, dtype)
+            init, hold, finish = programs[same]
+            try:
+                params[n], net_state[n] = finish(k, hold(k))
+            except (jax.errors.JAXTypeError, _weights.NotStaged):
+                params[n], net_state[n] = init(k)
+            # dispatch runs ahead of the device and a program's outputs
+            # are allocated when it is enqueued: unwaited, every layer's
+            # float32 draws are live at once (16.05 of 16 GB on the v5e
+            # for a 9.6 GB net, PR 30)
+            jax.block_until_ready(params[n])
+        return params, net_state, {n: {} for n in names}
 
     @functools.cached_property
     def _init_program(self):
@@ -193,10 +248,13 @@ class ComputationGraph:
                 in_masks = [masks.get(i) for i in v.inputs]
                 mask = next((m for m in in_masks if m is not None), None)
                 if isinstance(v, LayerVertex):
-                    x = xs[0]
+                    layer = v.layer
+                    # a layer of several inputs (a hyper-connection's
+                    # write) takes them all, as a tuple
+                    x = (tuple(xs) if getattr(layer, "MULTI_INPUT", False)
+                         else xs[0])
                     if v.preprocessor is not None:
                         x = v.preprocessor(x)
-                    layer = v.layer
                     if preoutput_outputs and name in conf.network_outputs \
                             and hasattr(layer, "pre_output"):
                         if layer.dropout and train:
@@ -750,19 +808,88 @@ class ComputationGraph:
 
         return _monitor.watched_jit(run, name="cg.advance")
 
-    @functools.cached_property
-    def _decode_step_fn(self):
+    def _build_decode_step(self, donate: bool):
         """Autoregressive decode step: the ``cg.advance`` contract over
         generalized state trees (RNN carries AND KV-cache rings), under
         its own jit name so the serving sanitizer can budget
-        ``serving.decode_step`` separately (one dispatch per token)."""
+        ``serving.decode_step`` separately (one dispatch per token).
+        ``donate`` gives the carries up to the step, which then updates
+        the rings in place (a session's path: it keeps the new tree and
+        never looks at the old one)."""
         def run(params, net_state, carries, features):
             acts, _, new_carries = self._forward(
                 params, net_state, features, train=False, rng=None,
                 carries=carries)
             return ([acts[o] for o in self.conf.network_outputs],
                     new_carries)
-        return _monitor.watched_jit(run, name="cg.decode_step")
+        return _monitor.watched_jit(run, name="cg.decode_step",
+                                    donate_argnums=(2,) if donate else ())
+
+    @functools.cached_property
+    def _decode_step_fn(self):
+        return self._build_decode_step(donate=False)
+
+    @functools.cached_property
+    def _decode_step_donating_fn(self):
+        return self._build_decode_step(donate=True)
+
+    def _expert_vertices(self) -> List[str]:
+        """Layer vertices whose state counts tokens by expert."""
+        return [n for n in self._layer_names()
+                if "expert_tokens" in self.net_state.get(n, {})]
+
+    @functools.cached_property
+    def _token_step_fn(self):
+        """One step of token generation, sampling included: integer ids
+        (batch, time) in, through the graph over the session's state
+        tree, the greedy argmax of the last position's logits out as
+        (batch, 1) int32 on the device, to be the next step's input
+        with no fetch between.  Also out: the float32 logits of the
+        first and the last row at that position (computed anyway; what
+        a comparison with a reference reads), and ``counts`` plus this
+        step's tokens by expert, one row a vertex of
+        ``_expert_vertices``.  The carries and the counts are donated:
+        the rings are updated in place.  Like ``cg.prefill_step`` and
+        ``cg.fork_state`` it says what it closes over, so the executable
+        store serves a warm start with it."""
+        out_name = self.conf.network_outputs[0]
+
+        def run(params, net_state, carries, ids, counts):
+            acts, new_state, new_carries = self._forward(
+                params, net_state, (ids,), train=False, rng=None,
+                carries=carries)
+            last = acts[out_name][:, -1]
+            next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)[:, None]
+            kept = jnp.stack([last[0], last[-1]])
+            picks = [new_state[n]["expert_tokens"]
+                     for n in self._expert_vertices()]
+            if picks:
+                counts = counts + jnp.stack(picks)
+            return next_ids, kept, counts, new_carries
+
+        return _monitor.watched_jit(
+            run, name="cg.token_step", donate_argnums=(2, 4),
+            identity=lambda: _monitor.program_identity(self, "token_step"))
+
+    @functools.cached_property
+    def _prefill_step_fn(self):
+        """A chunk of prompt ids through the graph for the state tree
+        alone (donated): nothing reads the logits, so the compiler
+        drops the head."""
+        def run(params, net_state, carries, ids):
+            return self._forward(params, net_state, (ids,), train=False,
+                                 rng=None, carries=carries)[2]
+        return _monitor.watched_jit(
+            run, name="cg.prefill_step", donate_argnums=(2,),
+            identity=lambda: _monitor.program_identity(self, "prefill_step"))
+
+    @functools.cached_property
+    def _fork_state_fn(self):
+        """A device copy of a state tree, as one dispatch."""
+        return _monitor.watched_jit(
+            lambda carries: jax.tree.map(jnp.copy, carries),
+            name="cg.fork_state",
+            identity=lambda: _monitor.program_identity(self, "fork_state"))
 
     def _decode_grow_fn(self, cache_len: int):
         """Jitted state-tree growth to a larger KV ring capacity — ONE
@@ -992,6 +1119,11 @@ class ComputationGraph:
                 f"unknown ingest mode {ingest!r}; expected 'auto', "
                 "'cache', 'window', or 'batch'")
         self.init()
+        if self._inference_only:
+            raise ValueError(
+                "this net was initialised with init(for_inference=True): "
+                "it holds no updater state and no master weights, so it "
+                "cannot be trained")
         ckpt, start_step, epochs = self._resolve_resilience(
             checkpoint, resume_from, epochs)
         if labels is not None:
@@ -1299,7 +1431,7 @@ class ComputationGraph:
             carries, xs, None)
 
     def decode_step(self, carries, *features, params=None,
-                    net_state=None):
+                    net_state=None, donate: bool = False):
         """Autoregressive decode step: :meth:`rnn_stateless_step`
         generalized to arbitrary per-session state trees (RNN carries
         and KV-cache rings) under the ``cg.decode_step`` jit name.
@@ -1308,7 +1440,8 @@ class ComputationGraph:
         ``output()`` with the fp32-logits contract intact.  Inputs must
         be 3-D; ``carries=None`` starts a fresh state tree;
         ``params``/``net_state`` pin a weight version (same shapes →
-        jit cache hit)."""
+        jit cache hit); ``donate`` consumes ``carries`` (the rings are
+        updated in place; a second program beside the default one)."""
         self.init()
         self._require_carry_support("decode_step")
         # jit commits np inputs itself; an eager device_put per token
@@ -1322,10 +1455,49 @@ class ComputationGraph:
                     f"inputs, got shape {x.shape}")
         if carries is None:
             carries = self._init_carries(int(xs[0].shape[0]))
-        return self._decode_step_fn(
+        step = (self._decode_step_donating_fn if donate
+                else self._decode_step_fn)
+        return step(
             self.params if params is None else params,
             self.net_state if net_state is None else net_state,
             carries, xs)
+
+    def token_step(self, carries, ids, counts=None, params=None,
+                   net_state=None):
+        """One generation step of a token model (single integer input,
+        logits out): ``(next ids (batch, 1) int32, kept logits (2,
+        vocabulary) float32 of rows 0 and batch-1, tokens by expert,
+        new carries)``, all device arrays, ONE dispatch.  ``ids`` is
+        (batch, time); ``carries`` and ``counts`` are consumed (donated).
+        ``counts`` is ``(len(_expert_vertices()), n_experts)`` int32 or
+        None to start from zero."""
+        self.init()
+        if counts is None:
+            counts = self.zero_expert_counts()
+        return self._token_step_fn(
+            self.params if params is None else params,
+            self.net_state if net_state is None else net_state,
+            carries, ids, counts)
+
+    def zero_expert_counts(self):
+        rows = [self.net_state[n]["expert_tokens"].shape[0]
+                for n in self._expert_vertices()]
+        return jnp.zeros((len(rows), max(rows, default=0)), jnp.int32)
+
+    def prefill_step(self, carries, ids, params=None, net_state=None):
+        """Advance ``carries`` (consumed) over a (batch, time) chunk of
+        ids; returns the new carries only.  ONE dispatch."""
+        self.init()
+        return self._prefill_step_fn(
+            self.params if params is None else params,
+            self.net_state if net_state is None else net_state,
+            carries, ids)
+
+    def fork_carries(self, carries):
+        """A copy of a state tree on the device (ONE dispatch): what a
+        session forked from a prefilled prefix starts from, so that the
+        donating steps of the fork leave the original intact."""
+        return self._fork_state_fn(carries)
 
     def grow_decode_carries(self, carries, cache_len: int):
         """Pad every KV ring in ``carries`` up to ``cache_len`` slots

@@ -280,6 +280,33 @@ class DuplicateToTimeSeriesVertex(BaseVertex):
                                 (x.shape[0], timesteps, x.shape[1]))
 
 
+@serde.register("vertex_stream_expand")
+@dataclasses.dataclass
+class StreamExpandVertex(BaseVertex):
+    """(batch, time, f) -> (batch, time, n_streams, f): a residual path
+    of ``n_streams`` streams enters as that many copies of the
+    embedding (hyper-connections, ``nn/layers/decoder.py``)."""
+
+    n_streams: int = 4
+
+    def apply(self, *xs: Array, masks=None) -> Array:
+        x = xs[0]
+        return jnp.broadcast_to(
+            x[:, :, None, :], x.shape[:2] + (self.n_streams, x.shape[2]))
+
+
+@serde.register("vertex_stream_sum")
+@dataclasses.dataclass
+class StreamSumVertex(BaseVertex):
+    """(batch, time, n_streams, f) -> (batch, time, f): the streams
+    leave the residual path as their sum (taken in float32)."""
+
+    def apply(self, *xs: Array, masks=None) -> Array:
+        x = xs[0]
+        return jnp.sum(x, axis=2, dtype=jnp.promote_types(
+            x.dtype, jnp.float32)).astype(x.dtype)
+
+
 # ----------------------------------------------------------- configuration
 @serde.register("computation_graph_conf")
 @dataclasses.dataclass

@@ -10,6 +10,7 @@ from . import attention  # noqa: F401
 from . import base  # noqa: F401
 from . import convolution  # noqa: F401
 from . import core  # noqa: F401
+from . import decoder  # noqa: F401
 from . import normalization  # noqa: F401
 from . import pooling  # noqa: F401
 from . import pretrain  # noqa: F401

@@ -1,0 +1,549 @@
+"""Decoder-block layers: token embedding, RMS norm, gated feed-forward,
+latent attention over a compressed ring, a sigmoid-routed expert layer,
+manifold-constrained hyper-connections, and the language-model head.
+
+Everything here is plain ``jax.numpy`` under the container's
+``layer.<name>`` scopes; the parts a trace has to tell apart open a
+sub-scope (``monitor.subscope``: ``layer.<name>.experts``,
+``.latent_attention``, ``.router``, ``.shared``, ``.sinkhorn``).
+
+The equations are DeepSeek-V2/V3's for latent attention (MLA), routing
+and the expert layer, and those of "Manifold-Constrained
+Hyper-Connections" (arXiv:2512.24880) for the residual path; the plain
+reference the benchmark compares with is
+``benchmark/reference/mla_moe_decoder.py``, written apart from this
+file.
+
+Activations are (batch, time, features); the residual path of a
+hyper-connected model is (batch, time, streams, features).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ... import monitor as _monitor
+from ...ops.attention import (_einsum_acc, latent_ring_attention,
+                              latent_ring_update)
+from ..conf import inputs as _inputs
+from ..conf import serde
+from ..weights import Distribution, init_weights
+from .base import (Array, BaseLayerConfig, FeedForwardLayerConfig,
+                   ParamTree)
+from .recurrent import BaseRecurrentLayer
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _acc(dtype):
+    """float32 for the small float32 islands (norms, routing, stream
+    mixing); float64 stays float64 (the CPU parity tests)."""
+    return jnp.promote_types(dtype, jnp.float32)
+
+
+def rms_normalize(x: Array, eps: float, gain: Optional[Array] = None):
+    """``x / sqrt(mean(x^2) + eps)`` over the last axis, in float32,
+    times ``gain`` where given; the caller casts back."""
+    xf = x.astype(_acc(x.dtype))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y if gain is None else y * gain.astype(y.dtype)
+
+
+def _matrix(layer: BaseLayerConfig, rng, shape, dtype) -> Array:
+    return init_weights(rng, shape, layer.weight_init or "xavier",
+                        layer.dist, dtype)
+
+
+def _normal(rng, shape, std: float, dtype) -> Array:
+    if not std:
+        return jnp.zeros(shape, dtype)
+    return Distribution(kind="normal", std=float(std)).sample(
+        rng, shape, dtype)
+
+
+def _gated(x: Array, wg: Array, wu: Array, wd: Array) -> Array:
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+# ------------------------------------------------------------- embedding
+@serde.register("token_embedding")
+@dataclasses.dataclass
+class TokenEmbedding(FeedForwardLayerConfig):
+    """Integer ids (batch, time) or (batch, time, 1) to rows of a
+    (``n_in`` = vocabulary, ``n_out``) table."""
+
+    def param_order(self) -> tuple:
+        return ("W",)
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        return {"W": _matrix(self, rng, (self.n_in, self.n_out), dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        ids = x[..., 0] if x.ndim == 3 else x
+        return jnp.take(params["W"], ids.astype(jnp.int32), axis=0), state
+
+
+@serde.register("lm_head")
+@dataclasses.dataclass
+class LMHead(FeedForwardLayerConfig):
+    """Hidden states to float32 logits over the vocabulary; no bias, not
+    tied to the embedding."""
+
+    def param_order(self) -> tuple:
+        return ("W",)
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        return {"W": _matrix(self, rng, (self.n_in, self.n_out), dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return _einsum_acc("btc,cv->btv", x, params["W"],
+                           _acc(x.dtype)), state
+
+
+# ------------------------------------------------------------------ norm
+@serde.register("rms_norm")
+@dataclasses.dataclass
+class RMSNorm(BaseLayerConfig):
+    n_out: int = 0
+    eps: float = 1e-6
+
+    def output_type(self, input_type):
+        return input_type
+
+    def set_n_in(self, input_type) -> None:
+        if self.n_out <= 0:
+            self.n_out = input_type.flat_size()
+
+    def param_order(self) -> tuple:
+        return ("gain",)
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        return {"gain": jnp.ones((self.n_out,), dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return rms_normalize(x, self.eps, params["gain"]).astype(x.dtype), \
+            state
+
+
+# ---------------------------------------------------------- feed-forward
+@serde.register("gated_feed_forward")
+@dataclasses.dataclass
+class GatedFeedForward(FeedForwardLayerConfig):
+    """``(silu(x Wg) * x Wu) Wd`` with ``n_in`` = ``n_out`` = hidden and
+    ``width`` the inner size."""
+
+    width: int = 0
+
+    def param_order(self) -> tuple:
+        return ("Wg", "Wu", "Wd")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        kg, ku, kd = jax.random.split(rng, 3)
+        return {"Wg": _matrix(self, kg, (self.n_in, self.width), dtype),
+                "Wu": _matrix(self, ku, (self.n_in, self.width), dtype),
+                "Wd": _matrix(self, kd, (self.width, self.n_out), dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return _gated(x, params["Wg"], params["Wu"], params["Wd"]), state
+
+
+@serde.register("mixture_of_experts")
+@dataclasses.dataclass
+class MixtureOfExperts(FeedForwardLayerConfig):
+    """Sigmoid-routed experts with a selection bias and shared experts
+    (DeepSeek-V3's ``noaux_tc`` with one group): ``g = sigmoid(x Wr)``,
+    the ``top_k`` largest of ``g + bias`` are chosen, their weights are
+    ``g`` there, divided by their sum and times ``routed_scaling``;
+    ``y = sum_i w_i E_i(x) + E_shared(x)``.  No token is dropped.
+
+    ``experts_held`` (default: all) says which experts this layer holds:
+    it routes over all ``n_experts`` and computes the held experts' part
+    of the sum (the shared expert included), which is what one chip of
+    an expert-parallel deployment computes before the exchange.
+
+    The experts' matrices are stored side by side as plain matrices,
+    ``Wg``/``Wu`` (hidden, held * width) and ``Wd`` (held * width,
+    hidden), expert after expert, so that all held experts are three
+    plain matrix products (a third axis would have the TPU's compiler
+    treat the experts as a convolution's window); each token's unchosen
+    experts are weighted 0.  State
+    ``expert_tokens`` (``n_experts`` int32) counts the picks of the last
+    call, for ``moe_expert_tokens_total``.
+    """
+
+    n_experts: int = 8
+    top_k: int = 2
+    width: int = 0
+    n_shared: int = 1
+    routed_scaling: float = 1.0
+    norm_topk: bool = True
+    router_bias_std: float = 0.0
+    experts_held: Optional[List[int]] = None
+
+    def held(self) -> List[int]:
+        return (list(range(self.n_experts)) if self.experts_held is None
+                else [int(e) for e in self.experts_held])
+
+    def param_order(self) -> tuple:
+        return ("router", "router_bias", "Wg", "Wu", "Wd") + (
+            ("Sg", "Su", "Sd") if self.n_shared else ())
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        c, f, e = self.n_in, self.width, self.n_experts
+        kr, kb, kg, ku, kd, ksg, ksu, ksd = jax.random.split(rng, 8)
+
+        def experts(key, shape, axis):
+            # every expert is drawn, the held ones kept: a layer that
+            # holds a share has the same experts as one that holds all
+            w = _matrix(self, key, shape, dtype)
+            if self.experts_held is not None:
+                w = jnp.take(w, jnp.asarray(self.held(), jnp.int32), axis)
+            return w.reshape((c, -1) if axis else (-1, c))
+
+        p = {"router": _matrix(self, kr, (c, e), dtype),
+             "router_bias": _normal(kb, (e,), self.router_bias_std, dtype),
+             "Wg": experts(kg, (c, e, f), 1),
+             "Wu": experts(ku, (c, e, f), 1),
+             "Wd": experts(kd, (e, f, c), 0)}
+        if self.n_shared:
+            fs = f * self.n_shared
+            p.update(Sg=_matrix(self, ksg, (c, fs), dtype),
+                     Su=_matrix(self, ksu, (c, fs), dtype),
+                     Sd=_matrix(self, ksd, (fs, c), dtype))
+        return p
+
+    def init_state(self, dtype=jnp.float32):
+        return {"expert_tokens": jnp.zeros((self.n_experts,), jnp.int32)}
+
+    def route(self, params: ParamTree, x: Array):
+        """(tokens, top_k) expert indices and weights, in float32."""
+        acc = _acc(x.dtype)
+        g = jax.nn.sigmoid(jnp.matmul(
+            x.astype(acc), params["router"].astype(acc),
+            precision=_HIGHEST))
+        _, idx = jax.lax.top_k(g + params["router_bias"].astype(acc),
+                               self.top_k)
+        w = jnp.take_along_axis(g, idx, axis=-1)
+        if self.norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx, w * self.routed_scaling
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
+        with _monitor.subscope("router"):
+            idx, w = self.route(params, x)
+            held = jnp.asarray(self.held(), jnp.int32)
+            # (tokens, held): the weight of each held expert, 0 unchosen
+            combine = jnp.sum(
+                jnp.where(idx[:, :, None] == held[None, None, :],
+                          w[:, :, None], 0.0), axis=1)
+            counts = jnp.sum(
+                idx[:, :, None] == jnp.arange(self.n_experts)[None, None],
+                axis=(0, 1), dtype=jnp.int32)
+        with _monitor.subscope("experts"):
+            a = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
+            a = (a.reshape(x.shape[0], held.shape[0], self.width)
+                 * combine[:, :, None].astype(a.dtype))
+            y = a.reshape(x.shape[0], -1) @ params["Wd"]
+        if self.n_shared:
+            with _monitor.subscope("shared"):
+                y = y + _gated(x, params["Sg"], params["Su"], params["Sd"])
+        return y.reshape(shape), {"expert_tokens": counts}
+
+
+# -------------------------------------------------------------- attention
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """Rotary inverse frequencies (``dim // 2`` of them) and the factor
+    on cos/sin, with YaRN scaling as DeepSeek's code has it: a linear
+    ramp between interpolated (``/ factor``) and extrapolated
+    frequencies over the dimensions that turn ``beta_fast`` to
+    ``beta_slow`` times within the original context."""
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / (theta ** exponent)
+    if not scaling:
+        return extra, 1.0
+    factor = float(scaling["factor"])
+    original = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inv_freq = (extra / factor) * ramp + extra * (1.0 - ramp)
+    return inv_freq, (yarn_mscale(factor, scaling.get("mscale", 1))
+                      / yarn_mscale(factor, scaling.get("mscale_all_dim", 0)))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    if factor <= 1 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotate(x: Array, positions: Array, inv_freq: Array,
+           factor: float = 1.0) -> Array:
+    """Rotary embedding of the last axis of (batch, time, ..., dim) by
+    (time,) positions, pairs ``(2i, 2i+1)`` turned by
+    ``position * inv_freq[i]``; float32 inside."""
+    acc = _acc(x.dtype)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    extra = (1,) * (x.ndim - 3)
+    cos = cos.reshape((1, -1) + extra + (cos.shape[-1],)).astype(acc)
+    sin = sin.reshape(cos.shape).astype(acc)
+    pairs = x.astype(acc).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+@serde.register("latent_attention")
+@dataclasses.dataclass
+class LatentAttention(BaseRecurrentLayer):
+    """Multi-head latent attention (DeepSeek-V2's MLA) over a ring of
+    compressed rows.
+
+    ``c_q = norm(x Wqa)``, ``q = c_q Wqb`` (heads of ``d_nope + d_rope``);
+    ``[c_kv | k_r] = x Wkva``, ``c_kv = norm(c_kv)``, ``k_r`` rotated and
+    shared by all heads; keys and values are ``c_kv Wkvb`` (heads of
+    ``d_nope + d_v``).  The carry is ``(c_kv ring (batch, capacity,
+    kv_rank), k_r ring (batch, capacity, d_rope), cursor)``:
+    ``kv_rank + d_rope`` numbers a token, nothing per head.  One path
+    serves prefill chunks, single steps and ``output()`` (from a zero
+    ring): the key half of ``Wkvb`` is absorbed into the query, scores
+    and context are taken against the latent ring, and the value half
+    is applied to the latent context.
+    """
+
+    HAS_KV_RING = True
+    STATE_KIND = "latent"       # serving_session_state_bytes{kind=}
+
+    activation: str = "identity"
+    n_heads: int = 1
+    q_rank: int = 0
+    kv_rank: int = 0
+    d_nope: int = 0
+    d_rope: int = 0
+    d_v: int = 0
+    eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    cache_len: int = 128
+
+    def param_order(self) -> tuple:
+        return ("Wqa", "q_gain", "Wqb", "Wkva", "kv_gain", "Wkvb", "Wo")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        h, c = self.n_heads, self.n_in
+        ka, kb, kc, kd, ko = jax.random.split(rng, 5)
+        return {
+            "Wqa": _matrix(self, ka, (c, self.q_rank), dtype),
+            "q_gain": jnp.ones((self.q_rank,), dtype),
+            "Wqb": _matrix(self, kb, (self.q_rank,
+                                      h * (self.d_nope + self.d_rope)), dtype),
+            "Wkva": _matrix(self, kc, (c, self.kv_rank + self.d_rope), dtype),
+            "kv_gain": jnp.ones((self.kv_rank,), dtype),
+            "Wkvb": _matrix(self, kd, (self.kv_rank,
+                                       h * (self.d_nope + self.d_v)), dtype),
+            "Wo": _matrix(self, ko, (h * self.d_v, self.n_out), dtype),
+        }
+
+    def sm_scale(self) -> float:
+        scale = (self.d_nope + self.d_rope) ** -0.5
+        s = self.rope_scaling
+        if s and s.get("mscale_all_dim", 0):
+            scale *= yarn_mscale(float(s["factor"]), s["mscale_all_dim"]) ** 2
+        return scale
+
+    # -------------------------------------------------------------- carry
+    def init_carry(self, batch: int, dtype, cache_len: Optional[int] = None):
+        cap = int(cache_len if cache_len is not None else self.cache_len)
+        if cap < 1:
+            raise ValueError("cache_len must be >= 1")
+        return (jnp.zeros((batch, cap, self.kv_rank), dtype),
+                jnp.zeros((batch, cap, self.d_rope), dtype),
+                jnp.zeros((), jnp.int32))
+
+    def grow_carry(self, carry, cache_len: int):
+        c_ring, r_ring, cursor = carry
+        cap = c_ring.shape[1]
+        if cache_len < cap:
+            raise ValueError(
+                f"cannot shrink the latent ring from {cap} to {cache_len}")
+        pad = [(0, 0), (0, cache_len - cap), (0, 0)]
+        return jnp.pad(c_ring, pad), jnp.pad(r_ring, pad), cursor
+
+    # ------------------------------------------------------------ forward
+    def compress(self, params: ParamTree, x: Array, turn):
+        """What the cache holds of ``x``: (normed ``c_kv``, ``k_r``
+        rotated by ``turn``)."""
+        kv = x @ params["Wkva"]
+        c_kv = rms_normalize(kv[..., :self.kv_rank], self.eps,
+                             params["kv_gain"]).astype(x.dtype)
+        return c_kv, turn(kv[..., self.kv_rank:])
+
+    def queries(self, params: ParamTree, x: Array, turn):
+        """((batch, time, heads, d_nope), (.., d_rope) rotated by
+        ``turn``)."""
+        c_q = rms_normalize(x @ params["Wqa"], self.eps,
+                            params["q_gain"]).astype(x.dtype)
+        q = (c_q @ params["Wqb"]).reshape(
+            x.shape[:2] + (self.n_heads, self.d_nope + self.d_rope))
+        return q[..., :self.d_nope], turn(q[..., self.d_nope:])
+
+    def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
+        c_ring, r_ring, cursor = carry
+        t, cap = x.shape[1], c_ring.shape[1]
+        if t > cap:
+            raise ValueError(f"chunk of {t} timesteps exceeds the latent "
+                             f"ring's capacity {cap}")
+        positions = cursor + jnp.arange(t, dtype=jnp.int32)
+        inv_freq, factor = yarn_inv_freq(self.d_rope, self.rope_theta,
+                                         self.rope_scaling)
+        turn = lambda a: rotate(a, positions, inv_freq, factor)
+        q_nope, q_rope = self.queries(params, x, turn)
+        c_ring, r_ring = latent_ring_update(
+            c_ring, r_ring, cursor, *self.compress(params, x, turn))
+        wkvb = params["Wkvb"].reshape(self.kv_rank, self.n_heads,
+                                      self.d_nope + self.d_v)
+        with _monitor.subscope("latent_attention"):
+            q_lat = jnp.einsum("bthd,rhd->bthr", q_nope,
+                               wkvb[..., :self.d_nope])
+            ctx = latent_ring_attention(q_lat, q_rope, c_ring, r_ring,
+                                        cursor, sm_scale=self.sm_scale())
+        out = jnp.einsum("bthr,rhd->bthd", ctx, wkvb[..., self.d_nope:])
+        out = self._activate(
+            out.reshape(x.shape[:2] + (-1,)) @ params["Wo"])
+        if mask is not None:
+            out = out * mask[..., None].astype(out.dtype)
+        return out, (c_ring, r_ring, cursor + jnp.asarray(t, jnp.int32))
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        out, _ = self.forward_seq(
+            params, x, self.init_carry(x.shape[0], x.dtype, x.shape[1]),
+            train=train, rng=rng, mask=mask)
+        return out, state
+
+
+# ------------------------------------------------------ hyper-connections
+def sinkhorn(m: Array, iters: int, eps: float) -> Array:
+    """``iters`` rounds of rows, then columns, divided by their sums
+    (+ ``eps``) over the last two axes: towards doubly stochastic."""
+    for _ in range(int(iters)):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+@dataclasses.dataclass
+class _HyperConnection(BaseLayerConfig):
+    """What the two halves of one hyper-connection share: ``n_streams``
+    streams of width ``n_in``, and the per-token coefficients
+    ``alpha * (norm(vec X) phi) + b`` (``norm`` an RMS norm over all
+    ``n_streams * n_in`` numbers, eps ``eps``, no gain)."""
+
+    n_in: int = 0
+    n_streams: int = 4
+    eps: float = 1e-6
+    alpha_init: float = 0.01
+    bias_std: float = 0.0
+
+    def output_type(self, input_type):
+        return _inputs.recurrent(self.n_in)
+
+    def _coefficients(self, params, flat, which: str, shape):
+        acc = flat.dtype
+        h = jnp.matmul(flat, params[f"phi_{which}"].astype(acc),
+                       precision=_HIGHEST)
+        return (params[f"alpha_{which}"].astype(acc) * h.reshape(
+            flat.shape[:-1] + shape) + params[f"b_{which}"].astype(acc))
+
+    def _init(self, rng, dtype, shapes: dict) -> ParamTree:
+        p = {}
+        for (which, shape), k in zip(shapes.items(),
+                                     jax.random.split(rng, len(shapes))):
+            kp, kb = jax.random.split(k)
+            n = math.prod(shape)
+            p[f"phi_{which}"] = _matrix(
+                self, kp, (self.n_streams * self.n_in, n), dtype)
+            p[f"alpha_{which}"] = jnp.full((1,), self.alpha_init, dtype)
+            p[f"b_{which}"] = _normal(kb, shape, self.bias_std, dtype)
+        return p
+
+
+@serde.register("hyper_connection_read")
+@dataclasses.dataclass
+class HyperConnectionRead(_HyperConnection):
+    """The stream's part a sublayer reads: ``u = H_pre X`` with
+    ``H_pre = sigmoid(coefficients)``; (batch, time, streams, n_in) to
+    (batch, time, n_in)."""
+
+    def param_order(self) -> tuple:
+        return ("phi_pre", "alpha_pre", "b_pre")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        return self._init(rng, dtype, {"pre": (self.n_streams,)})
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        flat = rms_normalize(x.reshape(x.shape[:2] + (-1,)), self.eps)
+        h_pre = jax.nn.sigmoid(self._coefficients(
+            params, flat, "pre", (self.n_streams,)))
+        u = jnp.einsum("btn,btnc->btc", h_pre, x.astype(flat.dtype))
+        return u.astype(x.dtype), state
+
+
+@serde.register("hyper_connection_write")
+@dataclasses.dataclass
+class HyperConnectionWrite(_HyperConnection):
+    """The stream after a sublayer: ``X' = H_res X + H_post^T y`` with
+    ``H_post = 2 sigmoid(.)`` and ``H_res = sinkhorn(exp(clip(.)))``,
+    doubly stochastic.  Takes two inputs, the stream and the sublayer's
+    output."""
+
+    MULTI_INPUT = True
+
+    sinkhorn_iters: int = 20
+    clamp_min: float = -30.0
+    clamp_max: float = 30.0
+
+    def param_order(self) -> tuple:
+        return ("phi_post", "alpha_post", "b_post",
+                "phi_res", "alpha_res", "b_res")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        n = self.n_streams
+        return self._init(rng, dtype, {"post": (n,), "res": (n, n)})
+
+    def mixing(self, params, x: Array):
+        """(H_post (batch, time, streams), H_res (.., streams, streams))
+        of the stream ``x``, in float32."""
+        n = self.n_streams
+        flat = rms_normalize(x.reshape(x.shape[:2] + (-1,)), self.eps)
+        h_post = 2.0 * jax.nn.sigmoid(self._coefficients(
+            params, flat, "post", (n,)))
+        with _monitor.subscope("sinkhorn"):
+            h_res = sinkhorn(jnp.exp(jnp.clip(
+                self._coefficients(params, flat, "res", (n, n)),
+                self.clamp_min, self.clamp_max)),
+                self.sinkhorn_iters, self.eps)
+        return h_post, h_res
+
+    def forward(self, params, state, xs, *, train, rng=None, mask=None):
+        x, y = xs
+        h_post, h_res = self.mixing(params, x)
+        acc = h_res.dtype
+        out = (jnp.einsum("btij,btjc->btic", h_res, x.astype(acc))
+               + h_post[..., None] * y.astype(acc)[:, :, None, :])
+        return out.astype(x.dtype), state

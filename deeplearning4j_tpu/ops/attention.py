@@ -676,3 +676,60 @@ def kv_ring_attention(q: Array, k_cache: Array, v_cache: Array, cursor, *,
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     ctx = jnp.einsum("bhtc,bhcd->bthd", p, v_cache.astype(acc))
     return ctx.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) ring: the cache holds one compressed row a token, shared by
+# all heads, and decode attends in that latent space.
+# ---------------------------------------------------------------------------
+
+def latent_ring_update(c_ring: Array, r_ring: Array, cursor,
+                       c_new: Array, r_new: Array):
+    """Write (batch, T, rank) compressed keys/values and (batch, T, d_rope)
+    rotary keys into their rings at the cursor (``kv_ring_update`` for a
+    cache with no head axis).  Callers guarantee ``cursor + T <=
+    capacity``."""
+    zero = jnp.zeros((), jnp.int32)
+    cursor = jnp.asarray(cursor, jnp.int32)
+    c_ring = jax.lax.dynamic_update_slice(
+        c_ring, c_new.astype(c_ring.dtype), (zero, cursor, zero))
+    r_ring = jax.lax.dynamic_update_slice(
+        r_ring, r_new.astype(r_ring.dtype), (zero, cursor, zero))
+    return c_ring, r_ring
+
+
+def _einsum_acc(spec: str, a: Array, b: Array, acc) -> Array:
+    """``einsum`` of two arrays of one storage dtype, accumulated and
+    returned in ``acc``.  XLA:CPU's runtime (jax 0.9.0) lacks some
+    bf16 x bf16 -> f32 products, so there the operands are widened
+    first: the same numbers."""
+    if jax.default_backend() == "cpu":
+        a, b = a.astype(acc), b.astype(acc)
+    return jnp.einsum(spec, a, b, preferred_element_type=acc)
+
+
+def latent_ring_attention(q_lat: Array, q_rope: Array, c_ring: Array,
+                          r_ring: Array, cursor, *,
+                          sm_scale: float) -> Array:
+    """Dense masked attention in the latent space: (batch, T, heads, rank)
+    queries (the ``kv_b`` key half already absorbed) and (batch, T, heads,
+    d_rope) rotary queries against a (batch, capacity, rank) latent ring
+    and a (batch, capacity, d_rope) rotary-key ring that every head
+    shares.  Slot ``c`` is visible to query ``t`` iff ``c <= cursor + t``.
+    Scores and softmax in float32 (float64 under float64 inputs); the
+    latent context (batch, T, heads, rank) comes back in the query
+    dtype, to be taken through the value half of ``kv_b`` by the
+    caller."""
+    acc = jnp.promote_types(q_lat.dtype, jnp.float32)
+    cap, t = c_ring.shape[1], q_lat.shape[1]
+    cursor = jnp.asarray(cursor, jnp.int32)
+    s = (_einsum_acc("bthr,bcr->bhtc", q_lat, c_ring, acc)
+         + _einsum_acc("bthd,bcd->bhtc", q_rope, r_ring, acc))
+    s = s * jnp.asarray(sm_scale, acc)
+    valid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+             <= cursor + jnp.arange(t, dtype=jnp.int32)[:, None])
+    s = jnp.where(valid[None, None], s, jnp.asarray(_NEG_INF, acc))
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    ctx = _einsum_acc("bhtc,bcr->bthr", p.astype(c_ring.dtype), c_ring, acc)
+    return ctx.astype(q_lat.dtype)

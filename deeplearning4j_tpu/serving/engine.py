@@ -593,6 +593,46 @@ class InferenceEngine:
                               tenant=tenant)
         return out
 
+    # ------------------------------------------------- token generation
+    def prefill_session(self, session_id: str, ids,
+                        chunk: Optional[int] = None,
+                        cache_len: Optional[int] = None) -> int:
+        """Put a prompt's ids (batch, tokens) into ``session_id``'s
+        state, ``chunk`` tokens a dispatch, computing no logits; a new
+        session's rings get ``cache_len`` slots.  Returns the session's
+        position (``SessionCache.prefill``)."""
+        if not self._running:
+            raise ServingError("engine not started (call start())")
+        return self.sessions.prefill(session_id, ids, chunk=chunk,
+                                     cache_len=cache_len)
+
+    def fork_session(self, src: str, dst: str) -> None:
+        """``dst`` becomes a copy of ``src``'s device state, version and
+        position: a conversation that continues a prefilled prefix
+        (``SessionCache.fork``)."""
+        self.sessions.fork(src, dst)
+
+    def generate(self, session_id: str, ids, max_new_tokens: int,
+                 tenant: Optional[str] = None):
+        """Greedy token generation in ``session_id``: integer ``ids``
+        (batch, tokens not yet seen) in, ``max_new_tokens`` sampled ids
+        a row out (``.ids``, on the host), sampled on the device and fed
+        back there, one dispatch a token (``SessionCache.generate``).
+        Admitted like ``predict_session``; the latency observed is the
+        whole call's."""
+        if not self._running:
+            raise ServingError("engine not started (call start())")
+        tenant = self._admit_or_shed(tenant)
+        t0 = time.perf_counter()
+        out = self.sessions.generate(session_id, ids, max_new_tokens)
+        _monitor.counter("serving_requests_total",
+                         "requests admitted to the serving queue").inc(
+            engine=self._name)
+        self._observe_latency((time.perf_counter() - t0) * 1000.0,
+                              _monitor.current_trace_hex(),
+                              tenant=tenant)
+        return out
+
     # ------------------------------------------------------------- warmup
     def warmup(self, example_shape) -> int:
         """Eagerly AOT-compile every bucket executable on every worker.
@@ -687,7 +727,10 @@ class InferenceEngine:
                                       feats if self._is_graph
                                       else feats[0])
                     elif self._is_graph:
-                        model.decode_step(carries, *feats)
+                        # the sessions' program: it donates its carries
+                        model.decode_step(
+                            model._init_carries(bb, cache_len=cap), *feats,
+                            donate=True)
                     else:
                         model.decode_step(carries, feats[0])
                     if i + 1 < len(ladder):

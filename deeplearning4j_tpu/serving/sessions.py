@@ -63,6 +63,7 @@ request) fully recovers the session slot.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from collections import OrderedDict
@@ -103,6 +104,28 @@ def _tree_nbytes(tree) -> int:
         itemsize = np.dtype(getattr(leaf, "dtype", np.float32)).itemsize
         total += size * itemsize
     return total
+
+
+def _as_features(f, dtype) -> np.ndarray:
+    """Features as a host array in ``dtype``; an array of integers
+    (token ids) keeps its own."""
+    if getattr(getattr(f, "dtype", None), "kind", "") in "iu":
+        return np.asarray(f)
+    return np.asarray(f, dtype=dtype)
+
+
+@dataclasses.dataclass
+class Generation:
+    """What :meth:`SessionCache.generate` returns.  ``ids`` is on the
+    host, (batch, new tokens); ``kept_logits`` stays on the device, one
+    (2, vocabulary) float32 array a step (rows 0 and batch-1), for
+    whoever compares them with a reference afterwards;
+    ``expert_tokens`` is ``{vertex: (n_experts,) picks}`` over the
+    call's steps."""
+
+    ids: np.ndarray
+    kept_logits: list
+    expert_tokens: dict
 
 
 class _Session:
@@ -167,6 +190,11 @@ class SessionCache:
         # per-token budget and ladder their ring capacity
         self._decode = bool(getattr(model, "has_kv_ring",
                                     lambda: False)())
+        # rings of compressed rows (latent attention), not of per-head
+        # keys and values: the second kind of decode state
+        self._latent = self._decode and self._is_graph and any(
+            getattr(model.vertices[n].layer, "STATE_KIND", "") == "latent"
+            for n in model._layer_names())
         self._scenario = ("serving.decode_step" if self._decode
                           else "serving.rnn_step")
         self._cache_ladder = (batch_ladder(model.max_cache_len())
@@ -188,6 +216,10 @@ class SessionCache:
             "(RNN carries + KV-cache rings)").set(
             sum(s.state_bytes for s in self._sessions.values()),
             model=self._name)
+        if self._latent:
+            _monitor.gauge("serving_session_state_bytes", "").set(
+                sum(s.state_bytes for s in self._sessions.values()),
+                model=self._name, kind="latent")
         if self._version_fn is not None:
             active = self._version_fn()
             pinned = sum(1 for s in self._sessions.values()
@@ -274,7 +306,7 @@ class SessionCache:
         if self._is_graph:
             feats = (tuple(features) if isinstance(features, (list, tuple))
                      else (features,))
-            arrays = tuple(np.asarray(f, dtype=dtype) for f in feats)
+            arrays = tuple(_as_features(f, dtype) for f in feats)
             batch = int(arrays[0].shape[0])
             squeeze = arrays[0].ndim == 2
             if squeeze:   # (batch, feat) = one timestep
@@ -282,7 +314,7 @@ class SessionCache:
                                for a in arrays)
             steps = int(arrays[0].shape[1])
         else:
-            x = np.asarray(features, dtype=dtype)
+            x = _as_features(features, dtype)
             batch = int(x.shape[0])
             squeeze = x.ndim == 2
             if squeeze:   # (batch, feat) = one timestep
@@ -293,11 +325,7 @@ class SessionCache:
             self._check_state(session_id, sess, batch)
             # Version pinning: a session created before a weight swap
             # keeps stepping with the version its carries came from.
-            kw = {}
-            if self._weights_fn is not None and sess.version is not None:
-                w = self._weights_fn(sess.version)
-                if w is not None:
-                    kw = {"params": w[0], "net_state": w[1]}
+            kw = self._pinned_weights(sess)
             grow_to = 0
             if self._decode:
                 grow_to = self._bucket_for(session_id, sess, steps)
@@ -346,8 +374,10 @@ class SessionCache:
                     return self._step_fn(sess.carries, features, **kw)
             elif self._decode:
                 if self._is_graph:
+                    # the session keeps the new tree only: the step may
+                    # update the rings in place
                     outs, new = self._model.decode_step(
-                        sess.carries, *features, **kw)
+                        sess.carries, *features, donate=True, **kw)
                 else:
                     return self._model.decode_step(sess.carries, features,
                                                    **kw)
@@ -385,8 +415,156 @@ class SessionCache:
             f"{self._cache_ladder[-1] if self._cache_ladder else 0} — "
             "clear() the session or raise the layer's cache_len")
 
+    # ----------------------------------------------------- token models
+    def _require_tokens(self) -> None:
+        if not (self._decode and self._is_graph
+                and len(self._model.conf.network_inputs) == 1
+                and len(self._model.conf.network_outputs) == 1):
+            raise SessionError(
+                "token generation needs a graph with one input of ids, "
+                "one output of logits and ring-carrying attention")
+
+    def _pinned_weights(self, sess: _Session) -> dict:
+        if self._weights_fn is not None and sess.version is not None:
+            w = self._weights_fn(sess.version)
+            if w is not None:
+                return {"params": w[0], "net_state": w[1]}
+        return {}
+
+    def prefill(self, session_id: str, ids, chunk: Optional[int] = None,
+                cache_len: Optional[int] = None) -> int:
+        """Advance ``session_id`` over the prompt ``ids`` (batch, tokens)
+        for its state alone, no logits: ``chunk`` tokens a dispatch (the
+        remainder first, so that every later chunk has one shape), each
+        under the ``serve/prefill_chunk`` span.  A new session gets a
+        ring of ``cache_len`` slots (default: the model's) and keeps it;
+        one that would outgrow its ring raises.  Returns the session's
+        position."""
+        self._require_tokens()
+        ids = np.asarray(ids)
+        if ids.ndim != 2 or ids.dtype.kind not in "iu":
+            raise SessionError("prefill wants integer ids (batch, tokens)")
+        ids = ids.astype(np.int32)
+        batch, total = int(ids.shape[0]), int(ids.shape[1])
+        chunk = int(chunk or total or 1)
+        sess = self._acquire(session_id, batch, total,
+                             capacity=int(cache_len
+                                          or self._cache_ladder[-1]))
+        with sess.lock:
+            self._check_state(session_id, sess, batch)
+            if sess.position + total > sess.capacity:
+                raise SessionError(
+                    f"session {session_id!r} holds {sess.position} tokens "
+                    f"in a ring of {sess.capacity}; {total} more do not fit")
+            kw = self._pinned_weights(sess)
+            first = total % chunk
+            bounds = ([0, first] if first else [0]) + list(
+                range(first + chunk, total + 1, chunk))
+            with _monitor.sanitize_scenario(self._scenario,
+                                            units=max(len(bounds) - 1, 1)):
+                for lo, hi in zip(bounds, bounds[1:]):
+                    with _monitor.span("serve/prefill_chunk",
+                                       tokens=hi - lo):
+                        sess.carries = self._model.prefill_step(
+                            sess.carries, ids[:, lo:hi], **kw)
+            sess.position += total
+            sess.steps += 1
+            sess.last_used = time.monotonic()
+            return sess.position
+
+    def fork(self, src: str, dst: str) -> None:
+        """Make ``dst`` a copy of ``src`` as it stands: a device copy of
+        the state tree (one dispatch, under ``serve/fork``), with its
+        weight version and position.  What a conversation that shares a
+        prefilled prefix starts from; the donating steps of ``dst`` leave
+        ``src`` intact.  An existing ``dst`` is dropped first, so that
+        its bytes are free for the copy."""
+        with self._lock:
+            self._sweep_locked(time.monotonic())
+            source = self._sessions.get(src)
+            if source is not None and dst != src:
+                self._sessions.pop(dst, None)
+        if source is None:
+            raise SessionError(f"no session {src!r} to fork")
+        with source.lock, _monitor.span("serve/fork"):
+            copy = _Session(self._model.fork_carries(source.carries),
+                            source.batch, source.version, source.capacity)
+            copy.position = source.position
+            source.last_used = time.monotonic()
+        with self._lock:
+            while len(self._sessions) >= self._max_sessions:
+                self._sessions.popitem(last=False)
+                self._count_eviction("capacity")
+            self._sessions[dst] = copy
+            self._observe_active()
+
+    def generate(self, session_id: str, ids,
+                 max_new_tokens: int) -> Generation:
+        """Greedy generation: feed ``ids`` (batch, tokens; the tokens
+        the session has not seen yet, at least one), then each step's
+        argmax, ``max_new_tokens`` times.  One dispatch a token
+        (``cg.token_step``, under ``serve/decode_step`` and the
+        ``serving.decode_step`` budget); the sampled ids go from step to
+        step as device arrays, and the host's copy of step ``t`` is taken
+        after step ``t + 1`` is dispatched (``serve/token_fetch``), so the
+        device never waits for the host."""
+        self._require_tokens()
+        ids = np.asarray(ids)
+        if ids.ndim != 2 or ids.dtype.kind not in "iu" or not ids.shape[1]:
+            raise SessionError("generate wants integer ids (batch, tokens)")
+        n = int(max_new_tokens)
+        if n < 1:
+            raise SessionError("max_new_tokens must be at least 1")
+        batch, fed = int(ids.shape[0]), int(ids.shape[1])
+        sess = self._acquire(session_id, batch, fed + n - 1,
+                             capacity=self._cache_ladder[-1])
+        model = self._model
+        with sess.lock, _monitor.span("serve/generate", tokens=n):
+            self._check_state(session_id, sess, batch)
+            if sess.position + fed + n - 1 > sess.capacity:
+                raise SessionError(
+                    f"session {session_id!r} holds {sess.position} tokens "
+                    f"in a ring of {sess.capacity}; {fed + n - 1} more do "
+                    "not fit")
+            kw = self._pinned_weights(sess)
+            step_ids, counts = ids.astype(np.int32), None
+            pending, host_ids, kept = None, [], []
+            with _monitor.sanitize_scenario(self._scenario, units=n):
+                for _ in range(n):
+                    with _monitor.span("serve/decode_step"):
+                        step_ids, logits, counts, sess.carries = \
+                            model.token_step(sess.carries, step_ids,
+                                             counts, **kw)
+                    kept.append(logits)
+                    if pending is not None:
+                        with _monitor.span("serve/token_fetch"):
+                            host_ids.append(np.asarray(pending))
+                    pending = step_ids
+                with _monitor.span("serve/token_fetch"):
+                    host_ids.append(np.asarray(pending))
+                    counts = np.asarray(counts)
+            sess.position += fed + n - 1
+            sess.steps += n
+            sess.last_used = time.monotonic()
+        _monitor.counter("serving_tokens_generated_total",
+                         "tokens sampled by session generation").inc(
+            batch * n, model=self._name)
+        _monitor.counter("serving_session_steps_total",
+                         "single-dispatch session timesteps served").inc(
+            n, model=self._name)
+        tokens = _monitor.counter(
+            "moe_expert_tokens_total",
+            "tokens routed to each expert, by layer")
+        by_vertex = dict(zip(model._expert_vertices(), counts))
+        for vertex, row in by_vertex.items():
+            for expert, picks in enumerate(row):
+                if picks:
+                    tokens.inc(int(picks), model=self._name, layer=vertex,
+                               expert=str(expert))
+        return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex)
+
     def _acquire(self, session_id: str, batch: int,
-                 steps: int = 1) -> _Session:
+                 steps: int = 1, capacity: int = 0) -> _Session:
         now = time.monotonic()
         with self._lock:
             changed = self._sweep_locked(now)
@@ -396,13 +574,13 @@ class SessionCache:
                 while len(self._sessions) >= self._max_sessions:
                     self._sessions.popitem(last=False)   # LRU out
                     self._count_eviction("capacity")
-                capacity = 0
-                if self._decode:
+                if self._decode and not capacity:
                     capacity = self._cache_ladder[0]
                     for cap in self._cache_ladder:
                         if cap >= steps:
                             capacity = cap
                             break
+                if self._decode:
                     carries = self._model._init_carries(
                         batch, cache_len=capacity)
                 else:

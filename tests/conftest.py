@@ -59,3 +59,13 @@ def _executable_store_of_its_own(tmp_path_factory, monkeypatch):
     monkeypatch.setattr(compile_cache, "_executables_dir", executables_dir)
     yield
     jit_watch.set_executable_store(None)
+    if jax.config.jax_compilation_cache_dir is not None:
+        # ``enable()`` also turns JAX's persistent cache on for the
+        # process, at the checkout's directory.  Left on, a later test
+        # in this worker has XLA:CPU reload its programs from there, and
+        # a reloaded executable is one the store declines to serialize
+        # (it lost its kernels): the test of the store's second run
+        # would read no hit, by the order the files happened to run in.
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()

@@ -98,7 +98,19 @@ def test_custom_layer_trains():
 
 # ---- a user-defined activation -----------------------------------------
 
-def test_custom_activation_by_name():
+@pytest.fixture
+def _registry_as_it_was():
+    """A registered activation stays for the process, and while one
+    from outside the package is there no net's programs are served by
+    the executable store (``monitor.program_identity``): a later test of
+    the store in the same worker would read no hit."""
+    before = dict(activations._ACTIVATIONS)
+    yield
+    activations._ACTIVATIONS.clear()
+    activations._ACTIVATIONS.update(before)
+
+
+def test_custom_activation_by_name(_registry_as_it_was):
     activations.register("test_tanh_cubed",
                          lambda x: jnp.tanh(x) ** 3)
     # shadowing a built-in requires explicit consent

@@ -10,9 +10,10 @@ benchmark's builder, so the program is the cell's), runs the unit the
 ``score()``) once to compile and twice untraced, then once under
 ``monitor.device_trace``, prints the table of PERF.md section 5 and
 writes the report as JSON.  Needs the chip: a CPU trace has no device
-plane.  The benchmark cannot show this itself yet: ``run.py`` deletes
-its trace before any reader runs (ROADMAP, the ``benchmark`` issue after
-PR 24).
+plane.  Since PR 29 the benchmark shows the same rows itself
+(``--trace 1``: ``breakdown`` and ``record["trace"]["by_scope"]``, for
+every cell, the serving ones too); this tool is for a ``fit`` cell's
+unit outside a benchmark window.
 """
 
 from __future__ import annotations
