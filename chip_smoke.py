@@ -17,7 +17,8 @@ same functions at toy widths on the CPU mesh):
                  session — prefill plus single-token steps
 - ``kernels``    ``ops.attention.flash_attention`` forward and gradient,
                  compiled by Mosaic (never the interpreter), against the
-                 dense reference
+                 dense reference; the streamed latent attention against
+                 its dense form at the decode cell's shape for one layer
 - ``four_chips`` ``ParallelWrapper`` and ``ZeroShardedParallelWrapper``
                  over four devices; says so when it finds fewer
 
@@ -106,6 +107,9 @@ class Sizes:
     kernel_bthd: Tuple[int, int, int, int] = (2, 8192, 4, 64)
     kernel_ref_t: int = 1024
     kernel_short_ts: Tuple[int, ...] = (40, 8)
+    # the latent attention of one layer of the decode cell: (batch,
+    # heads, rank, rotary, ring slots, cursor)
+    latent_shape: Tuple[int, ...] = (64, 32, 512, 64, 4096, 4000)
     interpret: bool = False         # True only where there is no Mosaic
     # four_chips: ZeRO needs a MultiLayerNetwork
     mln_conf: Callable = _lenet_conf
@@ -397,7 +401,41 @@ def phase_kernels(sz: Sizes):
                    f"{name} T={t}: flash differs from the dense reference "
                    f"by {err:.3g} > {KERNEL_BOUND}")
             report[f"{name}_T{t}_max_rel_err"] = err
+    report["latent_streamed_max_rel_err"] = _latent_kernel(sz)
     return report
+
+
+def _latent_kernel(sz: Sizes) -> float:
+    """The streamed latent attention (one token a row, bf16 rings)
+    against the dense form of the same arguments.  Both round ``p`` to
+    bf16 for the context product, the dense form after the division and
+    the streamed one before it: ``KERNEL_BOUND`` holds both."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops import attention
+
+    batch, heads, rank, rope, slots, cursor = sz.latent_shape
+    rng = np.random.RandomState(SEED + 5)
+    q_lat, q_rope, c_ring, r_ring = (
+        jnp.asarray(scale * rng.randn(*shape).astype(np.float32),
+                    jnp.bfloat16)
+        for scale, shape in ((0.3, (batch, 1, heads, rank)),
+                             (1.0, (batch, 1, heads, rope)),
+                             (1.0, (batch, slots, rank)),
+                             (1.0, (batch, slots, rope))))
+    scale = (3 * rope) ** -0.5          # (d_nope + d_rope)^-1/2 at 128 + 64
+    streamed = jax.jit(lambda *a: attention.latent_ring_attention_streamed(
+        *a, sm_scale=scale, interpret=sz.interpret))
+    dense = jax.jit(lambda *a: attention.latent_ring_attention_dense(
+        *a, sm_scale=scale))
+    args = (q_lat, q_rope, c_ring, r_ring, jnp.asarray(cursor, jnp.int32))
+    _check_mosaic(streamed.lower(*args).as_text(),
+                  "latent_ring_attention_streamed")
+    err = _rel_err(streamed(*args), dense(*args))
+    _check(err <= KERNEL_BOUND,
+           f"the streamed latent attention differs from its dense form by "
+           f"{err:.3g} > {KERNEL_BOUND}")
+    return err
 
 
 # --------------------------------------------------------------- four chips

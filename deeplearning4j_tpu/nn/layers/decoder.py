@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ... import monitor as _monitor
 from ...ops.attention import (_einsum_acc, latent_ring_attention,
-                              latent_ring_update)
+                              latent_ring_path, latent_ring_update)
 from ..conf import inputs as _inputs
 from ..conf import serde
 from ..weights import Distribution, init_weights
@@ -429,6 +429,15 @@ class LatentAttention(BaseRecurrentLayer):
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
         return out, (c_ring, r_ring, cursor + jnp.asarray(t, jnp.int32))
+
+    def attention_path(self, t: int, carry) -> str:
+        """``"streamed"`` or ``"dense"``: the form ``forward_seq`` takes
+        for ``t`` new positions against ``carry``, by the op's own
+        predicate (host code asks it without tracing the step)."""
+        c_ring, r_ring = carry[0], carry[1]
+        return latent_ring_path(t, self.n_heads, c_ring.shape[2],
+                                r_ring.shape[2], c_ring.shape[1],
+                                c_ring.dtype)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         out, _ = self.forward_seq(

@@ -680,7 +680,13 @@ def kv_ring_attention(q: Array, k_cache: Array, v_cache: Array, cursor, *,
 
 # ---------------------------------------------------------------------------
 # Latent (MLA) ring: the cache holds one compressed row a token, shared by
-# all heads, and decode attends in that latent space.
+# all heads, and decode attends in that latent space.  Two forms of one
+# algorithm under ``latent_ring_attention``: the dense masked form in plain
+# ``jax.numpy`` (any dtype, any backend; the ring read twice and the float32
+# scores through HBM), and a Pallas kernel that streams each conversation's
+# ring through VMEM once (a TPU, bfloat16 or float32: the token step and
+# prefill chunks).  ``latent_ring_path`` picks between them from the
+# arguments alone.
 # ---------------------------------------------------------------------------
 
 def latent_ring_update(c_ring: Array, r_ring: Array, cursor,
@@ -708,10 +714,206 @@ def _einsum_acc(spec: str, a: Array, b: Array, acc) -> Array:
     return jnp.einsum(spec, a, b, preferred_element_type=acc)
 
 
+def _make_latent_kernel(*, sm_scale: float, block: int, num_blocks: int,
+                        t: int, widen: bool):
+    """The streaming softmax of ``_make_flash_kernel`` for one
+    conversation's latent ring: every ``T x heads`` query row against one
+    ``block`` of slots a grid step, scores from the latent and the rotary
+    half together, the context folded from the SAME latent block.  Slot 0
+    is visible to every row, so the running maximum is finite from the
+    first block on and a masked score's ``exp`` is exactly 0.0."""
+
+    # numpy scalars: float32 literals in the kernel under x64 too
+    scale, masked = np.float32(sm_scale), np.float32(_NEG_INF)
+
+    def dot(a, b, contract):
+        if widen:           # XLA:CPU under the interpreter: _einsum_acc
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def kernel(cursor_ref, limit_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref,
+               m_scr, l_scr, acc_scr):
+        ki = pl.program_id(1)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr[:], masked)
+            l_scr[:] = jnp.zeros_like(l_scr[:])
+            acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+        # a block wholly beyond the newest visible slot was not fetched
+        # (the index map repeats the last needed one) and adds nothing
+        @pl.when(ki * block <= cursor_ref[0] + (t - 1))
+        def _fold():
+            c = c_ref[0]                                   # (block, rank)
+            s = (dot(ql_ref[0], c, ((1,), (1,)))
+                 + dot(qr_ref[0], r_ref[0], ((1,), (0,)))) * scale
+            slot = ki * block + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(slot <= limit_ref[:], s, masked)
+            m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[:] = acc_scr[:] * correction + dot(
+                p.astype(c.dtype), c, ((1,), (0,)))
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(ki == num_blocks - 1)
+        def _finalize():
+            o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+    return kernel
+
+
+#: ring slots a grid step, largest first; the first that divides the
+#: capacity and fits the kernel's VMEM serves.  Swept on a v5e at the
+#: decode cell's shape (PERF.md, PR 32): the token step reads 64 rings
+#: of 4,096 slots in 0.521 / 0.422 / 0.421 / 0.420 ms at 512 / 1,024 /
+#: 2,048 / 4,096 slots a block with the cursor at 4,000 and in 0.216 /
+#: 0.217 / 0.341 / 0.423 ms with it at 1,000 (a grid step costs
+#: ~0.35 us whether its block is skipped or not)
+_LATENT_BLOCKS = (1024, 512, 256, 128)
+#: what the kernel may hold of a v5e core's 128 MiB of VMEM, and what
+#: the block is chosen to stay under by the reckoning below
+_LATENT_VMEM_LIMIT = 40 << 20
+_LATENT_VMEM_BUDGET = 24 << 20
+
+
+def _latent_vmem_bytes(rows: int, rank: int, d_rope: int, block: int,
+                       itemsize: int) -> int:
+    """VMEM of one grid step, reckoned high: ring blocks, queries and
+    context double-buffered at 128 lanes; the float32 accumulator, both
+    statistics and the rows' limits at 128 lanes each; and four float32
+    (rows, block) tiles for the scores on their way to ``p``."""
+    lanes = lambda d: -(-d // 128) * 128
+    ring = 2 * block * (lanes(rank) + lanes(d_rope)) * itemsize
+    ends = 2 * rows * (2 * lanes(rank) + lanes(d_rope)) * itemsize
+    held = rows * (lanes(rank) + 3 * 128) * 4
+    return ring + ends + held + 4 * rows * block * 4
+
+
+def latent_ring_block(rows: int, rank: int, d_rope: int, capacity: int,
+                      dtype) -> int:
+    """Ring slots a grid step of the streamed form for ``rows = T x
+    heads`` query rows, or 0 where no block both divides ``capacity``
+    and fits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for block in _LATENT_BLOCKS:
+        if capacity % block == 0 and _latent_vmem_bytes(
+                rows, rank, d_rope, block, itemsize) <= _LATENT_VMEM_BUDGET:
+            return block
+    return 0
+
+
+def _mosaic() -> bool:
+    """Whether Mosaic compiles Pallas kernels here (a TPU); elsewhere
+    they are interpreted, which is for tests and not a faster form."""
+    return jax.default_backend() == "tpu"
+
+
+def latent_ring_path(t: int, heads: int, rank: int, d_rope: int,
+                     capacity: int, dtype) -> str:
+    """``"streamed"`` or ``"dense"``: which form
+    :func:`latent_ring_attention` takes for ``t`` new positions a row
+    against rings of ``capacity`` slots stored in ``dtype``.  Streamed
+    where Mosaic compiles the kernel (a TPU), the storage is bfloat16
+    or float32 (Mosaic has no float64), the chunk is shorter than the
+    ring (``output()`` from a zero ring, ``t == capacity``, is plain
+    causal attention and stays dense) and a block divides the capacity
+    with ``t x heads`` rows of accumulators in VMEM.  Also what
+    ``latent_attention_steps_total{path}`` is labelled by."""
+    streamed = (_mosaic()
+                and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                         jnp.dtype(jnp.float32))
+                and t < capacity and (t * heads) % 8 == 0
+                and latent_ring_block(t * heads, rank, d_rope, capacity,
+                                      dtype) > 0)
+    return "streamed" if streamed else "dense"
+
+
+def latent_ring_attention_streamed(q_lat: Array, q_rope: Array,
+                                   c_ring: Array, r_ring: Array, cursor, *,
+                                   sm_scale: float,
+                                   block: Optional[int] = None,
+                                   interpret: Optional[bool] = None
+                                   ) -> Array:
+    """:func:`latent_ring_attention` as one Pallas kernel that reads each
+    conversation's ring once: grid (batch, ring blocks), a block of both
+    rings a step, float32 scores and statistics that never leave VMEM,
+    ``p`` rounded to the ring's dtype only for the context product, the
+    context normalized at the last block.  Blocks wholly beyond
+    ``cursor + T - 1`` are neither fetched nor computed (masked slots
+    weigh exactly 0.0 in the dense form: the same mathematics).
+    ``block`` defaults to :func:`latent_ring_block`'s; ``interpret=None``
+    is Mosaic on a TPU and the Pallas interpreter elsewhere."""
+    batch, t, heads, rank = q_lat.shape
+    cap, d_rope = c_ring.shape[1], r_ring.shape[2]
+    rows = t * heads
+    if block is None:
+        block = latent_ring_block(rows, rank, d_rope, cap, c_ring.dtype)
+    if not block or cap % block:
+        raise ValueError(f"no block of the streamed latent attention "
+                         f"divides a ring of {cap} slots (block {block})")
+    if interpret is None:
+        # the backend itself, not _mosaic(): a test that steers the
+        # predicate still runs the kernel interpreted
+        interpret = jax.default_backend() != "tpu"
+    num_blocks = cap // block
+    cursor = jnp.asarray(cursor, jnp.int32).reshape(1)
+    # newest slot each query row sees: rows run (position, head)
+    limit = (cursor + jnp.repeat(jnp.arange(t, dtype=jnp.int32),
+                                 heads))[:, None]
+
+    def newest(k, cur):
+        # non-negative int32s: truncating division is the floor
+        return jnp.minimum(k, jax.lax.div(cur[0] + jnp.int32(t - 1),
+                                          jnp.int32(block)))
+
+    whole = lambda b, k, cur: (b, 0, 0)
+    # XLA:TPU keeps a (batch, capacity, d_rope) array of d_rope < 128
+    # with the capacity minor, so this view is a bitcast there, and a
+    # (d_rope, block) tile fills its lanes wherever it is not
+    r_slots_minor = jnp.swapaxes(r_ring, 1, 2)
+    out = pl.pallas_call(
+        _make_latent_kernel(sm_scale=float(sm_scale), block=block,
+                            num_blocks=num_blocks, t=t,
+                            widen=bool(interpret)),
+        out_shape=_sds((batch, rows, rank), q_lat.dtype, q_lat),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, num_blocks),
+            in_specs=[
+                pl.BlockSpec((rows, 1), lambda b, k, cur: (0, 0)),
+                pl.BlockSpec((1, rows, rank), whole),
+                pl.BlockSpec((1, rows, d_rope), whole),
+                pl.BlockSpec((1, block, rank),
+                             lambda b, k, cur: (b, newest(k, cur), 0)),
+                pl.BlockSpec((1, d_rope, block),
+                             lambda b, k, cur: (b, 0, newest(k, cur))),
+            ],
+            out_specs=pl.BlockSpec((1, rows, rank), whole),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 128), jnp.float32),    # running max
+                pltpu.VMEM((rows, 128), jnp.float32),    # running denom
+                pltpu.VMEM((rows, rank), jnp.float32),   # weighted sum
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT),
+        interpret=interpret,
+    )(cursor, limit, q_lat.reshape(batch, rows, rank),
+      q_rope.reshape(batch, rows, d_rope), c_ring, r_slots_minor)
+    return out.reshape(batch, t, heads, rank)
+
+
 def latent_ring_attention(q_lat: Array, q_rope: Array, c_ring: Array,
                           r_ring: Array, cursor, *,
                           sm_scale: float) -> Array:
-    """Dense masked attention in the latent space: (batch, T, heads, rank)
+    """Masked attention in the latent space: (batch, T, heads, rank)
     queries (the ``kv_b`` key half already absorbed) and (batch, T, heads,
     d_rope) rotary queries against a (batch, capacity, rank) latent ring
     and a (batch, capacity, d_rope) rotary-key ring that every head
@@ -719,7 +921,23 @@ def latent_ring_attention(q_lat: Array, q_rope: Array, c_ring: Array,
     Scores and softmax in float32 (float64 under float64 inputs); the
     latent context (batch, T, heads, rank) comes back in the query
     dtype, to be taken through the value half of ``kv_b`` by the
-    caller."""
+    caller.  Two forms of one algorithm, chosen by
+    :func:`latent_ring_path` from the arguments alone."""
+    form = (latent_ring_attention_streamed
+            if latent_ring_path(q_lat.shape[1], q_lat.shape[2],
+                                q_lat.shape[3], r_ring.shape[2],
+                                c_ring.shape[1], c_ring.dtype) == "streamed"
+            else latent_ring_attention_dense)
+    return form(q_lat, q_rope, c_ring, r_ring, cursor, sm_scale=sm_scale)
+
+
+def latent_ring_attention_dense(q_lat: Array, q_rope: Array, c_ring: Array,
+                                r_ring: Array, cursor, *,
+                                sm_scale: float) -> Array:
+    """:func:`latent_ring_attention` in plain ``jax.numpy``: every query
+    against every slot, the scores masked, maxed, exponentiated and
+    normalized as one (batch, heads, T, capacity) tensor, the ring read
+    for the scores and again for the context.  Any dtype, any backend."""
     acc = jnp.promote_types(q_lat.dtype, jnp.float32)
     cap, t = c_ring.shape[1], q_lat.shape[1]
     cursor = jnp.asarray(cursor, jnp.int32)

@@ -66,7 +66,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Optional
 
 import numpy as np
@@ -192,9 +192,10 @@ class SessionCache:
                                     lambda: False)())
         # rings of compressed rows (latent attention), not of per-head
         # keys and values: the second kind of decode state
-        self._latent = self._decode and self._is_graph and any(
-            getattr(model.vertices[n].layer, "STATE_KIND", "") == "latent"
-            for n in model._layer_names())
+        self._latent = [
+            n for n in model._layer_names()
+            if getattr(model.vertices[n].layer, "STATE_KIND", "") == "latent"
+        ] if self._decode and self._is_graph else []
         self._scenario = ("serving.decode_step" if self._decode
                           else "serving.rnn_step")
         self._cache_ladder = (batch_ladder(model.max_cache_len())
@@ -552,6 +553,16 @@ class SessionCache:
         _monitor.counter("serving_session_steps_total",
                          "single-dispatch session timesteps served").inc(
             n, model=self._name)
+        # by the op's own predicate, asked here and not where the step is
+        # traced: a warm start loads cg.token_step without tracing it
+        launched = _monitor.counter(
+            "latent_attention_steps_total",
+            "launched token steps, by the form their latent attention took")
+        # the first step takes the ``fed`` ids, every later one its own
+        for t, steps in Counter([fed] + [1] * (n - 1)).items():
+            for path in {model.vertices[v].layer.attention_path(
+                    t, sess.carries[v]) for v in self._latent}:
+                launched.inc(steps, path=path)
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
             "tokens routed to each expert, by layer")

@@ -1,0 +1,238 @@
+"""The streamed latent attention (``ops.attention
+.latent_ring_attention_streamed``) against the dense form on the CPU, in
+Pallas interpret mode, at the published head geometry (32 heads, rank
+512, rotary 64); the predicate that picks the form; the streamed form
+forced through ``LatentAttention`` and the served path; and the kernel
+compiled by Mosaic for a described v5e at the cell's shape, with the
+scope it carries there (``tests/test_mla_moe_decoder.py`` holds the
+token step's lowering to the same)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import mla_moe_decoder as ref
+from deeplearning4j_tpu import monitor
+from deeplearning4j_tpu.models.mla_moe_decoder import from_config
+from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
+from deeplearning4j_tpu.ops import attention
+from deeplearning4j_tpu.serving import InferenceEngine
+
+HEADS, RANK, ROPE = 32, 512, 64
+CAPACITY, BLOCK = 256, 128          # two blocks a ring
+SCALE = (128 + ROPE) ** -0.5
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def rings_and_queries(t, dtype, cursor, batch=2, seed=0):
+    """Rings written up to ``cursor + t`` and garbage (finite, large)
+    beyond, so that a slot read past the mask shows."""
+    rng = np.random.RandomState(seed)
+    draw = lambda *shape: rng.randn(*shape).astype(np.float32)
+    c, r = draw(batch, CAPACITY, RANK), draw(batch, CAPACITY, ROPE)
+    c[:, cursor + t:] = 1e4 * draw(batch, CAPACITY - cursor - t, RANK)
+    r[:, cursor + t:] = 1e4 * draw(batch, CAPACITY - cursor - t, ROPE)
+    q_lat, q_rope = draw(batch, t, HEADS, RANK), draw(batch, t, HEADS, ROPE)
+    return tuple(jnp.asarray(a).astype(dtype)
+                 for a in (0.3 * q_lat, q_rope, c, r))
+
+
+@pytest.mark.parametrize("cursor", ["zero", "inside", "boundary", "last"])
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("dtype,bound", [("float32", 1e-5),
+                                         ("bfloat16", 8e-3)])
+def test_streamed_agrees_with_dense(dtype, bound, t, cursor):
+    """float32: the two forms differ by the order of their sums.
+    bfloat16: by the rounding of ``p`` (the dense form rounds it
+    normalized, the streamed one before the division: 2^-9 a term)."""
+    cursor = {"zero": 0, "inside": 77, "boundary": BLOCK,
+              "last": CAPACITY - t}[cursor]
+    args = rings_and_queries(t, dtype, cursor)
+    want = attention.latent_ring_attention_dense(*args, cursor,
+                                                 sm_scale=SCALE)
+    got = attention.latent_ring_attention_streamed(
+        *args, jnp.asarray(cursor, jnp.int32), sm_scale=SCALE, block=BLOCK)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert rel(got, want) < bound
+
+
+def test_blocks_beyond_the_cursor_are_not_read():
+    """NaN in the second block: the dense form would spread it (0 x NaN),
+    the streamed form neither fetches nor folds that block."""
+    q_lat, q_rope, c, r = rings_and_queries(1, "float32", 5)
+    clean = attention.latent_ring_attention_dense(q_lat, q_rope, c, r, 5,
+                                                  sm_scale=SCALE)
+    c, r = c.at[:, BLOCK:].set(jnp.nan), r.at[:, BLOCK:].set(jnp.nan)
+    got = attention.latent_ring_attention_streamed(
+        q_lat, q_rope, c, r, 5, sm_scale=SCALE, block=BLOCK)
+    assert rel(got, clean) < 1e-5
+
+
+def test_a_block_has_to_divide_the_ring():
+    args = rings_and_queries(1, "float32", 0)
+    with pytest.raises(ValueError, match="divides a ring of 256"):
+        attention.latent_ring_attention_streamed(*args, 0, sm_scale=SCALE,
+                                                 block=96)
+
+
+# ------------------------------------------------------------ the predicate
+def test_the_path_is_chosen_from_the_arguments(monkeypatch):
+    path = attention.latent_ring_path
+    cell = (1, HEADS, RANK, ROPE, 4096)
+    # the CPU default: dense, whatever the arguments
+    assert path(*cell, jnp.bfloat16) == "dense"
+    monkeypatch.setattr(attention, "_mosaic", lambda: True)
+    assert path(*cell, jnp.bfloat16) == "streamed"       # the token step
+    assert path(*cell, jnp.float32) == "streamed"
+    assert path(*cell, jnp.float64) == "dense"           # Mosaic has none
+    assert path(32, HEADS, RANK, ROPE, 4096, jnp.bfloat16) == "streamed"
+    # output() from a zero ring: the chunk is the ring
+    assert path(4096, HEADS, RANK, ROPE, 4096, jnp.bfloat16) == "dense"
+    assert path(128, 4, 32, 8, 128, jnp.float32) == "dense"
+    # no block divides the ring; accumulators beyond VMEM; ragged rows
+    assert path(1, HEADS, RANK, ROPE, 4000, jnp.bfloat16) == "dense"
+    assert path(512, HEADS, RANK, ROPE, 4096, jnp.bfloat16) == "dense"
+    assert path(1, 3, RANK, ROPE, 4096, jnp.bfloat16) == "dense"
+    # the block shrinks as the rows grow, and stays a divisor
+    blocks = [attention.latent_ring_block(t * HEADS, RANK, ROPE, 4096,
+                                          jnp.bfloat16) for t in (1, 32, 64)]
+    assert blocks == sorted(blocks, reverse=True) and blocks[0] >= 1024
+    assert all(b and 4096 % b == 0 for b in blocks)
+
+
+# ------------------------------------------------- through the layer, served
+CFG = dict(
+    vocab_size=256, hidden_size=64, num_hidden_layers=2,
+    first_k_dense_replace=1, intermediate_size=160,
+    moe_intermediate_size=32, n_routed_experts=8, num_experts_per_tok=2,
+    n_shared_experts=1, routed_scaling_factor=2.0, norm_topk_prob=True,
+    num_attention_heads=8, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rms_norm_eps=1e-6, rope_theta=10000,
+    rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 64,
+                  "mscale": 1, "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 4096, "type": "yarn"},
+    hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+    mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)
+
+
+@pytest.fixture
+def streamed_net(monkeypatch):
+    """A float32 decoder whose rings of 256 slots take the streamed form:
+    the predicate is told that Mosaic is there; the kernel asks the real
+    backend whether to interpret, and does.  With the net, the ``T`` of
+    every trace of the streamed form."""
+    kernel, calls = attention.latent_ring_attention_streamed, []
+
+    def spy(*args, **kw):
+        calls.append(args[0].shape[1])          # T
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(attention, "_mosaic", lambda: True)
+    monkeypatch.setattr(attention, "latent_ring_attention_streamed", spy)
+    net = ComputationGraph(from_config(
+        CFG, dtype="float32", cache_len=256, init_std=0.1,
+        hc_alpha_init=0.5, hc_bias_std=1.0, router_bias_std=0.2,
+        seed=3)).init()
+    return net, calls
+
+
+def _steps(path):
+    return monitor.counter("latent_attention_steps_total", "").value(
+        path=path)
+
+
+def test_streamed_prefill_in_chunks_then_decode_agrees_with_the_full_forward(
+        streamed_net):
+    net, calls = streamed_net
+    ids = np.random.RandomState(0).randint(0, 256, (3, 20)).astype(np.int32)
+    want = np.asarray(ref.forward(CFG, net.params, ids))
+    with InferenceEngine(net, max_batch_size=4) as engine:
+        assert engine.prefill_session("snap", ids[:, :13], chunk=4,
+                                      cache_len=256) == 13
+        assert sorted(calls) == [1, 1, 4, 4]    # two layers, two shapes
+        engine.fork_session("snap", "s")
+        got = [engine.predict_session("s", ids[:, t:t + 1])
+               for t in range(13, 20)]
+        assert rel(np.stack(got, axis=1), want[:, 13:]) < 1e-5
+        # counted on the host, once a launched step, by the same predicate
+        streamed, dense = _steps("streamed"), _steps("dense")
+        engine.fork_session("snap", "g")
+        out = engine.generate("g", ids[:, 13:14], 3)
+        assert (_steps("streamed"), _steps("dense")) == (streamed + 3, dense)
+    assert rel(np.asarray(out.kept_logits[0]), want[[0, 2], 13]) < 1e-5
+    # output() from a zero ring (T == capacity) keeps the dense form
+    traced = len(calls)
+    assert rel(net.output(ids), want) < 1e-5
+    assert len(calls) == traced
+
+
+def test_the_dense_default_counts_dense_steps():
+    net = ComputationGraph(from_config(
+        CFG, dtype="float32", cache_len=32, seed=3)).init()
+    ids = np.random.RandomState(1).randint(0, 256, (2, 6)).astype(np.int32)
+    streamed, dense = _steps("streamed"), _steps("dense")
+    with InferenceEngine(net, max_batch_size=4) as engine:
+        engine.prefill_session("s", ids[:, :-1], chunk=5, cache_len=32)
+        engine.generate("s", ids[:, -1:], 4)
+    assert (_steps("streamed"), _steps("dense")) == (streamed, dense + 4)
+
+
+# -------------------------------------------------- the scope, and Mosaic
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _scoped(*args):
+    with monitor.scope("layer", "L0_attn"), \
+            monitor.subscope("latent_attention"):
+        return attention.latent_ring_attention_streamed(
+            *args, sm_scale=SCALE, interpret=False)
+
+
+def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip):
+    """The token step's shape (64 conversations, rings of 4,096, bf16)
+    and a prefill chunk's (T = 32), compiled ahead of time for a
+    described v5e: block shapes and VMEM are refused here, not on the
+    chip, and the compiled kernel is one instruction that carries the
+    scope it was called under.  Nothing runs.  (Without x64, which the tests turn on and
+    Mosaic cannot take, and without the compile cache, which cannot
+    read such an entry back.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            for t in (1, 32):
+                shapes = [
+                    jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+                    for s in ((64, t, HEADS, RANK), (64, t, HEADS, ROPE),
+                              (64, 4096, RANK), (64, 4096, ROPE))]
+                cursor = jax.ShapeDtypeStruct((), jnp.int32,
+                                              sharding=one_chip)
+                text = jax.jit(_scoped).lower(
+                    *shapes, cursor).compile().as_text()
+                calls = [line for line in text.splitlines()
+                         if 'custom_call_target="tpu_custom_call"' in line]
+                assert len(calls) == 1
+                # Mosaic's kernel is one instruction, named by its scope
+                op_name = calls[0].split('op_name="')[1].split('"')[0]
+                assert monitor.parse_op_name(op_name) == (
+                    "layer.L0_attn.latent_attention", "forward")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()

@@ -426,3 +426,30 @@ def test_the_parts_a_trace_has_to_tell_apart_have_scopes_of_their_own(net):
             "layer.L1_moe.experts", "forward")
     with monitor.subscope("alone"):                  # no scope open
         pass
+
+
+def test_the_streamed_kernel_carries_the_scope_it_is_called_under(
+        monkeypatch):
+    """A Pallas kernel takes the name stack it is called under like any
+    other operation: with the streamed form chosen (the predicate told
+    that Mosaic is there; 8 heads and rings of 256 slots, two blocks),
+    the token step's ``pallas_call`` carries
+    ``layer.<vertex>.latent_attention`` and ``parse_op_name`` gives it
+    that row, so the kernel's device seconds land where
+    ``mla_decode_roofline`` reads them."""
+    from deeplearning4j_tpu.ops import attention
+    monkeypatch.setattr(attention, "_mosaic", lambda: True)
+    net = ComputationGraph(from_config(
+        {**CFG, "num_attention_heads": 8}, dtype="float32",
+        **{**ORDER_ONE, "cache_len": 256})).init()
+    assert net.vertices["L1_attn"].layer.attention_path(
+        1, net._init_carries(2, cache_len=256)["L1_attn"]) == "streamed"
+    text = net._token_step_fn.lower(
+        net.params, net.net_state, net._init_carries(2, cache_len=256),
+        jnp.zeros((2, 1), jnp.int32), net.zero_expert_counts()).as_text(
+            debug_info=True)
+    names = [line.split('"')[1] for line in text.splitlines()
+             if "/layer.L1_attn.latent_attention/pallas_call" in line]
+    assert names, "no pallas_call under layer.L1_attn.latent_attention"
+    assert {monitor.parse_op_name(n) for n in names} == {
+        ("layer.L1_attn.latent_attention", "forward")}
