@@ -229,7 +229,7 @@ def _run_scan_bench(net, feats, labels, steps: int, pipeline: int,
 
     def dispatch():
         (state["p"], state["u"], state["s"],
-         scores) = compiled(state["p"], state["u"], state["s"],
+         scores, _) = compiled(state["p"], state["u"], state["s"],
                             state["it"], feats, labels, None, None,
                             net._rng_key)
         state["it"] += steps

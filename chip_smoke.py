@@ -205,13 +205,12 @@ def phase_train(sz: Sizes, expect_policy: str):
     t1 = time.perf_counter()
     _check(staged.value(path="cache") == f.nbytes + l.nbytes,
            "the epoch-cache ingest path did not stage the dataset")
-    # fit dispatches the health (_h) builds; the plain builds exist for
-    # tools only and must not have been constructed, let alone run
-    _check("_gather_train_step_h" in vars(net)
-           and net._gather_train_step_h.compile_count == 1,
-           "the health gather step did not dispatch exactly one program")
-    _check("_gather_train_step" not in vars(net),
-           "a non-health step build was constructed")
+    # the epoch cache dispatches the gather step and no other program
+    _check(net._gather_train_step.compile_count == 1,
+           "the gather step did not dispatch exactly one program")
+    _check("_train_step" not in vars(net)
+           and "_multi_train_step" not in vars(net),
+           "fit over the epoch cache built another step program")
     _check(monitor.health_snapshot()["last_dispatch_timestamp"] is not None,
            "no health vector was recorded")
 

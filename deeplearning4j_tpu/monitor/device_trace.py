@@ -14,7 +14,7 @@ is a single path component of the HLO ``op_name`` (no ``/`` inside):
                    masters, bf16 re-derivation
 ``ingest.gather``  the minibatch gather (+ wire decode) of the
                    epoch-cache step
-``health``         ``layer_stats`` + ``guard_select`` (``health=True``)
+``health``         ``layer_stats`` + ``guard_select``
 ``precision.cast`` parameters and inputs cast to the compute dtype
 =================  =====================================================
 

@@ -207,8 +207,11 @@ def layer_stats(old_params, new_params, grads, loss,
 
     ``old_params``/``new_params``/``grads`` are the per-layer containers
     the step already holds: lists of param trees for
-    ``MultiLayerNetwork`` (pass ``order=None``) or name-keyed dicts for
-    ``ComputationGraph`` (pass ``order=self._layer_names()``).  Returns
+    ``MultiLayerNetwork`` or name-keyed dicts for ``ComputationGraph``.
+    ``order`` is their keys in the packed vector's layer order (the
+    shared step passes those of ``Network._layer_items()``: list indices,
+    or vertex names in topological order); None is every index of a
+    list.  Returns
     ``(vec, bad)`` — the packed ``[loss, flag, grad_l2*, param_l2*,
     update_ratio*]`` f32 vector and the traced scalar bool that feeds
     :func:`guard_select`.  The update norm is taken from ``old - new``

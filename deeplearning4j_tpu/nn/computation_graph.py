@@ -16,7 +16,7 @@ from __future__ import annotations
 import functools
 import json
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -30,28 +30,10 @@ from .. import monitor as _monitor
 from .conf.computation_graph import (ComputationGraphConfiguration,
                                      DuplicateToTimeSeriesVertex,
                                      LastTimeStepVertex, LayerVertex)
-from ..datasets.dataset import DataSet, MultiDataSet, wire_of
+from .network import Network, _as_multi
+from ..datasets.dataset import DataSet, MultiDataSet
 
 Array = jax.Array
-
-
-def _as_multi(data) -> MultiDataSet:
-    if isinstance(data, MultiDataSet):
-        return data
-    if isinstance(data, DataSet):
-        mds = MultiDataSet(
-            features=[data.features], labels=[data.labels],
-            features_masks=(None if data.features_mask is None
-                            else [data.features_mask]),
-            labels_masks=(None if data.labels_mask is None
-                          else [data.labels_mask]))
-        wire = wire_of(data)
-        if wire is not None:
-            # per-input wire list (ingest.multi_window_wire): a wrapped
-            # DataSet wires its single input
-            mds._wires = [wire]
-        return mds
-    raise TypeError(f"Expected DataSet/MultiDataSet, got {type(data)}")
 
 
 def _layer_init_programs(layer, dtype):
@@ -65,8 +47,12 @@ def _layer_init_programs(layer, dtype):
     return init, jax.jit(hold), jax.jit(finish)
 
 
-class ComputationGraph:
-    """DAG network with named vertices (reference ``ComputationGraph``)."""
+class ComputationGraph(Network):
+    """DAG network with named vertices (reference ``ComputationGraph``).
+    The training path (step programs, ``fit``, the flat accessors) is
+    ``network.Network``'s."""
+
+    _jit_prefix = "cg"
 
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
@@ -88,28 +74,6 @@ class ComputationGraph:
         self._decode_grow_cache: Dict[int, Any] = {}
         self._precision: Optional[_precision.PrecisionPolicy] = None
         self._inference_only = False
-
-    def _pol(self) -> _precision.PrecisionPolicy:
-        """The precision policy, resolved once per network instance
-        (docs/PERFORMANCE.md)."""
-        p = self._precision
-        if p is None:
-            p = self._precision = _precision.resolve_policy(self.conf.conf)
-        return p
-
-    @functools.cached_property
-    def _solver(self):
-        """Line-search solver when ``optimization_algo`` asks for one
-        (reference ``Solver.java``); None selects the jitted SGD path."""
-        from ..optimize.solvers import SGD, Solver
-        algo = (self.conf.conf.optimization_algo or SGD).lower()
-        if algo == SGD:
-            return None
-        if getattr(self.conf, "backprop_type", "standard") == "tbptt":
-            raise ValueError(
-                f"optimization_algo {algo!r} is incompatible with tBPTT; "
-                "use stochastic_gradient_descent")
-        return Solver(self, algo)
 
     # ------------------------------------------------------------------ init
     def init(self, for_inference: bool = False) -> "ComputationGraph":
@@ -202,6 +166,14 @@ class ComputationGraph:
     def _layer_names(self) -> List[str]:
         return [n for n in self.topo
                 if isinstance(self.vertices[n], LayerVertex)]
+
+    def _layer_items(self):
+        return [(n, n, self.vertices[n].layer) for n in self._layer_names()]
+
+    @staticmethod
+    def _inputs_of(arrays):
+        """``_loss_fn`` takes the sequences as they are."""
+        return arrays
 
     def _output_layer_vertices(self) -> List[str]:
         return list(self.conf.network_outputs)
@@ -358,406 +330,6 @@ class ComputationGraph:
                         labels[i], acts[out_name], lmask,
                         average=self.conf.conf.mini_batch)
         return total, (new_state, new_carries)
-
-    def _reg_score(self, params) -> Array:
-        total = jnp.asarray(0.0, jnp.float32)
-        with _monitor.scope("reg"):
-            for name in self._layer_names():
-                layer = self.vertices[name].layer
-                total = total + _updaters.regularization_score(
-                    params[name], layer.l1_by_param(), layer.l2_by_param())
-        return total
-
-    # ------------------------------------------------------------ train step
-    def _apply_updates(self, params, updater_state, grads, iteration):
-        new_params, new_ustate = {}, {}
-        for name in self._layer_names():
-            layer = self.vertices[name].layer
-            g = grads[name]
-            if g:
-                with _monitor.scope("update", name):
-                    new_params[name], new_ustate[name] = \
-                        _updaters.apply_layer_updates(
-                            self._updater_conf(name), layer, params[name],
-                            updater_state[name], g, iteration)
-            else:
-                new_params[name] = params[name]
-                new_ustate[name] = updater_state[name]
-        return new_params, new_ustate
-
-    def _build_train_step(self, health: bool):
-        """Graph train step builder; ``health=True`` adds the packed
-        per-layer stats vector + in-jit divergence guard
-        (``monitor/health.py``), with per-vertex stats keyed in
-        ``_layer_names()`` topo order."""
-        from ..monitor import health as _health
-
-        def step(params, updater_state, net_state, iteration, features,
-                 labels, features_masks, labels_masks, base_rng):
-            rng = jax.random.fold_in(base_rng, iteration)
-            (data_loss, (new_state, _)), grads = jax.value_and_grad(
-                self._loss_fn, has_aux=True)(
-                    params, net_state, features, labels, features_masks,
-                    labels_masks, rng, True)
-            new_params, new_ustate = self._apply_updates(
-                params, updater_state, grads, iteration)
-            score = data_loss + self._reg_score(params)
-            if not health:
-                return new_params, new_ustate, new_state, score
-            hvec, bad = _health.layer_stats(params, new_params, grads,
-                                            data_loss,
-                                            order=self._layer_names())
-            new_params, new_ustate, new_state = _health.guard_select(
-                bad, (new_params, new_ustate, new_state),
-                (params, updater_state, net_state))
-            return new_params, new_ustate, new_state, score, hvec
-
-        return _monitor.watched_jit(step, name="cg.train_step",
-                                    donate_argnums=(0, 1, 2))
-
-    @functools.cached_property
-    def _train_step(self):
-        """Plain 4-output graph step (external callers)."""
-        return self._build_train_step(health=False)
-
-    @functools.cached_property
-    def _train_step_h(self):
-        """Health-instrumented graph step; the ``fit`` paths use this."""
-        return self._build_train_step(health=True)
-
-    def _build_multi_train_step(self, health: bool):
-        """S sequential graph train steps in ONE XLA program via
-        ``lax.scan`` over per-input stacked (S, B, ...) batches — the graph
-        twin of ``MultiLayerNetwork._multi_train_step``.  One dispatch runs
-        the whole loop on-chip, so throughput is set by the MXU rather
-        than by host→device dispatch latency (the reference's inner loop
-        is host-driven, ``StochasticGradientDescent.java:50-72``).
-        ``health=True`` stacks the packed per-step stats vector as a
-        second scan output riding the same dispatch."""
-
-        from . import ingest
-        from ..monitor import health as _health
-
-        def multi(params, updater_state, net_state, iteration, features,
-                  labels, features_masks, labels_masks, base_rng,
-                  wires=None):
-            def body(carry, xs):
-                p, u, s, it = carry
-                f, l, fm, lm = xs
-                if wires is not None:
-                    f = [ingest.device_decode(fi, w)
-                         for fi, w in zip(f, wires)]
-                rng = jax.random.fold_in(base_rng, it)
-                (data_loss, (new_s, _)), grads = jax.value_and_grad(
-                    self._loss_fn, has_aux=True)(
-                        p, s, f, l, fm, lm, rng, True)
-                new_p, new_u = self._apply_updates(p, u, grads, it)
-                score = data_loss + self._reg_score(p)
-                if not health:
-                    return (new_p, new_u, new_s, it + 1), score
-                hvec, bad = _health.layer_stats(
-                    p, new_p, grads, data_loss,
-                    order=self._layer_names())
-                new_p, new_u, new_s = _health.guard_select(
-                    bad, (new_p, new_u, new_s), (p, u, s))
-                return (new_p, new_u, new_s, it + 1), (score, hvec)
-
-            init = (params, updater_state, net_state,
-                    jnp.asarray(iteration, jnp.int32))
-            (params, updater_state, net_state, _), out = jax.lax.scan(
-                body, init,
-                (features, labels, features_masks, labels_masks))
-            if not health:
-                return params, updater_state, net_state, out
-            scores, hstack = out
-            return params, updater_state, net_state, scores, hstack
-
-        return _monitor.watched_jit(multi, name="cg.multi_train_step",
-                                    donate_argnums=(0, 1, 2))
-
-    @functools.cached_property
-    def _multi_train_step(self):
-        """Plain 4-output graph scan step (AOT benches, profilers)."""
-        return self._build_multi_train_step(health=False)
-
-    @functools.cached_property
-    def _multi_train_step_h(self):
-        """Health-instrumented graph scan step; ``fit`` paths use this."""
-        return self._build_multi_train_step(health=True)
-
-    def _build_gather_train_step(self, health: bool):
-        """Device-cached-epoch graph train step, v2 (see
-        ``MultiLayerNetwork._gather_train_step``): the epoch permutation
-        is derived ON DEVICE from ``fold_in(shuffle_key, epoch)`` and up
-        to ``fused`` epochs scan in one XLA program, each step gathering
-        its minibatch from HBM-resident per-input dataset arrays —
-        steady-state epochs move zero bytes host->device.  ``wires`` is
-        the per-input ``(denom, mult, add)``/None tuple fusing the uint8
-        wire decode into the gathered batch.  ``health=True`` adds the
-        per-step stats stack as a second scan output, keeping the fused
-        multi-epoch program at ONE dispatch per call.
-
-        ``start``/``run`` (static) carve a sub-range of one epoch's
-        steps for checkpoint-cadence chunking and mid-epoch resume —
-        same bit-identity guarantee as the MLN gather step (identical
-        per-step HLO; the carry chain crosses dispatches exactly)."""
-        from . import ingest
-        from ..monitor import health as _health
-
-        def multi(params, updater_state, net_state, iteration, data_fs,
-                  data_ls, base_rng, shuffle_key, first_epoch, fused,
-                  steps, batch, shuffle, tail, wires, start=0, run=None):
-            n = data_fs[0].shape[0]
-            span = steps if run is None else run
-
-            def epoch_rows(e):
-                if shuffle:
-                    perm = jax.random.permutation(
-                        jax.random.fold_in(shuffle_key, e), n)
-                else:
-                    perm = jnp.arange(n)
-                if tail:
-                    return perm[steps * batch:].reshape(1, tail)
-                return perm[start * batch:(start + span) * batch] \
-                    .reshape(span, batch)
-
-            rows = jax.vmap(epoch_rows)(first_epoch + jnp.arange(fused))
-            rows = rows.reshape((-1,) + rows.shape[2:])
-
-            def body(carry, idx_row):
-                p, u, s, it = carry
-                with _monitor.scope("ingest", "gather"):
-                    f = [ingest.device_decode(
-                             jnp.take(d, idx_row, axis=0), w)
-                         for d, w in zip(data_fs, wires)]
-                    l = [jnp.take(d, idx_row, axis=0) for d in data_ls]
-                rng = jax.random.fold_in(base_rng, it)
-                (data_loss, (new_s, _)), grads = jax.value_and_grad(
-                    self._loss_fn, has_aux=True)(
-                        p, s, f, l, None, None, rng, True)
-                new_p, new_u = self._apply_updates(p, u, grads, it)
-                score = data_loss + self._reg_score(p)
-                if not health:
-                    return (new_p, new_u, new_s, it + 1), score
-                hvec, bad = _health.layer_stats(
-                    p, new_p, grads, data_loss,
-                    order=self._layer_names())
-                new_p, new_u, new_s = _health.guard_select(
-                    bad, (new_p, new_u, new_s), (p, u, s))
-                return (new_p, new_u, new_s, it + 1), (score, hvec)
-
-            init = (params, updater_state, net_state,
-                    jnp.asarray(iteration, jnp.int32))
-            (params, updater_state, net_state, _), out = jax.lax.scan(
-                body, init, rows)
-            if not health:
-                return params, updater_state, net_state, out
-            scores, hstack = out
-            return params, updater_state, net_state, scores, hstack
-
-        return _monitor.watched_jit(
-            multi, name="cg.gather_train_step",
-            static_argnums=(9, 10, 11, 12, 13, 15, 16),
-            donate_argnums=(0, 1, 2),
-            identity=lambda: _monitor.program_identity(
-                self, "gather_train_step", health))
-
-    @functools.cached_property
-    def _gather_train_step(self):
-        """Plain 4-output gather step (profilers, external callers)."""
-        return self._build_gather_train_step(health=False)
-
-    @functools.cached_property
-    def _gather_train_step_h(self):
-        """Health-instrumented gather step; ``_fit_device_cached`` uses
-        this one."""
-        return self._build_gather_train_step(health=True)
-
-    def _fit_device_cached(self, source, epochs: int,
-                           start_step: int = 0, ckpt=None):
-        """Graph twin of ``MultiLayerNetwork._fit_device_cached``:
-        ``source`` is a vetted ``ListDataSetIterator`` (single-input
-        DataSets); the dataset lives on device across fits (uint8 wire
-        form when the source carries one) and consecutive epochs fuse
-        into single gather-scan dispatches via the shared
-        ``ingest.run_device_cached_fit`` driver, which also owns the
-        ``start_step`` resume offset and ``ckpt`` save cadence."""
-        from . import ingest
-
-        dev_f, dev_l, wire = ingest.device_cached_arrays(
-            self, source._ds, source.get_preprocessor())
-        data_fs, data_ls = (dev_f,), (dev_l,)
-        shuffle_key = jax.random.fold_in(self._rng_key, 0xFFFFFFFF)
-        steps = source._ds.num_examples() // source._batch
-
-        def dispatch(first_epoch, fused, tail, start=0, run=None):
-            (self.params, self.updater_state, self.net_state,
-             scores, health) = self._gather_train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, data_fs, data_ls, self._rng_key,
-                shuffle_key, first_epoch, fused, steps, source._batch,
-                bool(source._shuffle), tail, (wire,), start,
-                steps if run is None else run)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            return scores
-
-        return ingest.run_device_cached_fit(self, source, epochs, dispatch,
-                                            start_step=start_step,
-                                            ckpt=ckpt)
-
-    def _fit_windowed(self, iterator, epochs: int, window: int,
-                      ckpt=None):
-        """Graph twin of ``MultiLayerNetwork._fit_windowed``: stream
-        (Multi)DataSets in multi-batch windows, host stacking and
-        transfer overlapping the previous window's on-chip scan.
-        ``ckpt`` saves at epoch boundaries (mid-epoch offsets are not
-        replayable on this path)."""
-        from . import ingest
-        from ..resilience import faults as _faults
-
-        replay = ingest.ScoreReplayer(self)
-
-        def dispatch(buf):
-            t0 = time.perf_counter()
-            features, labels, fms, lms = ingest.stack_multi_window(buf)
-            cdt = self._pol().compute_name
-            u8s, wires = ingest.multi_window_wire(buf, len(features))
-            features = [
-                u8s[i] if u8s is not None and u8s[i] is not None
-                else ingest.cast_for_transfer(f, cdt)
-                for i, f in enumerate(features)]
-            features = [jnp.asarray(f) for f in features]
-            labels = [jnp.asarray(l) for l in labels]
-            fms = (None if fms is None else [
-                None if m is None else jnp.asarray(m) for m in fms])
-            lms = (None if lms is None else [
-                None if m is None else jnp.asarray(m) for m in lms])
-            _monitor.gauge(
-                "ingest_staged_bytes",
-                "bytes uploaded to the device per staging event").set(
-                sum(f.nbytes for f in features)
-                + sum(l.nbytes for l in labels), path="window")
-            t1 = time.perf_counter()
-            _monitor.observe_phase("data", t1 - t0)
-            (self.params, self.updater_state, self.net_state,
-             scores, health) = self._multi_train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, features, labels, fms, lms, self._rng_key,
-                wires)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            replay.add(self.iteration, scores)
-            _monitor.observe_phase("step", time.perf_counter() - t1)
-            _monitor.counter("train_iterations_total",
-                             "supervised train iterations").inc(len(buf))
-            self.iteration += len(buf)
-            self.last_batch_size = buf[0].num_examples()
-
-        it_mark = self.iteration
-        for _ in range(epochs):
-            with _monitor.span("fit/epoch", epoch=self.epoch,
-                               path="window"):
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_start"):
-                        listener.on_epoch_start(self)
-                if hasattr(iterator, "reset"):
-                    iterator.reset()
-                buf, sig = [], None
-                for ds in iterator:
-                    mds = _as_multi(ds)
-                    s = ingest.multi_window_signature(mds)
-                    if buf and (s != sig or len(buf) >= window):
-                        dispatch(buf)
-                        buf = []
-                    sig = s
-                    buf.append(mds)
-                if buf:
-                    dispatch(buf)
-                if self.listeners:
-                    t2 = time.perf_counter()
-                    replay.replay()
-                    _monitor.observe_phase("listener",
-                                           time.perf_counter() - t2)
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_end"):
-                        listener.on_epoch_end(self)
-                self.epoch += 1
-            if ckpt is not None:
-                ckpt.note_steps(self.iteration - it_mark)
-                it_mark = self.iteration
-                if ckpt.due(epoch_boundary=True):
-                    replay.replay()
-                    ckpt.save(self, step_in_epoch=0)
-            _faults.maybe_die(self.iteration)
-        if ckpt is not None:
-            replay.replay()
-            ckpt.save_if_progress(self, step_in_epoch=0)
-            ckpt.flush()
-        replay.finish()
-        return self
-
-    def fit_scan(self, batches) -> "np.ndarray":
-        """Fit a list of same-shaped DataSet/MultiDataSet minibatches in one
-        device dispatch (scan-based inner loop); returns per-step scores.
-        Listeners fire once at the end.  Standard-backprop regime only —
-        tBPTT / pretraining / num_iterations>1 / solver configs raise."""
-        self.init()
-        if getattr(self.conf, "backprop_type", "standard") == "tbptt":
-            raise ValueError("fit_scan does not support tBPTT; use fit()")
-        if self.conf.pretrain and not self._pretrain_done:
-            raise ValueError("fit_scan does not run pretraining; call "
-                             "pretrain() (or fit()) first")
-        if self.conf.conf.num_iterations != 1:
-            raise ValueError("fit_scan runs one update per batch; "
-                             "num_iterations > 1 must use fit()")
-        if self._solver is not None:
-            raise ValueError("fit_scan supports the SGD path only; this "
-                             "config uses a line-search solver")
-        mbs = [_as_multi(b) for b in batches]
-
-        def stack_inputs(get, count):
-            return [jnp.stack([jnp.asarray(get(m)[i]) for m in mbs])
-                    for i in range(count)]
-
-        def stack_masks(get, count):
-            if all(get(m) is None for m in mbs):
-                return None
-            # presence must agree per input INDEX across batches: batch 0
-            # is not a template (masks are Sequence[Optional[array]])
-            out = []
-            for i in range(count):
-                present = [get(m) is not None and get(m)[i] is not None
-                           for m in mbs]
-                if not any(present):
-                    out.append(None)
-                    continue
-                if not all(present):
-                    raise ValueError(
-                        f"Mixed mask presence across batches for input "
-                        f"{i} in fit_scan; provide masks on all batches "
-                        f"or none")
-                out.append(jnp.stack([jnp.asarray(get(m)[i]) for m in mbs]))
-            return out
-
-        n_in = len(mbs[0].features)
-        n_out = len(mbs[0].labels)
-        features = stack_inputs(lambda m: m.features, n_in)
-        labels = stack_inputs(lambda m: m.labels, n_out)
-        fmasks = stack_masks(lambda m: m.features_masks, n_in)
-        lmasks = stack_masks(lambda m: m.labels_masks, n_out)
-        t1 = time.perf_counter()
-        (self.params, self.updater_state, self.net_state,
-         scores, health) = self._multi_train_step_h(
-            self.params, self.updater_state, self.net_state, self.iteration,
-            features, labels, fmasks, lmasks, self._rng_key)
-        _monitor.health.record_dispatch(self, health, self.iteration)
-        _monitor.observe_phase("step", time.perf_counter() - t1)
-        _monitor.counter("train_iterations_total",
-                         "supervised train iterations").inc(len(mbs))
-        self.iteration += len(mbs)
-        self._score = scores[-1]
-        self.last_batch_size = mbs[0].num_examples()
-        self._fire_listeners()
-        return np.asarray(scores)
 
     @functools.cached_property
     def _tbptt_step(self):
@@ -1069,185 +641,6 @@ class ComputationGraph:
                 self.iteration += 1
                 self._fire_listeners()
         return self
-
-    # ------------------------------------------------------------------- fit
-    def _resolve_resilience(self, checkpoint, resume_from, epochs):
-        """(manager, start_step, remaining_epochs) for ``fit``'s
-        ``checkpoint=``/``resume_from=`` hooks; the no-resilience call
-        stays import-free."""
-        if checkpoint is None and resume_from is None:
-            return None, 0, epochs
-        from ..resilience.checkpoint import resolve_fit_resilience
-        return resolve_fit_resilience(self, checkpoint, resume_from,
-                                      epochs)
-
-    def _warn_partial_epoch_restart(self, start_step: int,
-                                    path: str) -> None:
-        """Mid-epoch resume offsets are only replayable on the
-        epoch-cache path (the shuffle lives in the on-device threefry
-        stream); other paths restart the interrupted epoch."""
-        if start_step:
-            import warnings
-            warnings.warn(
-                f"resume_from checkpoint was taken mid-epoch "
-                f"(step_in_epoch={start_step}) but the {path} path "
-                "cannot seek into an epoch; restarting the epoch from "
-                "step 0 (at-least-once semantics)", RuntimeWarning)
-
-    def fit(self, data, labels=None, epochs: int = 1,
-            ingest: str = "auto", window: int = 16, checkpoint=None,
-            resume_from=None) -> "ComputationGraph":
-        """Train (reference ``fit`` variants ``:650-810``).  ``data`` may be
-        a (Multi)DataSet, an iterator of them, or features with ``labels``.
-
-        With ``conf.pretrain=True`` the first call pretrains every
-        pretrainable layer vertex; ``conf.backprop=False`` skips the
-        supervised phase (reference ``fit:740`` + ``pretrain:510``).
-
-        ``ingest``/``window``: iterator data-path selection, same
-        semantics as :meth:`MultiLayerNetwork.fit` — ``"auto"`` picks
-        the device-resident epoch cache when the dataset fits HBM, else
-        windowed double-buffered staging; listeners fire by exact
-        per-step score replay.
-
-        ``checkpoint=``/``resume_from=``: preemption-safe checkpointing
-        and resume, same semantics as :meth:`MultiLayerNetwork.fit`
-        (``epochs`` is the TOTAL epoch target when resuming; see
-        ``docs/RESILIENCE.md``)."""
-        if ingest not in ("auto", "cache", "window", "batch"):
-            raise ValueError(
-                f"unknown ingest mode {ingest!r}; expected 'auto', "
-                "'cache', 'window', or 'batch'")
-        self.init()
-        if self._inference_only:
-            raise ValueError(
-                "this net was initialised with init(for_inference=True): "
-                "it holds no updater state and no master weights, so it "
-                "cannot be trained")
-        ckpt, start_step, epochs = self._resolve_resilience(
-            checkpoint, resume_from, epochs)
-        if labels is not None:
-            data = DataSet(np.asarray(data), np.asarray(labels))
-        if isinstance(data, (DataSet, MultiDataSet)):
-            batches = [data]
-            iterator = None
-        else:
-            iterator = data
-            batches = None
-        from ..optimize.listeners.listeners import finalize_listeners
-        try:
-            if self.conf.pretrain and not self._pretrain_done:
-                if batches is None and not hasattr(iterator, "reset"):
-                    # One-shot iterable: materialize so layer-wise
-                    # pretraining and the supervised phase each see the
-                    # full data.
-                    batches = list(iterator)
-                    iterator = None
-                self.pretrain(batches if batches is not None else iterator)
-                self._pretrain_done = True
-            if not getattr(self.conf, "backprop", True):
-                return self
-            if (iterator is not None and ingest != "batch"
-                    and self._solver is None
-                    and getattr(self.conf, "backprop_type",
-                                "standard") != "tbptt"
-                    and self.conf.conf.num_iterations == 1):
-                from . import ingest as ingest_mod
-                if ingest in ("auto", "cache"):
-                    source = ingest_mod.cacheable_source(iterator)
-                    if source is not None:
-                        return self._fit_device_cached(
-                            source, epochs, start_step=start_step,
-                            ckpt=ckpt)
-                    if ingest == "cache":
-                        raise ValueError(
-                            "ingest='cache' but the iterator is not "
-                            "device-cacheable (see nn/ingest.py "
-                            "eligibility)")
-                self._warn_partial_epoch_restart(start_step, "window")
-                return self._fit_windowed(iterator, epochs, window,
-                                          ckpt=ckpt)
-            self._warn_partial_epoch_restart(start_step, "batch")
-            from ..resilience import faults as _faults
-            it_mark = self.iteration
-            for _ in range(epochs):
-                with _monitor.span("fit/epoch", epoch=self.epoch,
-                                   path="batch"):
-                    for listener in self.listeners:
-                        if hasattr(listener, "on_epoch_start"):
-                            listener.on_epoch_start(self)
-                    it = batches if batches is not None else iterator
-                    if hasattr(it, "reset"):
-                        it.reset()
-                    for ds in it:
-                        self._fit_batch(_as_multi(ds))
-                    for listener in self.listeners:
-                        if hasattr(listener, "on_epoch_end"):
-                            listener.on_epoch_end(self)
-                    self.epoch += 1
-                if ckpt is not None:
-                    ckpt.note_steps(self.iteration - it_mark)
-                    it_mark = self.iteration
-                    if ckpt.due(epoch_boundary=True):
-                        ckpt.save(self, step_in_epoch=0)
-                _faults.maybe_die(self.iteration)
-            if ckpt is not None:
-                ckpt.save_if_progress(self, step_in_epoch=0)
-                ckpt.flush()
-            return self
-        finally:
-            finalize_listeners(self.listeners)
-
-    def _fire_listeners(self) -> None:
-        """Per-iteration listener callbacks, timed as the ``listener``
-        phase (they run on the host and may force a device score fetch)."""
-        if not self.listeners:
-            return
-        t0 = time.perf_counter()
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration)
-        _monitor.observe_phase("listener", time.perf_counter() - t0)
-
-    def _fit_batch(self, mds: MultiDataSet) -> None:
-        self.last_batch_size = mds.num_examples()
-        t0 = time.perf_counter()
-        features = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fmasks = (None if mds.features_masks is None else tuple(
-            None if m is None else jnp.asarray(m)
-            for m in mds.features_masks))
-        lmasks = (None if mds.labels_masks is None else tuple(
-            None if m is None else jnp.asarray(m) for m in mds.labels_masks))
-        _monitor.observe_phase("data", time.perf_counter() - t0)
-        iters = _monitor.counter("train_iterations_total",
-                                 "supervised train iterations")
-        if self._solver is not None:
-            for _ in range(self.conf.conf.num_iterations):
-                t1 = time.perf_counter()
-                self._score = self._solver.optimize(features, labels,
-                                                    fmasks, lmasks)
-                _monitor.observe_phase("step", time.perf_counter() - t1)
-                self.iteration += 1
-                iters.inc()
-                self._fire_listeners()
-            return
-        if getattr(self.conf, "backprop_type", "standard") == "tbptt":
-            for _ in range(self.conf.conf.num_iterations):
-                self._fit_tbptt(features, labels, fmasks, lmasks)
-            return
-        for _ in range(self.conf.conf.num_iterations):
-            t1 = time.perf_counter()
-            (self.params, self.updater_state, self.net_state,
-             score, health) = self._train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, features, labels, fmasks, lmasks,
-                self._rng_key)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            _monitor.observe_phase("step", time.perf_counter() - t1)
-            self._score = score
-            self.iteration += 1
-            iters.inc()
-            self._fire_listeners()
 
     # ---------------------------------------------------------------- tBPTT
     def _fit_tbptt(self, features, labels, fmasks, lmasks) -> None:
@@ -1675,98 +1068,7 @@ class ComputationGraph:
             raise ValueError("predict() requires a single-output graph")
         return np.argmax(out, axis=-1)
 
-    # ------------------------------------------------ flat-param invariant
-    def param_table(self) -> Dict[str, np.ndarray]:
-        from ..utils.device import fetch_all
-        self.init()
-        dev = {}
-        for name in self._layer_names():
-            for p in self.vertices[name].layer.param_order():
-                dev[f"{name}_{p}"] = self.params[name][p]
-        # fetch_all: per-array synchronous np.asarray costs one full
-        # host<->device round trip EACH (~320 arrays per StatsListener
-        # post on ResNet-50).
-        return dict(zip(dev, fetch_all(dev.values())))
-
-    def num_params(self) -> int:
-        self.init()
-        return sum(int(np.prod(p.shape))
-                   for tree in self.params.values()
-                   for p in jax.tree_util.tree_leaves(tree))
-
-    def get_flat_params(self) -> np.ndarray:
-        from ..utils.device import fetch_all
-        self.init()
-        dev = [self.params[name][p]
-               for name in self._layer_names()
-               for p in self.vertices[name].layer.param_order()]
-        chunks = [a.ravel() for a in fetch_all(dev)]
-        if not chunks:
-            return np.zeros((0,), np.float32)
-        return np.concatenate(chunks)
-
-    def set_flat_params(self, flat: np.ndarray) -> None:
-        self.init()
-        flat = np.asarray(flat)
-        offset = 0
-        for name in self._layer_names():
-            for p in self.vertices[name].layer.param_order():
-                shape = self.params[name][p].shape
-                size = int(np.prod(shape))
-                self.params[name][p] = jnp.asarray(
-                    flat[offset:offset + size].reshape(shape),
-                    self.params[name][p].dtype)
-                offset += size
-        if offset != flat.size:
-            raise ValueError(
-                f"Flat param size mismatch: expected {offset}, got "
-                f"{flat.size}")
-        self._sync_masters_from_params()
-
-    def _sync_masters_from_params(self) -> None:
-        """Re-derive fp32 masters after a direct param write; checkpoint
-        restore overwrites them with the saved fp32 values afterwards."""
-        for name, tree in self.updater_state.items():
-            if isinstance(tree, dict) and _updaters.MASTER_KEY in tree:
-                tree[_updaters.MASTER_KEY] = {
-                    k: jnp.asarray(self.params[name][k], jnp.float32)
-                    for k in tree[_updaters.MASTER_KEY]}
-
-    def get_flat_updater_state(self) -> np.ndarray:
-        self.init()
-        leaves = []
-        for name in self._layer_names():
-            leaves.extend(
-                np.asarray(l).ravel()
-                for l in jax.tree_util.tree_leaves(self.updater_state[name]))
-        if not leaves:
-            return np.zeros((0,), np.float32)
-        return np.concatenate(leaves)
-
-    def set_flat_updater_state(self, flat: np.ndarray) -> None:
-        self.init()
-        flat = np.asarray(flat)
-        offset = 0
-        for name in self._layer_names():
-            tree = self.updater_state[name]
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            new_leaves = []
-            for leaf in leaves:
-                size = int(np.prod(leaf.shape))
-                new_leaves.append(jnp.asarray(
-                    flat[offset:offset + size].reshape(leaf.shape),
-                    leaf.dtype))
-                offset += size
-            self.updater_state[name] = jax.tree_util.tree_unflatten(
-                treedef, new_leaves)
-
     # -------------------------------------------------------------- misc API
-    def set_listeners(self, *listeners) -> None:
-        self.listeners = list(listeners)
-
-    def add_listener(self, listener) -> None:
-        self.listeners.append(listener)
-
     def clone(self) -> "ComputationGraph":
         import copy
         other = ComputationGraph(copy.deepcopy(self.conf))

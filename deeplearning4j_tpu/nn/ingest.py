@@ -187,9 +187,12 @@ def device_decode(f, wire):
     each op bit-identical between numpy and XLA — the root of the
     wire-vs-float32 parity guarantee.  ``wire`` is a ``(denom, mult,
     add)`` python-float triple (weak-typed scalars: values never force
-    a retrace) or None for pass-through."""
+    a retrace) or None for pass-through; for a graph's tuple of inputs,
+    a tuple with one of those for each."""
     if wire is None:
         return f
+    if isinstance(f, (list, tuple)):
+        return tuple(device_decode(fi, w) for fi, w in zip(f, wire))
     import jax.numpy as jnp
     denom, mult, add = wire
     return f.astype(jnp.float32) / denom * mult + add
@@ -225,17 +228,11 @@ def epoch_index_batches(order: np.ndarray,
     return out
 
 
-def window_signature(ds) -> Tuple:
-    """Shape/mask-presence signature of a DataSet; a window only stacks
-    batches with identical signatures (a change flushes the window)."""
-    def shp(a):
-        return None if a is None else np.shape(a)
-    return (shp(ds.features), shp(ds.labels), shp(ds.features_mask),
-            shp(ds.labels_mask))
-
-
-def multi_window_signature(mds) -> Tuple:
-    """Signature for a MultiDataSet (lists of inputs/labels/masks)."""
+def window_signature(mds) -> Tuple:
+    """Shape/mask-presence signature of a MultiDataSet (a chain's
+    DataSet arrives wrapped as one: ``network._as_multi``); a window
+    only stacks batches with identical signatures (a change flushes the
+    window)."""
     def shps(seq):
         if seq is None:
             return None
@@ -244,22 +241,12 @@ def multi_window_signature(mds) -> Tuple:
             shps(mds.features_masks), shps(mds.labels_masks))
 
 
-def stack_window(batches) -> Tuple:
-    """Stack a window of same-signature DataSets into (W, B, ...) numpy
-    arrays (host-side, so the work overlaps on-chip execution of the
-    previous window).  Returns (features, labels, fmask, lmask)."""
-    features = np.stack([np.asarray(b.features) for b in batches])
-    labels = np.stack([np.asarray(b.labels) for b in batches])
-    fm = (None if batches[0].features_mask is None else
-          np.stack([np.asarray(b.features_mask) for b in batches]))
-    lm = (None if batches[0].labels_mask is None else
-          np.stack([np.asarray(b.labels_mask) for b in batches]))
-    return features, labels, fm, lm
-
-
-def stack_multi_window(mbs) -> Tuple:
-    """Graph twin of :func:`stack_window` for MultiDataSets: per-input
-    stacked lists (the shapes already agreed via the signature)."""
+def stack_window(mbs) -> Tuple:
+    """Stack a window of same-signature MultiDataSets into per-input
+    lists of (W, B, ...) numpy arrays (host-side, so the work overlaps
+    on-chip execution of the previous window).  Returns (features,
+    labels, fmasks, lmasks); a masks entry is None where no batch has
+    masks."""
     n_in = len(mbs[0].features)
     n_out = len(mbs[0].labels)
     features = [np.stack([np.asarray(m.features[i]) for m in mbs])
@@ -283,34 +270,16 @@ def stack_multi_window(mbs) -> Tuple:
     return features, labels, fmasks, lmasks
 
 
-def window_wire(batches) -> Tuple[Optional[np.ndarray], Optional[Tuple]]:
-    """When EVERY batch in a window carries the same-format uint8 wire
-    twin (and the wire is enabled), return the stacked ``(W, B, ...)``
-    uint8 array plus the ``(denom, mult, add)`` spec — the windowed
-    path then ships 1 byte/pixel and decodes on device.  Else
-    ``(None, None)`` and the window stages float32 (or host-cast
-    bfloat16) as before."""
-    if not wire_enabled():
-        return None, None
-    wires = [wire_of(b) for b in batches]
-    if any(w is None for w in wires):
-        return None, None
-    if len({w[1] for w in wires}) != 1:
-        return None, None
-    if any(w[0].shape != np.shape(b.features)
-           for w, b in zip(wires, batches)):
-        return None, None
-    return np.stack([w[0] for w in wires]), wires[0][1].as_tuple()
-
-
-def multi_window_wire(mbs, n_in: int):
-    """Graph twin of :func:`window_wire`: per-input wire staging for a
-    window of MultiDataSets (wire twins ride on ``_wires``, attached by
-    ``computation_graph._as_multi`` when the source batch carried one).
-    Returns ``(stacks, specs)`` — per-input lists where a wired slot
-    holds its stacked (W, B, ...) uint8 array / ``(denom, mult, add)``
-    spec and an unwired slot holds None — or ``(None, None)`` when no
-    input wires."""
+def window_wire(mbs, n_in: int):
+    """Per-input wire staging for a window of MultiDataSets (wire twins
+    ride on ``_wires``, attached by ``network._as_multi`` when the source
+    batch carried one).  Where EVERY batch of the window carries the
+    same-format uint8 twin of an input (and the wire is enabled), that
+    input ships 1 byte/pixel and decodes on device.  Returns ``(stacks,
+    specs)`` — per-input lists where a wired slot holds its stacked
+    (W, B, ...) uint8 array / ``(denom, mult, add)`` spec and an unwired
+    slot holds None — or ``(None, None)`` when no input wires and the
+    window stages float32 (or host-cast bfloat16) as before."""
     if not wire_enabled():
         return None, None
     wire_lists = [getattr(m, "_wires", None) for m in mbs]

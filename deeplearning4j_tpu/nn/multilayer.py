@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -29,13 +29,18 @@ from . import updaters as _updaters
 from . import weights as _weights
 from .. import monitor as _monitor
 from .conf.neural_net_configuration import MultiLayerConfiguration
+from .network import Network
 from ..datasets.dataset import DataSet
 
 Array = jax.Array
 
 
-class MultiLayerNetwork:
-    """Sequential model: list of layer configs -> pure train/inference fns."""
+class MultiLayerNetwork(Network):
+    """Sequential model: list of layer configs -> pure train/inference fns.
+    The training path (step programs, ``fit``, the flat accessors) is
+    ``network.Network``'s."""
+
+    _jit_prefix = "mln"
 
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
@@ -56,30 +61,6 @@ class MultiLayerNetwork:
         self._tbptt_step_cache: Dict[int, Any] = {}
         self._decode_grow_cache: Dict[int, Any] = {}
         self._precision: Optional[_precision.PrecisionPolicy] = None
-
-    def _pol(self) -> _precision.PrecisionPolicy:
-        """The precision policy, resolved once per network instance
-        (docs/PERFORMANCE.md) — param storage dtype, compute dtype,
-        updater-state dtype, and the fp32-master-weights flag."""
-        p = self._precision
-        if p is None:
-            p = self._precision = _precision.resolve_policy(self.conf.conf)
-        return p
-
-    @functools.cached_property
-    def _solver(self):
-        """Line-search solver when ``optimization_algo`` asks for one
-        (reference ``Solver.java``); None selects the jitted SGD path.
-        Unknown algorithms raise instead of silently training with SGD."""
-        from ..optimize.solvers import SGD, Solver
-        algo = (self.conf.conf.optimization_algo or SGD).lower()
-        if algo == SGD:
-            return None
-        if self.conf.backprop_type == "tbptt":
-            raise ValueError(
-                f"optimization_algo {algo!r} is incompatible with tBPTT; "
-                "use stochastic_gradient_descent")
-        return Solver(self, algo)
 
     # ------------------------------------------------------------------ init
     def init(self) -> "MultiLayerNetwork":
@@ -133,6 +114,18 @@ class MultiLayerNetwork:
         """Layer ``i`` in the step program's scopes (``layer.<name>``,
         ``update.<name>``; ``monitor/device_trace.py``)."""
         return f"{i}_{type(self.layers[i]).__name__}"
+
+    def _layer_items(self):
+        return [(i, self._scope_name(i), layer)
+                for i, layer in enumerate(self.layers)]
+
+    @staticmethod
+    def _inputs_of(arrays):
+        """One input, one output: ``_loss_fn`` takes each bare."""
+        if arrays is None:
+            return None
+        (only,) = arrays
+        return only
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, net_state, x, *, train: bool,
@@ -272,419 +265,6 @@ class MultiLayerNetwork:
                     labels, preout, lmask,
                     average=self.conf.conf.mini_batch)
         return data_loss, (new_state, new_carries)
-
-    def _reg_score(self, params) -> Array:
-        total = jnp.asarray(0.0, jnp.float32)
-        with _monitor.scope("reg"):
-            for i, layer in enumerate(self.layers):
-                total = total + _updaters.regularization_score(
-                    params[i], layer.l1_by_param(), layer.l2_by_param())
-        return total
-
-    # ------------------------------------------------------------ train step
-    def _apply_updates(self, params, updater_state, grads, iteration):
-        """DL4J-order updater application (l1/l2 into grad, grad-norm, then
-        per-param update rule)."""
-        new_params, new_updater_state = [], []
-        for i, layer in enumerate(self.layers):
-            g = grads[i]
-            if g:
-                with _monitor.scope("update", self._scope_name(i)):
-                    new_p, ustate = _updaters.apply_layer_updates(
-                        self._updater_conf(i), layer, params[i],
-                        updater_state[i], g, iteration)
-                new_params.append(new_p)
-                new_updater_state.append(ustate)
-            else:
-                new_params.append(params[i])
-                new_updater_state.append(updater_state[i])
-        return new_params, new_updater_state
-
-    def _build_train_step(self, health: bool):
-        """Build the jitted train step: fwd + bwd + updater in one XLA
-        program.  Donation lets XLA update params/updater state in place in
-        HBM (the analogue of the reference's in-place flat-buffer step).
-
-        With ``health=True`` the step additionally packs the per-layer
-        grad/param/update statistics (``monitor/health.py``) — a few
-        scalar reductions over values already in registers — applies the
-        in-jit divergence guard, and returns the packed vector as a
-        fifth output.  Both variants register under the same compile-
-        watch name: the fit paths dispatch the health variant only, so
-        the per-``fn`` compile counters stay meaningful."""
-        from ..monitor import health as _health
-
-        def step(params, updater_state, net_state, iteration, features,
-                 labels, features_mask, labels_mask, base_rng):
-            rng = jax.random.fold_in(base_rng, iteration)
-            (data_loss, (new_state, _)), grads = jax.value_and_grad(
-                self._loss_fn, has_aux=True)(
-                    params, net_state, features, labels, features_mask,
-                    labels_mask, rng, True)
-            new_params, new_updater_state = self._apply_updates(
-                params, updater_state, grads, iteration)
-            score = data_loss + self._reg_score(params)
-            if not health:
-                return new_params, new_updater_state, new_state, score
-            hvec, bad = _health.layer_stats(params, new_params, grads,
-                                            data_loss)
-            new_params, new_updater_state, new_state = _health.guard_select(
-                bad, (new_params, new_updater_state, new_state),
-                (params, updater_state, net_state))
-            return new_params, new_updater_state, new_state, score, hvec
-
-        return _monitor.watched_jit(step, name="mln.train_step",
-                                    donate_argnums=(0, 1, 2))
-
-    @functools.cached_property
-    def _train_step(self):
-        """Plain 4-output step (external callers: benches, scaling)."""
-        return self._build_train_step(health=False)
-
-    @functools.cached_property
-    def _train_step_h(self):
-        """Health-instrumented step; the ``fit`` paths use this one."""
-        return self._build_train_step(health=True)
-
-    def _build_multi_train_step(self, health: bool):
-        """S sequential train steps in ONE XLA program via ``lax.scan`` over
-        stacked (S, B, ...) batches.  The reference runs its inner loop on
-        the host (``StochasticGradientDescent.java:50-72``, one dispatch per
-        iteration); on TPU the scan keeps the whole loop on-chip, so
-        throughput is set by the MXU, not by host dispatch latency.
-
-        ``health=True`` stacks the packed per-step health vector as a
-        second scan output — (S, 2+3L) f32 riding the same dispatch, so
-        exact per-step telemetry costs zero extra dispatches."""
-
-        from . import ingest
-        from ..monitor import health as _health
-
-        def multi(params, updater_state, net_state, iteration, features,
-                  labels, features_mask, labels_mask, base_rng, wire=None):
-            def body(carry, xs):
-                p, u, s, it = carry
-                f, l, fm, lm = xs
-                f = ingest.device_decode(f, wire)
-                rng = jax.random.fold_in(base_rng, it)
-                (data_loss, (new_s, _)), grads = jax.value_and_grad(
-                    self._loss_fn, has_aux=True)(
-                        p, s, f, l, fm, lm, rng, True)
-                new_p, new_u = self._apply_updates(p, u, grads, it)
-                score = data_loss + self._reg_score(p)
-                if not health:
-                    return (new_p, new_u, new_s, it + 1), score
-                hvec, bad = _health.layer_stats(p, new_p, grads, data_loss)
-                new_p, new_u, new_s = _health.guard_select(
-                    bad, (new_p, new_u, new_s), (p, u, s))
-                return (new_p, new_u, new_s, it + 1), (score, hvec)
-
-            init = (params, updater_state, net_state,
-                    jnp.asarray(iteration, jnp.int32))
-            (params, updater_state, net_state, _), out = jax.lax.scan(
-                body, init, (features, labels, features_mask, labels_mask))
-            if not health:
-                return params, updater_state, net_state, out
-            scores, hstack = out
-            return params, updater_state, net_state, scores, hstack
-
-        return _monitor.watched_jit(multi, name="mln.multi_train_step",
-                                    donate_argnums=(0, 1, 2))
-
-    @functools.cached_property
-    def _multi_train_step(self):
-        """Plain 4-output scan step (AOT benches, profilers)."""
-        return self._build_multi_train_step(health=False)
-
-    @functools.cached_property
-    def _multi_train_step_h(self):
-        """Health-instrumented scan step; the ``fit`` paths use this."""
-        return self._build_multi_train_step(health=True)
-
-    def _build_gather_train_step(self, health: bool):
-        """Device-cached-epoch train step, v2: the epoch PERMUTATION is
-        computed on device (threefry ``fold_in(shuffle_key, epoch)``
-        feeding ``jax.random.permutation``) and up to ``fused`` whole
-        epochs scan in ONE XLA program, each step gathering its
-        minibatch from the HBM-resident dataset arrays.  v1 uploaded a
-        host-shuffled (S, B) int32 index array every epoch; v2's
-        steady-state epochs move ZERO bytes host->device — the epoch
-        loop never leaves the chip.  When the resident features are the
-        uint8 wire, the affine decode fuses into the gathered batch
-        (``ingest.device_decode``).
-
-        Static args (``fused``/``steps``/``batch``/``shuffle``/
-        ``tail``/``start``/``run``) fix the program shape;
-        ``first_epoch`` stays dynamic (weak int32) so advancing epochs
-        never retraces.  ``tail > 0`` selects the 1-step tail dispatch:
-        the SAME epoch permutation is recomputed and its last ``tail``
-        entries form the ragged final batch, keeping v1's batch
-        boundaries.  ``start``/``run`` select the sub-range
-        ``[start, start+run)`` of the epoch's full-batch steps — the
-        preemption-resume hook: a checkpoint restored mid-epoch
-        re-derives the SAME permutation and scans from the saved
-        offset, so the split epoch is bit-identical to the fused one
-        (the scan body compiles to the same per-step HLO regardless of
-        trip count, and the carry chain crosses dispatches exactly).
-
-        ``health=True`` adds the (S, 2+3L) packed per-step health stack
-        as a second scan output, fetched once per dispatch — the fused
-        multi-epoch program stays ONE dispatch per call."""
-        from . import ingest
-        from ..monitor import health as _health
-
-        def multi(params, updater_state, net_state, iteration, data_f,
-                  data_l, base_rng, shuffle_key, first_epoch, fused,
-                  steps, batch, shuffle, tail, wire, start=0, run=None):
-            n = data_f.shape[0]
-            span = steps if run is None else run
-
-            def epoch_rows(e):
-                if shuffle:
-                    perm = jax.random.permutation(
-                        jax.random.fold_in(shuffle_key, e), n)
-                else:
-                    perm = jnp.arange(n)
-                if tail:
-                    return perm[steps * batch:].reshape(1, tail)
-                return perm[start * batch:(start + span) * batch] \
-                    .reshape(span, batch)
-
-            rows = jax.vmap(epoch_rows)(first_epoch + jnp.arange(fused))
-            rows = rows.reshape((-1,) + rows.shape[2:])
-
-            def body(carry, idx_row):
-                p, u, s, it = carry
-                with _monitor.scope("ingest", "gather"):
-                    f = ingest.device_decode(
-                        jnp.take(data_f, idx_row, axis=0), wire)
-                    l = jnp.take(data_l, idx_row, axis=0)
-                rng = jax.random.fold_in(base_rng, it)
-                (data_loss, (new_s, _)), grads = jax.value_and_grad(
-                    self._loss_fn, has_aux=True)(
-                        p, s, f, l, None, None, rng, True)
-                new_p, new_u = self._apply_updates(p, u, grads, it)
-                score = data_loss + self._reg_score(p)
-                if not health:
-                    return (new_p, new_u, new_s, it + 1), score
-                hvec, bad = _health.layer_stats(p, new_p, grads, data_loss)
-                new_p, new_u, new_s = _health.guard_select(
-                    bad, (new_p, new_u, new_s), (p, u, s))
-                return (new_p, new_u, new_s, it + 1), (score, hvec)
-
-            init = (params, updater_state, net_state,
-                    jnp.asarray(iteration, jnp.int32))
-            (params, updater_state, net_state, _), out = jax.lax.scan(
-                body, init, rows)
-            if not health:
-                return params, updater_state, net_state, out
-            scores, hstack = out
-            return params, updater_state, net_state, scores, hstack
-
-        return _monitor.watched_jit(
-            multi, name="mln.gather_train_step",
-            static_argnums=(9, 10, 11, 12, 13, 15, 16),
-            donate_argnums=(0, 1, 2),
-            identity=lambda: _monitor.program_identity(
-                self, "gather_train_step", health))
-
-    @functools.cached_property
-    def _gather_train_step(self):
-        """Plain 4-output gather step (profilers, external callers)."""
-        return self._build_gather_train_step(health=False)
-
-    @functools.cached_property
-    def _gather_train_step_h(self):
-        """Health-instrumented gather step; ``_fit_device_cached`` uses
-        this one."""
-        return self._build_gather_train_step(health=True)
-
-    def _fit_device_cached(self, source, epochs: int,
-                           start_step: int = 0, ckpt=None):
-        """One ``fit`` over a device-resident dataset (see
-        ``_gather_train_step``).  ``source`` is the underlying
-        ``ListDataSetIterator`` vetted by ``ingest.cacheable_source``.
-        Batch boundaries (incl. the tail batch) and the per-iteration
-        RNG/updater stream are IDENTICAL to the per-batch path; the
-        example order comes from the on-device threefry permutation
-        stream (keyed off the fit RNG, continuing across fits via
-        ``self.epoch``) — parity-tested against a host replay of the
-        same permutations.  Listeners fire per iteration by replaying
-        the scanned scores.  ``start_step``/``ckpt`` are the resume
-        offset and checkpoint manager threaded through to the shared
-        driver (``ingest.run_device_cached_fit``)."""
-        from . import ingest
-
-        data_f, data_l, wire = ingest.device_cached_arrays(
-            self, source._ds, source.get_preprocessor())
-        shuffle_key = jax.random.fold_in(self._rng_key, 0xFFFFFFFF)
-        steps = source._ds.num_examples() // source._batch
-
-        def dispatch(first_epoch, fused, tail, start=0, run=None):
-            (self.params, self.updater_state, self.net_state,
-             scores, health) = self._gather_train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, data_f, data_l, self._rng_key,
-                shuffle_key, first_epoch, fused, steps, source._batch,
-                bool(source._shuffle), tail, wire, start,
-                steps if run is None else run)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            return scores
-
-        return ingest.run_device_cached_fit(self, source, epochs, dispatch,
-                                            start_step=start_step,
-                                            ckpt=ckpt)
-
-    def _fit_windowed(self, iterator, epochs: int, window: int,
-                      ckpt=None):
-        """Streaming ``fit(iterator)`` in multi-batch windows: the host
-        stacks window k+1 (numpy) and enqueues its transfer while window
-        k's multi-step scan runs on-chip — JAX async dispatch provides
-        the overlap, nothing blocks until scores are fetched (the
-        double-buffered-staging half of the ingest design; datasets that
-        fit HBM take ``_fit_device_cached`` instead).  ``ckpt`` saves at
-        epoch boundaries (windows re-stack from the host iterator, so
-        mid-epoch offsets are not replayable here — the epoch-cache
-        path owns exact mid-epoch resume)."""
-        from . import ingest
-        from ..resilience import faults as _faults
-
-        replay = ingest.ScoreReplayer(self)
-
-        def dispatch(buf):
-            t0 = time.perf_counter()
-            # straggler point inside the timed data phase, so an armed
-            # DL4J_TPU_FAULT_SLOW_WORKER_MS stall lands in phase_data_ms
-            # and the step attributor names "data" as the dominant
-            # component (monitor/attribution.py)
-            _faults.slow_worker()
-            features, labels, fm, lm = ingest.stack_window(buf)
-            u8, wire = ingest.window_wire(buf)
-            if u8 is not None:
-                features = u8      # 1 byte/pixel; decode fused on device
-            else:
-                features = ingest.cast_for_transfer(
-                    features, self._pol().compute_name)
-            features = jnp.asarray(features)
-            labels = jnp.asarray(labels)
-            fm = None if fm is None else jnp.asarray(fm)
-            lm = None if lm is None else jnp.asarray(lm)
-            _monitor.gauge(
-                "ingest_staged_bytes",
-                "bytes uploaded to the device per staging event").set(
-                features.nbytes + labels.nbytes, path="window")
-            t1 = time.perf_counter()
-            _monitor.observe_phase("data", t1 - t0)
-            (self.params, self.updater_state, self.net_state,
-             scores, health) = self._multi_train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, features, labels, fm, lm, self._rng_key,
-                wire)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            replay.add(self.iteration, scores)
-            _monitor.observe_phase("step", time.perf_counter() - t1)
-            _monitor.counter("train_iterations_total",
-                             "supervised train iterations").inc(len(buf))
-            self.iteration += len(buf)
-            self.last_batch_size = buf[0].num_examples()
-
-        it_mark = self.iteration
-        for _ in range(epochs):
-            with _monitor.span("fit/epoch", epoch=self.epoch,
-                               path="window"):
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_start"):
-                        listener.on_epoch_start(self)
-                if hasattr(iterator, "reset"):
-                    iterator.reset()
-                buf, sig = [], None
-                for ds in iterator:
-                    s = ingest.window_signature(ds)
-                    if buf and (s != sig or len(buf) >= window):
-                        dispatch(buf)
-                        buf = []
-                    sig = s
-                    buf.append(ds)
-                if buf:
-                    dispatch(buf)
-                if self.listeners:
-                    t2 = time.perf_counter()
-                    replay.replay()
-                    _monitor.observe_phase("listener",
-                                           time.perf_counter() - t2)
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_end"):
-                        listener.on_epoch_end(self)
-                self.epoch += 1
-            if ckpt is not None:
-                ckpt.note_steps(self.iteration - it_mark)
-                it_mark = self.iteration
-                if ckpt.due(epoch_boundary=True):
-                    replay.replay()
-                    ckpt.save(self, step_in_epoch=0)
-            _faults.maybe_die(self.iteration)
-        if ckpt is not None:
-            replay.replay()
-            ckpt.save_if_progress(self, step_in_epoch=0)
-            ckpt.flush()
-        replay.finish()
-        return self
-
-    def fit_scan(self, batches: Sequence[DataSet]) -> np.ndarray:
-        """Fit a list of same-shaped minibatches in one device dispatch
-        (scan-based inner loop).  Returns the per-step scores.  Listeners
-        fire once at the end with the final iteration — per-step host
-        callbacks would break the single-HLO hot loop.
-
-        Supports the standard-backprop regime only: configs using tBPTT,
-        pretraining, or ``num_iterations > 1`` must go through ``fit()``
-        (raises loudly rather than silently training differently)."""
-        self.init()
-        if self.conf.backprop_type == "tbptt":
-            raise ValueError("fit_scan does not support tBPTT; use fit()")
-        if self.conf.pretrain and not self._pretrain_done:
-            raise ValueError("fit_scan does not run pretraining; call "
-                             "pretrain() (or fit()) first")
-        if self.conf.conf.num_iterations != 1:
-            raise ValueError("fit_scan runs one update per batch; "
-                             "num_iterations > 1 must use fit()")
-        if self._solver is not None:
-            raise ValueError("fit_scan supports the SGD path only; this "
-                             "config uses a line-search solver")
-
-        def stack_masks(get):
-            present = [get(b) is not None for b in batches]
-            if not any(present):
-                return None
-            if not all(present):
-                raise ValueError(
-                    "Mixed mask presence across batches in fit_scan; "
-                    "provide masks on all batches or none")
-            return jnp.stack([jnp.asarray(get(b)) for b in batches])
-
-        from ..resilience import faults as _faults
-        t0 = time.perf_counter()
-        # straggler point inside the timed data phase (see dispatch())
-        _faults.slow_worker()
-        features = jnp.stack([jnp.asarray(b.features) for b in batches])
-        labels = jnp.stack([jnp.asarray(b.labels) for b in batches])
-        fmask = stack_masks(lambda b: b.features_mask)
-        lmask = stack_masks(lambda b: b.labels_mask)
-        t1 = time.perf_counter()
-        _monitor.observe_phase("data", t1 - t0)
-        (self.params, self.updater_state, self.net_state,
-         scores, health) = self._multi_train_step_h(
-            self.params, self.updater_state, self.net_state, self.iteration,
-            features, labels, fmask, lmask, self._rng_key)
-        _monitor.health.record_dispatch(self, health, self.iteration)
-        _monitor.observe_phase("step", time.perf_counter() - t1)
-        _monitor.counter("train_iterations_total",
-                         "supervised train iterations").inc(len(batches))
-        self.iteration += len(batches)
-        self._score = scores[-1]
-        self.last_batch_size = batches[0].num_examples()
-        self._fire_listeners()
-        return np.asarray(scores)
 
     def _last_stateful_recurrent(self) -> int:
         """Index of the deepest layer carrying real recurrent state (-1 if
@@ -941,208 +521,7 @@ class MultiLayerNetwork:
                 self._fire_listeners()
         return self
 
-    # ------------------------------------------------------------------- fit
-    def _resolve_resilience(self, checkpoint, resume_from, epochs):
-        """(manager, start_step, remaining_epochs) for ``fit``'s
-        ``checkpoint=``/``resume_from=`` hooks; the no-resilience call
-        stays import-free."""
-        if checkpoint is None and resume_from is None:
-            return None, 0, epochs
-        from ..resilience.checkpoint import resolve_fit_resilience
-        return resolve_fit_resilience(self, checkpoint, resume_from,
-                                      epochs)
-
-    def _warn_partial_epoch_restart(self, start_step: int,
-                                    path: str) -> None:
-        """Mid-epoch resume offsets are only replayable on the
-        epoch-cache path (the shuffle lives in the on-device threefry
-        stream); other paths restart the interrupted epoch."""
-        if start_step:
-            import warnings
-            warnings.warn(
-                f"resume_from checkpoint was taken mid-epoch "
-                f"(step_in_epoch={start_step}) but the {path} path "
-                "cannot seek into an epoch; restarting the epoch from "
-                "step 0 (at-least-once semantics)", RuntimeWarning)
-
-    def fit(self, data, labels=None, epochs: int = 1,
-            ingest: str = "auto",
-            window: int = 16, checkpoint=None,
-            resume_from=None) -> "MultiLayerNetwork":
-        """Train (reference ``fit(DataSetIterator):976`` /
-        ``fit(INDArray,INDArray):1406``).
-
-        ``data`` may be a DataSetIterator-like iterable of :class:`DataSet`,
-        a single :class:`DataSet`, or a features array with ``labels``.
-
-        With ``conf.pretrain=True`` the first call runs layer-wise
-        unsupervised pretraining before supervised backprop (reference
-        ``fit`` at ``:991``); with ``conf.backprop=False`` only pretraining
-        runs.
-
-        ``ingest`` selects the iterator data path (the reference hides
-        ETL behind ``AsyncDataSetIterator`` prefetch; on TPU the wins
-        are device residency and transfer/compute overlap):
-
-        - ``"auto"`` (default): device-resident epoch cache when the
-          dataset fits HBM (``nn/ingest.py`` eligibility), else
-          windowed double-buffered staging, else per-batch.
-        - ``"cache"`` / ``"window"`` / ``"batch"``: force one path.
-
-        The cache/window paths run multi-step ``lax.scan`` dispatches
-        and fire listeners by exact per-step score replay (params seen
-        by a replayed listener are end-of-dispatch — the ``fit_scan``
-        compromise).  Solver/tBPTT/num_iterations>1 configs always use
-        the per-batch path.
-
-        Resilience (``docs/RESILIENCE.md``): ``checkpoint=`` (a
-        ``resilience.CheckpointManager`` or a directory) saves
-        preemption-safe checkpoints at the manager's step/second
-        cadence (epoch boundaries by default); ``resume_from=``
-        (``"auto"``, a directory, or a checkpoint path) restores
-        params/updater/RNG/progress before training.  With
-        ``resume_from``, ``epochs`` is the TOTAL epoch target the
-        original run aimed for — the restored epoch counter determines
-        how much work remains, so callers re-issue the identical fit
-        call after a preemption.  On the epoch-cache path a mid-epoch
-        restore resumes at the exact fused-scan step offset
-        (bit-identical to the uninterrupted run); the window/batch
-        paths restart the interrupted epoch from its beginning.
-        """
-        if ingest not in ("auto", "cache", "window", "batch"):
-            raise ValueError(
-                f"unknown ingest mode {ingest!r}; expected 'auto', "
-                "'cache', 'window', or 'batch'")
-        self.init()
-        ckpt, start_step, epochs = self._resolve_resilience(
-            checkpoint, resume_from, epochs)
-        if labels is not None:
-            data = DataSet(np.asarray(data), np.asarray(labels))
-        if isinstance(data, DataSet):
-            batches: Sequence[DataSet] = [data]
-            iterator = None
-        else:
-            iterator = data
-            batches = None
-
-        from ..optimize.listeners.listeners import finalize_listeners
-        try:
-            if self.conf.pretrain and not self._pretrain_done:
-                if batches is None and not hasattr(iterator, "reset"):
-                    # One-shot iterable: materialize so layer-wise
-                    # pretraining and the supervised phase each see the
-                    # full data.
-                    batches = list(iterator)
-                    iterator = None
-                self.pretrain(batches if batches is not None else iterator)
-                self._pretrain_done = True
-            if not self.conf.backprop:
-                return self
-
-            if (iterator is not None and ingest != "batch"
-                    and self._solver is None
-                    and self.conf.backprop_type != "tbptt"
-                    and self.conf.conf.num_iterations == 1):
-                from . import ingest as ingest_mod
-                if ingest in ("auto", "cache"):
-                    source = ingest_mod.cacheable_source(iterator)
-                    if source is not None:
-                        return self._fit_device_cached(
-                            source, epochs, start_step=start_step,
-                            ckpt=ckpt)
-                    if ingest == "cache":
-                        raise ValueError(
-                            "ingest='cache' but the iterator is not "
-                            "device-cacheable (see nn/ingest.py "
-                            "eligibility)")
-                self._warn_partial_epoch_restart(start_step, "window")
-                return self._fit_windowed(iterator, epochs, window,
-                                          ckpt=ckpt)
-
-            self._warn_partial_epoch_restart(start_step, "batch")
-            from ..resilience import faults as _faults
-            it_mark = self.iteration
-            for _ in range(epochs):
-                with _monitor.span("fit/epoch", epoch=self.epoch,
-                                   path="batch"):
-                    for listener in self.listeners:
-                        if hasattr(listener, "on_epoch_start"):
-                            listener.on_epoch_start(self)
-                    it = batches if batches is not None else iterator
-                    if hasattr(it, "reset"):
-                        it.reset()
-                    for ds in it:
-                        self._fit_batch(ds)
-                    for listener in self.listeners:
-                        if hasattr(listener, "on_epoch_end"):
-                            listener.on_epoch_end(self)
-                    self.epoch += 1
-                if ckpt is not None:
-                    ckpt.note_steps(self.iteration - it_mark)
-                    it_mark = self.iteration
-                    if ckpt.due(epoch_boundary=True):
-                        ckpt.save(self, step_in_epoch=0)
-                _faults.maybe_die(self.iteration)
-            if ckpt is not None:
-                ckpt.save_if_progress(self, step_in_epoch=0)
-                ckpt.flush()
-            return self
-        finally:
-            finalize_listeners(self.listeners)
-
-    def _fire_listeners(self) -> None:
-        """Per-iteration listener callbacks, timed as the ``listener``
-        phase (they run on the host and may force a device score fetch)."""
-        if not self.listeners:
-            return
-        t0 = time.perf_counter()
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration)
-        _monitor.observe_phase("listener", time.perf_counter() - t0)
-
-    def _fit_batch(self, ds: DataSet) -> None:
-        from ..resilience import faults as _faults
-        self.last_batch_size = ds.num_examples()
-        t0 = time.perf_counter()
-        # straggler point inside the timed data phase (see dispatch())
-        _faults.slow_worker()
-        features = jnp.asarray(ds.features)
-        labels = jnp.asarray(ds.labels)
-        fmask = (None if ds.features_mask is None
-                 else jnp.asarray(ds.features_mask))
-        lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        _monitor.observe_phase("data", time.perf_counter() - t0)
-        iters = _monitor.counter("train_iterations_total",
-                                 "supervised train iterations")
-        if self._solver is not None:
-            # line-search solver family (reference Solver.optimize path)
-            for _ in range(self.conf.conf.num_iterations):
-                t1 = time.perf_counter()
-                self._score = self._solver.optimize(features, labels,
-                                                    fmask, lmask)
-                _monitor.observe_phase("step", time.perf_counter() - t1)
-                self.iteration += 1
-                iters.inc()
-                self._fire_listeners()
-            return
-        if self.conf.backprop_type == "tbptt":
-            for _ in range(self.conf.conf.num_iterations):
-                self._fit_tbptt(features, labels, fmask, lmask)
-            return
-        for _ in range(self.conf.conf.num_iterations):
-            t1 = time.perf_counter()
-            (self.params, self.updater_state, self.net_state,
-             score, health) = self._train_step_h(
-                self.params, self.updater_state, self.net_state,
-                self.iteration, features, labels, fmask, lmask,
-                self._rng_key)
-            _monitor.health.record_dispatch(self, health, self.iteration)
-            _monitor.observe_phase("step", time.perf_counter() - t1)
-            self._score = score
-            self.iteration += 1
-            iters.inc()
-            self._fire_listeners()
-
+    # ----------------------------------------------------------------- tBPTT
     def _fit_tbptt(self, features, labels, fmask, lmask) -> None:
         """Slice the time axis into tbptt_fwd_length windows, carrying
         recurrent state forward across windows (reference
@@ -1543,102 +922,7 @@ class MultiLayerNetwork:
         """Macro F1 on a DataSet/iterator (reference ``f1Score``)."""
         return self.evaluate(data).f1()
 
-    # ------------------------------------------------ flat-param invariant
-    def param_table(self) -> Dict[str, np.ndarray]:
-        """Named params ``{"0_W": ..., "0_b": ...}`` (reference
-        ``paramTable()`` naming)."""
-        from ..utils.device import fetch_all
-        self.init()
-        dev = {}
-        for i, layer in enumerate(self.layers):
-            for name in layer.param_order():
-                dev[f"{i}_{name}"] = self.params[i][name]
-        return dict(zip(dev, fetch_all(dev.values())))
-
-    def num_params(self) -> int:
-        self.init()
-        return sum(int(np.prod(p.shape))
-                   for tree in self.params
-                   for p in jax.tree_util.tree_leaves(tree))
-
-    def get_flat_params(self) -> np.ndarray:
-        """One contiguous vector over all params in deterministic layer/param
-        order — the reference's single flat buffer (``init():396-470``)."""
-        from ..utils.device import fetch_all
-        self.init()
-        dev = [self.params[i][name]
-               for i, layer in enumerate(self.layers)
-               for name in layer.param_order()]
-        chunks = [a.ravel() for a in fetch_all(dev)]
-        if not chunks:
-            return np.zeros((0,), np.float32)
-        return np.concatenate(chunks)
-
-    def set_flat_params(self, flat: np.ndarray) -> None:
-        self.init()
-        flat = np.asarray(flat)
-        offset = 0
-        for i, layer in enumerate(self.layers):
-            for name in layer.param_order():
-                shape = self.params[i][name].shape
-                size = int(np.prod(shape))
-                self.params[i][name] = jnp.asarray(
-                    flat[offset:offset + size].reshape(shape),
-                    self.params[i][name].dtype)
-                offset += size
-        if offset != flat.size:
-            raise ValueError(
-                f"Flat param size mismatch: expected {offset}, got {flat.size}")
-        self._sync_masters_from_params()
-
-    def _sync_masters_from_params(self) -> None:
-        """Re-derive the fp32 masters from freshly-assigned params so the
-        master/param coherence invariant holds after a direct param write
-        (param averaging, solvers).  Checkpoint restore overwrites the
-        masters afterwards with the exact saved fp32 values
-        (set_flat_params runs before set_flat_updater_state)."""
-        for i, tree in enumerate(self.updater_state):
-            if isinstance(tree, dict) and _updaters.MASTER_KEY in tree:
-                tree[_updaters.MASTER_KEY] = {
-                    k: jnp.asarray(self.params[i][k], jnp.float32)
-                    for k in tree[_updaters.MASTER_KEY]}
-
-    def get_flat_updater_state(self) -> np.ndarray:
-        """Updater state as one flat vector (reference
-        ``BaseUpdater.getStateViewArray`` -> ``updaterState.bin``)."""
-        self.init()
-        leaves = []
-        for tree in self.updater_state:
-            leaves.extend(np.asarray(l).ravel()
-                          for l in jax.tree_util.tree_leaves(tree))
-        if not leaves:
-            return np.zeros((0,), np.float32)
-        return np.concatenate(leaves)
-
-    def set_flat_updater_state(self, flat: np.ndarray) -> None:
-        self.init()
-        flat = np.asarray(flat)
-        offset = 0
-        new_states = []
-        for tree in self.updater_state:
-            leaves, treedef = jax.tree_util.tree_flatten(tree)
-            new_leaves = []
-            for leaf in leaves:
-                size = int(np.prod(leaf.shape))
-                new_leaves.append(jnp.asarray(
-                    flat[offset:offset + size].reshape(leaf.shape),
-                    leaf.dtype))
-                offset += size
-            new_states.append(jax.tree_util.tree_unflatten(treedef, new_leaves))
-        self.updater_state = new_states
-
     # -------------------------------------------------------------- misc API
-    def set_listeners(self, *listeners) -> None:
-        self.listeners = list(listeners)
-
-    def add_listener(self, listener) -> None:
-        self.listeners.append(listener)
-
     def clone(self) -> "MultiLayerNetwork":
         """Config+params copy (reference ``clone()``)."""
         import copy

@@ -175,14 +175,10 @@ class ParallelWrapper:
                 from ..nn import ingest
                 params, updater_state, net_state, it = carry
                 f, l, fm, lm = batch
-                if wire is not None:
-                    # uint8 wire staging: batches crossed the host->device
-                    # link at 1 byte/pixel; the affine decode fuses here
-                    if isinstance(f, tuple):      # graph: per-input specs
-                        f = tuple(ingest.device_decode(fi, w)
-                                  for fi, w in zip(f, wire))
-                    else:
-                        f = ingest.device_decode(f, wire)
+                # uint8 wire staging: batches crossed the host->device
+                # link at 1 byte/pixel; the affine decode fuses here
+                # (a graph: one spec for each input)
+                f = ingest.device_decode(f, wire)
                 if tbptt:
                     # the single-device windowed program, per worker:
                     # slice tbptt_fwd_length windows, carry recurrent

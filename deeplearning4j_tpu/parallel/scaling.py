@@ -105,7 +105,7 @@ def collective_overhead_report(net_factory: Callable[[], object],
     state = [net.params, net.updater_state, net.net_state, 0]
 
     def plain_dispatch():
-        (state[0], state[1], state[2], score) = net._train_step(
+        (state[0], state[1], state[2], score, _) = net._train_step(
             state[0], state[1], state[2], state[3], fj, lj, None, None,
             net._rng_key)
         state[3] += 1

@@ -422,12 +422,28 @@ def test_attributor_quiet_without_steps():
     assert att.tick() is None                     # no steps -> no record
 
 
-def test_slow_worker_fault_attributed_to_data_component():
+def _graph():
+    from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
+    conf = (NeuralNetConfiguration.builder()
+            .seed(7).updater("sgd").learning_rate(0.1)
+            .weight_init("xavier").graph_builder().add_inputs("in")
+            .add_layer("d", DenseLayer(n_in=4, n_out=8, activation="tanh"),
+                       "in")
+            .add_layer("o", OutputLayer(n_in=8, n_out=3,
+                                        activation="softmax",
+                                        loss="mcxent"), "d")
+            .set_outputs("o").build())
+    return ComputationGraph(conf).init()
+
+
+@pytest.mark.parametrize("build", [_net, _graph], ids=["mln", "graph"])
+def test_slow_worker_fault_attributed_to_data_component(build):
     """DL4J_TPU_FAULT_SLOW_WORKER acceptance: an armed straggler stall
     lands in the timed data phase, so the attributor's anomaly names
-    ``data`` as the dominant component."""
+    ``data`` as the dominant component, whichever container trains
+    (the fault point sits once, in ``nn/network.py``)."""
     att = StepAttributor(warmup_ticks=3)
-    net = _net()
+    net = build()
     ds = _data(n=32)
     net.fit(ds)                                   # compile outside baseline
     att.tick()

@@ -699,6 +699,100 @@ class NetClient:
     assert run(root, rules={"R3"}) == []
 
 
+def test_self_call_in_a_base_class_reaches_the_subclasses(tmp_path):
+    """A step written once in a base class calls hooks its subclasses
+    define in OTHER modules: the traced set rooted at the base's jit
+    site reaches every subclass's definition (as a call and as the
+    argument of a transformation), through a closure the base returns;
+    a subclass calling up resolves to the base; an unrelated class with
+    a same-named method stays out."""
+    from tools.analyze import callgraph
+    root = str(tmp_path)
+    _write(root, "deeplearning4j_tpu/__init__.py", "")
+    _write(root, "deeplearning4j_tpu/base.py", '''
+import jax
+
+class Net:
+    def _reg(self, p):
+        return p
+
+    def _step_fn(self):
+        def body(p, x):
+            loss, g = jax.value_and_grad(self._loss_fn)(p, x)
+            return self._update(p, g), loss
+        return body
+
+    def build(self):
+        def step(p, x):
+            return self._step_fn()(p, x)
+        return jax.jit(step)
+''')
+    _write(root, "deeplearning4j_tpu/kinds.py", '''
+import time
+from deeplearning4j_tpu.base import Net
+
+class Chain(Net):
+    def _loss_fn(self, p, x):
+        return self._reg(p) * time.time()
+
+    def _update(self, p, g):
+        return p - g
+
+class Graph(Net):
+    def _loss_fn(self, p, x):
+        return p * x
+
+    def _update(self, p, g):
+        return p - g * time.perf_counter()
+
+class Stranger:
+    def _loss_fn(self, p, x):
+        return time.time()
+''')
+    traced = callgraph.load(root).traced()
+    assert traced["deeplearning4j_tpu.base"] >= {
+        "Net.step", "Net._step_fn", "Net.body", "Net._reg"}
+    assert traced["deeplearning4j_tpu.kinds"] == {
+        "Chain._loss_fn", "Chain._update", "Graph._loss_fn",
+        "Graph._update"}
+    fs = run(root, rules={"R1"})
+    assert sorted((f.path.rsplit("/", 1)[-1], f.line) for f in fs) == [
+        ("kinds.py", 7), ("kinds.py", 17)]
+    # one file holding base and subclass: the per-module index alone
+    fs = lint_source('''
+import time
+import jax
+
+class Net:
+    def build(self):
+        def step(x):
+            return self._hook(x)
+        return jax.jit(step)
+
+class Kind(Net):
+    def _hook(self, x):
+        return x * time.time()
+''', path="deeplearning4j_tpu/one.py", rules={"R1"})
+    assert [f.line for f in fs] == [13]
+
+
+def test_repo_traced_set_reaches_both_containers():
+    """The step is written once in ``nn/network.py``; its jit sites
+    still reach both containers' ``_forward`` and ``_loss_fn``, so R1
+    keeps guarding them."""
+    from tools.analyze import callgraph
+    traced = callgraph.load(REPO_ROOT).traced()
+    assert {"Network.step", "Network._step_fn", "Network.body",
+            "Network._apply_updates", "Network._reg_score"} <= \
+        traced["deeplearning4j_tpu.nn.network"]
+    assert {"MultiLayerNetwork._forward", "MultiLayerNetwork._loss_fn",
+            "MultiLayerNetwork._layer_items"} <= \
+        traced["deeplearning4j_tpu.nn.multilayer"]
+    assert {"ComputationGraph._forward", "ComputationGraph._loss_fn",
+            "ComputationGraph._layer_items"} <= \
+        traced["deeplearning4j_tpu.nn.computation_graph"]
+
+
 # ------------------------------------------------ CLI exit codes
 
 def test_cli_exit_codes(tmp_path):

@@ -32,9 +32,13 @@ def _ds(n=6, n_in=4, n_classes=3, seed=0):
 
 
 # -------------------------------------------------------------- basic DAGs
-def test_linear_graph_matches_multilayer():
+@pytest.mark.parametrize("how", ["cache", "window", "batch", "fit_scan"])
+def test_linear_graph_matches_multilayer(how):
     """A chain CG must compute exactly what the MLN computes with the same
-    params (reference: CG with single path == MLN)."""
+    params (reference: CG with single path == MLN), and train to the same
+    bits however the data arrives: both go through the one driver and
+    the one step of ``nn/network.py``."""
+    from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
     g = (_builder().add_inputs("in")
          .add_layer("dense", DenseLayer(n_in=4, n_out=6), "in")
          .add_layer("out", OutputLayer(n_in=6, n_out=3), "dense")
@@ -49,14 +53,24 @@ def test_linear_graph_matches_multilayer():
     mln = MultiLayerNetwork(mln_conf).init()
     cg.set_flat_params(mln.get_flat_params())
 
-    ds = _ds()
+    ds = _ds(n=24)
+    ds = DataSet(ds.features.astype(np.float32),
+                 ds.labels.astype(np.float32))
     np.testing.assert_allclose(mln.output(ds.features), cg.output(ds.features),
                                rtol=1e-10)
-    # and one training step stays identical
-    mln.fit(ds)
-    cg.fit(ds)
-    np.testing.assert_allclose(mln.get_flat_params(), cg.get_flat_params(),
-                               rtol=1e-10)
+
+    def train(net):
+        if how == "fit_scan":
+            return net.fit_scan(list(ListDataSetIterator(ds, 8)))
+        net.fit(ListDataSetIterator(ds, 8), epochs=2, ingest=how)
+        return net.score()
+
+    np.testing.assert_array_equal(train(mln), train(cg))
+    assert mln.iteration == cg.iteration == (3 if how == "fit_scan" else 6)
+    np.testing.assert_array_equal(mln.get_flat_params(),
+                                  cg.get_flat_params())
+    np.testing.assert_array_equal(mln.get_flat_updater_state(),
+                                  cg.get_flat_updater_state())
 
 
 def test_topological_order_and_cycle_detection():

@@ -58,36 +58,38 @@ CONTAINERS = {
 }
 
 
-def _op_names(net, program: str, with_health: bool):
+def _op_names(net, program: str):
     """The ``op_name`` of every instruction in the compiled HLO text of
     one of ``net``'s programs."""
     graph = isinstance(net, ComputationGraph)
     x = jnp.ones((8, 4), jnp.float32)
     y = jnp.eye(3, dtype=jnp.float32)[jnp.arange(8) % 3]
     one = (lambda a: (a,)) if graph else (lambda a: a)
+    state = (net.params, net.updater_state, net.net_state, 0)
     if program == "output":
         lowered = net._output_fn.lower(net.params, net.net_state, one(x),
                                        None)
     elif program == "train_step":
-        lowered = net._build_train_step(with_health).lower(
-            net.params, net.updater_state, net.net_state, 0, one(x), one(y),
-            None, None, net._rng_key)
+        lowered = net._train_step.lower(
+            *state, one(x), one(y), None, None, net._rng_key)
+    elif program == "multi_train_step":
+        lowered = net._multi_train_step.lower(
+            *state, one(x[None]), one(y[None]), None, None, net._rng_key)
     else:
-        lowered = net._build_gather_train_step(with_health).lower(
-            net.params, net.updater_state, net.net_state, 0, one(x), one(y),
-            net._rng_key, net._rng_key, 0, 2, 2, 4, True, 0,
-            one(None), 0, 2)
+        lowered = net._gather_train_step.lower(
+            *state, one(x), one(y), net._rng_key, net._rng_key, 0, 2, 2, 4,
+            True, 0, one(None), 0, 2)
     return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
 
 
-@pytest.mark.parametrize("with_health", [False, True],
-                         ids=["plain", "health"])
-@pytest.mark.parametrize("program", ["train_step", "gather_train_step"])
+@pytest.mark.parametrize(
+    "program", ["train_step", "multi_train_step", "gather_train_step"])
 @pytest.mark.parametrize("container", sorted(CONTAINERS))
-def test_train_programs_carry_the_scopes(container, program, with_health):
+def test_train_programs_carry_the_scopes(container, program):
+    """The step is built once (``nn/network.py``): each of its three
+    programs, from either container, carries every scope."""
     build, layers = CONTAINERS[container]
-    parsed = {parse_op_name(n) for n in _op_names(build(), program,
-                                                  with_health)}
+    parsed = {parse_op_name(n) for n in _op_names(build(), program)}
     for name in layers:
         assert (f"layer.{name}", "forward") in parsed
         assert (f"layer.{name}", "backward") in parsed
@@ -96,7 +98,7 @@ def test_train_programs_carry_the_scopes(container, program, with_health):
     assert ("reg", "other") in parsed
     assert (("ingest.gather", "other") in parsed) == \
         (program == "gather_train_step")
-    assert (("health", "other") in parsed) == with_health
+    assert ("health", "other") in parsed
     assert {scope.split(".")[0] for scope, _ in parsed} <= \
         set(GROUPS) | {"unscoped"}
 
@@ -104,7 +106,7 @@ def test_train_programs_carry_the_scopes(container, program, with_health):
 @pytest.mark.parametrize("container", sorted(CONTAINERS))
 def test_output_program_carries_bare_layer_scopes(container):
     build, layers = CONTAINERS[container]
-    names = _op_names(build(), "output", False)
+    names = _op_names(build(), "output")
     for name in layers:
         assert any(f"/layer.{name}/" in n for n in names)
     assert not any("jvp(" in n or "transpose(" in n for n in names)

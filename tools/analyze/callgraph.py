@@ -17,6 +17,14 @@ forms), and builds one directed call graph whose nodes are
   blocking primitives over the same edges, so ``_recv_exact`` defined
   in a wire-utils module is caught at a ``with lock:`` site in another.
 
+A ``self.x()`` resolves through the class hierarchy (bases resolved
+like any import): the caller's own class, else the nearest ancestor
+that defines ``x``, else EVERY descendant that does — a hook written in
+a base class (``nn/network.py``'s step calls ``self._loss_fn``) reaches
+the definitions in the package's subclasses.  A function that returns
+one of its nested functions has an edge to it (whoever called for the
+closure runs it).
+
 Like the rest of ``tools.analyze``, this is stdlib-only (``ast`` +
 ``os``): importing it pulls neither jax nor numpy, so the CI gate stays
 pre-pip-install.  Resolution is name-based and deliberately
@@ -30,11 +38,16 @@ import ast
 import os
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+ClassId = Tuple[str, str]  # (dotted module name, class name)
 FuncId = Tuple[str, str]   # (dotted module name, QUALIFIED function
                            # name: "fn" at module level, "Cls.meth" for
                            # methods — two classes never conflate)
 
 _JIT_FACTORIES = {"jit", "watched_jit"}
+#: transformations that trace their first argument when the function
+#: that applies them is traced (``value_and_grad(self._loss_fn, ...)``)
+_TRANSFORMS = {"value_and_grad", "grad", "vmap", "jvp", "vjp",
+               "checkpoint", "remat"}
 
 
 def _dotted(node: ast.AST) -> Optional[str]:
@@ -46,6 +59,44 @@ def _dotted(node: ast.AST) -> Optional[str]:
         parts.append(node.id)
         return ".".join(reversed(parts))
     return None
+
+
+def method_owners(start, name: str, bases: dict, subs: dict,
+                  defines) -> list:
+    """The classes whose ``name`` a ``self.name()`` written in class
+    ``start`` may run: ``start`` itself where it defines it, else the
+    nearest ancestors that do, else every descendant that does (a hook
+    the base calls and its subclasses supply).  ``bases``/``subs`` map a
+    class to its direct bases/subclasses; ``defines(cls, name)`` says
+    whether a class body defines the method."""
+    if defines(start, name):
+        return [start]
+    for links, climb in ((bases, True), (subs, False)):
+        found, seen, frontier = [], {start}, [start]
+        while frontier:
+            cur = frontier.pop()
+            for rel in links.get(cur, ()):
+                if rel in seen:
+                    continue
+                seen.add(rel)
+                if defines(rel, name):
+                    found.append(rel)
+                    if climb:
+                        continue     # nearer definition shadows further
+                frontier.append(rel)
+        if found:
+            return found
+    return []
+
+
+def returned_closures(fnode: ast.AST) -> Set[str]:
+    """Names of functions nested in ``fnode`` that it returns."""
+    nested = {n.name for n in ast.walk(fnode)
+              if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))
+              and n is not fnode}
+    return {n.value.id for n in ast.walk(fnode)
+            if isinstance(n, ast.Return) and isinstance(n.value, ast.Name)
+            and n.value.id in nested}
 
 
 class ModuleNode:
@@ -62,6 +113,10 @@ class ModuleNode:
         self.mod_aliases: Dict[str, str] = {}
         #: local alias -> (module, function) (``from pkg.mod import fn``)
         self.func_aliases: Dict[str, FuncId] = {}
+        #: local alias -> (module, class) (``from pkg.mod import Cls``)
+        self.class_aliases: Dict[str, ClassId] = {}
+        #: class name -> ClassDef
+        self.classes: Dict[str, ast.ClassDef] = {}
         #: QUALIFIED function name ("fn" / "Cls.meth") -> FunctionDef
         self.functions: Dict[str, ast.FunctionDef] = {}
         #: bare name -> qualified names (collision-aware resolution)
@@ -76,6 +131,7 @@ class ModuleNode:
         def visit(node: ast.AST, cls: Optional[str]) -> None:
             for child in ast.iter_child_nodes(node):
                 if isinstance(child, ast.ClassDef):
+                    self.classes[child.name] = child
                     visit(child, child.name)
                 elif isinstance(child, (ast.FunctionDef,
                                         ast.AsyncFunctionDef)):
@@ -157,6 +213,9 @@ class Program:
             self.modules[mod.name] = mod
             self.by_path[rel] = mod
         self._finish_imports()
+        self._bases: Dict[ClassId, List[ClassId]] = {}
+        self._subs: Dict[ClassId, List[ClassId]] = {}
+        self._link_classes()
         self._edges: Dict[FuncId, Set[FuncId]] = {}
         self._build_edges()
 
@@ -170,6 +229,47 @@ class Program:
                 elif base in self.modules and \
                         name in self.modules[base].functions:
                     mod.func_aliases[local] = (base, name)
+                elif base in self.modules and \
+                        name in self.modules[base].classes:
+                    mod.class_aliases[local] = (base, name)
+
+    def _link_classes(self) -> None:
+        for mod in self.modules.values():
+            for cname, cnode in mod.classes.items():
+                cid = (mod.name, cname)
+                for expr in cnode.bases:
+                    bid = self._resolve_class(mod, expr)
+                    if bid is not None:
+                        self._bases.setdefault(cid, []).append(bid)
+                        self._subs.setdefault(bid, []).append(cid)
+
+    def _resolve_class(self, mod: ModuleNode,
+                       expr: ast.AST) -> Optional[ClassId]:
+        if isinstance(expr, ast.Name):
+            if expr.id in mod.classes:
+                return (mod.name, expr.id)
+            return mod.class_aliases.get(expr.id)
+        dotted = _dotted(expr)
+        if dotted and "." in dotted:
+            head, tail = dotted.rsplit(".", 1)
+            target = self._resolve_attr_base(mod, head)
+            if target is not None and tail in self.modules[target].classes:
+                return (target, tail)
+        return None
+
+    def resolve_self(self, mod: ModuleNode, cls: Optional[str],
+                     name: str) -> List[FuncId]:
+        """Every definition a ``self.name`` written in ``cls`` may
+        mean: through the class hierarchy (:func:`method_owners`), else
+        the module-level / unique-bare-name fallback."""
+        if cls is not None:
+            owners = method_owners(
+                (mod.name, cls), name, self._bases, self._subs,
+                lambda c, n: f"{c[1]}.{n}" in self.modules[c[0]].functions)
+            if owners:
+                return [(m, f"{c}.{name}") for m, c in owners]
+        local = mod.resolve_local(name, cls, via_self=True)
+        return [(mod.name, local)] if local is not None else []
 
     def _resolve_attr_base(self, mod: ModuleNode,
                            base: str) -> Optional[str]:
@@ -181,53 +281,29 @@ class Program:
             return cand if cand in self.modules else None
         return base if base in self.modules else None
 
-    def resolve_call(self, mod: ModuleNode, call: ast.Call,
-                     caller_cls: Optional[str] = None
-                     ) -> Optional[FuncId]:
-        """The (module, function) a call resolves to, or None."""
-        func = call.func
-        if isinstance(func, ast.Name):
-            local = mod.resolve_local(func.id)
+    def resolve_ref(self, mod: ModuleNode, ref: ast.AST,
+                    caller_cls: Optional[str] = None) -> List[FuncId]:
+        """The (module, function)s a reference to a callable (a call's
+        ``func``, a jit factory's function argument) resolves to; empty
+        where it cannot be resolved."""
+        if isinstance(ref, ast.Name):
+            local = mod.resolve_local(ref.id)
             if local is not None:
-                return (mod.name, local)
-            return mod.func_aliases.get(func.id)
-        if isinstance(func, ast.Attribute):
-            if isinstance(func.value, ast.Name) and \
-                    func.value.id in ("self", "cls"):
-                local = mod.resolve_local(func.attr, caller_cls,
-                                          via_self=True)
-                return (mod.name, local) if local is not None else None
-            base = _dotted(func.value)
+                return [(mod.name, local)]
+            alias = mod.func_aliases.get(ref.id)
+            return [alias] if alias is not None else []
+        if isinstance(ref, ast.Attribute):
+            if isinstance(ref.value, ast.Name) and \
+                    ref.value.id in ("self", "cls"):
+                return self.resolve_self(mod, caller_cls, ref.attr)
+            base = _dotted(ref.value)
             if base is None:
-                return None
+                return []
             target = self._resolve_attr_base(mod, base)
             if target is not None and \
-                    func.attr in self.modules[target].functions:
-                return (target, func.attr)
-        return None
-
-    def _resolve_root_arg(self, mod: ModuleNode, arg: ast.AST,
-                          caller_cls: Optional[str] = None
-                          ) -> Optional[FuncId]:
-        """Resolve a jit factory's function argument to a FuncId."""
-        if isinstance(arg, ast.Name):
-            local = mod.resolve_local(arg.id)
-            if local is not None:
-                return (mod.name, local)
-            return mod.func_aliases.get(arg.id)
-        if isinstance(arg, ast.Attribute):
-            if isinstance(arg.value, ast.Name) and \
-                    arg.value.id in ("self", "cls"):
-                local = mod.resolve_local(arg.attr, caller_cls,
-                                          via_self=True)
-                return (mod.name, local) if local is not None else None
-            base = _dotted(arg.value)
-            if base is not None:
-                target = self._resolve_attr_base(mod, base)
-                if target is not None and \
-                        arg.attr in self.modules[target].functions:
-                    return (target, arg.attr)
-        return None
+                    ref.attr in self.modules[target].functions:
+                return [(target, ref.attr)]
+        return []
 
     @staticmethod
     def _cls_of(qname: str) -> Optional[str]:
@@ -241,10 +317,18 @@ class Program:
                 cls = self._cls_of(qname)
                 edges = self._edges.setdefault(src, set())
                 for sub in ast.walk(fnode):
-                    if isinstance(sub, ast.Call):
-                        dst = self.resolve_call(mod, sub, cls)
-                        if dst is not None and dst != src:
-                            edges.add(dst)
+                    if not isinstance(sub, ast.Call):
+                        continue
+                    edges.update(self.resolve_ref(mod, sub.func, cls))
+                    name = _dotted(sub.func)
+                    if name and sub.args and \
+                            name.split(".")[-1] in _TRANSFORMS:
+                        edges.update(self.resolve_ref(mod, sub.args[0],
+                                                      cls))
+                for nested in returned_closures(fnode):
+                    edges.add((mod.name,
+                               f"{cls}.{nested}" if cls else nested))
+                edges.discard(src)
 
     def jit_roots(self) -> Set[FuncId]:
         roots: Set[FuncId] = set()
@@ -257,27 +341,23 @@ class Program:
                     if name and name.split(".")[-1] in _JIT_FACTORIES:
                         roots.add((mod.name, qname))
                 for node in ast.walk(fnode):
-                    root = self._factory_root(mod, node, cls)
-                    if root is not None:
-                        roots.add(root)
+                    roots.update(self._factory_roots(mod, node, cls))
             for node in ast.walk(mod.tree):   # module-scope factories
-                root = self._factory_root(mod, node, None)
-                if root is not None:
-                    roots.add(root)
+                roots.update(self._factory_roots(mod, node, None))
         return roots
 
-    def _factory_root(self, mod: ModuleNode, node: ast.AST,
-                      caller_cls: Optional[str]) -> Optional[FuncId]:
+    def _factory_roots(self, mod: ModuleNode, node: ast.AST,
+                       caller_cls: Optional[str]) -> List[FuncId]:
         if not isinstance(node, ast.Call) or not node.args:
-            return None
+            return []
         name = _dotted(node.func)
         if name is None:
-            return None
+            return []
         tail = name.split(".")[-1]
         is_scan = (tail == "scan" and name.split(".")[-2:-1] == ["lax"])
         if tail not in _JIT_FACTORIES and not is_scan:
-            return None
-        return self._resolve_root_arg(mod, node.args[0], caller_cls)
+            return []
+        return self.resolve_ref(mod, node.args[0], caller_cls)
 
     def traced(self) -> Dict[str, Set[str]]:
         """module name -> bare names of jit-reachable functions, via the

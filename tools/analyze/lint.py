@@ -76,6 +76,8 @@ import re
 import tokenize
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
+from tools.analyze.callgraph import method_owners, returned_closures
+
 ALL_RULES = ("R1", "R2", "R3", "R4", "R5", "R6", "R7", "R8")
 
 #: paths (relative, slash-normalized prefixes or exact files) under the
@@ -233,6 +235,9 @@ class _ModuleIndex:
         #: -> info; bare-name view in :attr:`by_bare`
         self.functions: Dict[str, _FunctionInfo] = {}
         self.by_bare: Dict[str, List[str]] = {}
+        #: class -> its bases / subclasses defined in this module
+        self.bases: Dict[str, List[str]] = {}
+        self.subs: Dict[str, List[str]] = {}
         self.jit_roots: Set[str] = set()
         # binding name -> donate arg positions
         self.donated: Dict[str, Tuple[int, ...]] = {}
@@ -248,6 +253,12 @@ class _ModuleIndex:
 
         def visit(node: ast.AST) -> None:
             if isinstance(node, ast.ClassDef):
+                for base in node.bases:
+                    if isinstance(base, ast.Name):
+                        self.bases.setdefault(node.name,
+                                              []).append(base.id)
+                        self.subs.setdefault(base.id,
+                                             []).append(node.name)
                 cls_stack.append(node.name)
                 for child in node.body:
                     visit(child)
@@ -273,34 +284,41 @@ class _ModuleIndex:
         for info in self.functions.values():
             for sub in ast.walk(info.node):
                 if isinstance(sub, ast.Call):
-                    q = self.resolve_callee(info.cls, sub)
-                    if q is not None:
-                        info.calls.add(q)
+                    info.calls.update(self.resolve_callees(info.cls, sub))
+            # whoever calls for a closure runs it
+            info.calls.update(
+                f"{info.cls}.{n}" if info.cls else n
+                for n in returned_closures(info.node))
 
-    def resolve_callee(self, cls: Optional[str],
-                       call: ast.Call) -> Optional[str]:
-        """Qualified name of the local function a call hits, preferring
-        the caller's own class for ``self.x(...)`` and module level for
-        bare names; an ambiguous bare name resolves only when unique
-        (conservative under-approximation)."""
+    def resolve_callees(self, cls: Optional[str],
+                        call: ast.Call) -> List[str]:
+        """Qualified names of the local functions a call may hit.  A
+        ``self.x(...)`` goes through the class hierarchy as far as this
+        module defines it (``callgraph.method_owners``: the caller's own
+        class, else the nearest ancestor that defines ``x``, else every
+        subclass that does); a bare name prefers module level; either
+        falls back to a bare name that is unique in the module (an
+        ambiguous one resolves to nothing: conservative
+        under-approximation)."""
         func = call.func
         if isinstance(func, ast.Name):
-            if func.id in self.functions:       # module-level / nested
-                return func.id
-            cands = self.by_bare.get(func.id, [])
-            return cands[0] if len(cands) == 1 else None
-        if isinstance(func, ast.Attribute) and \
+            name = func.id
+        elif isinstance(func, ast.Attribute) and \
                 isinstance(func.value, ast.Name) and \
                 func.value.id in ("self", "cls"):
+            name = func.attr
             if cls is not None:
-                q = f"{cls}.{func.attr}"
-                if q in self.functions:
-                    return q
-            if func.attr in self.functions:
-                return func.attr
-            cands = self.by_bare.get(func.attr, [])
-            return cands[0] if len(cands) == 1 else None
-        return None
+                owners = method_owners(
+                    cls, name, self.bases, self.subs,
+                    lambda c, n: f"{c}.{n}" in self.functions)
+                if owners:
+                    return [f"{c}.{name}" for c in owners]
+        else:
+            return []
+        if name in self.functions:              # module-level / nested
+            return [name]
+        cands = self.by_bare.get(name, [])
+        return cands if len(cands) == 1 else []
 
     def expand(self, names: Iterable[str]) -> Tuple[Set[str], Set[str]]:
         """Split seed names into (local qualified names, foreign bare
@@ -526,8 +544,8 @@ def _is_blocking_call(node: ast.Call, blocking_fns: Set[str],
             return f"{recv}.{attr}"
         if recv in ("self", "cls"):
             if index is not None:
-                q = index.resolve_callee(cls, node)
-                if q is not None and q in blocking_fns:
+                if blocking_fns.intersection(
+                        index.resolve_callees(cls, node)):
                     return f"self.{attr}"
             elif attr in blocking_fns:       # no index: bare matching
                 return f"self.{attr}"
@@ -538,8 +556,7 @@ def _is_blocking_call(node: ast.Call, blocking_fns: Set[str],
             return f"{recv}.{attr}" if recv else attr
     if isinstance(node.func, ast.Name):
         if index is not None:
-            q = index.resolve_callee(cls, node)
-            if q is not None and q in blocking_fns:
+            if blocking_fns.intersection(index.resolve_callees(cls, node)):
                 return node.func.id
         elif node.func.id in blocking_fns:
             return node.func.id

@@ -245,13 +245,13 @@ def autotune(model: str = "lenet", batches=None, steps_ladder=None,
                 continue
             if not det:
                 # donated state: re-feed what the program returns
-                p, u, s, scores = compiled(*args)
+                p, u, s, scores = compiled(*args)[:4]
                 float(np.asarray(scores)[-1])        # warm + barrier
                 t0 = time.perf_counter()
                 for _ in range(trials):
-                    p, u, s, scores = compiled(p, u, s, net.iteration,
-                                               f, l, None, None,
-                                               net._rng_key)
+                    p, u, s, scores = compiled(
+                        p, u, s, net.iteration, f, l, None, None,
+                        net._rng_key)[:4]
                 float(np.asarray(scores)[-1])
                 elapsed = time.perf_counter() - t0
                 rung["samples_per_sec"] = round(
