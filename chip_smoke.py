@@ -18,7 +18,9 @@ same functions at toy widths on the CPU mesh):
 - ``kernels``    ``ops.attention.flash_attention`` forward and gradient,
                  compiled by Mosaic (never the interpreter), against the
                  dense reference; the streamed latent attention against
-                 its dense form at the decode cell's shape for one layer
+                 its dense form at the decode cell's shape for one layer;
+                 the routed experts' grouped product against their dense
+                 form for a prefill chunk's tokens at the cell's widths
 - ``four_chips`` ``ParallelWrapper`` and ``ZeroShardedParallelWrapper``
                  over four devices; says so when it finds fewer
 
@@ -110,6 +112,9 @@ class Sizes:
     # the latent attention of one layer of the decode cell: (batch,
     # heads, rank, rotary, ring slots, cursor)
     latent_shape: Tuple[int, ...] = (64, 32, 512, 64, 4096, 4000)
+    # the routed experts of one layer of the decode cell under a prefill
+    # chunk: (tokens, experts, width, hidden, picks a token)
+    experts_shape: Tuple[int, ...] = (2048, 64, 1024, 3584, 4)
     interpret: bool = False         # True only where there is no Mosaic
     # four_chips: ZeRO needs a MultiLayerNetwork
     mln_conf: Callable = _lenet_conf
@@ -401,6 +406,7 @@ def phase_kernels(sz: Sizes):
                    f"by {err:.3g} > {KERNEL_BOUND}")
             report[f"{name}_T{t}_max_rel_err"] = err
     report["latent_streamed_max_rel_err"] = _latent_kernel(sz)
+    report["experts_grouped_max_rel_err"] = _experts_kernel(sz)
     return report
 
 
@@ -433,6 +439,48 @@ def _latent_kernel(sz: Sizes) -> float:
     err = _rel_err(streamed(*args), dense(*args))
     _check(err <= KERNEL_BOUND,
            f"the streamed latent attention differs from its dense form by "
+           f"{err:.3g} > {KERNEL_BOUND}")
+    return err
+
+
+def _experts_kernel(sz: Sizes) -> float:
+    """The routed experts' grouped form (each pair through the expert it
+    chose; bf16) against the dense form of the same layer and routing.
+    The grouped form keeps the two products in float32 up to the one
+    rounding before the last product, the dense form rounds them first:
+    ``KERNEL_BOUND`` holds both.  On a TPU the layer's own predicate has
+    to pick the grouped form at this shape."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.layers import decoder
+    from deeplearning4j_tpu.ops import experts
+
+    tokens, n_experts, width, hidden, picks = sz.experts_shape
+    layer = decoder.MixtureOfExperts(
+        n_in=hidden, n_out=hidden, n_experts=n_experts, top_k=picks,
+        width=width, n_shared=0, routed_scaling=2.0,
+        weight_init="distribution",
+        dist=decoder.Distribution(kind="normal", std=hidden ** -0.5))
+    params = layer.init_params(jax.random.PRNGKey(SEED + 6), jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(SEED + 7), (tokens, hidden),
+                          jnp.bfloat16)
+    if jax.devices()[0].platform == "tpu":
+        _check(layer.experts_path(tokens, x.dtype) == "grouped",
+               f"the experts' predicate keeps the dense form for {tokens} "
+               f"tokens at {sz.experts_shape}")
+    grouped = jax.jit(lambda p, x: experts.grouped_experts(
+        x, *layer.route(p, x), p["Wg"], p["Wu"], p["Wd"], held=layer.held(),
+        n_experts=n_experts, interpret=sz.interpret))
+    _check_mosaic(grouped.lower(params, x).as_text(), "grouped_experts")
+    with mock.patch.object(decoder, "moe_experts_path",
+                           lambda *a, **k: "dense"):
+        dense = jax.jit(lambda p, x: layer.forward(
+            p, layer.init_state(), x, train=False)[0])(params, x)
+    err = _rel_err(grouped(params, x), dense)
+    _check(err <= KERNEL_BOUND,
+           f"the grouped experts differ from their dense form by "
            f"{err:.3g} > {KERNEL_BOUND}")
     return err
 

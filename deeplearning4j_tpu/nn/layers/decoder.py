@@ -3,7 +3,10 @@ latent attention over a compressed ring, a sigmoid-routed expert layer,
 manifold-constrained hyper-connections, and the language-model head.
 
 Everything here is plain ``jax.numpy`` under the container's
-``layer.<name>`` scopes; the parts a trace has to tell apart open a
+``layer.<name>`` scopes, but for the two kernels that ``ops/`` puts
+under the latent attention (``ops.attention``) and under the routed
+experts of a long chunk (``ops.experts``), each chosen from the call's
+shapes alone; the parts a trace has to tell apart open a
 sub-scope (``monitor.subscope``: ``layer.<name>.experts``,
 ``.latent_attention``, ``.router``, ``.shared``, ``.sinkhorn``).
 
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 from ... import monitor as _monitor
 from ...ops.attention import (_einsum_acc, latent_ring_attention,
                               latent_ring_path, latent_ring_update)
+from ...ops.experts import grouped_experts, moe_experts_path
 from ..conf import inputs as _inputs
 from ..conf import serde
 from ..weights import Distribution, init_weights
@@ -171,7 +175,12 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     hidden), expert after expert, so that all held experts are three
     plain matrix products (a third axis would have the TPU's compiler
     treat the experts as a convolution's window); each token's unchosen
-    experts are weighted 0.  State
+    experts are weighted 0.  That dense form serves while the tokens are
+    few (the token step: the experts' bytes bound it); a long chunk on a
+    TPU takes the grouped form, ``ops.experts.grouped_experts``, which
+    puts each (token, pick) pair through its own expert only, over the
+    same matrices where they lie (``experts_path``: from the call's
+    shapes alone).  State
     ``expert_tokens`` (``n_experts`` int32) counts the picks of the last
     call, for ``moe_expert_tokens_total``.
     """
@@ -233,24 +242,39 @@ class MixtureOfExperts(FeedForwardLayerConfig):
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx, w * self.routed_scaling
 
+    def experts_path(self, tokens: int, dtype, train: bool = False) -> str:
+        """``"grouped"`` or ``"dense"``: the form ``forward`` takes for
+        ``tokens`` tokens stored in ``dtype``, by the op's own predicate
+        (host code asks it without tracing the step)."""
+        return moe_experts_path(tokens, len(self.held()), self.top_k,
+                                self.n_in, self.width, dtype, train)
+
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         shape = x.shape
         x = x.reshape(-1, shape[-1])
+        grouped = self.experts_path(x.shape[0], x.dtype, train) == "grouped"
         with _monitor.subscope("router"):
             idx, w = self.route(params, x)
-            held = jnp.asarray(self.held(), jnp.int32)
-            # (tokens, held): the weight of each held expert, 0 unchosen
-            combine = jnp.sum(
-                jnp.where(idx[:, :, None] == held[None, None, :],
-                          w[:, :, None], 0.0), axis=1)
+            if not grouped:
+                held = jnp.asarray(self.held(), jnp.int32)
+                # (tokens, held): the weight of each held expert, 0
+                # unchosen
+                combine = jnp.sum(
+                    jnp.where(idx[:, :, None] == held[None, None, :],
+                              w[:, :, None], 0.0), axis=1)
             counts = jnp.sum(
                 idx[:, :, None] == jnp.arange(self.n_experts)[None, None],
                 axis=(0, 1), dtype=jnp.int32)
         with _monitor.subscope("experts"):
-            a = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
-            a = (a.reshape(x.shape[0], held.shape[0], self.width)
-                 * combine[:, :, None].astype(a.dtype))
-            y = a.reshape(x.shape[0], -1) @ params["Wd"]
+            if grouped:
+                y = grouped_experts(x, idx, w, params["Wg"], params["Wu"],
+                                    params["Wd"], held=self.held(),
+                                    n_experts=self.n_experts)
+            else:
+                a = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
+                a = (a.reshape(x.shape[0], held.shape[0], self.width)
+                     * combine[:, :, None].astype(a.dtype))
+                y = a.reshape(x.shape[0], -1) @ params["Wd"]
         if self.n_shared:
             with _monitor.subscope("shared"):
                 y = y + _gated(x, params["Sg"], params["Su"], params["Sd"])

@@ -132,9 +132,13 @@ def dequantize_host(qparams, specs):
 
 
 def tree_nbytes(tree) -> int:
-    """Total bytes of every leaf in a pytree (host or device arrays)."""
+    """Total bytes of every leaf in a pytree (host or device arrays),
+    by the leaf's own ``nbytes``: a device array is counted where it
+    lies, not fetched (``InferenceEngine`` asks this of the model's
+    whole parameter tree when it is built)."""
     import jax
-    return int(sum(np.asarray(l).nbytes for l in jax.tree.leaves(tree)))
+    return int(sum(l.nbytes if hasattr(l, "nbytes") else np.asarray(l).nbytes
+                   for l in jax.tree.leaves(tree)))
 
 
 def quantized_output_jit(model, specs, name: str):

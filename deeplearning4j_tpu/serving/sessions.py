@@ -74,6 +74,7 @@ import numpy as np
 from .. import monitor as _monitor
 from ..monitor.locks import make_lock
 from .bucketing import batch_ladder
+from .quantize import tree_nbytes
 
 
 class SessionError(RuntimeError):
@@ -94,16 +95,6 @@ class SessionStateError(SessionError):
     def __init__(self, message: str, leaf_path: Optional[str] = None):
         super().__init__(message)
         self.leaf_path = leaf_path
-
-
-def _tree_nbytes(tree) -> int:
-    import jax
-    total = 0
-    for leaf in jax.tree.leaves(tree):
-        size = int(np.prod(getattr(leaf, "shape", ())) or 1)
-        itemsize = np.dtype(getattr(leaf, "dtype", np.float32)).itemsize
-        total += size * itemsize
-    return total
 
 
 def _as_features(f, dtype) -> np.ndarray:
@@ -142,7 +133,7 @@ class _Session:
         self.version = version
         self.position = 0          # tokens already decoded (host-side)
         self.capacity = capacity   # current KV ring bucket (0 = RNN)
-        self.state_bytes = _tree_nbytes(carries)
+        self.state_bytes = tree_nbytes(carries)
 
 
 class SessionCache:
@@ -348,7 +339,7 @@ class SessionCache:
                         self._check_structure(session_id, sess)
                         raise
                     sess.capacity = grow_to
-                    sess.state_bytes = _tree_nbytes(sess.carries)
+                    sess.state_bytes = tree_nbytes(sess.carries)
                 out, sess.carries = self._dispatch(
                     session_id, sess, arrays if self._is_graph else x, kw)
             sess.position += steps
@@ -468,6 +459,8 @@ class SessionCache:
                                        tokens=hi - lo):
                         sess.carries = self._model.prefill_step(
                             sess.carries, ids[:, lo:hi], **kw)
+            self._count_expert_steps(
+                batch, Counter(hi - lo for lo, hi in zip(bounds, bounds[1:])))
             sess.position += total
             sess.steps += 1
             sess.last_used = time.monotonic()
@@ -559,10 +552,12 @@ class SessionCache:
             "latent_attention_steps_total",
             "launched token steps, by the form their latent attention took")
         # the first step takes the ``fed`` ids, every later one its own
-        for t, steps in Counter([fed] + [1] * (n - 1)).items():
+        by_length = Counter([fed] + [1] * (n - 1))
+        for t, steps in by_length.items():
             for path in {model.vertices[v].layer.attention_path(
                     t, sess.carries[v]) for v in self._latent}:
                 launched.inc(steps, path=path)
+        self._count_expert_steps(batch, by_length)
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
             "tokens routed to each expert, by layer")
@@ -573,6 +568,27 @@ class SessionCache:
                     tokens.inc(int(picks), model=self._name, layer=vertex,
                                expert=str(expert))
         return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex)
+
+    def _count_expert_steps(self, batch: int, by_length) -> None:
+        """``moe_experts_steps_total{path}``: the launched steps of
+        ``by_length`` (tokens a row -> steps), by the form their routed
+        experts took.  Asked of the layers' own predicate here, where
+        steps are launched, and not where they are traced: a warm start
+        loads ``cg.prefill_step`` and ``cg.token_step`` without tracing
+        them."""
+        experts = [self._model.vertices[v].layer
+                   for v in self._model._expert_vertices()]
+        if not experts:
+            return
+        launched = _monitor.counter(
+            "moe_experts_steps_total",
+            "launched prefill chunks and token steps, by the form their "
+            "routed experts' products took")
+        dtype = self._model._pol().compute_dtype
+        for t, steps in by_length.items():
+            for path in {layer.experts_path(batch * t, dtype)
+                         for layer in experts}:
+                launched.inc(steps, path=path)
 
     def _acquire(self, session_id: str, batch: int,
                  steps: int = 1, capacity: int = 0) -> _Session:

@@ -51,7 +51,8 @@ TOY = chip_smoke.Sizes(
     hidden=16, heads=2, cache_len=16, layers=2, vocab=7, train_t=16,
     train_batch=2, prefill=6, decode_steps=4,
     kernel_bthd=(1, 64, 2, 16), kernel_ref_t=32, kernel_short_ts=(40, 8),
-    latent_shape=(2, 8, 128, 16, 256, 200), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
+    latent_shape=(2, 8, 128, 16, 256, 200),
+    experts_shape=(40, 8, 32, 64, 2), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
 
 
 def test_train_then_serve():
@@ -77,7 +78,8 @@ def test_kernels():
     info = chip_smoke.phase_kernels(TOY)
     assert {"bfloat16_T40_max_rel_err", "bfloat16_T8_max_rel_err",
             "float32_T32_max_rel_err",
-            "latent_streamed_max_rel_err"} <= set(info)
+            "latent_streamed_max_rel_err",
+            "experts_grouped_max_rel_err"} <= set(info)
 
 
 def test_four_chips():

@@ -5,7 +5,10 @@ Pallas interpret mode, at the published head geometry (32 heads, rank
 forced through ``LatentAttention`` and the served path; and the kernel
 compiled by Mosaic for a described v5e at the cell's shape, with the
 scope it carries there (``tests/test_mla_moe_decoder.py`` holds the
-token step's lowering to the same)."""
+token step's lowering to the same).  The experts' grouped product
+(``ops.experts``, ``tests/test_grouped_experts.py``) is compiled for
+the described v5e here too: one file describes the topology, so that
+one test worker loads the TPU's compiler."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +19,7 @@ from benchmark.reference import mla_moe_decoder as ref
 from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.models.mla_moe_decoder import from_config
 from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
-from deeplearning4j_tpu.ops import attention
+from deeplearning4j_tpu.ops import attention, experts
 from deeplearning4j_tpu.serving import InferenceEngine
 
 HEADS, RANK, ROPE = 32, 512, 64
@@ -233,6 +236,53 @@ def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip):
                 op_name = calls[0].split('op_name="')[1].split('"')[0]
                 assert monitor.parse_op_name(op_name) == (
                     "layer.L0_attn.latent_attention", "forward")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _scoped_experts(*args):
+    with monitor.scope("layer", "L1_moe"), monitor.subscope("experts"):
+        return experts.grouped_experts(*args, held=list(range(64)),
+                                       n_experts=64, interpret=False)
+
+
+def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip):
+    """A prefill chunk's 2,048 tokens (and the remainder chunk's 1,984)
+    through 64 experts of (3,584, 1,024), 4 picks, bf16: three kernels
+    under the scope they were called under, the experts' matrices read
+    where they lie: no instruction but the parameters has a matrix's
+    shape, and the temporaries stay under one matrix's bytes."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    matrix = ("bf16[3584,65536]", "bf16[65536,3584]")
+    try:
+        with jax.enable_x64(False):
+            for tokens in (2048, 1984):
+                shapes = [
+                    jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                    for s, d in (((tokens, 3584), jnp.bfloat16),
+                                 ((tokens, 4), jnp.int32),
+                                 ((tokens, 4), jnp.float32),
+                                 ((3584, 65536), jnp.bfloat16),
+                                 ((3584, 65536), jnp.bfloat16),
+                                 ((65536, 3584), jnp.bfloat16))]
+                compiled = jax.jit(_scoped_experts).lower(*shapes).compile()
+                lines = compiled.as_text().splitlines()
+                calls = [line for line in lines
+                         if 'custom_call_target="tpu_custom_call"' in line]
+                assert len(calls) == 3
+                assert {monitor.parse_op_name(
+                    line.split('op_name="')[1].split('"')[0])
+                    for line in calls} == {("layer.L1_moe.experts",
+                                            "forward")}
+                made = [line for line in lines if " = " in line
+                        and line.split(" = ")[1].startswith(matrix)
+                        and " parameter(" not in line]
+                assert not made, made
+                assert (compiled.memory_analysis().temp_size_in_bytes
+                        < 3584 * 65536 * 2)
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
