@@ -1,29 +1,44 @@
-"""A decoder of latent-attention blocks with routed experts on a
-hyper-connected residual path, built on the ComputationGraph DSL.
+"""A decoder of latent-attention blocks with routed experts, on a plain
+or a hyper-connected residual path, built on the ComputationGraph DSL.
 
 One builder for the family whose published ``config.json`` carries
 DeepSeek-V3's keys (``kv_lora_rank``, ``n_routed_experts``,
-``first_k_dense_replace``, ...) plus the hyper-connection keys
-(``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
-``mhc_h_res_clamp_min/max``): the benchmark's ``xing4_29b_a4b``
-configuration is one such file, the CPU tests run a small one.
+``first_k_dense_replace``, ...), with or without the hyper-connection
+keys (``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+``mhc_h_res_clamp_min/max``).  The benchmark's ``xing4_29b_a4b``
+configuration is a file with them, ``ax_k1`` one without; the CPU tests
+run a small one of each.
 
-Graph, per token: ``embed`` -> ``streams`` (``hc_mult`` copies) ->
-for each layer ``L<i>`` two sublayers, attention then feed-forward,
-each wrapped as ``_read`` (the streams' part the sublayer sees) ->
-``_norm`` -> the sublayer (``L<i>_attn``; ``L<i>_ffn`` dense for the
-first ``first_k_dense_replace`` layers, ``L<i>_moe`` after) ->
+With ``hc_mult``, per token: ``embed`` -> ``streams`` (``hc_mult``
+copies) -> for each layer ``L<i>`` two sublayers, attention then
+feed-forward, each wrapped as ``_read`` (the streams' part the sublayer
+sees) -> ``_norm`` -> the sublayer (``L<i>_attn``; ``L<i>_ffn`` dense
+for the first ``first_k_dense_replace`` layers, ``L<i>_moe`` after) ->
 ``_write`` (streams mixed, the output added) -> ``stream_sum`` ->
-``final_norm`` -> ``head`` (float32 logits).  The multi-token-
-prediction module of the published model is not built: it is not part
-of the served forward pass.
+``final_norm`` -> ``head`` (float32 logits).
+
+Without it the residual path is the plain pre-norm one, ``x + F(rmsnorm
+(x))``: ``embed`` -> for each layer ``L<i>_attn_norm`` -> ``L<i>_attn``
+-> ``L<i>_attn_add``, ``L<i>_ffn_norm`` -> ``L<i>_ffn`` | ``L<i>_moe``
+-> ``L<i>_ffn_add`` -> ``final_norm`` -> ``head``: one stream, no
+``streams`` / ``stream_sum``, no Sinkhorn; the sublayers keep their
+names.
+
+A file that is one chip's share of an expert-parallel deployment gives
+the experts it holds under ``n_routed_experts`` and the source's count
+under ``published``: the router keeps the published width, and
+``experts_held`` says which of its outputs are the held experts.
+
+The multi-token-prediction module of a published model is not built: it
+is not part of the served forward pass.
 """
 
 from __future__ import annotations
 
 from typing import Dict, List, Optional
 
-from ..nn.conf.computation_graph import StreamExpandVertex, StreamSumVertex
+from ..nn.conf.computation_graph import (ElementWiseVertex,
+                                         StreamExpandVertex, StreamSumVertex)
 from ..nn.conf.neural_net_configuration import NeuralNetConfiguration
 from ..nn.layers.decoder import (GatedFeedForward, HyperConnectionRead,
                                  HyperConnectionWrite, LatentAttention,
@@ -43,9 +58,9 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
     all); ``init_std`` the normal deviation of every matrix;
     ``hc_alpha_init``, ``hc_bias_std`` and ``router_bias_std`` the
     initial values the source does not give (a hyper-connection's
-    ``alpha`` and the deviation of its ``b``; the deviation of the
-    router's selection bias); ``dtype`` the parameter dtype (default:
-    the backend's policy)."""
+    ``alpha`` and the deviation of its ``b``, read only where ``cfg``
+    has ``hc_mult``; the deviation of the router's selection bias);
+    ``dtype`` the parameter dtype (default: the backend's policy)."""
     b = (NeuralNetConfiguration.builder().seed(seed).updater("sgd")
          .weight_init("distribution")
          .dist(Distribution(kind="normal", std=float(init_std)))
@@ -53,29 +68,54 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
     if dtype:
         b = b.dtype(dtype)
     g = b.graph_builder()
-    c, n = int(cfg["hidden_size"]), int(cfg["hc_mult"])
-    hc = dict(n_in=c, n_streams=n, eps=float(cfg["hc_eps"]),
-              alpha_init=hc_alpha_init, bias_std=hc_bias_std)
+    c = int(cfg["hidden_size"])
+    # a share's file: the router's width is the source's count, the
+    # file's own count how many of its experts this chip holds
+    n_experts = int(cfg["n_routed_experts"])
+    published = cfg.get("published", {}).get("n_routed_experts")
+    if published is not None:
+        if len(experts_held or ()) != n_experts:
+            raise ValueError(
+                f"the file holds {n_experts} of {published} routed experts; "
+                f"experts_held has to name them, not {experts_held}")
+        n_experts = int(published)
 
-    def sublayer(prefix: str, layer, stream: str, kind: str) -> str:
-        g.add_layer(f"{prefix}_read", HyperConnectionRead(**hc), stream)
-        g.add_layer(f"{prefix}_norm",
-                    RMSNorm(n_out=c, eps=float(cfg["rms_norm_eps"])),
-                    f"{prefix}_read")
-        name = f"{prefix.split('_')[0]}_{kind}"
-        g.add_layer(name, layer, f"{prefix}_norm")
-        g.add_layer(f"{prefix}_write", HyperConnectionWrite(
-            sinkhorn_iters=int(cfg["hc_sinkhorn_iters"]),
-            clamp_min=float(cfg["mhc_h_res_clamp_min"]),
-            clamp_max=float(cfg["mhc_h_res_clamp_max"]), **hc),
-            stream, name)
-        return f"{prefix}_write"
+    def norm():
+        return RMSNorm(n_out=c, eps=float(cfg["rms_norm_eps"]))
+
+    streams = "hc_mult" in cfg
+    if streams:
+        n = int(cfg["hc_mult"])
+        hc = dict(n_in=c, n_streams=n, eps=float(cfg["hc_eps"]),
+                  alpha_init=hc_alpha_init, bias_std=hc_bias_std)
+
+        def sublayer(prefix: str, layer, stream: str, kind: str) -> str:
+            g.add_layer(f"{prefix}_read", HyperConnectionRead(**hc), stream)
+            g.add_layer(f"{prefix}_norm", norm(), f"{prefix}_read")
+            name = f"{prefix.split('_')[0]}_{kind}"
+            g.add_layer(name, layer, f"{prefix}_norm")
+            g.add_layer(f"{prefix}_write", HyperConnectionWrite(
+                sinkhorn_iters=int(cfg["hc_sinkhorn_iters"]),
+                clamp_min=float(cfg["mhc_h_res_clamp_min"]),
+                clamp_max=float(cfg["mhc_h_res_clamp_max"]), **hc),
+                stream, name)
+            return f"{prefix}_write"
+    else:
+        def sublayer(prefix: str, layer, stream: str, kind: str) -> str:
+            g.add_layer(f"{prefix}_norm", norm(), stream)
+            name = f"{prefix.split('_')[0]}_{kind}"
+            g.add_layer(name, layer, f"{prefix}_norm")
+            g.add_vertex(f"{prefix}_add", ElementWiseVertex(op="add"),
+                         stream, name)
+            return f"{prefix}_add"
 
     g.add_inputs("ids")
     g.add_layer("embed", TokenEmbedding(n_in=int(cfg["vocab_size"]),
                                         n_out=c), "ids")
-    g.add_vertex("streams", StreamExpandVertex(n_streams=n), "embed")
-    x = "streams"
+    x = "embed"
+    if streams:
+        g.add_vertex("streams", StreamExpandVertex(n_streams=n), x)
+        x = "streams"
     for i in range(int(cfg["num_hidden_layers"])):
         x = sublayer(f"L{i}_attn", LatentAttention(
             n_in=c, n_out=c, n_heads=int(cfg["num_attention_heads"]),
@@ -92,7 +132,7 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
                 x, "ffn")
         else:
             x = sublayer(f"L{i}_ffn", MixtureOfExperts(
-                n_in=c, n_out=c, n_experts=int(cfg["n_routed_experts"]),
+                n_in=c, n_out=c, n_experts=n_experts,
                 top_k=int(cfg["num_experts_per_tok"]),
                 width=int(cfg["moe_intermediate_size"]),
                 n_shared=int(cfg["n_shared_experts"]),
@@ -100,10 +140,10 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
                 norm_topk=bool(cfg["norm_topk_prob"]),
                 router_bias_std=router_bias_std,
                 experts_held=experts_held), x, "moe")
-    g.add_vertex("stream_sum", StreamSumVertex(), x)
-    g.add_layer("final_norm", RMSNorm(n_out=c,
-                                      eps=float(cfg["rms_norm_eps"])),
-                "stream_sum")
+    if streams:
+        g.add_vertex("stream_sum", StreamSumVertex(), x)
+        x = "stream_sum"
+    g.add_layer("final_norm", norm(), x)
     g.add_layer("head", LMHead(n_in=c, n_out=int(cfg["vocab_size"])),
                 "final_norm")
     g.set_outputs("head")

@@ -207,18 +207,21 @@ class MixtureOfExperts(FeedForwardLayerConfig):
         kr, kb, kg, ku, kd, ksg, ksu, ksd = jax.random.split(rng, 8)
 
         def experts(key, shape, axis):
-            # every expert is drawn, the held ones kept: a layer that
-            # holds a share has the same experts as one that holds all
-            w = _matrix(self, key, shape, dtype)
-            if self.experts_held is not None:
-                w = jnp.take(w, jnp.asarray(self.held(), jnp.int32), axis)
-            return w.reshape((c, -1) if axis else (-1, c))
+            # each expert from a key of its own (the matrix's key folded
+            # with the expert's id), all in one draw: a share is drawn
+            # without the whole layer ever existing, and holds what the
+            # whole layer has
+            keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
+                jnp.asarray(self.held(), jnp.uint32))
+            w = _matrix(self, keys, shape, dtype)       # (held, *shape)
+            return (jnp.moveaxis(w, 0, 1).reshape(c, -1) if axis
+                    else w.reshape(-1, c))
 
         p = {"router": _matrix(self, kr, (c, e), dtype),
              "router_bias": _normal(kb, (e,), self.router_bias_std, dtype),
-             "Wg": experts(kg, (c, e, f), 1),
-             "Wu": experts(ku, (c, e, f), 1),
-             "Wd": experts(kd, (e, f, c), 0)}
+             "Wg": experts(kg, (c, f), 1),
+             "Wu": experts(ku, (c, f), 1),
+             "Wd": experts(kd, (f, c), 0)}
         if self.n_shared:
             fs = f * self.n_shared
             p.update(Sg=_matrix(self, ksg, (c, fs), dtype),

@@ -67,7 +67,7 @@ class Distribution:
                 # product and sum fuse into one rounding in a program
                 raise NotStaged("normal distribution with a mean")
             return self.mean + self.std * _held(
-                lambda: jax.random.normal(rng, shape, dtype))
+                lambda: _normal(rng, shape, dtype))
         if self.kind == "uniform":
             return jax.random.uniform(rng, shape, dtype, self.lower, self.upper)
         if self.kind == "binomial":
@@ -85,6 +85,15 @@ class Distribution:
     @staticmethod
     def from_dict(d: dict) -> "Distribution":
         return Distribution(**d)
+
+
+def _normal(rng: jax.Array, shape: Sequence[int], dtype) -> Array:
+    """Standard normal draws of ``shape``; for a stack of keys ``(n,
+    2)``, ``(n, *shape)``: one draw a key, as one operation (the routed
+    experts of a layer, each from a key of its own)."""
+    if rng.ndim == 2:
+        return jax.vmap(lambda k: jax.random.normal(k, shape, dtype))(rng)
+    return jax.random.normal(rng, shape, dtype)
 
 
 class NotStaged(Exception):
@@ -225,7 +234,7 @@ def init_weights(rng: jax.Array, shape: Sequence[int], scheme: str = "xavier",
             raise ValueError("WeightInit 'distribution' requires a Distribution")
         return distribution.sample(rng, shape, dtype)
     def normal():
-        return _held(lambda: jax.random.normal(rng, shape, dtype))
+        return _held(lambda: _normal(rng, shape, dtype))
 
     if scheme == "xavier":
         # Gaussian with var = 2/(fanIn+fanOut) (WeightInitUtil XAVIER)

@@ -561,12 +561,24 @@ class SessionCache:
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
             "tokens routed to each expert, by layer")
+        # of those, the picks that named an expert this layer holds (a
+        # share routes over more experts than it computes): summed here
+        # from the same counts, no output of the step's own
+        held_picks = _monitor.counter(
+            "moe_held_picks_total",
+            "picks that named an expert the layer holds, by layer")
+        experts_held = _monitor.gauge(
+            "moe_experts_held", "experts an expert layer holds, by layer")
         by_vertex = dict(zip(model._expert_vertices(), counts))
         for vertex, row in by_vertex.items():
             for expert, picks in enumerate(row):
                 if picks:
                     tokens.inc(int(picks), model=self._name, layer=vertex,
                                expert=str(expert))
+            held = model.vertices[vertex].layer.held()
+            held_picks.inc(int(row[held].sum()), model=self._name,
+                           layer=vertex)
+            experts_held.set(len(held), model=self._name, layer=vertex)
         return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex)
 
     def _count_expert_steps(self, batch: int, by_length) -> None:

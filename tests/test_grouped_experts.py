@@ -55,9 +55,12 @@ def force(monkeypatch, form, tm=TILE):
         experts.grouped_experts, tm=tm))
 
 
-#: tokens, picks a token, experts held, and the selection bias that
-#: shapes the groups (expert -> bias; a large one wins every token, a
-#: very negative one none)
+#: tokens, picks a token, experts held, the selection bias that shapes
+#: the groups (expert -> bias; a large one wins every token, a very
+#: negative one none) and, where it is not ``EXPERTS``, the router's
+#: width: a share of a wider router, most of whose picks name experts
+#: that lie elsewhere
+WIDE = 24
 CASES = {
     "uneven_groups": (37, 2, None, {1: 0.5, 6: -0.5}),
     "an_expert_with_no_row": (37, 2, None, {3: -100.0}),
@@ -67,6 +70,12 @@ CASES = {
     "four_picks": (40, 4, None, {}),
     "an_unordered_share": (37, 2, [5, 2], {}),
     "a_share_no_token_chose": (16, 2, [5, 2], {5: -100.0, 2: -100.0}),
+    "a_quarter_of_a_wide_router": (40, 3, list(range(6, 12)), {}, WIDE),
+    "a_wide_share_with_one_busy_expert": (24, 3, list(range(6, 12)),
+                                          {7: 100.0}, WIDE),
+    "a_wide_share_under_one_tile": (5, 3, list(range(6)), {}, WIDE),
+    "a_wide_share_no_token_chose": (16, 3, [0, 1, 2, 3],
+                                    {e: -100.0 for e in range(4)}, WIDE),
 }
 
 
@@ -78,15 +87,15 @@ def test_grouped_agrees_with_dense(monkeypatch, dtype, bound, case):
     bfloat16: the dense form rounds the two products before ``silu``
     and the weighted activation once more, the grouped form keeps them
     in float32 up to the one rounding before the last product."""
-    tokens, top_k, held, bias = CASES[case]
+    tokens, top_k, held, bias, n_experts = (*CASES[case], EXPERTS)[:5]
     layer = decoder.MixtureOfExperts(
-        n_in=HIDDEN, n_out=HIDDEN, n_experts=EXPERTS, top_k=top_k,
+        n_in=HIDDEN, n_out=HIDDEN, n_experts=n_experts, top_k=top_k,
         width=WIDTH, n_shared=1, routed_scaling=2.0, experts_held=held,
         weight_init="distribution",
         dist=decoder.Distribution(kind="normal", std=0.3))
     params = layer.init_params(jax.random.PRNGKey(7), jnp.dtype(dtype))
     params["router_bias"] = jnp.asarray(
-        [bias.get(e, 0.0) for e in range(EXPERTS)], jnp.float32)
+        [bias.get(e, 0.0) for e in range(n_experts)], jnp.float32)
     x = jnp.asarray(np.random.RandomState(1).randn(1, tokens, HIDDEN),
                     jnp.dtype(dtype))
     want, counted = layer.forward(params, layer.init_state(), x, train=False)
@@ -96,9 +105,14 @@ def test_grouped_agrees_with_dense(monkeypatch, dtype, bound, case):
     assert np.isfinite(np.asarray(got, np.float32)).all()
     np.testing.assert_array_equal(state["expert_tokens"],
                                   counted["expert_tokens"])
+    assert state["expert_tokens"].shape == (n_experts,)
     assert int(state["expert_tokens"].sum()) == tokens * top_k
-    if case == "a_share_no_token_chose":        # the shared expert alone
+    if case.endswith("no_token_chose"):         # the shared expert alone
         want = decoder._gated(x, params["Sg"], params["Su"], params["Sd"])
+    elif held is not None:
+        # a pick of an absent expert weighs nothing: most do
+        on_held = int(np.asarray(state["expert_tokens"])[held].sum())
+        assert 0 < on_held < tokens * top_k
     assert rel(got, want) < bound
     if held is not None and dtype == "float32":
         cfg = {**CFG, "num_experts_per_tok": top_k}
@@ -149,6 +163,15 @@ def test_the_path_is_chosen_from_the_arguments(monkeypatch):
     assert experts.grouped_tile_columns(3584, 1024, 2) == 1024
     assert experts.grouped_tile_columns(1024, 3584, 2) == 3584
     assert experts.grouped_tile_columns(7168, 2048, 2) == 1024
+    assert experts.grouped_tile_columns(2048, 7168, 2) == 3584
+    # one chip of sixteen: 12 held of a 192-wide router, 8 picks.  The
+    # predicate counts neither the router's width nor how few picks
+    # land here: the token step's 256 rows stay dense, a chunk of 2,048
+    # is grouped
+    share = (12, 8, 7168, 2048)
+    assert path(256, *share, jnp.bfloat16, False) == "dense"
+    assert path(2048, *share, jnp.bfloat16, False) == "grouped"
+    assert path(2048, 8, 8, 7168, 2048, jnp.bfloat16, False) == "dense"
 
 
 # ------------------------------------------------- through the served path

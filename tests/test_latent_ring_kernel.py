@@ -10,6 +10,8 @@ token step's lowering to the same).  The experts' grouped product
 the described v5e here too: one file describes the topology, so that
 one test worker loads the TPU's compiler."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -207,24 +209,34 @@ def _scoped(*args):
             *args, sm_scale=SCALE, interpret=False)
 
 
-def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip):
-    """The token step's shape (64 conversations, rings of 4,096, bf16)
-    and a prefill chunk's (T = 32), compiled ahead of time for a
-    described v5e: block shapes and VMEM are refused here, not on the
-    chip, and the compiled kernel is one instruction that carries the
-    scope it was called under.  Nothing runs.  (Without x64, which the tests turn on and
-    Mosaic cannot take, and without the compile cache, which cannot
-    read such an entry back.)"""
+#: the two decode cells' shapes: rows, heads, ring slots, a prefill
+#: chunk's positions a row; and their experts: hidden, width, held,
+#: the router's width, picks a token
+CELLS = {"xing4_29b_a4b": ((64, 32, 4096, 32), (3584, 1024, 64, 64, 4)),
+         "ax_k1": ((256, 64, 1024, 8), (7168, 2048, 12, 192, 8))}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip, cell):
+    """The token step's shape (64 conversations, rings of 4,096, bf16;
+    256 conversations of 64 heads over rings of 1,024) and a prefill
+    chunk's (T = 32; T = 8, 512 rows of accumulators), compiled ahead of
+    time for a described v5e: block shapes and VMEM are refused here,
+    not on the chip, and the compiled kernel is one instruction that
+    carries the scope it was called under.  Nothing runs.  (Without x64,
+    which the tests turn on and Mosaic cannot take, and without the
+    compile cache, which cannot read such an entry back.)"""
     from jax.experimental.compilation_cache import compilation_cache
+    rows, heads, slots, chunk = CELLS[cell][0]
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
         with jax.enable_x64(False):
-            for t in (1, 32):
+            for t in (1, chunk):
                 shapes = [
                     jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
-                    for s in ((64, t, HEADS, RANK), (64, t, HEADS, ROPE),
-                              (64, 4096, RANK), (64, 4096, ROPE))]
+                    for s in ((rows, t, heads, RANK), (rows, t, heads, ROPE),
+                              (rows, slots, RANK), (rows, slots, ROPE))]
                 cursor = jax.ShapeDtypeStruct((), jnp.int32,
                                               sharding=one_chip)
                 text = jax.jit(_scoped).lower(
@@ -241,34 +253,43 @@ def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip):
         compilation_cache.reset_cache()
 
 
-def _scoped_experts(*args):
+def _scoped_experts(held, n_experts, *args):
     with monitor.scope("layer", "L1_moe"), monitor.subscope("experts"):
-        return experts.grouped_experts(*args, held=list(range(64)),
-                                       n_experts=64, interpret=False)
+        return experts.grouped_experts(*args, held=list(range(held)),
+                                       n_experts=n_experts, interpret=False)
 
 
-def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip):
-    """A prefill chunk's 2,048 tokens (and the remainder chunk's 1,984)
-    through 64 experts of (3,584, 1,024), 4 picks, bf16: three kernels
-    under the scope they were called under, the experts' matrices read
-    where they lie: no instruction but the parameters has a matrix's
-    shape, and the temporaries stay under one matrix's bytes."""
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
+                                                                 cell):
+    """A prefill chunk's 2,048 tokens (and a shorter remainder chunk's)
+    through 64 experts of (3,584, 1,024), 4 picks, and through 12 held
+    of 192 experts of (7,168, 2,048), 8 picks, bf16: three kernels under
+    the scope they were called under, the experts' matrices read where
+    they lie: no instruction but the parameters has a matrix's shape,
+    and the temporaries are the pairs' rows, nothing weight-sized (a
+    share gathers a row for every pick, those of absent experts too:
+    15/16 of the second cell's, wasteful in set-up only)."""
     from jax.experimental.compilation_cache import compilation_cache
+    hidden, width, held, n_experts, top_k = CELLS[cell][1]
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    matrix = ("bf16[3584,65536]", "bf16[65536,3584]")
+    matrix = (f"bf16[{hidden},{held * width}]",
+              f"bf16[{held * width},{hidden}]")
     try:
         with jax.enable_x64(False):
             for tokens in (2048, 1984):
                 shapes = [
                     jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                    for s, d in (((tokens, 3584), jnp.bfloat16),
-                                 ((tokens, 4), jnp.int32),
-                                 ((tokens, 4), jnp.float32),
-                                 ((3584, 65536), jnp.bfloat16),
-                                 ((3584, 65536), jnp.bfloat16),
-                                 ((65536, 3584), jnp.bfloat16))]
-                compiled = jax.jit(_scoped_experts).lower(*shapes).compile()
+                    for s, d in (((tokens, hidden), jnp.bfloat16),
+                                 ((tokens, top_k), jnp.int32),
+                                 ((tokens, top_k), jnp.float32),
+                                 ((hidden, held * width), jnp.bfloat16),
+                                 ((hidden, held * width), jnp.bfloat16),
+                                 ((held * width, hidden), jnp.bfloat16))]
+                compiled = jax.jit(functools.partial(
+                    _scoped_experts, held, n_experts)).lower(
+                        *shapes).compile()
                 lines = compiled.as_text().splitlines()
                 calls = [line for line in lines
                          if 'custom_call_target="tpu_custom_call"' in line]
@@ -281,8 +302,11 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip):
                         and line.split(" = ")[1].startswith(matrix)
                         and " parameter(" not in line]
                 assert not made, made
+                # what a pair holds between the kernels: its row in
+                # bf16, two float32 products and their bf16 product,
+                # its float32 row out (235 and 940 MB at 2,048 tokens)
                 assert (compiled.memory_analysis().temp_size_in_bytes
-                        < 3584 * 65536 * 2)
+                        < tokens * top_k * (6 * hidden + 10 * width))
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()

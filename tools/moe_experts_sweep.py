@@ -3,12 +3,17 @@ of ``MixtureOfExperts.forward`` (router and experts, no shared expert)
 with the form forced, over token counts, row tiles and two routings:
 
     python tools/moe_experts_sweep.py [--tokens 64 128 ...] [--tiles 128]
+    python tools/moe_experts_sweep.py --hidden 7168 --width 2048 \
+        --experts 192 --held 12 --top-k 8       # one chip of sixteen
 
 What ``ops.experts._GROUPED_MIN_TOKENS`` and ``_TILE_ROWS`` were set
 from (PERF.md, PR 34).  Widths default to the decode cell's (hidden
 3,584, 64 experts of 1,024, 4 picks; one layer, 1.41 GB in bfloat16).
 ``even`` draws the router at random; ``one_expert`` biases expert 0 so
-that every token picks it (a quarter of all rows in one group).  Each
+that every token picks it (a quarter of all rows in one group).
+``--held N`` holds the first ``N`` of ``--experts`` (a share: the router
+keeps its width, most picks name experts that lie elsewhere; PERF.md,
+PR 35).  Each
 timing is the wall of ``--calls`` back-to-back dispatches of one jitted
 call after three warm ones, divided by their number; the device runs
 them one after another.  Needs the chip: Mosaic compiles the kernel.
@@ -36,6 +41,9 @@ def main() -> int:
     ap.add_argument("--tiles", type=int, nargs="+", default=[64, 128, 256])
     ap.add_argument("--hidden", type=int, default=3584)
     ap.add_argument("--experts", type=int, default=64)
+    ap.add_argument("--held", type=int, default=None,
+                    help="experts held, the first of --experts "
+                         "(default: all)")
     ap.add_argument("--width", type=int, default=1024)
     ap.add_argument("--top-k", type=int, default=4)
     ap.add_argument("--calls", type=int, default=20)
@@ -58,6 +66,8 @@ def main() -> int:
     layer = decoder.MixtureOfExperts(
         n_in=args.hidden, n_out=args.hidden, n_experts=args.experts,
         top_k=args.top_k, width=args.width, n_shared=0, routed_scaling=2.0,
+        experts_held=(None if args.held is None
+                      else list(range(args.held))),
         weight_init="distribution",
         dist=decoder.Distribution(kind="normal", std=0.02))
     params = layer.init_params(jax.random.PRNGKey(0), jnp.bfloat16)
