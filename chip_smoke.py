@@ -21,6 +21,7 @@ same functions at toy widths on the CPU mesh):
                  its dense form at the decode cell's shape for one layer;
                  the routed experts' grouped product against their dense
                  form for a prefill chunk's tokens at the cell's widths
+                 and for a token step's under a share (12 of 192 held)
 - ``four_chips`` ``ParallelWrapper`` and ``ZeroShardedParallelWrapper``
                  over four devices; says so when it finds fewer
 
@@ -115,6 +116,9 @@ class Sizes:
     # the routed experts of one layer of the decode cell under a prefill
     # chunk: (tokens, experts, width, hidden, picks a token)
     experts_shape: Tuple[int, ...] = (2048, 64, 1024, 3584, 4)
+    # and of one layer of the share's cell under its token step: (tokens,
+    # the router's width, experts held, width, hidden, picks a token)
+    experts_share_shape: Tuple[int, ...] = (256, 192, 12, 2048, 7168, 8)
     interpret: bool = False         # True only where there is no Mosaic
     # four_chips: ZeRO needs a MultiLayerNetwork
     mln_conf: Callable = _lenet_conf
@@ -406,7 +410,11 @@ def phase_kernels(sz: Sizes):
                    f"by {err:.3g} > {KERNEL_BOUND}")
             report[f"{name}_T{t}_max_rel_err"] = err
     report["latent_streamed_max_rel_err"] = _latent_kernel(sz)
-    report["experts_grouped_max_rel_err"] = _experts_kernel(sz)
+    tokens, n_experts, *widths = sz.experts_shape
+    report["experts_grouped_max_rel_err"] = _experts_kernel(
+        sz, "grouped", tokens, n_experts, n_experts, *widths)
+    report["experts_share_grouped_max_rel_err"] = _experts_kernel(
+        sz, "held_rows", *sz.experts_share_shape)
     return report
 
 
@@ -443,13 +451,16 @@ def _latent_kernel(sz: Sizes) -> float:
     return err
 
 
-def _experts_kernel(sz: Sizes) -> float:
+def _experts_kernel(sz: Sizes, path: str, tokens: int, n_experts: int,
+                    held: int, width: int, hidden: int, picks: int) -> float:
     """The routed experts' grouped form (each pair through the expert it
-    chose; bf16) against the dense form of the same layer and routing.
-    The grouped form keeps the two products in float32 up to the one
-    rounding before the last product, the dense form rounds them first:
-    ``KERNEL_BOUND`` holds both.  On a TPU the layer's own predicate has
-    to pick the grouped form at this shape."""
+    chose; bf16; the first ``held`` of ``n_experts`` held, and under a
+    share the held pairs alone laid in rows) against the dense form of
+    the same layer and routing.  The grouped form keeps the two products
+    in float32 up to the one rounding before the last product, the dense
+    form rounds them first: ``KERNEL_BOUND`` holds both.  On a TPU the
+    layer's own predicate has to answer ``path`` at this shape, and
+    what the layer then runs has to agree with the dense form too."""
     from unittest import mock
 
     import jax
@@ -457,31 +468,35 @@ def _experts_kernel(sz: Sizes) -> float:
     from deeplearning4j_tpu.nn.layers import decoder
     from deeplearning4j_tpu.ops import experts
 
-    tokens, n_experts, width, hidden, picks = sz.experts_shape
+    shape = (tokens, n_experts, held, width, hidden, picks)
     layer = decoder.MixtureOfExperts(
         n_in=hidden, n_out=hidden, n_experts=n_experts, top_k=picks,
         width=width, n_shared=0, routed_scaling=2.0,
-        weight_init="distribution",
+        experts_held=list(range(held)), weight_init="distribution",
         dist=decoder.Distribution(kind="normal", std=hidden ** -0.5))
     params = layer.init_params(jax.random.PRNGKey(SEED + 6), jnp.bfloat16)
     x = jax.random.normal(jax.random.PRNGKey(SEED + 7), (tokens, hidden),
                           jnp.bfloat16)
+    # a function a form: jit keeps what it traced for a function it knows
+    picked = jax.jit(lambda p, x: layer.forward(
+        p, layer.init_state(), x, train=False)[0])(params, x)
     if jax.devices()[0].platform == "tpu":
-        _check(layer.experts_path(tokens, x.dtype) == "grouped",
-               f"the experts' predicate keeps the dense form for {tokens} "
-               f"tokens at {sz.experts_shape}")
+        _check(layer.experts_path(tokens, x.dtype) == path,
+               f"the experts' predicate answers "
+               f"{layer.experts_path(tokens, x.dtype)!r}, not {path!r}, for "
+               f"{tokens} tokens at {shape}")
     grouped = jax.jit(lambda p, x: experts.grouped_experts(
         x, *layer.route(p, x), p["Wg"], p["Wu"], p["Wd"], held=layer.held(),
-        n_experts=n_experts, interpret=sz.interpret))
+        n_experts=n_experts, interpret=sz.interpret)[0])
     _check_mosaic(grouped.lower(params, x).as_text(), "grouped_experts")
     with mock.patch.object(decoder, "moe_experts_path",
                            lambda *a, **k: "dense"):
         dense = jax.jit(lambda p, x: layer.forward(
             p, layer.init_state(), x, train=False)[0])(params, x)
-    err = _rel_err(grouped(params, x), dense)
+    err = max(_rel_err(grouped(params, x), dense), _rel_err(picked, dense))
     _check(err <= KERNEL_BOUND,
-           f"the grouped experts differ from their dense form by "
-           f"{err:.3g} > {KERNEL_BOUND}")
+           f"the grouped experts at {shape} differ from their dense form "
+           f"by {err:.3g} > {KERNEL_BOUND}")
     return err
 
 

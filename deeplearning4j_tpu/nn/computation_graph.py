@@ -419,7 +419,8 @@ class ComputationGraph(Network):
         with no fetch between.  Also out: the float32 logits of the
         first and the last row at that position (computed anyway; what
         a comparison with a reference reads), and ``counts`` plus this
-        step's tokens by expert, one row a vertex of
+        step's tokens by expert and, last in the row, whether its
+        grouped experts spilled, one row a vertex of
         ``_expert_vertices``.  The carries and the counts are donated:
         the rings are updated in place.  Like ``cg.prefill_step`` and
         ``cg.fork_state`` it says what it closes over, so the executable
@@ -433,7 +434,8 @@ class ComputationGraph(Network):
             last = acts[out_name][:, -1]
             next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)[:, None]
             kept = jnp.stack([last[0], last[-1]])
-            picks = [new_state[n]["expert_tokens"]
+            picks = [jnp.append(new_state[n]["expert_tokens"],
+                                new_state[n]["experts_spilled"])
                      for n in self._expert_vertices()]
             if picks:
                 counts = counts + jnp.stack(picks)
@@ -862,7 +864,7 @@ class ComputationGraph(Network):
         vocabulary) float32 of rows 0 and batch-1, tokens by expert,
         new carries)``, all device arrays, ONE dispatch.  ``ids`` is
         (batch, time); ``carries`` and ``counts`` are consumed (donated).
-        ``counts`` is ``(len(_expert_vertices()), n_experts)`` int32 or
+        ``counts`` is ``(len(_expert_vertices()), n_experts + 1)`` int32 or
         None to start from zero."""
         self.init()
         if counts is None:
@@ -873,7 +875,7 @@ class ComputationGraph(Network):
             carries, ids, counts)
 
     def zero_expert_counts(self):
-        rows = [self.net_state[n]["expert_tokens"].shape[0]
+        rows = [self.net_state[n]["expert_tokens"].shape[0] + 1
                 for n in self._expert_vertices()]
         return jnp.zeros((len(rows), max(rows, default=0)), jnp.int32)
 

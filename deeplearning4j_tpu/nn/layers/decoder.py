@@ -33,7 +33,9 @@ import jax.numpy as jnp
 from ... import monitor as _monitor
 from ...ops.attention import (_einsum_acc, latent_ring_attention,
                               latent_ring_path, latent_ring_update)
-from ...ops.experts import grouped_experts, moe_experts_path
+from ...ops.experts import (dense_experts, grouped_experts,
+                            held_rows_experts, held_token_rows,
+                            moe_experts_path)
 from ..conf import inputs as _inputs
 from ..conf import serde
 from ..weights import Distribution, init_weights
@@ -179,10 +181,13 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     few (the token step: the experts' bytes bound it); a long chunk on a
     TPU takes the grouped form, ``ops.experts.grouped_experts``, which
     puts each (token, pick) pair through its own expert only, over the
-    same matrices where they lie (``experts_path``: from the call's
-    shapes alone).  State
+    same matrices where they lie, and so does the token step of a share
+    whose tokens are many for the few picks that land on it
+    (``experts_path``: from the call's shapes alone).  State
     ``expert_tokens`` (``n_experts`` int32) counts the picks of the last
-    call, for ``moe_expert_tokens_total``.
+    call, for ``moe_expert_tokens_total``, and ``experts_spilled``
+    (int32) says whether its held pairs outgrew the grouped form's rows
+    and took further rounds, for ``moe_experts_spilled_total``.
     """
 
     n_experts: int = 8
@@ -230,7 +235,8 @@ class MixtureOfExperts(FeedForwardLayerConfig):
         return p
 
     def init_state(self, dtype=jnp.float32):
-        return {"expert_tokens": jnp.zeros((self.n_experts,), jnp.int32)}
+        return {"expert_tokens": jnp.zeros((self.n_experts,), jnp.int32),
+                "experts_spilled": jnp.zeros((), jnp.int32)}
 
     def route(self, params: ParamTree, x: Array):
         """(tokens, top_k) expert indices and weights, in float32."""
@@ -240,7 +246,11 @@ class MixtureOfExperts(FeedForwardLayerConfig):
             precision=_HIGHEST))
         _, idx = jax.lax.top_k(g + params["router_bias"].astype(acc),
                                self.top_k)
-        w = jnp.take_along_axis(g, idx, axis=-1)
+        # g at the picks, compared and not looked up: the same numbers
+        # (a gather of 256 x 8 of them costs a v5e 21 us; PERF.md, PR 36)
+        w = jnp.sum(jnp.where(
+            idx[..., None] == jnp.arange(self.n_experts, dtype=idx.dtype),
+            g[..., None, :], 0.0), axis=-1)
         if self.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx, w * self.routed_scaling
@@ -249,39 +259,43 @@ class MixtureOfExperts(FeedForwardLayerConfig):
         """``"grouped"`` or ``"dense"``: the form ``forward`` takes for
         ``tokens`` tokens stored in ``dtype``, by the op's own predicate
         (host code asks it without tracing the step)."""
-        return moe_experts_path(tokens, len(self.held()), self.top_k,
-                                self.n_in, self.width, dtype, train)
+        return moe_experts_path(tokens, len(self.held()), self.n_experts,
+                                self.top_k, self.n_in, self.width, dtype,
+                                train)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         shape = x.shape
         x = x.reshape(-1, shape[-1])
-        grouped = self.experts_path(x.shape[0], x.dtype, train) == "grouped"
+        path = self.experts_path(x.shape[0], x.dtype, train)
         with _monitor.subscope("router"):
             idx, w = self.route(params, x)
-            if not grouped:
-                held = jnp.asarray(self.held(), jnp.int32)
+            if path != "grouped":
+                hit = idx[:, :, None] == jnp.asarray(self.held(), jnp.int32)
                 # (tokens, held): the weight of each held expert, 0
                 # unchosen
-                combine = jnp.sum(
-                    jnp.where(idx[:, :, None] == held[None, None, :],
-                              w[:, :, None], 0.0), axis=1)
+                combine = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
             counts = jnp.sum(
                 idx[:, :, None] == jnp.arange(self.n_experts)[None, None],
                 axis=(0, 1), dtype=jnp.int32)
+        spilled = jnp.zeros((), jnp.int32)
+        matrices = params["Wg"], params["Wu"], params["Wd"]
         with _monitor.subscope("experts"):
-            if grouped:
-                y = grouped_experts(x, idx, w, params["Wg"], params["Wu"],
-                                    params["Wd"], held=self.held(),
-                                    n_experts=self.n_experts)
+            if path == "grouped":
+                y, spilled = grouped_experts(
+                    x, idx, w, *matrices, held=self.held(),
+                    n_experts=self.n_experts)
+            elif path == "held_rows":
+                y, spilled = held_rows_experts(
+                    x, jnp.any(hit, axis=(1, 2)), combine, *matrices,
+                    rows=held_token_rows(x.shape[0], self.top_k,
+                                         len(self.held()), self.n_experts))
             else:
-                a = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
-                a = (a.reshape(x.shape[0], held.shape[0], self.width)
-                     * combine[:, :, None].astype(a.dtype))
-                y = a.reshape(x.shape[0], -1) @ params["Wd"]
+                y = dense_experts(x, combine, *matrices)
         if self.n_shared:
             with _monitor.subscope("shared"):
                 y = y + _gated(x, params["Sg"], params["Su"], params["Sd"])
-        return y.reshape(shape), {"expert_tokens": counts}
+        return y.reshape(shape), {"expert_tokens": counts,
+                                  "experts_spilled": spilled}
 
 
 # -------------------------------------------------------------- attention

@@ -112,11 +112,13 @@ class Generation:
     (2, vocabulary) float32 array a step (rows 0 and batch-1), for
     whoever compares them with a reference afterwards;
     ``expert_tokens`` is ``{vertex: (n_experts,) picks}`` over the
-    call's steps."""
+    call's steps, ``experts_spilled`` ``{vertex: steps}`` whose held
+    pairs outgrew the grouped form's rows (more rounds, the same sum)."""
 
     ids: np.ndarray
     kept_logits: list
     expert_tokens: dict
+    experts_spilled: dict
 
 
 class _Session:
@@ -569,8 +571,14 @@ class SessionCache:
             "picks that named an expert the layer holds, by layer")
         experts_held = _monitor.gauge(
             "moe_experts_held", "experts an expert layer holds, by layer")
-        by_vertex = dict(zip(model._expert_vertices(), counts))
+        spilled = _monitor.counter(
+            "moe_experts_spilled_total",
+            "token steps whose held pairs outgrew the grouped form's rows "
+            "and took further rounds, by layer")
+        by_vertex = dict(zip(model._expert_vertices(), counts[:, :-1]))
+        spills = dict(zip(by_vertex, counts[:, -1:].ravel().tolist()))
         for vertex, row in by_vertex.items():
+            spilled.inc(spills[vertex], model=self._name, layer=vertex)
             for expert, picks in enumerate(row):
                 if picks:
                     tokens.inc(int(picks), model=self._name, layer=vertex,
@@ -579,7 +587,8 @@ class SessionCache:
             held_picks.inc(int(row[held].sum()), model=self._name,
                            layer=vertex)
             experts_held.set(len(held), model=self._name, layer=vertex)
-        return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex)
+        return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex,
+                          spills)
 
     def _count_expert_steps(self, batch: int, by_length) -> None:
         """``moe_experts_steps_total{path}``: the launched steps of

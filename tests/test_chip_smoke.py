@@ -52,7 +52,8 @@ TOY = chip_smoke.Sizes(
     train_batch=2, prefill=6, decode_steps=4,
     kernel_bthd=(1, 64, 2, 16), kernel_ref_t=32, kernel_short_ts=(40, 8),
     latent_shape=(2, 8, 128, 16, 256, 200),
-    experts_shape=(40, 8, 32, 64, 2), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
+    experts_shape=(40, 8, 32, 64, 2),
+    experts_share_shape=(32, 24, 4, 32, 64, 3), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
 
 
 def test_train_then_serve():
@@ -79,7 +80,8 @@ def test_kernels():
     assert {"bfloat16_T40_max_rel_err", "bfloat16_T8_max_rel_err",
             "float32_T32_max_rel_err",
             "latent_streamed_max_rel_err",
-            "experts_grouped_max_rel_err"} <= set(info)
+            "experts_grouped_max_rel_err",
+            "experts_share_grouped_max_rel_err"} <= set(info)
 
 
 def test_four_chips():

@@ -264,12 +264,13 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
                                                                  cell):
     """A prefill chunk's 2,048 tokens (and a shorter remainder chunk's)
     through 64 experts of (3,584, 1,024), 4 picks, and through 12 held
-    of 192 experts of (7,168, 2,048), 8 picks, bf16: three kernels under
+    of 192 experts of (7,168, 2,048), 8 picks, and 256 tokens of that
+    share (one tile of 256 rows), bf16: three kernels under
     the scope they were called under, the experts' matrices read where
     they lie: no instruction but the parameters has a matrix's shape,
-    and the temporaries are the pairs' rows, nothing weight-sized (a
-    share gathers a row for every pick, those of absent experts too:
-    15/16 of the second cell's, wasteful in set-up only)."""
+    and the temporaries are the rows', nothing weight-sized; under a
+    share the rows are those of the held pairs and nothing is as large
+    as the pairs' rows would be."""
     from jax.experimental.compilation_cache import compilation_cache
     hidden, width, held, n_experts, top_k = CELLS[cell][1]
     jax.config.update("jax_enable_compilation_cache", False)
@@ -278,7 +279,7 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
               f"bf16[{held * width},{hidden}]")
     try:
         with jax.enable_x64(False):
-            for tokens in (2048, 1984):
+            for tokens in (2048, 1984) + (256,) * (held < n_experts):
                 shapes = [
                     jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                     for s, d in (((tokens, hidden), jnp.bfloat16),
@@ -293,20 +294,35 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
                 lines = compiled.as_text().splitlines()
                 calls = [line for line in lines
                          if 'custom_call_target="tpu_custom_call"' in line]
-                assert len(calls) == 3
+                # under a share the first round and the loop of the
+                # further ones hold the three each
+                assert len(calls) == (6 if held < n_experts else 3)
                 assert {monitor.parse_op_name(
                     line.split('op_name="')[1].split('"')[0])
                     for line in calls} == {("layer.L1_moe.experts",
                                             "forward")}
                 made = [line for line in lines if " = " in line
                         and line.split(" = ")[1].startswith(matrix)
-                        and " parameter(" not in line]
+                        and " parameter(" not in line
+                        # a share's rounds are a loop; its body is
+                        # handed the matrices, no copy of them
+                        and " get-tuple-element(" not in line]
                 assert not made, made
-                # what a pair holds between the kernels: its row in
-                # bf16, two float32 products and their bf16 product,
-                # its float32 row out (235 and 940 MB at 2,048 tokens)
+                # what a row holds between the kernels: the pair's row
+                # in bf16, two float32 products and their bf16 product,
+                # its float32 row out and, under a share, that row's
+                # three bf16 terms and the tokens' float32 sums (235 MB
+                # where every expert is held; 940 MB at 2,048 tokens of
+                # the share before its pairs were compacted, now 16,384
+                # pairs in 2,048 rows)
+                rows, _ = experts.grouped_rows(tokens, top_k, held,
+                                               n_experts)
+                assert rows == (tokens * top_k if held == n_experts
+                                else 2048 if tokens > 256 else 256)
                 assert (compiled.memory_analysis().temp_size_in_bytes
-                        < tokens * top_k * (6 * hidden + 10 * width))
+                        < rows * (6 * hidden + 10 * width)
+                        + (held < n_experts) * (rows * 6 + tokens * 8)
+                        * hidden)
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()

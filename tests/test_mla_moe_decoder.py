@@ -626,11 +626,12 @@ def test_the_parts_a_trace_has_to_tell_apart_have_scopes_of_their_own(net):
                   "layer.L0_ffn_write.sinkhorn", "layer.L0_attn_read"):
         assert f"/{scope}/" in text, scope
     # the token step keeps the experts' dense form: three plain products
-    # under the experts' scope and no kernel (a few tokens are bound by
-    # the experts' bytes, which both forms read once)
+    # over the matrices under the experts' scope (and the 0/1 one that
+    # spreads a weight over its expert's columns) and no kernel (a few
+    # tokens are bound by the experts' bytes, which both forms read once)
     experts = [line.split('"')[1] for line in text.splitlines()
                if line.startswith("#loc") and "/layer.L1_moe.experts/" in line]
-    assert sum(name.endswith("/dot_general") for name in experts) == 3
+    assert sum(name.endswith("/dot_general") for name in experts) == 4
     assert not any("pallas_call" in name for name in experts)
     assert monitor.parse_op_name(
         "jit(run)/layer.L1_moe/layer.L1_moe.experts/dot_general") == (
