@@ -119,6 +119,10 @@ class Sizes:
     # and of one layer of the share's cell under its token step: (tokens,
     # the router's width, experts held, width, hidden, picks a token)
     experts_share_shape: Tuple[int, ...] = (256, 192, 12, 2048, 7168, 8)
+    # the indexed sparse attention of one layer of the sparse cell:
+    # (batch, query heads, key/value heads, head size, indexer heads,
+    # indexer head size, ring slots, selected rows, a chunk's positions)
+    sparse_shape: Tuple[int, ...] = (8, 32, 4, 128, 16, 64, 8192, 2048, 256)
     interpret: bool = False         # True only where there is no Mosaic
     # four_chips: ZeRO needs a MultiLayerNetwork
     mln_conf: Callable = _lenet_conf
@@ -415,6 +419,7 @@ def phase_kernels(sz: Sizes):
         sz, "grouped", tokens, n_experts, n_experts, *widths)
     report["experts_share_grouped_max_rel_err"] = _experts_kernel(
         sz, "held_rows", *sz.experts_share_shape)
+    report["sparse_streamed_max_rel_err"] = _sparse_kernels(sz)
     return report
 
 
@@ -449,6 +454,62 @@ def _latent_kernel(sz: Sizes) -> float:
            f"the streamed latent attention differs from its dense form by "
            f"{err:.3g} > {KERNEL_BOUND}")
     return err
+
+
+def _sparse_kernels(sz: Sizes) -> float:
+    """The indexed sparse attention's three streamed kernels (bf16 rings)
+    against the plain forms of the same arguments, for a token step's
+    one position and a chunk's: the indexer's scores (float32 both
+    ways), the selection (the same rows), then the attention over them.  Both
+    attentions round ``p`` to bf16 for the context product, the plain
+    form after the division and the streamed one before it."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops import attention
+
+    batch, heads, kv_heads, d, j, di, slots, topk, chunk = sz.sparse_shape
+    rng = np.random.RandomState(SEED + 8)
+    draw = lambda *shape: jnp.asarray(
+        rng.randn(*shape).astype(np.float32), jnp.bfloat16)
+    k_ring, v_ring, i_ring = (draw(batch, slots, kv_heads * d),
+                              draw(batch, slots, kv_heads * d),
+                              draw(batch, slots, di))
+    worst = 0.0
+    for t in (1, chunk):
+        cursor = jnp.asarray(slots - t - 3, jnp.int32)
+        q, q_idx, w_idx = (draw(batch, t, heads, d), draw(batch, t, j, di),
+                           draw(batch, t, j))
+        visible = attention.visible_slots(cursor, t, slots)[None]
+        scores = jax.jit(attention.indexer_scores)
+        plain = scores(q_idx, w_idx, i_ring)
+        scored = jax.jit(lambda *a: attention.indexer_scores_streamed(
+            *a, cursor, interpret=sz.interpret))
+        _check_mosaic(scored.lower(q_idx, w_idx, i_ring).as_text(),
+                      "indexer_scores_streamed")
+        worst = max(worst, _rel_err(
+            jnp.where(visible, scored(q_idx, w_idx, i_ring), 0.0),
+            jnp.where(visible, plain, 0.0)))
+        select = jax.jit(lambda s: attention.select_mask(s, visible, topk))
+        selected = select(plain)
+        _check(bool(jnp.all(jnp.sum(selected, axis=-1) == topk)),
+               f"the selection of {topk} rows picked another number")
+        search = jax.jit(lambda s: attention.select_mask_streamed(
+            s, cursor, topk, interpret=sz.interpret))
+        _check_mosaic(search.lower(plain).as_text(), "select_mask_streamed")
+        _check(bool(jnp.all((search(plain) != 0) == selected)),
+               "the streamed selection picked other rows than the plain one")
+        streamed = jax.jit(lambda *a: attention.sparse_attention_streamed(
+            *a, cursor, sm_scale=d ** -0.5, interpret=sz.interpret))
+        _check_mosaic(streamed.lower(q, k_ring, v_ring, selected).as_text(),
+                      "sparse_attention_streamed")
+        masked = jax.jit(lambda *a: attention.sparse_attention_masked(
+            *a, sm_scale=d ** -0.5))
+        worst = max(worst, _rel_err(streamed(q, k_ring, v_ring, selected),
+                                    masked(q, k_ring, v_ring, selected)))
+    _check(worst <= KERNEL_BOUND,
+           f"the streamed sparse attention differs from its plain form by "
+           f"{worst:.3g} > {KERNEL_BOUND}")
+    return worst
 
 
 def _experts_kernel(sz: Sizes, path: str, tokens: int, n_experts: int,

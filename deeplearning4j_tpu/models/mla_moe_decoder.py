@@ -1,13 +1,26 @@
-"""A decoder of latent-attention blocks with routed experts, on a plain
-or a hyper-connected residual path, built on the ComputationGraph DSL.
+"""A decoder of attention blocks with routed experts, on a plain or a
+hyper-connected residual path, built on the ComputationGraph DSL.
 
-One builder for the family whose published ``config.json`` carries
-DeepSeek-V3's keys (``kv_lora_rank``, ``n_routed_experts``,
-``first_k_dense_replace``, ...), with or without the hyper-connection
-keys (``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
-``mhc_h_res_clamp_min/max``).  The benchmark's ``xing4_29b_a4b``
-configuration is a file with them, ``ax_k1`` one without; the CPU tests
-run a small one of each.
+One builder for the published ``config.json`` files it can read, the
+sublayers picked from the file's own keys:
+
+- attention: latent attention (``LatentAttention``) from DeepSeek-V3's
+  ``kv_lora_rank`` and its companions; grouped-query attention over the
+  rows a learned indexer selects (``SparseGroupedQueryAttention``) from
+  ``sa_config`` with ``num_key_value_heads`` and ``head_dim``;
+- experts: DeepSeek-V3's keys (``n_routed_experts``,
+  ``n_shared_experts``, ``routed_scaling_factor``, ``scoring_func``,
+  ``first_k_dense_replace``) or the Qwen3-MoE family's (``num_experts``,
+  softmax scores, no shared expert, ``mlp_only_layers`` /
+  ``decoder_sparse_step`` for the dense layers);
+- residual path: the hyper-connection keys (``hc_mult``,
+  ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min/max``) or
+  none of them.
+
+The benchmark's ``xing4_29b_a4b`` configuration is a latent-attention
+file with the hyper-connection keys, ``ax_k1`` one without,
+``keye_vl2_30b_a3b`` a ``sa_config`` file; the CPU tests run a small one
+of each.
 
 With ``hc_mult``, per token: ``embed`` -> ``streams`` (``hc_mult``
 copies) -> for each layer ``L<i>`` two sublayers, attention then
@@ -25,12 +38,13 @@ Without it the residual path is the plain pre-norm one, ``x + F(rmsnorm
 names.
 
 A file that is one chip's share of an expert-parallel deployment gives
-the experts it holds under ``n_routed_experts`` and the source's count
-under ``published``: the router keeps the published width, and
-``experts_held`` says which of its outputs are the held experts.
+the experts it holds under ``n_routed_experts`` (``num_experts``) and
+the source's count under ``published``: the router keeps the published
+width, and ``experts_held`` says which of its outputs are the held
+experts.
 
 The multi-token-prediction module of a published model is not built: it
-is not part of the served forward pass.
+is not part of the served forward pass; nor is a vision tower.
 """
 
 from __future__ import annotations
@@ -43,6 +57,7 @@ from ..nn.conf.neural_net_configuration import NeuralNetConfiguration
 from ..nn.layers.decoder import (GatedFeedForward, HyperConnectionRead,
                                  HyperConnectionWrite, LatentAttention,
                                  LMHead, MixtureOfExperts, RMSNorm,
+                                 SparseGroupedQueryAttention,
                                  TokenEmbedding)
 from ..nn.weights import Distribution
 
@@ -53,7 +68,7 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
                 hc_bias_std: float = 0.0, router_bias_std: float = 0.0,
                 dtype: Optional[str] = None, seed: int = 0):
     """The graph configuration for the published keys in ``cfg``.
-    ``cache_len`` is the latent ring's default capacity;
+    ``cache_len`` is the attention rings' default capacity;
     ``experts_held`` the experts every expert layer holds (default:
     all); ``init_std`` the normal deviation of every matrix;
     ``hc_alpha_init``, ``hc_bias_std`` and ``router_bias_std`` the
@@ -69,10 +84,14 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
         b = b.dtype(dtype)
     g = b.graph_builder()
     c = int(cfg["hidden_size"])
+    # the two families' keys for the same thing: DeepSeek-V3's, whose
+    # router scores with a sigmoid, and Qwen3-MoE's, with a softmax
+    qwen = "num_experts" in cfg
+    experts_key = "num_experts" if qwen else "n_routed_experts"
     # a share's file: the router's width is the source's count, the
     # file's own count how many of its experts this chip holds
-    n_experts = int(cfg["n_routed_experts"])
-    published = cfg.get("published", {}).get("n_routed_experts")
+    n_experts = int(cfg[experts_key])
+    published = cfg.get("published", {}).get(experts_key)
     if published is not None:
         if len(experts_held or ()) != n_experts:
             raise ValueError(
@@ -116,17 +135,41 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
     if streams:
         g.add_vertex("streams", StreamExpandVertex(n_streams=n), x)
         x = "streams"
+    def attention():
+        common = dict(n_in=c, n_out=c, eps=float(cfg["rms_norm_eps"]),
+                      n_heads=int(cfg["num_attention_heads"]),
+                      rope_theta=float(cfg["rope_theta"]),
+                      cache_len=int(cache_len))
+        if "kv_lora_rank" in cfg:
+            return LatentAttention(
+                q_rank=int(cfg["q_lora_rank"]),
+                kv_rank=int(cfg["kv_lora_rank"]),
+                d_nope=int(cfg["qk_nope_head_dim"]),
+                d_rope=int(cfg["qk_rope_head_dim"]),
+                d_v=int(cfg["v_head_dim"]),
+                rope_scaling=cfg.get("rope_scaling"), **common)
+        if "sa_config" in cfg:
+            sa = cfg["sa_config"]
+            if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+                raise ValueError("the indexer caches one key head a token")
+            return SparseGroupedQueryAttention(
+                n_kv_heads=int(cfg["num_key_value_heads"]),
+                head_dim=int(cfg["head_dim"]),
+                index_heads=int(sa["indexer_num_heads"]),
+                index_dim=int(sa["indexer_head_dim"]),
+                topk=int(sa["topk"]), **common)
+        raise ValueError("the file names no attention this builder makes: "
+                         "neither kv_lora_rank nor sa_config")
+
+    def dense(i: int) -> bool:
+        if qwen:
+            return (i in cfg.get("mlp_only_layers", ())
+                    or (i + 1) % int(cfg.get("decoder_sparse_step", 1)) != 0)
+        return i < int(cfg["first_k_dense_replace"])
+
     for i in range(int(cfg["num_hidden_layers"])):
-        x = sublayer(f"L{i}_attn", LatentAttention(
-            n_in=c, n_out=c, n_heads=int(cfg["num_attention_heads"]),
-            q_rank=int(cfg["q_lora_rank"]), kv_rank=int(cfg["kv_lora_rank"]),
-            d_nope=int(cfg["qk_nope_head_dim"]),
-            d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
-            eps=float(cfg["rms_norm_eps"]),
-            rope_theta=float(cfg["rope_theta"]),
-            rope_scaling=cfg.get("rope_scaling"), cache_len=int(cache_len)),
-            x, "attn")
-        if i < int(cfg["first_k_dense_replace"]):
+        x = sublayer(f"L{i}_attn", attention(), x, "attn")
+        if dense(i):
             x = sublayer(f"L{i}_ffn", GatedFeedForward(
                 n_in=c, n_out=c, width=int(cfg["intermediate_size"])),
                 x, "ffn")
@@ -135,11 +178,14 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
                 n_in=c, n_out=c, n_experts=n_experts,
                 top_k=int(cfg["num_experts_per_tok"]),
                 width=int(cfg["moe_intermediate_size"]),
-                n_shared=int(cfg["n_shared_experts"]),
-                routed_scaling=float(cfg["routed_scaling_factor"]),
+                n_shared=int(cfg.get("n_shared_experts", 0)),
+                routed_scaling=float(cfg.get("routed_scaling_factor", 1.0)),
                 norm_topk=bool(cfg["norm_topk_prob"]),
                 router_bias_std=router_bias_std,
-                experts_held=experts_held), x, "moe")
+                experts_held=experts_held,
+                scoring=cfg.get("scoring_func",
+                                "softmax" if qwen else "sigmoid")),
+                x, "moe")
     if streams:
         g.add_vertex("stream_sum", StreamSumVertex(), x)
         x = "stream_sum"

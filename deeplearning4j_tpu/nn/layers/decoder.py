@@ -1,21 +1,24 @@
 """Decoder-block layers: token embedding, RMS norm, gated feed-forward,
-latent attention over a compressed ring, a sigmoid-routed expert layer,
-manifold-constrained hyper-connections, and the language-model head.
+latent attention over a compressed ring, grouped-query attention over
+the cached rows a learned indexer selects, a routed expert layer
+(sigmoid or softmax scores), manifold-constrained hyper-connections,
+and the language-model head.
 
 Everything here is plain ``jax.numpy`` under the container's
-``layer.<name>`` scopes, but for the two kernels that ``ops/`` puts
-under the latent attention (``ops.attention``) and under the routed
-experts of a long chunk (``ops.experts``), each chosen from the call's
-shapes alone; the parts a trace has to tell apart open a
-sub-scope (``monitor.subscope``: ``layer.<name>.experts``,
-``.latent_attention``, ``.router``, ``.shared``, ``.sinkhorn``).
+``layer.<name>`` scopes, but for the kernels that ``ops/`` puts under
+the two attentions (``ops.attention``) and under the routed experts of
+a long chunk (``ops.experts``), each chosen from the call's shapes
+alone; the parts a trace has to tell apart open a sub-scope
+(``monitor.subscope``: ``layer.<name>.experts``, ``.latent_attention``,
+``.router``, ``.shared``, ``.sinkhorn``, ``.indexer``, ``.select``,
+``.sparse_attention``).
 
 The equations are DeepSeek-V2/V3's for latent attention (MLA), routing
-and the expert layer, and those of "Manifold-Constrained
-Hyper-Connections" (arXiv:2512.24880) for the residual path; the plain
-reference the benchmark compares with is
-``benchmark/reference/mla_moe_decoder.py``, written apart from this
-file.
+and the expert layer, DeepSeek-V3.2's for the indexer and its
+selection, and those of "Manifold-Constrained Hyper-Connections"
+(arXiv:2512.24880) for the residual path; the plain references the
+benchmark compares with are ``benchmark/reference/mla_moe_decoder.py``
+and ``gqa_sparse_moe.py``, written apart from this file.
 
 Activations are (batch, time, features); the residual path of a
 hyper-connected model is (batch, time, streams, features).
@@ -32,7 +35,9 @@ import jax.numpy as jnp
 
 from ... import monitor as _monitor
 from ...ops.attention import (_einsum_acc, latent_ring_attention,
-                              latent_ring_path, latent_ring_update)
+                              latent_ring_path, latent_ring_update,
+                              sparse_attention_path, sparse_ring_attention,
+                              sparse_ring_update)
 from ...ops.experts import (dense_experts, grouped_experts,
                             held_rows_experts, held_token_rows,
                             moe_experts_path)
@@ -161,11 +166,14 @@ class GatedFeedForward(FeedForwardLayerConfig):
 @serde.register("mixture_of_experts")
 @dataclasses.dataclass
 class MixtureOfExperts(FeedForwardLayerConfig):
-    """Sigmoid-routed experts with a selection bias and shared experts
+    """Routed experts with a selection bias and shared experts
     (DeepSeek-V3's ``noaux_tc`` with one group): ``g = sigmoid(x Wr)``,
     the ``top_k`` largest of ``g + bias`` are chosen, their weights are
     ``g`` there, divided by their sum and times ``routed_scaling``;
     ``y = sum_i w_i E_i(x) + E_shared(x)``.  No token is dropped.
+    ``scoring="softmax"`` scores with ``g = softmax(x Wr)`` over all
+    ``n_experts`` instead (the Qwen3-MoE family's router: no bias drawn,
+    no scaling, ``n_shared`` 0).
 
     ``experts_held`` (default: all) says which experts this layer holds:
     it routes over all ``n_experts`` and computes the held experts' part
@@ -198,6 +206,7 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     norm_topk: bool = True
     router_bias_std: float = 0.0
     experts_held: Optional[List[int]] = None
+    scoring: str = "sigmoid"
 
     def held(self) -> List[int]:
         return (list(range(self.n_experts)) if self.experts_held is None
@@ -241,7 +250,9 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     def route(self, params: ParamTree, x: Array):
         """(tokens, top_k) expert indices and weights, in float32."""
         acc = _acc(x.dtype)
-        g = jax.nn.sigmoid(jnp.matmul(
+        score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[
+            self.scoring]
+        g = score(jnp.matmul(
             x.astype(acc), params["router"].astype(acc),
             precision=_HIGHEST))
         _, idx = jax.lax.top_k(g + params["router_bias"].astype(acc),
@@ -348,6 +359,21 @@ def rotate(x: Array, positions: Array, inv_freq: Array,
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotate_half(x: Array, positions: Array, inv_freq: Array) -> Array:
+    """Rotary embedding of the last axis of (batch, time, ..., dim) by
+    (time,) positions over the half-split pairs ``(i, i + dim / 2)``
+    (the Llama/Qwen layout, where :func:`rotate` turns ``(2i, 2i+1)``);
+    float32 inside."""
+    acc = _acc(x.dtype)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    shape = (1, -1) + (1,) * (x.ndim - 3) + (angle.shape[-1],)
+    cos = jnp.cos(angle).reshape(shape).astype(acc)
+    sin = jnp.sin(angle).reshape(shape).astype(acc)
+    a, b = jnp.split(x.astype(acc), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 @serde.register("latent_attention")
@@ -479,6 +505,158 @@ class LatentAttention(BaseRecurrentLayer):
         return latent_ring_path(t, self.n_heads, c_ring.shape[2],
                                 r_ring.shape[2], c_ring.shape[1],
                                 c_ring.dtype)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        out, _ = self.forward_seq(
+            params, x, self.init_carry(x.shape[0], x.dtype, x.shape[1]),
+            train=train, rng=rng, mask=mask)
+        return out, state
+
+
+@serde.register("sparse_grouped_query_attention")
+@dataclasses.dataclass
+class SparseGroupedQueryAttention(BaseRecurrentLayer):
+    """Grouped-query attention over the cached rows a learned indexer
+    selects (DeepSeek-V3.2's sparse attention under grouped-query heads).
+
+    ``q = norm_head(x Wq)`` (``n_heads`` of ``head_dim``), ``k =
+    norm_head(x Wk)``, ``v = x Wv`` (``n_kv_heads`` each; query head
+    ``h`` reads key/value head ``h // (n_heads / n_kv_heads)``), rotary
+    on ``q`` and ``k`` over the half-split pairs; ``norm_head`` an RMS
+    norm over a head with one gain.  The indexer: ``qI = x WqI``
+    (``index_heads`` of ``index_dim``), ``kI = LayerNorm(x WkI)`` (one
+    head, cached), rotary on both, ``w = x Ww``; ``I[t, s] = sum_j
+    w[t, j] relu(qI[t, j] . kI[s])`` in float32 whatever the storage;
+    query ``t`` attends over the ``topk`` visible positions of largest
+    ``I[t, .]`` (every visible one while they are no more; equal scores:
+    the lowest position first), softmax of ``q . k / sqrt(head_dim)``
+    over them.  The carry is ``(key ring (batch, capacity, n_kv_heads x
+    head_dim), value ring, indexer-key ring (batch, capacity,
+    index_dim), cursor)``: slots-major, a slot's heads side by side.
+    One path serves prefill chunks, single steps and ``output()`` (from
+    a zero ring); the form the selection
+    and the attention take is ``ops.attention.sparse_attention_path``'s,
+    from the call's shapes alone.
+    """
+
+    HAS_KV_RING = True
+    STATE_KIND = "sparse_kv"    # serving_session_state_bytes{kind=}
+
+    activation: str = "identity"
+    n_heads: int = 1
+    n_kv_heads: int = 1
+    head_dim: int = 0
+    index_heads: int = 1
+    index_dim: int = 0
+    topk: int = 2048
+    eps: float = 1e-6
+    rope_theta: float = 10000.0
+    cache_len: int = 128
+
+    def param_order(self) -> tuple:
+        return ("Wq", "q_gain", "Wk", "k_gain", "Wv", "Wo",
+                "WqI", "WkI", "kI_gain", "kI_bias", "Ww")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        h, g, d, c = self.n_heads, self.n_kv_heads, self.head_dim, self.n_in
+        kq, kk, kv, ko, kqi, kki, kw = jax.random.split(rng, 7)
+        return {
+            "Wq": _matrix(self, kq, (c, h * d), dtype),
+            "q_gain": jnp.ones((d,), dtype),
+            "Wk": _matrix(self, kk, (c, g * d), dtype),
+            "k_gain": jnp.ones((d,), dtype),
+            "Wv": _matrix(self, kv, (c, g * d), dtype),
+            "Wo": _matrix(self, ko, (h * d, self.n_out), dtype),
+            "WqI": _matrix(self, kqi,
+                           (c, self.index_heads * self.index_dim), dtype),
+            "WkI": _matrix(self, kki, (c, self.index_dim), dtype),
+            "kI_gain": jnp.ones((self.index_dim,), dtype),
+            "kI_bias": jnp.zeros((self.index_dim,), dtype),
+            "Ww": _matrix(self, kw, (c, self.index_heads), dtype),
+        }
+
+    # -------------------------------------------------------------- carry
+    def init_carry(self, batch: int, dtype, cache_len: Optional[int] = None):
+        cap = int(cache_len if cache_len is not None else self.cache_len)
+        if cap < 1:
+            raise ValueError("cache_len must be >= 1")
+        kv = (batch, cap, self.n_kv_heads * self.head_dim)
+        return (jnp.zeros(kv, dtype), jnp.zeros(kv, dtype),
+                jnp.zeros((batch, cap, self.index_dim), dtype),
+                jnp.zeros((), jnp.int32))
+
+    def grow_carry(self, carry, cache_len: int):
+        *rings, cursor = carry
+        cap = rings[0].shape[1]
+        if cache_len < cap:
+            raise ValueError(
+                f"cannot shrink the key/value ring from {cap} to {cache_len}")
+        pad = [(0, 0), (0, cache_len - cap), (0, 0)]
+        return tuple(jnp.pad(r, pad) for r in rings) + (cursor,)
+
+    # ------------------------------------------------------------ forward
+    def _turn(self, positions: Array):
+        """Rotary by ``positions`` (time,) over the whole last axis."""
+        def turn(a):
+            inv_freq, _ = yarn_inv_freq(a.shape[-1], self.rope_theta, None)
+            return rotate_half(a, positions, inv_freq)
+        return turn
+
+    def indexer(self, params: ParamTree, x: Array, positions: Array):
+        """The indexer's view of ``x`` (batch, time, hidden) at
+        ``positions``: ``(queries (batch, time, index_heads, index_dim),
+        head weights (batch, time, index_heads), keys (batch, time,
+        index_dim): LayerNorm(x WkI))``, queries and keys rotated."""
+        turn = self._turn(positions)
+        q_idx = turn((x @ params["WqI"]).reshape(
+            x.shape[:2] + (self.index_heads, self.index_dim)))
+        k = (x @ params["WkI"]).astype(_acc(x.dtype))
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                              + self.eps)
+        k = (k * params["kI_gain"].astype(k.dtype)
+             + params["kI_bias"].astype(k.dtype))
+        return q_idx, x @ params["Ww"], turn(k.astype(x.dtype))
+
+    def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
+        k_ring, v_ring, i_ring, cursor = carry
+        (b, t), cap = x.shape[:2], k_ring.shape[1]
+        if t > cap:
+            raise ValueError(f"chunk of {t} timesteps exceeds the "
+                             f"key/value ring's capacity {cap}")
+        h, g, d = self.n_heads, self.n_kv_heads, self.head_dim
+        positions = cursor + jnp.arange(t, dtype=jnp.int32)
+        turn = self._turn(positions)
+
+        def heads(w, gain, n):
+            a = (x @ params[w]).reshape(b, t, n, d)
+            return turn(rms_normalize(a, self.eps,
+                                      params[gain]).astype(x.dtype))
+
+        q = heads("Wq", "q_gain", h)
+        k = heads("Wk", "k_gain", g).reshape(b, t, g * d)
+        v = x @ params["Wv"]
+        with _monitor.subscope("indexer"):
+            q_idx, w_idx, k_idx = self.indexer(params, x, positions)
+        k_ring, v_ring, i_ring = sparse_ring_update(
+            k_ring, v_ring, i_ring, cursor, k, v, k_idx)
+        ctx = sparse_ring_attention(
+            q, q_idx, w_idx, k_ring, v_ring, i_ring, cursor,
+            topk=self.topk, sm_scale=d ** -0.5, scope=_monitor.subscope)
+        out = self._activate(ctx.reshape(b, t, h * d) @ params["Wo"])
+        if mask is not None:
+            out = out * mask[..., None].astype(out.dtype)
+        return out, (k_ring, v_ring, i_ring,
+                     cursor + jnp.asarray(t, jnp.int32))
+
+    def attention_path(self, t: int, carry) -> str:
+        """``"streamed"`` or ``"masked"``: the form ``forward_seq`` takes for ``t`` new positions against ``carry``,
+        by the op's own predicate (host code asks it without tracing the
+        step)."""
+        k_ring = carry[0]
+        return sparse_attention_path(t, self.n_heads, self.n_kv_heads,
+                                     self.head_dim, k_ring.shape[1],
+                                     k_ring.dtype)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         out, _ = self.forward_seq(

@@ -31,6 +31,7 @@ formulation (`parallel/sequence._full_attention`).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -951,3 +952,538 @@ def latent_ring_attention_dense(q_lat: Array, q_rope: Array, c_ring: Array,
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     ctx = _einsum_acc("bhtc,bcr->bthr", p.astype(c_ring.dtype), c_ring, acc)
     return ctx.astype(q_lat.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Indexed sparse attention over a grouped-query key/value ring (the sparse
+# attention of DeepSeek-V3.2): a small indexer scores every cached row for
+# every query, the ``topk`` best are selected EXACTLY (ties to the lowest
+# position), and the query heads attend over the selected rows alone.  The
+# rings are slots-major with the heads side by side: keys and values
+# (batch, capacity, kv heads x d), so that a slot's row is one contiguous
+# read and a new row is written as the projection leaves it; indexer keys
+# (batch, capacity, d_index), one head.  Two forms of one algorithm under
+# ``sparse_ring_attention``, picked by ``sparse_attention_path`` from the
+# call's shapes alone, both with the selection as a mask over the ring:
+#
+# streamed  on a TPU, a chunk or a token step: Pallas kernels that stream
+#           the rings through VMEM in blocks, so that neither the
+#           indexer's (heads, chunk, slots) scores nor the attention's
+#           ever reach HBM; blocks beyond the newest visible slot are
+#           neither fetched nor computed;
+# masked    the same mask over dense ``jax.numpy`` attention: any dtype,
+#           any backend, ``output()`` from a zero ring.
+#
+# Where the ring holds no more than ``topk`` slots every visible row is
+# selected and no score is computed: plain causal grouped-query attention.
+# ---------------------------------------------------------------------------
+
+def sparse_ring_update(k_ring: Array, v_ring: Array, i_ring: Array, cursor,
+                       k_new: Array, v_new: Array, i_new: Array):
+    """Write (batch, T, kv heads x d) keys and values and (batch, T,
+    d_index) indexer keys into their rings at the cursor.  Callers
+    guarantee ``cursor + T <= capacity``."""
+    zero = jnp.zeros((), jnp.int32)
+    at = (zero, jnp.asarray(cursor, jnp.int32), zero)
+    return tuple(
+        jax.lax.dynamic_update_slice(ring, new.astype(ring.dtype), at)
+        for ring, new in ((k_ring, k_new), (v_ring, v_new),
+                          (i_ring, i_new)))
+
+
+def visible_slots(cursor, t: int, capacity: int) -> Array:
+    """(T, capacity) bool: slot ``c`` is visible to query ``t`` iff
+    ``c <= cursor + t``."""
+    return (jnp.arange(capacity, dtype=jnp.int32)[None, :]
+            <= jnp.asarray(cursor, jnp.int32)
+            + jnp.arange(t, dtype=jnp.int32)[:, None])
+
+
+def indexer_scores(q_idx: Array, w_idx: Array, i_ring: Array) -> Array:
+    """``I[b, t, s] = sum_j w[b, t, j] * relu(q[b, t, j] . k[b, s])`` in
+    float32, (batch, T, capacity), for (batch, T, heads, d) indexer
+    queries, (batch, T, heads) head weights and the (batch, capacity, d)
+    indexer-key ring; invisible slots are scored like any other (the
+    selection masks them).  A zero is +0.0 whatever the signs of the
+    weights, so that equal scores are equal bit patterns."""
+    w_idx = w_idx.astype(jnp.float32)
+    if q_idx.shape[1] == 1:
+        # the token step: one product for all heads, (batch, heads, slots)
+        s = _einsum_acc("bjd,bsd->bjs", q_idx[:, 0], i_ring, jnp.float32)
+        out = jnp.sum(jnp.maximum(s, 0.0) * w_idx[:, 0, :, None],
+                      axis=1)[:, None]
+    else:
+        # head after head: no (heads, T, slots) array
+        def head(acc, qw):
+            q, w = qw                   # (batch, T, d), (batch, T)
+            s = _einsum_acc("btd,bsd->bts", q, i_ring, jnp.float32)
+            return acc + jnp.maximum(s, 0.0) * w[..., None], None
+        out, _ = jax.lax.scan(
+            head, jnp.zeros(q_idx.shape[:2] + i_ring.shape[1:2],
+                            jnp.float32),
+            (jnp.moveaxis(q_idx, 2, 0), jnp.moveaxis(w_idx, 2, 0)))
+    return jnp.where(out == 0.0, 0.0, out)
+
+
+def _sortable(scores: Array) -> Array:
+    """float32 to uint32 whose unsigned order is the floats' order (the
+    sign bit set on a positive number, every bit flipped on a negative
+    one): the smallest key of a number is above 0, which is left for
+    what may not be selected."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32),
+                                        jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+#: bits of the key settled in one pass over the scores: 15 candidates
+#: are counted a pass, 8 passes in all
+_SELECT_BITS = 4
+
+
+def _kth_largest_key(keys: Array, k: int) -> Array:
+    """The ``k``-th largest of ``keys`` (uint32, (..., n)) along the last
+    axis, exactly, by a radix search from the top bits: a pass counts
+    the keys at or above each candidate and keeps the largest candidate
+    that ``k`` keys reach.  0 where fewer than ``k`` keys are above 0."""
+    prefix = jnp.zeros(keys.shape[:-1], jnp.uint32)
+    for low in range(32 - _SELECT_BITS, -1, -_SELECT_BITS):
+        digit = jnp.zeros(prefix.shape, jnp.uint32)
+        for c in range(1, 1 << _SELECT_BITS):
+            candidate = prefix | jnp.uint32(c << low)
+            reached = jnp.sum(keys >= candidate[..., None], axis=-1,
+                              dtype=jnp.int32) >= k
+            digit = digit + reached.astype(jnp.uint32)
+        prefix = prefix | (digit << low)
+    return prefix
+
+
+def select_mask(scores: Array, visible: Array, k: int) -> Array:
+    """Bool like ``scores`` (..., n): along the last axis the ``k``
+    largest visible scores, every visible one where they are no more
+    than ``k``; of equal scores at the ``k``-th place the lowest
+    positions.  Exact."""
+    keys = jnp.where(visible, _sortable(scores), jnp.uint32(0))
+    kth = _kth_largest_key(keys, k)[..., None]
+    at_or_above = visible & (keys >= kth)
+
+    def with_ties():
+        above = keys > kth
+        tied = visible & (keys == kth)
+        room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+        before = jnp.cumsum(tied, axis=-1, dtype=jnp.int32) - tied
+        return above | (tied & (before < room))
+
+    # more than k at or above the k-th key: equal scores straddle the
+    # k-th place, which a running count along the positions settles;
+    # rare, so the count is taken only then
+    crowded = jnp.any(jnp.sum(at_or_above, axis=-1, dtype=jnp.int32) > k)
+    return jax.lax.cond(crowded, with_ties, lambda: at_or_above)
+
+
+#: queries a grid step of the streamed selection: (rows, capacity)
+#: float32 scores are one block, 4 MB at 32 queries of 32,768 slots
+_SELECT_ROWS = 32
+
+
+def select_mask_streamed(scores: Array, cursor, k: int, *,
+                         interpret: Optional[bool] = None) -> Array:
+    """:func:`select_mask` for a ring's scores (batch, T, capacity) with
+    slot ``c`` visible to query ``t`` iff ``c <= cursor + t``, as one
+    Pallas kernel; the mask comes back as 0/1 in bfloat16, which is what
+    :func:`sparse_attention_streamed` reads.  A grid step holds a few
+    queries' whole score rows in VMEM and finds each row's ``k``-th key
+    by a binary search over its 32 bits (a compare and a count a bit,
+    nothing read twice from HBM), then writes ``key >= k-th``.  A token
+    step's single query is folded into 8 rows of an eighth of the ring
+    each, so that it fills its vector registers.  Where equal scores
+    straddle the ``k``-th place in some row (more than ``k`` at or above
+    it: rare) the whole call falls back on :func:`select_mask`, which
+    settles them by position."""
+    batch, t, cap = scores.shape
+    fold = 8 if t == 1 and cap % 1024 == 0 else 1       # rows a query
+    rows = fold if fold > 1 else next(
+        r for r in (_SELECT_ROWS, 16, 8, 4, 2, 1) if t % r == 0)
+    width = cap // fold
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    cursor = jnp.asarray(cursor, jnp.int32).reshape(1)
+    lowest = np.int32(-2 ** 31)
+
+    def kernel(cursor_ref, s_ref, keep_ref, count_ref):
+        bits = jax.lax.bitcast_convert_type(s_ref[0], jnp.int32)
+        # int32 whose SIGNED order is the floats' order
+        keys = jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        slot = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        if fold > 1:            # the rows are one query's eighths
+            slot, newest = slot + row * width, cursor_ref[0]
+        else:
+            newest = cursor_ref[0] + pl.program_id(1) * rows + row
+        visible = slot <= newest
+        # what may not be selected sorts below every number
+        keys = jnp.where(visible, keys, lowest)
+
+        def count(hit):
+            n = jnp.sum(hit.astype(jnp.int32), axis=-1, keepdims=True)
+            return jnp.sum(n, axis=0, keepdims=True) if fold > 1 else n
+
+        def narrow(i, prefix):
+            # ``prefix`` holds the bits found so far in offset binary
+            # (unsigned order); flipping the top bit gives the signed
+            # number the keys compare with
+            candidate = prefix | jnp.left_shift(np.int32(1), 31 - i)
+            return jnp.where(count(keys >= (candidate ^ lowest)) >= k,
+                             candidate, prefix)
+
+        kth = jax.lax.fori_loop(
+            0, 32, narrow,
+            jnp.zeros((1 if fold > 1 else rows, 1), jnp.int32)) ^ lowest
+        keep = visible & (keys >= kth)
+        keep_ref[0] = keep.astype(keep_ref.dtype)
+        count_ref[0] = jnp.broadcast_to(count(keep), (rows, 128))
+
+    at = lambda b, q, cur: (b, q, 0)
+    keep, count = pl.pallas_call(
+        kernel,
+        out_shape=[_sds((batch, t * fold, width), jnp.bfloat16, scores),
+                   _sds((batch, t * fold, 128), jnp.int32, scores)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, t * fold // rows),
+            in_specs=[pl.BlockSpec((1, rows, width), at)],
+            out_specs=[pl.BlockSpec((1, rows, width), at),
+                       pl.BlockSpec((1, rows, 128), at)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(cursor, scores.astype(jnp.float32).reshape(batch, t * fold, width))
+    return jax.lax.cond(
+        jnp.any(count[..., 0] > k),
+        lambda: select_mask(scores, visible_slots(cursor[0], t, cap)[None],
+                            k).astype(jnp.bfloat16),
+        lambda: keep.reshape(batch, t, cap))
+
+
+def _grouped(q: Array, kv_heads: int) -> Array:
+    """(batch, T, heads, d) queries as (batch, T, kv heads, group, d):
+    query head ``h`` reads key/value head ``h // group``."""
+    b, t, h, d = q.shape
+    return q.reshape(b, t, kv_heads, h // kv_heads, d)
+
+
+def _by_head(ring: Array, d: int) -> Array:
+    """A (batch, slots, kv heads x d) ring as (batch, slots, kv heads,
+    d)."""
+    return ring.reshape(ring.shape[:2] + (ring.shape[2] // d, d))
+
+
+def _softmax_context(s: Array, keep: Array, v: Array, spec: str, dtype):
+    """Softmax of the float32 scores ``s`` over their last axis with
+    ``keep`` false entries left out, times ``v`` by ``spec``.  Every
+    query keeps at least one entry, so the row maximum is finite and a
+    masked entry weighs exactly 0.0."""
+    s = jnp.where(keep, s, jnp.asarray(_NEG_INF, s.dtype))
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return _einsum_acc(spec, p.astype(v.dtype), v, s.dtype).astype(dtype)
+
+
+def sparse_attention_masked(q: Array, k_ring: Array, v_ring: Array,
+                            selected: Array, *, sm_scale: float) -> Array:
+    """The dense form: (batch, T, heads, d) queries against every slot,
+    ``selected`` (batch, T, capacity) saying which count.  Any dtype, any
+    backend; the scores are one (batch, heads, T, capacity) array."""
+    acc = jnp.promote_types(q.dtype, jnp.float32)
+    k, v = _by_head(k_ring, q.shape[-1]), _by_head(v_ring, q.shape[-1])
+    qg = _grouped(q, k.shape[2])                             # (b, t, g, r, d)
+    s = _einsum_acc("btgrd,bsgd->bgrts", qg, k, acc) \
+        * jnp.asarray(sm_scale, acc)
+    ctx = _softmax_context(s, selected[:, None, None], v,
+                           "bgrts,bsgd->btgrd", q.dtype)
+    return ctx.reshape(q.shape)
+
+
+#: ring slots a grid step of the two streamed kernels, largest first
+_SPARSE_BLOCKS = (1024, 512, 256, 128)
+#: and of the indexer's kernel under a token step's single position
+_INDEXER_TOKEN_BLOCKS = (8192, 4096, 2048) + _SPARSE_BLOCKS
+_SPARSE_VMEM_LIMIT = 64 << 20
+
+def sparse_ring_block(capacity: int) -> int:
+    """Ring slots a grid step of the streamed form, or 0 where no block
+    divides ``capacity``."""
+    return next((b for b in _SPARSE_BLOCKS if capacity % b == 0), 0)
+
+
+def sparse_attention_path(t: int, heads: int, kv_heads: int, d: int,
+                          capacity: int, dtype) -> str:
+    """``"streamed"`` or ``"masked"``: which form
+    :func:`sparse_ring_attention` takes for ``t`` new positions a row
+    against rings of ``capacity`` slots stored in ``dtype``.  Streamed
+    where Mosaic compiles the kernels (a TPU), the storage is bfloat16
+    or float32, a head fills the 128 lanes, the new positions are
+    fewer than the ring's slots (more than one are padded to whole
+    sublane tiles of 8), and a block divides the capacity; masked
+    elsewhere.  A token step does not gather its selected rows: on a
+    v5e XLA moves the 16,384 selected rows of one ring (8 conversations,
+    1 KB a row) in 1.11 ms out of rings of 32,768 slots and in 3.56 ms
+    out of rings of 131,072, where the streamed kernel reads BOTH rings
+    whole in 0.80 and 2.93 ms (``tools/sparse_attention_sweep.py``;
+    PERF.md, PR 37).  Also what ``sparse_attention_steps_total{path}``
+    is labelled by."""
+    streamed = (_mosaic()
+                and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                         jnp.dtype(jnp.float32))
+                and d % 128 == 0 and t < capacity
+                and heads % kv_heads == 0
+                and sparse_ring_block(capacity) > 0)
+    return "streamed" if streamed else "masked"
+
+
+def _newest_block(k, cur, t: int, block: int):
+    """Index map of a ring block: the block itself, or the newest one
+    that holds a visible slot where this one holds none (its fetch is
+    then a repeat, which Pallas leaves out).  Non-negative int32s:
+    truncating division is the floor."""
+    return jnp.minimum(k, jax.lax.div(cur[0] + jnp.int32(t - 1),
+                                      jnp.int32(block)))
+
+
+def _kernel_dot(widen: bool):
+    def dot(a, b, contract):
+        if widen:           # XLA:CPU under the interpreter: _einsum_acc
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32)
+    return dot
+
+
+def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
+                            cursor, *, block: Optional[int] = None,
+                            interpret: Optional[bool] = None) -> Array:
+    """:func:`indexer_scores` as one Pallas kernel: grid (batch, ring
+    blocks), a block of indexer keys a step, every head's product
+    weighted and summed in VMEM, the (batch, T, capacity) float32 sum
+    the only thing written.  A chunk's heads go one at a time, (T,
+    block) each; the single position of a token step takes its heads as
+    the rows of one product.  A block wholly beyond ``cursor + T - 1``
+    is not fetched and reads 0."""
+    batch, t, heads, d = q_idx.shape
+    cap = i_ring.shape[1]
+    # a token step's product is (heads, block): long blocks, or the grid
+    # steps' own cost outweighs so narrow a ring's bytes
+    block = block or (sparse_ring_block(cap) if t > 1 else next(
+        (b for b in _INDEXER_TOKEN_BLOCKS if cap % b == 0), 0))
+    if not block or cap % block:
+        raise ValueError(f"no block of the streamed indexer divides a "
+                         f"ring of {cap} slots (block {block})")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    cursor = jnp.asarray(cursor, jnp.int32).reshape(1)
+    w_idx = w_idx.astype(jnp.float32)[..., None]
+    if t > 1:       # (batch, heads, T, .): a product a head, rows = T
+        q_idx, w_idx = jnp.swapaxes(q_idx, 1, 2), jnp.swapaxes(w_idx, 1, 2)
+    products, rows = q_idx.shape[1], q_idx.shape[2]
+
+    def kernel(cursor_ref, q_ref, w_ref, ring_ref, o_ref):
+        ki = pl.program_id(1)
+
+        @pl.when(ki * block <= cursor_ref[0] + (t - 1))
+        def _score():
+            ring = ring_ref[0]                          # (d, block)
+            total = jnp.zeros((rows, block), jnp.float32)
+            for j in range(products):
+                s = dot(q_ref[0, j], ring, ((1,), (0,)))
+                total = total + jnp.maximum(s, 0.0) * w_ref[0, j]
+            if t == 1:                                  # rows are heads
+                total = jnp.sum(total, axis=0, keepdims=True)
+            o_ref[0] = jnp.where(total == 0.0, 0.0, total)
+
+        @pl.when(ki * block > cursor_ref[0] + (t - 1))
+        def _beyond():
+            o_ref[0] = jnp.zeros((t, block), jnp.float32)
+
+    whole = lambda b, k, cur: (b, 0, 0, 0)
+    # the ring with its slots minor, as ``latent_ring_attention_streamed``
+    # takes its narrow ring: a bitcast where XLA:TPU keeps it so, and a
+    # (d, block) tile fills its lanes wherever it does not
+    return pl.pallas_call(
+        kernel,
+        out_shape=_sds((batch, t, cap), jnp.float32, q_idx),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, cap // block),
+            in_specs=[
+                pl.BlockSpec((1, products, rows, d), whole),
+                pl.BlockSpec((1, products, rows, 1), whole),
+                pl.BlockSpec((1, d, block), lambda b, k, cur: (
+                    b, 0, _newest_block(k, cur, t, block))),
+            ],
+            out_specs=pl.BlockSpec((1, t, block),
+                                   lambda b, k, cur: (b, 0, k))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(cursor, q_idx, w_idx, jnp.swapaxes(i_ring, 1, 2))
+
+
+def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
+                              selected: Array, cursor, *, sm_scale: float,
+                              block: Optional[int] = None,
+                              interpret: Optional[bool] = None) -> Array:
+    """:func:`sparse_attention_masked` as one Pallas kernel that reads
+    each conversation's rings once: grid (batch, ring blocks); a step
+    takes one block of both rings, every key/value head of it (whole
+    rows: one contiguous read), and the block of ``selected`` (batch, T,
+    capacity), and folds it into the streaming softmax of every query
+    head, float32 scores that never leave VMEM.  A chunk's query heads
+    go one at a time, (T, block) scores each; the single position of a
+    token step takes a key/value head's whole group as rows.  Blocks
+    wholly beyond ``cursor + T - 1`` are neither fetched nor computed; a
+    block in which a query selected nothing adds nothing to it."""
+    batch, t, heads, d = q.shape
+    cap, kv_heads = k_ring.shape[1], k_ring.shape[2] // d
+    group = heads // kv_heads
+    block = block or sparse_ring_block(cap)
+    if not block or cap % block:
+        raise ValueError(f"no block of the streamed sparse attention "
+                         f"divides a ring of {cap} slots (block {block})")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    num_blocks = cap // block
+    scale, masked = np.float32(sm_scale), np.float32(_NEG_INF)
+    cursor = jnp.asarray(cursor, jnp.int32).reshape(1)
+    # rows of a key/value head run (query head of the group, position);
+    # they are folded ``part`` rows at a time, all of which share ``keep``
+    part = group if t == 1 else t
+    rows = group * t
+
+    def kernel(cursor_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+               m_scr, l_scr, acc_scr):
+        ki = pl.program_id(1)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr[:], masked)
+            l_scr[:] = jnp.zeros_like(l_scr[:])
+            acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+        @pl.when(ki * block <= cursor_ref[0] + (t - 1))
+        def _fold():
+            keep = keep_ref[0].astype(jnp.float32) != 0.0   # (t, block)
+            if part != t:
+                keep = jnp.broadcast_to(keep, (part, block))
+            for g in range(kv_heads):
+                k = k_ref[0, :, g * d:(g + 1) * d]          # (block, d)
+                v = v_ref[0, :, g * d:(g + 1) * d]
+                for start in range(0, rows, part):
+                    at = (g, pl.ds(start, part))
+                    s = dot(q_ref[(0,) + at], k, ((1,), (1,))) * scale
+                    s = jnp.where(keep, s, masked)
+                    m_prev, l_prev = m_scr[at][:, :1], l_scr[at][:, :1]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True))
+                    # a query that has selected nothing yet keeps m at
+                    # the mask's value: its entries are not exp(0)
+                    alive = m_new > masked / 2
+                    p = jnp.where(alive & keep, jnp.exp(s - m_new), 0.0)
+                    correction = jnp.where(alive, jnp.exp(m_prev - m_new),
+                                           0.0)
+                    l_new = l_prev * correction + jnp.sum(
+                        p, axis=-1, keepdims=True)
+                    acc_scr[at] = acc_scr[at] * correction + dot(
+                        p.astype(v.dtype), v, ((1,), (0,)))
+                    m_scr[at] = jnp.broadcast_to(m_new, (part, 128))
+                    l_scr[at] = jnp.broadcast_to(l_new, (part, 128))
+
+        @pl.when(ki == num_blocks - 1)
+        def _finalize():
+            o_ref[0] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
+
+    newest = lambda k, cur: _newest_block(k, cur, t, block)
+    whole = lambda b, k, cur: (b, 0, 0, 0)
+    # (batch, kv heads, group x T, d): a head's rows, query head major
+    qg = jnp.transpose(_grouped(q, kv_heads), (0, 2, 3, 1, 4)).reshape(
+        batch, kv_heads, rows, d)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=_sds(qg.shape, q.dtype, q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, num_blocks),
+            in_specs=[
+                pl.BlockSpec((1, kv_heads, rows, d), whole),
+                pl.BlockSpec((1, block, kv_heads * d),
+                             lambda b, k, cur: (b, newest(k, cur), 0)),
+                pl.BlockSpec((1, block, kv_heads * d),
+                             lambda b, k, cur: (b, newest(k, cur), 0)),
+                pl.BlockSpec((1, t, block),
+                             lambda b, k, cur: (b, 0, newest(k, cur))),
+            ],
+            out_specs=pl.BlockSpec((1, kv_heads, rows, d), whole),
+            scratch_shapes=[
+                pltpu.VMEM((kv_heads, rows, 128), jnp.float32),  # max
+                pltpu.VMEM((kv_heads, rows, 128), jnp.float32),  # denom
+                pltpu.VMEM((kv_heads, rows, d), jnp.float32),    # sum
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(cursor, qg, k_ring, v_ring, selected.astype(jnp.bfloat16))
+    out = out.reshape(batch, kv_heads, group, t, d)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape)
+
+
+def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
+                          k_ring: Array, v_ring: Array, i_ring: Array,
+                          cursor, *, topk: int, sm_scale: float,
+                          scope=None):
+    """Attention of (batch, T, heads, d) queries over the ``topk`` cached
+    rows their indexer picks; the context, (batch, T, heads, d).
+    ``q_idx`` (batch, T, index heads, d_index) and
+    ``w_idx`` (batch, T, index heads) are the indexer's queries and head
+    weights; slot ``c`` is visible to query ``t`` iff ``c <= cursor +
+    t``.  The rings already hold the new positions.  ``scope(part)``,
+    where given, is entered around each part (``indexer``, ``select``,
+    ``sparse_attention``) so that a trace can tell them apart."""
+    scope = scope or (lambda part: contextlib.nullcontext())
+    batch, t, heads, d = q.shape
+    cap, kv_heads = k_ring.shape[1], k_ring.shape[2] // d
+    path = sparse_attention_path(t, heads, kv_heads, d, cap, k_ring.dtype)
+    cursor = jnp.asarray(cursor, jnp.int32)
+    if path == "streamed" and t > 1 and t % 8:
+        # the kernels take whole sublane tiles of positions: a chunk of
+        # another length (a prompt's remainder) is padded with queries
+        # whose rows are cut off again; what they read is nobody's
+        pad = lambda a: jnp.pad(
+            a, [(0, 0), (0, -t % 8)] + [(0, 0)] * (a.ndim - 2))
+        return sparse_ring_attention(
+            pad(q), pad(q_idx), pad(w_idx), k_ring, v_ring, i_ring, cursor,
+            topk=topk, sm_scale=sm_scale, scope=scope)[:, :t]
+
+    def attend(selected):
+        with scope("sparse_attention"):
+            if path == "streamed":
+                return sparse_attention_streamed(
+                    q, k_ring, v_ring, selected, cursor, sm_scale=sm_scale)
+            return sparse_attention_masked(q, k_ring, v_ring, selected,
+                                           sm_scale=sm_scale)
+
+    if cap <= topk:
+        # every visible row is selected: no score decides anything
+        return attend(jnp.broadcast_to(visible_slots(cursor, t, cap)[None],
+                                       (batch, t, cap)))
+    with scope("indexer"):
+        scores = (indexer_scores_streamed(q_idx, w_idx, i_ring, cursor)
+                  if path == "streamed"
+                  else indexer_scores(q_idx, w_idx, i_ring))
+    with scope("select"):
+        selected = (select_mask_streamed(scores, cursor, topk)
+                    if path == "streamed" else select_mask(
+                        scores, visible_slots(cursor, t, cap)[None], topk))
+    return attend(selected)

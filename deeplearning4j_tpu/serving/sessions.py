@@ -183,12 +183,15 @@ class SessionCache:
         # per-token budget and ladder their ring capacity
         self._decode = bool(getattr(model, "has_kv_ring",
                                     lambda: False)())
-        # rings of compressed rows (latent attention), not of per-head
-        # keys and values: the second kind of decode state
-        self._latent = [
-            n for n in model._layer_names()
-            if getattr(model.vertices[n].layer, "STATE_KIND", "") == "latent"
-        ] if self._decode and self._is_graph else []
+        # ring state by kind, as the layers name theirs (``STATE_KIND``:
+        # ``latent`` rings of compressed rows, ``sparse_kv`` key/value
+        # rings with an indexer's beside them): ``{kind: [vertices]}``
+        self._ring_kinds: dict = {}
+        if self._decode and self._is_graph:
+            for n in model._layer_names():
+                kind = getattr(model.vertices[n].layer, "STATE_KIND", "")
+                if kind:
+                    self._ring_kinds.setdefault(kind, []).append(n)
         self._scenario = ("serving.decode_step" if self._decode
                           else "serving.rnn_step")
         self._cache_ladder = (batch_ladder(model.max_cache_len())
@@ -210,10 +213,11 @@ class SessionCache:
             "(RNN carries + KV-cache rings)").set(
             sum(s.state_bytes for s in self._sessions.values()),
             model=self._name)
-        if self._latent:
+        for kind, vertices in self._ring_kinds.items():
             _monitor.gauge("serving_session_state_bytes", "").set(
-                sum(s.state_bytes for s in self._sessions.values()),
-                model=self._name, kind="latent")
+                sum(tree_nbytes([s.carries[v] for v in vertices])
+                    for s in self._sessions.values()),
+                model=self._name, kind=kind)
         if self._version_fn is not None:
             active = self._version_fn()
             pinned = sum(1 for s in self._sessions.values()
@@ -461,9 +465,10 @@ class SessionCache:
                                        tokens=hi - lo):
                         sess.carries = self._model.prefill_step(
                             sess.carries, ids[:, lo:hi], **kw)
-            self._count_expert_steps(
-                batch, Counter(hi - lo for lo, hi in zip(bounds, bounds[1:])))
+            chunks = Counter(hi - lo for lo, hi in zip(bounds, bounds[1:]))
+            self._count_expert_steps(batch, chunks)
             sess.position += total
+            self._count_attention_steps(sess, chunks, ("sparse_kv",))
             sess.steps += 1
             sess.last_used = time.monotonic()
             return sess.position
@@ -548,17 +553,10 @@ class SessionCache:
         _monitor.counter("serving_session_steps_total",
                          "single-dispatch session timesteps served").inc(
             n, model=self._name)
-        # by the op's own predicate, asked here and not where the step is
-        # traced: a warm start loads cg.token_step without tracing it
-        launched = _monitor.counter(
-            "latent_attention_steps_total",
-            "launched token steps, by the form their latent attention took")
         # the first step takes the ``fed`` ids, every later one its own
         by_length = Counter([fed] + [1] * (n - 1))
-        for t, steps in by_length.items():
-            for path in {model.vertices[v].layer.attention_path(
-                    t, sess.carries[v]) for v in self._latent}:
-                launched.inc(steps, path=path)
+        self._count_attention_steps(sess, by_length,
+                                    ("latent", "sparse_kv"))
         self._count_expert_steps(batch, by_length)
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
@@ -589,6 +587,44 @@ class SessionCache:
             experts_held.set(len(held), model=self._name, layer=vertex)
         return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex,
                           spills)
+
+    def _count_attention_steps(self, sess: _Session, by_length,
+                               kinds) -> None:
+        """The launched steps of ``by_length`` (tokens a row -> steps)
+        by the form their attention took, for the ring state of
+        ``kinds``.  By the op's own predicate, asked here and not where
+        the step is traced: a warm start loads ``cg.token_step`` and
+        ``cg.prefill_step`` without tracing them.  Also publishes
+        ``sparse_attention_selected{layer}``: the rows a query at the
+        session's position (already advanced by the caller) reads."""
+        model = self._model
+        counters = {
+            "latent": _monitor.counter(
+                "latent_attention_steps_total",
+                "launched token steps, by the form their latent attention "
+                "took"),
+            "sparse_kv": _monitor.counter(
+                "sparse_attention_steps_total",
+                "launched token steps and prefill chunks, by the form "
+                "their indexed sparse attention took")}
+        for kind in kinds:
+            vertices = self._ring_kinds.get(kind, ())
+            if not vertices:
+                continue
+            launched = counters[kind]
+            for t, steps in by_length.items():
+                for path in {model.vertices[v].layer.attention_path(
+                        t, sess.carries[v]) for v in vertices}:
+                    launched.inc(steps, path=path)
+            if kind == "sparse_kv":
+                selected = _monitor.gauge(
+                    "sparse_attention_selected",
+                    "cached rows a query of the newest position reads, "
+                    "by layer")
+                for v in vertices:
+                    selected.set(min(sess.position,
+                                     model.vertices[v].layer.topk),
+                                 model=self._name, layer=v)
 
     def _count_expert_steps(self, batch: int, by_length) -> None:
         """``moe_experts_steps_total{path}``: the launched steps of

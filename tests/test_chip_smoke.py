@@ -53,7 +53,8 @@ TOY = chip_smoke.Sizes(
     kernel_bthd=(1, 64, 2, 16), kernel_ref_t=32, kernel_short_ts=(40, 8),
     latent_shape=(2, 8, 128, 16, 256, 200),
     experts_shape=(40, 8, 32, 64, 2),
-    experts_share_shape=(32, 24, 4, 32, 64, 3), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
+    experts_share_shape=(32, 24, 4, 32, 64, 3),
+    sparse_shape=(2, 4, 2, 16, 3, 8, 256, 16, 8), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
 
 
 def test_train_then_serve():
@@ -81,7 +82,8 @@ def test_kernels():
             "float32_T32_max_rel_err",
             "latent_streamed_max_rel_err",
             "experts_grouped_max_rel_err",
-            "experts_share_grouped_max_rel_err"} <= set(info)
+            "experts_share_grouped_max_rel_err",
+            "sparse_streamed_max_rel_err"} <= set(info)
 
 
 def test_four_chips():

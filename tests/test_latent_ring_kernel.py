@@ -7,8 +7,10 @@ compiled by Mosaic for a described v5e at the cell's shape, with the
 scope it carries there (``tests/test_mla_moe_decoder.py`` holds the
 token step's lowering to the same).  The experts' grouped product
 (``ops.experts``, ``tests/test_grouped_experts.py``) is compiled for
-the described v5e here too: one file describes the topology, so that
-one test worker loads the TPU's compiler."""
+the described v5e here too, and so are the two kernels of the indexed
+sparse attention (``tests/test_gqa_sparse_decoder.py``): one file
+describes the topology, so that one test worker loads the TPU's
+compiler."""
 
 import functools
 
@@ -323,6 +325,61 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
                         < rows * (6 * hidden + 10 * width)
                         + (held < n_experts) * (rows * 6 + tokens * 8)
                         * hidden)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _scoped_sparse(q, q_idx, w_idx, k_ring, v_ring, i_ring, cursor):
+    with monitor.scope("layer", "L0_attn"):
+        with monitor.subscope("indexer"):
+            scores = attention.indexer_scores_streamed(
+                q_idx, w_idx, i_ring, cursor, interpret=False)
+        with monitor.subscope("select"):
+            selected = attention.select_mask_streamed(scores, cursor, 2048,
+                                                      interpret=False)
+        with monitor.subscope("sparse_attention"):
+            return attention.sparse_attention_streamed(
+                q, k_ring, v_ring, selected, cursor, sm_scale=128 ** -0.5,
+                interpret=False)
+
+
+def test_mosaic_compiles_the_sparse_kernels_at_the_cells_shape(one_chip):
+    """``keye_vl2_30b_a3b.decode_b8_ctx32k``: 8 conversations, 32 query
+    heads over 4 key/value heads of 128, an indexer of 16 heads of 64,
+    rings of 32,768 slots, bf16; the token step's single position and a
+    prefill chunk's 256, compiled ahead of time for a described v5e.
+    Each of the three kernels (indexer, selection, attention) is one
+    instruction under the scope it was called under."""
+    from jax.experimental.compilation_cache import compilation_cache
+    rows, slots = 8, 32768
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            for t in (1, 256):
+                shapes = [
+                    jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+                    for s in ((rows, t, 32, 128), (rows, t, 16, 64),
+                              (rows, t, 16), (rows, slots, 512),
+                              (rows, slots, 512), (rows, slots, 64))]
+                cursor = jax.ShapeDtypeStruct((), jnp.int32,
+                                              sharding=one_chip)
+                compiled = jax.jit(_scoped_sparse).lower(
+                    *shapes, cursor).compile()
+                calls = [line for line in compiled.as_text().splitlines()
+                         if 'custom_call_target="tpu_custom_call"' in line]
+                assert [monitor.parse_op_name(
+                    c.split('op_name="')[1].split('"')[0])[0]
+                    for c in calls] == ["layer.L0_attn.indexer",
+                                        "layer.L0_attn.select",
+                                        "layer.L0_attn.sparse_attention"]
+                # float32 scores, the bfloat16 mask, and what the rare
+                # tie's fallback keeps (keys, counts, a bool): nothing
+                # is as large as a (heads, T, slots) array would be
+                assert compiled.memory_analysis().temp_size_in_bytes \
+                    < 32 * rows * t * slots
+        assert attention.sparse_ring_block(slots) == 1024
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()

@@ -13,8 +13,9 @@ tokens a row (so that the latent attention reads about half a ring, as
 the mean chunk of the cell's prefill does), then traces three
 ``prefill_step`` calls and prints, a chunk, the busy seconds and the
 rows of the layers' parts (``.experts``, ``.latent_attention``,
-``.router``, ``.shared``, ``.sinkhorn``) summed over the layers, with
-what is left.  The executable store is left off: the second pass
+``.router``, ``.shared``, ``.sinkhorn``, and of a sparse decoder
+``.indexer``, ``.select``, ``.sparse_attention``) summed over the
+layers, with what is left.  The executable store is left off: the second pass
 patches the predicate in memory, which no digest of files sees.  Needs
 the chip.
 """
@@ -35,7 +36,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-PARTS = ("experts", "latent_attention", "router", "shared", "sinkhorn")
+PARTS = ("experts", "latent_attention", "router", "shared", "sinkhorn",
+         "indexer", "select", "sparse_attention")
 TRACED = 3
 
 
