@@ -36,6 +36,12 @@ from ..datasets.dataset import DataSet, MultiDataSet
 Array = jax.Array
 
 
+def _same_layer(layer) -> str:
+    """What two layers have to share to share a program: their
+    configuration, as canonical JSON."""
+    return json.dumps(serde.to_dict(layer), sort_keys=True, default=str)
+
+
 def _layer_init_programs(layer, dtype):
     """``(init, hold, finish)`` for one layer: its parameters and state
     from a key leaf by leaf, and the same as the two staged programs of
@@ -45,6 +51,13 @@ def _layer_init_programs(layer, dtype):
 
     hold, finish = _weights.staged(init)
     return init, jax.jit(hold), jax.jit(finish)
+
+
+def _lay_program(layer, identity):
+    """``layer.lay`` (its stored parameters in the forms its step
+    multiplies) as a program the executable store can serve."""
+    return _monitor.watched_jit(lambda stored: layer.lay(stored),
+                                name="cg.lay_weights", identity=identity)
 
 
 class ComputationGraph(Network):
@@ -74,6 +87,8 @@ class ComputationGraph(Network):
         self._decode_grow_cache: Dict[int, Any] = {}
         self._precision: Optional[_precision.PrecisionPolicy] = None
         self._inference_only = False
+        # vertex -> (the stored leaves its forms were laid from, the forms)
+        self._laid: Dict[str, Any] = {}
 
     # ------------------------------------------------------------------ init
     def init(self, for_inference: bool = False) -> "ComputationGraph":
@@ -102,6 +117,8 @@ class ComputationGraph(Network):
         self.params, self.net_state, self.updater_state = (
             {n: tree[n] for n in self._layer_names()} for tree in out)
         self._init_done = True
+        if for_inference:
+            self.served_params()        # laid in set-up, not by a step
         return self
 
     def _init_for_inference(self, key):
@@ -116,8 +133,7 @@ class ComputationGraph(Network):
         programs, params, net_state = {}, {}, {}
         for n, k in zip(names, keys):
             layer = self.vertices[n].layer
-            same = json.dumps(serde.to_dict(layer), sort_keys=True,
-                              default=str)
+            same = _same_layer(layer)
             if same not in programs:
                 programs[same] = _layer_init_programs(layer, dtype)
             init, hold, finish = programs[same]
@@ -404,6 +420,80 @@ class ComputationGraph(Network):
     @functools.cached_property
     def _decode_step_donating_fn(self):
         return self._build_decode_step(donate=True)
+
+    # ------------------------------------------------- weights laid once
+    @functools.cached_property
+    def _lay_programs(self) -> Dict[str, Any]:
+        """``{vertex: program}`` for the layer vertices whose layer
+        lays its weights (``layer.lay``: the stored parameters in the
+        forms its step multiplies): one jitted program a distinct layer
+        configuration, which the executable store serves like
+        ``cg.token_step``.  Empty for a net of layers that lay
+        nothing."""
+        programs, by_vertex = {}, {}
+        for n in self._layer_names():
+            layer = self.vertices[n].layer
+            if not hasattr(layer, "lay"):
+                continue
+            same = _same_layer(layer)
+            if same not in programs:
+                programs[same] = _lay_program(
+                    layer, lambda same=same: _monitor.program_identity(
+                        self, "lay_weights", same))
+            by_vertex[n] = programs[same]
+        return by_vertex
+
+    def laid_vertices(self) -> List[str]:
+        """The vertices whose steps multiply weights laid once: those
+        of :meth:`served_params`."""
+        return list(self._lay_programs) if self._inference_only else []
+
+    def _laid_tree(self, vertex: str, stored, forms):
+        """A laying vertex's parameters as its step takes them: the
+        laid ``forms`` in place of the leaves they were laid from, every
+        other leaf of ``stored`` as it is (the same array)."""
+        sources = self.vertices[vertex].layer.LAID_FROM
+        return {**{k: v for k, v in stored.items() if k not in sources},
+                **forms}
+
+    def lay_weights(self, params):
+        """``params`` with every laying vertex's parameters as its step
+        takes them: the pure form of :meth:`served_params` (traceable;
+        what ``tools/step_copies.py`` shapes a step's arguments with)."""
+        return {n: (self._laid_tree(n, p, self.vertices[n].layer.lay(p))
+                    if n in self._lay_programs else p)
+                for n, p in params.items()}
+
+    def served_params(self):
+        """The parameters ``token_step`` and ``prefill_step`` multiply
+        when handed none.  A served net (``init(for_inference=True)``)
+        whose layers lay their weights holds, beside ``params``, each
+        such vertex's laid forms, derived by ``cg.lay_weights`` once:
+        when the net is initialised, and again at the first step after
+        a leaf they were laid from was replaced (assignment to
+        ``params``, ``set_flat_params``, a serializer's load: found by
+        the leaves' identity, so no way of writing them is missed).
+        Every other net, and every vertex that lays nothing, is served
+        ``params`` as it is.  ``params`` keeps every name and shape."""
+        programs = self._lay_programs
+        if not (self._inference_only and programs):
+            return self.params
+        served = dict(self.params)
+        for n, program in programs.items():
+            stored = self.params[n]
+            sources = {k: stored[k]
+                       for k in self.vertices[n].layer.LAID_FROM}
+            held = self._laid.get(n)
+            if held is None or any(held[0][k] is not a
+                                   for k, a in sources.items()):
+                held = self._laid[n] = (sources, program(sources))
+                _monitor.gauge(
+                    "serving_laid_weight_bytes",
+                    "bytes of the forms a served net laid its weights in, "
+                    "held beside the stored ones, by vertex").set(
+                    sum(a.nbytes for a in held[1].values()), vertex=n)
+            served[n] = self._laid_tree(n, stored, held[1])
+        return served
 
     def _expert_vertices(self) -> List[str]:
         """Layer vertices whose state counts tokens by expert."""
@@ -870,7 +960,7 @@ class ComputationGraph(Network):
         if counts is None:
             counts = self.zero_expert_counts()
         return self._token_step_fn(
-            self.params if params is None else params,
+            self.served_params() if params is None else params,
             self.net_state if net_state is None else net_state,
             carries, ids, counts)
 
@@ -884,7 +974,7 @@ class ComputationGraph(Network):
         ids; returns the new carries only.  ONE dispatch."""
         self.init()
         return self._prefill_step_fn(
-            self.params if params is None else params,
+            self.served_params() if params is None else params,
             self.net_state if net_state is None else net_state,
             carries, ids)
 

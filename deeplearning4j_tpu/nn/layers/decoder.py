@@ -391,7 +391,10 @@ class LatentAttention(BaseRecurrentLayer):
     serves prefill chunks, single steps and ``output()`` (from a zero
     ring): the key half of ``Wkvb`` is absorbed into the query, scores
     and context are taken against the latent ring, and the value half
-    is applied to the latent context.
+    is applied to the latent context.  ``Wqb`` and ``Wkvb`` are
+    multiplied in the forms :meth:`lay` gives them, laid once by a
+    served net or inside the step from the stored parameters: the same
+    products either way.
     """
 
     HAS_KV_RING = True
@@ -453,6 +456,41 @@ class LatentAttention(BaseRecurrentLayer):
         return jnp.pad(c_ring, pad), jnp.pad(r_ring, pad), cursor
 
     # ------------------------------------------------------------ forward
+    #: the stored matrices :meth:`lay` turns into the forms the step
+    #: multiplies; every other parameter is multiplied as it is stored
+    LAID_FROM = ("Wqb", "Wkvb")
+
+    def lay(self, params: ParamTree) -> ParamTree:
+        """The forms ``forward_seq`` multiplies in place of ``Wqb`` and
+        ``Wkvb`` (stored (in, heads x out), as ``init_params`` draws and
+        a serializer writes them): split by what reads them and turned
+        heads-major with the contracted rank minor, the orientation the
+        compiled step reads without a copy (``tools/step_copies.py``):
+
+        - ``Wq_nope`` (heads, d_nope, q_rank): the query's no-position
+          columns;
+        - ``Wq_rope`` (d_rope / 2, 2, heads, q_rank): its rotary
+          columns, the two members of a rotated pair apart;
+        - ``Wk_absorbed`` (heads, d_nope, kv_rank): the key half of
+          ``Wkvb``, which the query absorbs;
+        - ``Wv`` (heads, kv_rank, d_v): its value half.
+
+        Transposes and column splits: every number is kept as it is.
+        A served net runs this once when its weights are set
+        (``ComputationGraph.served_params``); ``forward_seq`` given
+        stored parameters runs it inside the step."""
+        h = self.n_heads
+        wqb = params["Wqb"].reshape(self.q_rank, h, self.d_nope + self.d_rope)
+        wkvb = params["Wkvb"].reshape(self.kv_rank, h,
+                                      self.d_nope + self.d_v)
+        return {
+            "Wq_nope": wqb[..., :self.d_nope].transpose(1, 2, 0),
+            "Wq_rope": wqb[..., self.d_nope:].reshape(
+                self.q_rank, h, self.d_rope // 2, 2).transpose(2, 3, 1, 0),
+            "Wk_absorbed": wkvb[..., :self.d_nope].transpose(1, 2, 0),
+            "Wv": wkvb[..., self.d_nope:].transpose(1, 0, 2),
+        }
+
     def compress(self, params: ParamTree, x: Array, turn):
         """What the cache holds of ``x``: (normed ``c_kv``, ``k_r``
         rotated by ``turn``)."""
@@ -461,14 +499,14 @@ class LatentAttention(BaseRecurrentLayer):
                              params["kv_gain"]).astype(x.dtype)
         return c_kv, turn(kv[..., self.kv_rank:])
 
-    def queries(self, params: ParamTree, x: Array, turn):
+    def queries(self, laid: ParamTree, x: Array, turn):
         """((batch, time, heads, d_nope), (.., d_rope) rotated by
-        ``turn``)."""
-        c_q = rms_normalize(x @ params["Wqa"], self.eps,
-                            params["q_gain"]).astype(x.dtype)
-        q = (c_q @ params["Wqb"]).reshape(
-            x.shape[:2] + (self.n_heads, self.d_nope + self.d_rope))
-        return q[..., :self.d_nope], turn(q[..., self.d_nope:])
+        ``turn``), from the laid forms."""
+        c_q = rms_normalize(x @ laid["Wqa"], self.eps,
+                            laid["q_gain"]).astype(x.dtype)
+        q_nope = jnp.einsum("btq,hdq->bthd", c_q, laid["Wq_nope"])
+        q_rope = jnp.einsum("btq,pjhq->bthpj", c_q, laid["Wq_rope"])
+        return q_nope, turn(q_rope.reshape(q_rope.shape[:3] + (self.d_rope,)))
 
     def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
         c_ring, r_ring, cursor = carry
@@ -476,23 +514,26 @@ class LatentAttention(BaseRecurrentLayer):
         if t > cap:
             raise ValueError(f"chunk of {t} timesteps exceeds the latent "
                              f"ring's capacity {cap}")
+        # stored parameters (``fit``, ``output()``, a net not prepared,
+        # a pinned version) are laid here, inside the step; behind a
+        # barrier, so that the compiler multiplies the forms as made
+        # and the two ways give the same numbers bit for bit
+        laid = ({**params, **jax.lax.optimization_barrier(self.lay(params))}
+                if "Wqb" in params else params)
         positions = cursor + jnp.arange(t, dtype=jnp.int32)
         inv_freq, factor = yarn_inv_freq(self.d_rope, self.rope_theta,
                                          self.rope_scaling)
         turn = lambda a: rotate(a, positions, inv_freq, factor)
-        q_nope, q_rope = self.queries(params, x, turn)
+        q_nope, q_rope = self.queries(laid, x, turn)
         c_ring, r_ring = latent_ring_update(
-            c_ring, r_ring, cursor, *self.compress(params, x, turn))
-        wkvb = params["Wkvb"].reshape(self.kv_rank, self.n_heads,
-                                      self.d_nope + self.d_v)
+            c_ring, r_ring, cursor, *self.compress(laid, x, turn))
         with _monitor.subscope("latent_attention"):
-            q_lat = jnp.einsum("bthd,rhd->bthr", q_nope,
-                               wkvb[..., :self.d_nope])
+            q_lat = jnp.einsum("bthd,hdr->bthr", q_nope, laid["Wk_absorbed"])
             ctx = latent_ring_attention(q_lat, q_rope, c_ring, r_ring,
                                         cursor, sm_scale=self.sm_scale())
-        out = jnp.einsum("bthr,rhd->bthd", ctx, wkvb[..., self.d_nope:])
+        out = jnp.einsum("bthr,hrd->bthd", ctx, laid["Wv"])
         out = self._activate(
-            out.reshape(x.shape[:2] + (-1,)) @ params["Wo"])
+            out.reshape(x.shape[:2] + (-1,)) @ laid["Wo"])
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
         return out, (c_ring, r_ring, cursor + jnp.asarray(t, jnp.int32))

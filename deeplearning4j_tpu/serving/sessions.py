@@ -468,7 +468,8 @@ class SessionCache:
             chunks = Counter(hi - lo for lo, hi in zip(bounds, bounds[1:]))
             self._count_expert_steps(batch, chunks)
             sess.position += total
-            self._count_attention_steps(sess, chunks, ("sparse_kv",))
+            self._count_attention_steps(sess, chunks, ("sparse_kv",),
+                                        pinned=bool(kw))
             sess.steps += 1
             sess.last_used = time.monotonic()
             return sess.position
@@ -556,7 +557,8 @@ class SessionCache:
         # the first step takes the ``fed`` ids, every later one its own
         by_length = Counter([fed] + [1] * (n - 1))
         self._count_attention_steps(sess, by_length,
-                                    ("latent", "sparse_kv"))
+                                    ("latent", "sparse_kv"),
+                                    pinned=bool(kw))
         self._count_expert_steps(batch, by_length)
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
@@ -589,20 +591,26 @@ class SessionCache:
                           spills)
 
     def _count_attention_steps(self, sess: _Session, by_length,
-                               kinds) -> None:
+                               kinds, pinned: bool = False) -> None:
         """The launched steps of ``by_length`` (tokens a row -> steps)
         by the form their attention took, for the ring state of
         ``kinds``.  By the op's own predicate, asked here and not where
         the step is traced: a warm start loads ``cg.token_step`` and
-        ``cg.prefill_step`` without tracing them.  Also publishes
+        ``cg.prefill_step`` without tracing them.  The latent
+        attention's count also says which weights its steps multiplied
+        (``weights``): ``laid`` once by a served net
+        (``ComputationGraph.served_params``), or ``stored``, laid inside
+        the step (a net not prepared; ``pinned``: a deploy's version
+        handed to the step as it is stored).  Also publishes
         ``sparse_attention_selected{layer}``: the rows a query at the
         session's position (already advanced by the caller) reads."""
         model = self._model
+        laid = () if pinned else model.laid_vertices()
         counters = {
             "latent": _monitor.counter(
                 "latent_attention_steps_total",
                 "launched token steps, by the form their latent attention "
-                "took"),
+                "took and the weights it multiplied"),
             "sparse_kv": _monitor.counter(
                 "sparse_attention_steps_total",
                 "launched token steps and prefill chunks, by the form "
@@ -613,9 +621,15 @@ class SessionCache:
                 continue
             launched = counters[kind]
             for t, steps in by_length.items():
-                for path in {model.vertices[v].layer.attention_path(
-                        t, sess.carries[v]) for v in vertices}:
-                    launched.inc(steps, path=path)
+                forms = set()
+                for v in vertices:
+                    form = {"path": model.vertices[v].layer.attention_path(
+                        t, sess.carries[v])}
+                    if kind == "latent":
+                        form["weights"] = "laid" if v in laid else "stored"
+                    forms.add(tuple(form.items()))
+                for form in forms:
+                    launched.inc(steps, **dict(form))
             if kind == "sparse_kv":
                 selected = _monitor.gauge(
                     "sparse_attention_selected",

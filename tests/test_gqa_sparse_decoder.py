@@ -469,3 +469,33 @@ def test_the_parts_a_trace_has_to_tell_apart_have_scopes_of_their_own(net):
     assert monitor.parse_op_name(
         "jit(run)/layer.L1_attn/layer.L1_attn.select/cond") == (
             "layer.L1_attn.select", "forward")
+
+
+# ------------------------------------------------- weights laid once (PR 38)
+def test_a_net_that_lays_nothing_is_served_its_parameters_as_they_are(ids):
+    """No layer of this decoder lays its weights: prepared for serving
+    or not, the net holds no laid tree, ``token_step`` is handed
+    ``params`` itself, and the step lowers to the same program text."""
+    def lowered(model):
+        return model._token_step_fn.lower(
+            model.served_params(), model.net_state,
+            model._init_carries(3, cache_len=64),
+            jnp.zeros((3, 1), jnp.int32),
+            model.zero_expert_counts()).as_text()
+
+    gauge = monitor.gauge("serving_laid_weight_bytes", "")
+    gauge.set(0, vertex="L0_attn")     # whatever an earlier file laid
+    plain = build()
+    served = ComputationGraph(from_config(CFG, **ARGS)).init(
+        for_inference=True)
+    for model in (plain, served):
+        assert model.laid_vertices() == [] and model._lay_programs == {}
+        assert model.served_params() is model.params and model._laid == {}
+    assert lowered(served) == lowered(plain)
+    out = served.token_step(served.prefill_step(
+        served._init_carries(3, cache_len=64), ids[:, :8]), ids[:, 8:9])
+    want = plain.token_step(plain.prefill_step(
+        plain._init_carries(3, cache_len=64), ids[:, :8]), ids[:, 8:9])
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert gauge.value(vertex="L0_attn") == 0

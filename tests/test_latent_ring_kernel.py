@@ -12,7 +12,10 @@ sparse attention (``tests/test_gqa_sparse_decoder.py``): one file
 describes the topology, so that one test worker loads the TPU's
 compiler."""
 
+import contextlib
 import functools
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -150,9 +153,9 @@ def streamed_net(monkeypatch):
     return net, calls
 
 
-def _steps(path):
+def _steps(path, weights="stored"):
     return monitor.counter("latent_attention_steps_total", "").value(
-        path=path)
+        path=path, weights=weights)
 
 
 def test_streamed_prefill_in_chunks_then_decode_agrees_with_the_full_forward(
@@ -253,6 +256,92 @@ def test_mosaic_compiles_the_kernel_at_the_cells_shape(one_chip, cell):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
+
+
+@contextlib.contextmanager
+def _without_compile_cache():
+    """The persistent cache cannot read an executable of a described
+    topology back: off around such compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _one_layer_token_step(one_chip, cfg, rows, slots, weights):
+    """The scheduled module of a one-layer net's token step, compiled
+    for the described chip by ``tools/step_copies.py``."""
+    from tools import step_copies
+    with _without_compile_cache():
+        net = step_copies.abstract_net(cfg, slots, one_chip, layers=1)
+        return net, step_copies.compile_step(net, "token_step", rows, slots,
+                                             1, one_chip, weights)
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_served_token_step_turns_no_weight(one_chip, cell):
+    """One decoder layer's token step at the cell's widths and rows,
+    handed what a served net hands it (the forms laid once): the
+    compiled module holds no ``copy`` that reads a matrix among the
+    parameters, and all
+    its copies that are kernels of their own read under 25 MB (what is
+    left is the absorbed query turned rank-minor for the kernel, 16.8
+    MB at 256 rows of 64 heads, and the rotary query, 2.1 MB).  Handed
+    the stored parameters the same step turns both matrices every time
+    (the parent's program: 77.6 MB a layer in ``ax_k1``)."""
+    from tools import step_copies
+    rows, _, slots, _ = CELLS[cell][0]
+    with open(os.path.join(step_copies.ROOT, "benchmark", "configs",
+                           f"{cell}.json")) as fh:
+        cfg = json.load(fh)
+    net, text = _one_layer_token_step(one_chip, cfg, rows, slots, "served")
+    assert net.laid_vertices() == ["L0_attn"]
+    assert step_copies.kernel_counts(text)["mosaic_kernels"] == 1
+    alone = [r for r in step_copies.copies(text) if r["alone"]]
+    # (a hyper-connection's one-number parameters go to scalar memory
+    # by a copy of two bytes: not a weight turned)
+    assert not [r for r in alone if r["parameter"].startswith("params[")
+                and r["bytes"] > 64]
+    assert sum(r["bytes"] for r in alone) < 25e6
+    _, text = _one_layer_token_step(one_chip, cfg, rows, slots, "stored")
+    turned = {r["parameter"] for r in step_copies.copies(text)
+              if r["alone"] and r["parameter"].startswith("params[")}
+    assert {"params['L0_attn']['Wqb']", "params['L0_attn']['Wkvb']"} <= turned
+
+
+def test_step_copies_reads_a_toy_nets_steps(one_chip):
+    """``tools/step_copies.py`` at toy widths: both steps of a two-layer
+    net compile for the described chip, the kernel under each latent
+    attention is there, and every ``copy`` comes back with its bytes,
+    layouts, whether it is a kernel of its own and what it reads."""
+    from tools import step_copies
+    cfg = {"builder": "deeplearning4j_tpu.models.mla_moe_decoder:from_config",
+           "container":
+               "deeplearning4j_tpu.nn.computation_graph:ComputationGraph",
+           "builder_args": {"init_std": 0.02},
+           **{**CFG, "num_attention_heads": 8}}
+    with _without_compile_cache():
+        net = step_copies.abstract_net(cfg, 256, one_chip, layers=2)
+        assert net.laid_vertices() == ["L0_attn", "L1_attn"]
+        for step, weights in (("token_step", "served"),
+                              ("prefill_step", "stored")):
+            text = step_copies.compile_step(net, step, 8, 256, 8, one_chip,
+                                            weights)
+            counts = step_copies.kernel_counts(text)
+            # a prefill chunk is run for the rings alone: nothing reads
+            # the last layer's attention, only what it caches
+            assert counts["mosaic_kernels"] == (2 if step == "token_step"
+                                                else 1)
+            assert counts["fusions"] > 0
+            found = step_copies.copies(text)
+            assert len(found) == counts["copies"]
+            assert all(set(r) == {"bytes", "shape", "from", "to", "alone",
+                                  "parameter", "op_name"} for r in found)
+            assert found == sorted(found, key=lambda r: -r["bytes"])
 
 
 def _scoped_experts(held, n_experts, *args):

@@ -24,6 +24,7 @@ from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
 from deeplearning4j_tpu.nn.conf.computation_graph import (
     ComputationGraphConfiguration, StreamExpandVertex, StreamSumVertex)
 from deeplearning4j_tpu.nn.layers import decoder
+from deeplearning4j_tpu.ops.attention import latent_ring_attention_dense
 from deeplearning4j_tpu.serving import InferenceEngine
 from deeplearning4j_tpu.serving.sessions import SessionError
 
@@ -578,12 +579,13 @@ def test_a_share_counts_the_picks_that_named_its_experts(nets, ids):
         return monitor.counter("moe_held_picks_total", "").value(
             model="default", layer=layer)
 
-    def steps(name, path):
-        return monitor.counter(name, "").value(path=path)
+    def steps(name, path, **labels):
+        return monitor.counter(name, "").value(path=path, **labels)
 
     before = {v: held_picks(v) for v in ("L1_moe", "L2_moe")}
     launched = (steps("moe_experts_steps_total", "dense"),
-                steps("latent_attention_steps_total", "dense"))
+                steps("latent_attention_steps_total", "dense",
+                      weights="stored"))
     with InferenceEngine(flat, max_batch_size=4) as engine:
         engine.prefill_session("s", ids[:, :-1], chunk=8, cache_len=32)
         out = engine.generate("s", ids[:, -1:], 5)
@@ -596,7 +598,9 @@ def test_a_share_counts_the_picks_that_named_its_experts(nets, ids):
             model="default", layer=vertex) == 6
     # three prefill chunks (8, 8 and 3 tokens a row) and five token steps
     assert steps("moe_experts_steps_total", "dense") - launched[0] == 8
-    assert steps("latent_attention_steps_total", "dense") - launched[1] == 5
+    # a net not prepared for serving lays its weights inside the step
+    assert steps("latent_attention_steps_total", "dense",
+                 weights="stored") - launched[1] == 5
     # a net that holds every expert counts every pick
     _, whole, _ = nets["streams"]
     with InferenceEngine(whole, max_batch_size=4) as engine:
@@ -685,3 +689,213 @@ def test_the_plain_paths_adds_carry_their_vertex_scope(nets):
             if line.startswith("#loc") and "/layer.L1_ffn_add/" in line]
     assert adds and all(name.endswith("/add") for name in adds)
     assert monitor.parse_op_name(adds[0]) == ("layer.L1_ffn_add", "forward")
+
+
+# ------------------------------------------------- weights laid once (PR 38)
+def _laid_bytes(vertex):
+    return monitor.gauge("serving_laid_weight_bytes", "").value(
+        vertex=vertex)
+
+
+def _same_leaves(a, b):
+    a, b = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x, np.float64),
+                                      np.asarray(y, np.float64))
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_laid_forms_step_bit_for_bit_as_the_stored_parameters(
+        ids, dtype, kind):
+    """A served net hands its steps the forms it laid once; handed the
+    stored parameters (a pinned version, a net not prepared) the layer
+    lays them inside the step: the same products on the same numbers.
+    A prefill chunk and a token step, every ring, id and logit."""
+    served = build(dtype, for_inference=True, kind=kind)
+    attn = [n for n in served._layer_names() if n.endswith("_attn")]
+    assert served.laid_vertices() == attn
+    laid = served.served_params()
+    layer = served.vertices["L1_attn"].layer
+    # the stored tree keeps every name; the laid one swaps two matrices
+    # for the four forms and shares every other leaf
+    assert sorted(served.params["L1_attn"]) == sorted(layer.param_order())
+    assert sorted(laid["L1_attn"]) == sorted(
+        set(layer.param_order()) - set(layer.LAID_FROM)
+        | {"Wq_nope", "Wq_rope", "Wk_absorbed", "Wv"})
+    assert laid["L1_attn"]["Wo"] is served.params["L1_attn"]["Wo"]
+    assert laid["L1_moe"] is served.params["L1_moe"]
+    assert [laid["L1_attn"][k].shape for k in (
+        "Wq_nope", "Wq_rope", "Wk_absorbed", "Wv")] == [
+            (4, 16, 48), (4, 2, 4, 48), (4, 16, 32), (4, 32, 16)]
+    forms = sum(laid["L1_attn"][k].nbytes for k in (
+        "Wq_nope", "Wq_rope", "Wk_absorbed", "Wv"))
+    assert forms == sum(served.params["L1_attn"][k].nbytes
+                        for k in layer.LAID_FROM)
+    assert _laid_bytes("L1_attn") == forms
+    carries = lambda: served._init_carries(3, cache_len=32)
+    a = served.prefill_step(carries(), ids[:, :8])
+    b = served.prefill_step(carries(), ids[:, :8], params=served.params)
+    _same_leaves(a, b)
+    a = served.token_step(a, ids[:, 8:9])
+    b = served.token_step(b, ids[:, 8:9], params=served.params)
+    _same_leaves(a, b)
+    # and a net that was not prepared takes the stored form by itself
+    plain_net = build(dtype, kind=kind)
+    assert plain_net.laid_vertices() == []
+    assert plain_net.served_params() is plain_net.params
+    c = plain_net.token_step(
+        plain_net.prefill_step(plain_net._init_carries(3, cache_len=32),
+                               ids[:, :8]), ids[:, 8:9])
+    _same_leaves(a, c)
+
+
+def _parent_attention(layer, p, x):
+    """``LatentAttention.forward`` as the parent commit wrote it: the
+    stored matrices multiplied whole, then reshaped and sliced."""
+    b, t = x.shape[:2]
+    h, dn, dr, dv = layer.n_heads, layer.d_nope, layer.d_rope, layer.d_v
+    positions = jnp.arange(t, dtype=jnp.int32)
+    inv_freq, factor = decoder.yarn_inv_freq(dr, layer.rope_theta,
+                                             layer.rope_scaling)
+    turn = lambda a: decoder.rotate(a, positions, inv_freq, factor)
+    c_q = decoder.rms_normalize(x @ p["Wqa"], layer.eps,
+                                p["q_gain"]).astype(x.dtype)
+    q = (c_q @ p["Wqb"]).reshape(b, t, h, dn + dr)
+    q_nope, q_rope = q[..., :dn], turn(q[..., dn:])
+    kv = x @ p["Wkva"]
+    c_kv = decoder.rms_normalize(kv[..., :layer.kv_rank], layer.eps,
+                                 p["kv_gain"]).astype(x.dtype)
+    k_rope = turn(kv[..., layer.kv_rank:])
+    wkvb = p["Wkvb"].reshape(layer.kv_rank, h, dn + dv)
+    q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, wkvb[..., :dn])
+    ctx = latent_ring_attention_dense(
+        q_lat, q_rope, c_kv, k_rope, jnp.zeros((), jnp.int32),
+        sm_scale=layer.sm_scale())
+    out = jnp.einsum("bthr,rhd->bthd", ctx, wkvb[..., dn:])
+    return out.reshape(b, t, -1) @ p["Wo"]
+
+
+@pytest.mark.parametrize("dtype,bound", [("float32", 1e-6),
+                                         ("bfloat16", 2e-2)])
+def test_output_and_gradient_are_the_parents(net, dtype, bound):
+    """``forward`` (what ``output()`` and ``fit`` run) lays the stored
+    parameters inside the program: the parent's numbers up to the order
+    of a product's sums, value and gradient."""
+    layer = net.vertices["L1_attn"].layer
+    p = jax.tree.map(lambda a: a.astype(dtype), net.params["L1_attn"])
+    x = acts((2, 9, 64)).astype(dtype)
+
+    def ours(p, x):
+        return layer.forward(p, {}, x, train=True)[0]
+
+    def loss(f):
+        return lambda p, x: jnp.sum(
+            f(p, x).astype(jnp.float32) * jnp.cos(jnp.arange(64.0)))
+
+    assert rel(ours(p, x), _parent_attention(layer, p, x)) < bound
+    got = jax.grad(loss(ours), argnums=(0, 1))(p, x)
+    want = jax.grad(loss(lambda p, x: _parent_attention(layer, p, x)),
+                    argnums=(0, 1))(p, x)
+    assert sorted(got[0]) == sorted(layer.param_order())
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert rel(g, w) < bound
+
+
+def test_a_served_net_is_written_as_any_other(tmp_path, nets):
+    """``params`` keeps every name and published shape, and what the
+    serializer writes of a served net is what it writes of one that was
+    not prepared: the laid forms are held beside the parameters, not
+    among them."""
+    import zipfile
+    from deeplearning4j_tpu.utils import model_serializer
+    _, flat, _ = nets["plain"]
+    served = build(for_inference=True, kind="plain")
+    assert {n: {k: a.shape for k, a in p.items()}
+            for n, p in served.params.items()} == {
+                n: {k: a.shape for k, a in p.items()}
+                for n, p in flat.params.items()}
+    assert served.num_params() == flat.num_params()
+    paths = [str(tmp_path / name) for name in ("served.zip", "flat.zip")]
+    for model, path in zip((served, flat), paths):
+        model_serializer.write_model(model, path, save_updater=False)
+    a, b = (zipfile.ZipFile(path) for path in paths)
+    assert sorted(a.namelist()) == sorted(b.namelist())
+    assert (a.read(model_serializer.COEFFICIENTS_BIN)
+            == b.read(model_serializer.COEFFICIENTS_BIN))
+    back = model_serializer.restore_computation_graph(paths[0])
+    _same_leaves(back.params, served.params)
+
+
+def _stepped(model, ids, **kw):
+    """Ids and kept logits of one token step after a prefill chunk."""
+    carries = model.prefill_step(model._init_carries(3, cache_len=32),
+                                 ids[:, :8], **kw)
+    return model.token_step(carries, ids[:, 8:9], **kw)[:2]
+
+
+@pytest.mark.parametrize("how", ["assigned", "loaded", "one_leaf"])
+def test_replaced_parameters_are_laid_again(tmp_path, ids, how):
+    """The laid forms follow the stored parameters however they are
+    replaced: ``params`` assigned, a saved net's weights loaded leaf by
+    leaf (``set_flat_params``), one matrix swapped in place.  The next
+    step computes with the new weights, and the gauge is set again."""
+    from deeplearning4j_tpu.utils import model_serializer
+    served = build(for_inference=True, kind="plain")
+    other = build(kind="plain", seed=11)
+    before = _stepped(served, ids)
+    _same_leaves(before, _stepped(served, ids, params=served.params))
+    gauge = monitor.gauge("serving_laid_weight_bytes", "")
+    want = _laid_bytes("L1_attn")
+    for vertex in served.laid_vertices():
+        gauge.set(0, vertex=vertex)
+    if how == "assigned":
+        served.params = jax.tree.map(jnp.copy, other.params)
+    elif how == "loaded":
+        path = str(tmp_path / "other.zip")
+        model_serializer.write_model(other, path, save_updater=False)
+        served.set_flat_params(model_serializer.restore_computation_graph(
+            path).get_flat_params())
+    else:
+        served.params["L1_attn"]["Wkvb"] = jnp.copy(
+            other.params["L1_attn"]["Wkvb"])
+    after = _stepped(served, ids)
+    relaid = (["L1_attn"] if how == "one_leaf" else served.laid_vertices())
+    assert [v for v in served.laid_vertices() if _laid_bytes(v)] == relaid
+    assert _laid_bytes("L1_attn") == want
+    _same_leaves(after, _stepped(served, ids, params=served.params))
+    assert not np.array_equal(np.asarray(after[1]), np.asarray(before[1]))
+    if how != "one_leaf":
+        _same_leaves(after, _stepped(other, ids))
+    # nothing replaced: nothing laid again
+    for vertex in served.laid_vertices():
+        gauge.set(0, vertex=vertex)
+    _same_leaves(after, _stepped(served, ids))
+    assert not any(_laid_bytes(v) for v in served.laid_vertices())
+
+
+def test_a_served_session_counts_its_steps_as_laid(ids):
+    """``latent_attention_steps_total{weights}``: a served net's steps
+    multiply the laid forms; the same net stepping a pinned version's
+    tree (handed to the step as stored) says so."""
+    def steps(weights):
+        return monitor.counter("latent_attention_steps_total", "").value(
+            path="dense", weights=weights)
+
+    served = build(for_inference=True, kind="plain")
+    before = steps("laid"), steps("stored")
+    with InferenceEngine(served, max_batch_size=4) as engine:
+        engine.prefill_session("s", ids[:, :-1], chunk=8, cache_len=32)
+        engine.generate("s", ids[:, -1:], 3)
+        assert (steps("laid"), steps("stored")) == (before[0] + 3,
+                                                    before[1])
+        # a deploy's swap: sessions made after it are pinned to the
+        # staged tree, which reaches the step as it is stored
+        engine.swap_weights(jax.tree.map(np.asarray, served.params))
+        engine.prefill_session("t", ids[:, :-1], chunk=8, cache_len=32)
+        engine.generate("t", ids[:, -1:], 2)
+    assert (steps("laid"), steps("stored")) == (before[0] + 3,
+                                                before[1] + 2)
