@@ -15,7 +15,8 @@ import pytest
 from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.monitor import health
 from deeplearning4j_tpu.monitor.device_trace import (
-    GROUPS, find_trace, hlo_modules, parse_op_name, reduce, table)
+    GROUPS, estimate_cost, find_trace, hlo_modules, instruction_costs,
+    parse_hlo_line, parse_op_name, publish, reduce, table)
 from deeplearning4j_tpu.nn.computation_graph import ComputationGraph
 from deeplearning4j_tpu.nn.conf.neural_net_configuration import \
     NeuralNetConfiguration
@@ -26,6 +27,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 SMALL_TRACE = os.path.join(HERE, "benchmark", "data",
                            "small_trace.xplane.pb")
 SCOPE_TRACE = os.path.join(HERE, "data", "scope_trace.xplane.pb.gz")
+TOY_FIT_TRACE = os.path.join(HERE, "benchmark", "data",
+                             "toy_fit_trace.xplane.pb.gz")
 
 
 def _builder():
@@ -327,6 +330,307 @@ def test_table_prints_every_section(scoped):
                     "idle time of the first device"):
         assert heading in text
     assert "ingest.gather" in text
+
+
+# ------------------------- the compiler's costs and the idle time by cause
+TRACES = {"small": (SMALL_TRACE, "bench/window"),
+          "scope": (SCOPE_TRACE, "profiler/capture"),
+          "toy_fit": (TOY_FIT_TRACE, "bench/window")}
+
+
+@pytest.fixture(scope="module")
+def reports():
+    return {name: reduce(path, window=window)
+            for name, (path, window) in TRACES.items()}
+
+
+def test_small_trace_costs_are_the_compilers_own_numbers():
+    """The stats of each instruction's ``XEventMetadata``: a product
+    whose operand the layout put on the chip (``S(1)``) moves no HBM
+    byte; the prefetch that brought the operand there reads it from HBM
+    (space 1), once."""
+    (program, lines), = instruction_costs(SMALL_TRACE).items()
+    assert program > 2 ** 32
+    by_name = {line.split(" = ")[0]: cost for line, cost in lines.items()}
+    assert by_name["%fusion.8"] == (
+        2151677952.0, 0.0, 0.0, 6291456.0, "convolution fusion", "counted")
+    assert by_name["%copy-start"] == (
+        0.0, 2097152.0, 0.0, 0.0, "copy-start", "counted")
+    # its done writes the chip's memory, and the last product two bytes
+    assert by_name["%copy-done"][:4] == (0.0, 0.0, 0.0, 2097152.0)
+    assert by_name["%fusion.1"][1:3] == (0.0, 2.0)
+
+
+def test_small_trace_counts_each_byte_once(small):
+    """Two units in the window: two prefetches of 2 MiB and two scalar
+    results.  ``copy-start`` stands on ``XLA Ops`` and on ``Async XLA
+    Ops``, and the second line adds nothing."""
+    assert small["hbm_bytes"] == 2 * 2097152 + 2 * 2
+    assert small["flops"] == 2 * (7 * 2151677952 + 2152726528)
+    assert small["estimated_s"] == small["uncounted_s"] == 0.0
+    assert all(len(row) == 4 for row in small["by_scope"])
+
+
+@pytest.mark.parametrize("name", sorted(TRACES))
+def test_cost_by_scope_has_by_scopes_rows_in_its_order(reports, name):
+    report = reports[name]
+    assert [r[:3] for r in report["cost_by_scope"]] == \
+        [r[:3] for r in report["by_scope"]]
+    assert all(len(r) == 7 for r in report["cost_by_scope"])
+    assert all(len(r) == 4 for r in report["by_scope"])
+    assert sum(r[3] + r[4] for r in report["cost_by_scope"]) == \
+        pytest.approx(report["hbm_bytes"], rel=1e-12)
+    assert sum(r[5] for r in report["cost_by_scope"]) == \
+        pytest.approx(report["flops"], rel=1e-12)
+    assert sum(r[6] for r in report["cost_by_scope"]) == \
+        pytest.approx(report["estimated_s"], abs=1e-15)
+    assert report["hbm_bytes"] > 0 and report["flops"] > 0
+    assert report["uncounted_s"] == 0.0
+
+
+@pytest.mark.parametrize("name", sorted(TRACES))
+def test_idle_time_by_cause_sums_to_the_idle_time(reports, name):
+    report = reports[name]
+    idle = report["window_s"] - report["busy_s"]
+    assert report["idle_in_program_s"] + report["idle_between_programs_s"] \
+        == pytest.approx(idle, abs=1e-9)
+    assert report["idle_in_program_s"] > 0
+    assert report["idle_between_programs_s"] > 0
+    assert sum(r[2] for r in report["gaps_in_program"]) == \
+        pytest.approx(report["idle_in_program_s"], abs=1e-9)
+    assert sum(r[1] for r in report["idle_between_by_span"]) == \
+        pytest.approx(report["idle_between_programs_s"], abs=1e-9)
+    seconds = [r[2] for r in report["gaps_in_program"]]
+    assert seconds == sorted(seconds, reverse=True)
+    # what the host's spans cover of the time BETWEEN programs is part
+    # of what they cover of all idle time
+    every = dict(report["idle_by_span"])
+    for span, sec in report["idle_between_by_span"]:
+        assert sec <= every[span] + 1e-12
+
+
+def test_scope_trace_gaps_name_who_waited_and_what_came_before(scoped):
+    rows = {(r[0], r[1]): r for r in scoped["gaps_in_program"]}
+    # the health statistics wait behind the kernels before them, 40 steps
+    assert rows[("health", "fusion")][3] >= 40
+    # a custom call is named by its target, and where it joins the
+    # slices of a fetch the consumer of the fetch is who waited
+    assert ("unscoped", "ConcatBitcast") in rows
+    assert ("unscoped", "slice-done") in rows
+    assert not any(before in ("custom-call", "async-done")
+                   for _, before in rows)
+    # a program's first operation, and the time after its last one
+    assert any(before == "program-start" for _, before in rows)
+    assert any(scope == "program-end" for scope, _ in rows)
+    # the host waited for the scores while no program ran
+    between = dict(scoped["idle_between_by_span"])
+    assert between["fit/score_wait"] > 0.5 * sum(between.values())
+    assert scoped["idle_between_programs_s"] > \
+        10 * scoped["idle_in_program_s"]
+
+
+def test_a_custom_call_the_compiler_did_not_count_is_estimated(scoped):
+    """``custom-call.10`` (a ``ConcatBitcast`` of four slices) has
+    operands and all-zero stats: its seconds are ``estimated_s``, its
+    bytes those of its operands and result outside ``S(n)``, here none
+    (all five are on the chip).  ``custom-call.5`` (``AllocateBuffer``)
+    has nothing in and counts as nothing."""
+    step = max(instruction_costs(SCOPE_TRACE).values(), key=len)
+    by_name = {line.split(" = ")[0]: cost for line, cost in step.items()}
+    assert by_name["%custom-call.10"] == (
+        0.0, 0.0, 0.0, 0.0, "custom-call", "estimated")
+    assert by_name["%custom-call.5"][5] == "counted"
+    assert 0 < scoped["estimated_s"] < 1e-6
+    assert scoped["uncounted_s"] == 0.0
+
+
+KERNEL = ("%custom-call.7 = (bf16[64,128]{1,0:T(8,128)(2,1)}, "
+          "f32[64]{0:T(128)S(1)}) custom-call(bf16[64,512]{1,0:T(8,128)(2,1)}"
+          " %q, bf16[4,1024,512]{2,1,0:T(8,128)(2,1)} %ring, "
+          "s32[4]{0:T(128)S(1)} %cursor), "
+          "custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("line, expected", [
+    (KERNEL, (0.0, 64 * 512 * 2 + 4 * 1024 * 512 * 2.0, 64 * 128 * 2.0,
+              0.0, "custom-call", "estimated")),
+    # a result that is an operand again is that operand, once
+    (KERNEL + ", output_to_operand_aliasing={{0}: (0, {})}",
+     (0.0, 64 * 512 * 2 + 4 * 1024 * 512 * 2.0, 0.0, 0.0, "custom-call",
+      "estimated")),
+    # the done of a pair adds nothing: its start holds the fetch
+    ("%slice-done.8 = bf16[256,2048]{1,0:T(8,128)(2,1)} async-done("
+     "((bf16[1024,2048]{1,0}), bf16[256,2048]{1,0}, s32[]{:S(2)}) "
+     "%slice-start.8)", (0.0, 0.0, 0.0, 0.0, "async-done", "estimated")),
+    ("no HLO line at all", (0.0, 0.0, 0.0, 0.0, "", "uncounted")),
+    ("%cut = f32[8]{0} fusion(f32[8]{0} %p", (0.0, 0.0, 0.0, 0.0, "",
+                                             "uncounted")),
+], ids=["kernel", "aliased", "done", "no_line", "cut_short"])
+def test_estimate_cost_counts_what_the_layout_leaves_in_hbm(line, expected):
+    assert estimate_cost(line) == expected
+
+
+def test_parse_hlo_line_splits_result_opcode_operands_and_attributes():
+    result, opcode, operands, attributes = parse_hlo_line(KERNEL)
+    assert result.startswith("(bf16[64,128]") and result.endswith("S(1)})")
+    assert opcode == "custom-call"
+    assert operands.startswith("bf16[64,512]") and \
+        operands.endswith("%cursor")
+    assert attributes == 'custom_call_target="tpu_custom_call"'
+    assert parse_hlo_line("%c = f32[]{:T(128)} constant(0)")[1:3] == \
+        ("constant", "0")
+
+
+# ...................................................... a hand-made plane
+def _varint(value):
+    out = bytearray()
+    while True:
+        out.append((value & 0x7F) | (0x80 if value > 0x7F else 0))
+        value >>= 7
+        if not value:
+            return bytes(out)
+
+
+def _message(*fields):
+    """``(number, value)`` pairs as one serialized message: ints as
+    varints, bytes and strings length-delimited."""
+    out = bytearray()
+    for number, value in fields:
+        if isinstance(value, int):
+            out += _varint(number << 3) + _varint(value)
+        else:
+            value = value.encode() if isinstance(value, str) else value
+            out += _varint(number << 3 | 2) + _varint(len(value)) + value
+    return bytes(out)
+
+
+LINE = "%fusion.1 = f32[8]{0:T(128)} fusion(f32[8]{0:T(128)} %p), kind=kLoop"
+LOOP = ("%while.1 = (s32[]{:T(128)}, f32[8]{0:T(128)}) while((s32[]{:T(128)}"
+        ", f32[8]{0:T(128)}) %tuple.1), condition=%cond.1, body=%body.1")
+UNREADABLE = "an event the profiler named by hand"
+#: metadata id -> (name, program id, flops, HBM bytes read, category);
+#: the same HLO line in two programs, with costs of their own, and a
+#: loop that carries its body's numbers
+EVENTS = {1: (LINE, 111, 1000, 64, "loop fusion"),
+          2: (LINE, 222, 5000, 4096, "loop fusion"),
+          3: (UNREADABLE, None, 0, 0, ""), 4: ("jit_a(111)", None, 0, 0, ""),
+          5: ("jit_b(222)", None, 0, 0, ""),
+          6: (LOOP, 222, 777777, 8192, "while")}
+STATS = {1: "program_id", 2: "flops", 3: "bytes_accessed",
+         4: "memory_access_breakdown", 5: "hlo_category"}
+
+
+def _hand_made_plane():
+    """One device, two programs, times in microseconds:
+
+    ``jit_a`` runs 10-40: ``fusion.1`` 10-20, a gap, ``fusion.1`` 25-35,
+    then 5 idle to the program's end; nothing runs 40-60; ``jit_b`` runs
+    60-95: 2 idle, ``fusion.1`` 62-80, an event without stats 80-90, a
+    loop of no round 90-92 (a leaf that carries its body's stats)."""
+    def metadata(ident):
+        name, program, flops, read, category = EVENTS[ident]
+        stats = [] if program is None else [
+            _message((1, 1), (3, program)), _message((1, 2), (4, flops)),
+            _message((1, 3), (4, read)),
+            _message((1, 4), (6, _message((1, _message(
+                (1, 1), (2, 1), (3, read)))))),
+            _message((1, 5), (5, category))]
+        return _message((1, ident), (2, _message(
+            (1, ident), (2, name), *((5, s) for s in stats))))
+
+    def line(name, events):
+        return _message((2, name), (3, 0), *(
+            (4, _message((1, ident), (2, start * 10 ** 6),
+                         (3, (end - start) * 10 ** 6)))
+            for ident, start, end in events))
+
+    plane = _message(
+        (2, "/device:TPU:0"),
+        (3, line("XLA Modules", [(4, 10, 40), (5, 60, 95)])),
+        (3, line("XLA Ops", [(1, 10, 20), (1, 25, 35), (2, 62, 80),
+                             (3, 80, 90), (6, 90, 92)])),
+        *((4, metadata(i)) for i in EVENTS),
+        *((5, _message((1, i), (2, _message((1, i), (2, name)))))
+          for i, name in STATS.items()))
+    return _message((1, plane))
+
+
+@pytest.fixture(scope="module")
+def hand_made(tmp_path_factory):
+    path = tmp_path_factory.mktemp("plane") / "hand.xplane.pb"
+    path.write_bytes(_hand_made_plane())
+    return reduce(str(path))
+
+
+def test_a_gap_inside_a_program_and_a_gap_outside_split_as_said(hand_made):
+    us = 1e-6
+    assert hand_made["window_s"] == pytest.approx(82 * us)
+    assert hand_made["busy_s"] == pytest.approx(50 * us)
+    assert hand_made["idle_in_program_s"] == pytest.approx(12 * us)
+    assert hand_made["idle_between_programs_s"] == pytest.approx(20 * us)
+    assert hand_made["idle_between_by_span"] == [
+        ["no_span", pytest.approx(20 * us)]]
+    rows = {(r[0], r[1]): (r[2], r[3]) for r in hand_made["gaps_in_program"]}
+    assert rows == {
+        ("unscoped", "fusion"): (pytest.approx(5 * us), 1),
+        ("program-end", "fusion"): (pytest.approx(5 * us), 1),
+        ("unscoped", "program-start"): (pytest.approx(2 * us), 1)}
+    assert [r[:2] for r in hand_made["gaps_in_program"]][-1] == \
+        ["unscoped", "program-start"]
+
+
+def test_two_programs_with_one_hlo_line_keep_their_own_costs(hand_made):
+    """``cg.token_step`` and ``cg.fork_state`` can hold the same line:
+    an event is matched by the program that contains it."""
+    assert hand_made["flops"] == 2 * 1000 + 5000
+    assert hand_made["hbm_bytes"] == 2 * 64 + 4096
+    (row,) = hand_made["cost_by_scope"]
+    assert row[:2] == ["unscoped", "other"] and row[3:6] == [
+        2 * 64 + 4096, 0.0, 2 * 1000 + 5000]
+
+
+def test_an_event_nothing_can_be_read_of_is_uncounted_not_dropped(hand_made):
+    assert hand_made["uncounted_s"] == pytest.approx(10e-6)
+    assert hand_made["estimated_s"] == 0.0
+    assert hand_made["by_scope"] == [
+        ["unscoped", "other", pytest.approx(50e-6), 5]]
+
+
+def test_a_loop_that_ran_no_round_counts_none_of_its_bodys_numbers(
+        hand_made, tmp_path):
+    """A ``while`` carries the stats of its body.  Where the body ran,
+    its events are the leaves and the loop is a container; where it ran
+    no round the loop itself is a leaf, and did nothing of what the
+    stats say (``ax_k1``'s loop over a share's further rounds)."""
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(_hand_made_plane())
+    assert instruction_costs(str(path))[222][LOOP] == (
+        0.0, 0.0, 0.0, 0.0, "while", "counted")
+    # the sums of the test above hold with the loop's 2 us in the window
+    assert hand_made["flops"] == 2 * 1000 + 5000
+    assert ["while", pytest.approx(2e-6), 1] in \
+        hand_made["unscoped_by_opcode"]
+
+
+def test_table_prints_the_costs_and_the_idle_time_by_cause(scoped):
+    text = table(scoped, top=5)
+    for heading in ("HBM traffic", "GB/s", "TFLOP/s", "estimated from",
+                    "uncounted", "idle while a program ran",
+                    "between programs", "opcode before"):
+        assert heading in text
+    assert "program-start" in table(scoped, top=50)
+
+
+def test_publish_keeps_the_bytes_and_the_idle_time_by_cause(scoped):
+    publish(scoped)
+    snapshot = monitor.snapshot()
+    traffic = snapshot["device_scope_hbm_bytes"]["values"]
+    assert len(traffic) == len(scoped["cost_by_scope"])
+    assert sum(traffic.values()) == pytest.approx(scoped["hbm_bytes"])
+    assert snapshot["device_idle_in_program_seconds"]["values"][""] == \
+        pytest.approx(scoped["idle_in_program_s"])
+    assert snapshot["device_idle_between_programs_seconds"]["values"][""] \
+        == pytest.approx(scoped["idle_between_programs_s"])
 
 
 # --------------------------------------------- spans on the profiler's clock

@@ -8,8 +8,10 @@ Builds the cell's net and data through ``benchmark/nets.py`` (the
 benchmark's builder, so the program is the cell's), runs the unit the
 ``fit_cached`` driver times (one fused ``fit`` call ending in a blocking
 ``score()``) once to compile and twice untraced, then once under
-``monitor.device_trace``, prints the table of PERF.md section 5 and
-writes the report as JSON.  Needs the chip: a CPU trace has no device
+``monitor.device_trace``, prints the table of PERF.md section 5 (each
+row with its HBM GB/s and TFLOP/s as the compiler counted them, the
+idle time inside and between programs with its largest gaps: PR 39)
+and writes the report as JSON.  Needs the chip: a CPU trace has no device
 plane.  Since PR 29 the benchmark shows the same rows itself
 (``--trace 1``: ``breakdown`` and ``record["trace"]["by_scope"]``, for
 every cell, the serving ones too); this tool is for a ``fit`` cell's

@@ -12,6 +12,15 @@ across consumers are charged to each).  This is the same accounting
 XLA's own cost model uses for "bytes accessed", but per-op instead of
 aggregate.
 
+This is a PROJECTION from HLO compiled for whatever backend runs it (the
+CPU's, in the sandbox), not a measurement.  Since PR 39 the measurement
+is the device trace's own bytes: every ``.xplane.pb`` from the chip
+carries the TPU compiler's cost analysis of each instruction with its
+memory spaces, and ``monitor/device_trace.py:reduce`` reports HBM bytes
+by scope from it (``cost_by_scope``, ``hbm_bytes``;
+``docs/OBSERVABILITY.md`` section 8).  This tool stays while ``bench.py``
+reads it (ROADMAP design debts).
+
 Reference analogue: the cuDNN tier's workspace/memory accounting
 (``CudnnConvolutionHelper.java:64-140``) — the reference's only
 memory-tuning surface.

@@ -15,7 +15,10 @@ the mean chunk of the cell's prefill does), then traces three
 rows of the layers' parts (``.experts``, ``.latent_attention``,
 ``.router``, ``.shared``, ``.sinkhorn``, and of a sparse decoder
 ``.indexer``, ``.select``, ``.sparse_attention``) summed over the
-layers, with what is left.  The executable store is left off: the second pass
+layers, with what is left; beside the seconds (PR 39) the HBM traffic
+the compiler counted for each part in GB/s, the seconds whose bytes are
+estimated from shapes (the Pallas kernels), and the idle time inside
+and between programs with its largest gaps.  The executable store is left off: the second pass
 patches the predicate in memory, which no digest of files sees.  Needs
 the chip.
 """
@@ -43,19 +46,38 @@ TRACED = 3
 
 def by_part(report: dict) -> dict:
     """Seconds a chunk: busy, each part over all layers, the ten largest
-    other rows."""
+    other rows; and, from the compiler's counts the trace carries
+    (``cost_by_scope``), the HBM bytes a chunk and GB/s of the whole and
+    of each part, the seconds whose bytes are estimated from shapes, and
+    the idle time inside and between programs with its largest gaps."""
     out = {"busy_s": report["busy_s"] / TRACED,
            "window_s": report["window_s"] / TRACED,
-           "idle_share": report["idle_share"]}
+           "idle_share": report["idle_share"],
+           "hbm_gb": report["hbm_bytes"] / TRACED / 1e9,
+           "hbm_gb_per_s": report["hbm_bytes"] / report["busy_s"] / 1e9,
+           "estimated_s": report["estimated_s"] / TRACED,
+           "uncounted_s": report["uncounted_s"] / TRACED,
+           "idle_in_program_s": report["idle_in_program_s"] / TRACED,
+           "idle_between_programs_s":
+               report["idle_between_programs_s"] / TRACED,
+           "gaps_in_program": [[scope, before, seconds / TRACED, n]
+                               for scope, before, seconds, n
+                               in report["gaps_in_program"][:5]]}
     parts = dict.fromkeys(PARTS, 0.0)
+    traffic = dict.fromkeys(PARTS, 0.0)
     others = []
-    for scope, pass_, seconds, _ in report["by_scope"]:
+    for scope, pass_, seconds, read, written, _, _ in \
+            report["cost_by_scope"]:
         part = scope.rsplit(".", 1)[-1]
         if scope.startswith("layer.") and part in parts:
             parts[part] += seconds / TRACED
+            traffic[part] += (read + written) / TRACED
         else:
-            others.append([f"{scope}/{pass_}", seconds / TRACED])
+            others.append([f"{scope}/{pass_}", seconds / TRACED,
+                           (read + written) / seconds / 1e9])
     out.update(parts)
+    out["gb_per_s"] = {part: traffic[part] / seconds / 1e9
+                       for part, seconds in parts.items() if seconds}
     out["other_s"] = out["busy_s"] - sum(parts.values())
     out["other_rows"] = others[:10]
     return out
