@@ -457,12 +457,14 @@ def _latent_kernel(sz: Sizes) -> float:
 
 
 def _sparse_kernels(sz: Sizes) -> float:
-    """The indexed sparse attention's three streamed kernels (bf16 rings)
-    against the plain forms of the same arguments, for a token step's
-    one position and a chunk's: the indexer's scores (float32 both
-    ways), the selection (the same rows), then the attention over them.  Both
-    attentions round ``p`` to bf16 for the context product, the plain
-    form after the division and the streamed one before it."""
+    """The indexed sparse attention's kernels (bf16 rings) against the
+    plain forms of the same arguments, for a token step's one position
+    and a chunk's: the indexer's scores (float32 both ways), the
+    selection (the same rows), then the attention over them, streamed
+    through the mask and, for the token step, with the selected slots
+    fetched by the kernel's own descriptors.  All attentions round ``p``
+    to bf16 for the context product, the plain form after the division
+    and the kernels before it."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops import attention
@@ -471,9 +473,8 @@ def _sparse_kernels(sz: Sizes) -> float:
     rng = np.random.RandomState(SEED + 8)
     draw = lambda *shape: jnp.asarray(
         rng.randn(*shape).astype(np.float32), jnp.bfloat16)
-    k_ring, v_ring, i_ring = (draw(batch, slots, kv_heads * d),
-                              draw(batch, slots, kv_heads * d),
-                              draw(batch, slots, di))
+    kv_ring, i_ring = draw(batch, slots, 2 * kv_heads, d), \
+        draw(batch, slots, di)
     worst = 0.0
     for t in (1, chunk):
         cursor = jnp.asarray(slots - t - 3, jnp.int32)
@@ -500,14 +501,25 @@ def _sparse_kernels(sz: Sizes) -> float:
                "the streamed selection picked other rows than the plain one")
         streamed = jax.jit(lambda *a: attention.sparse_attention_streamed(
             *a, cursor, sm_scale=d ** -0.5, interpret=sz.interpret))
-        _check_mosaic(streamed.lower(q, k_ring, v_ring, selected).as_text(),
+        _check_mosaic(streamed.lower(q, kv_ring, selected).as_text(),
                       "sparse_attention_streamed")
         masked = jax.jit(lambda *a: attention.sparse_attention_masked(
             *a, sm_scale=d ** -0.5))
-        worst = max(worst, _rel_err(streamed(q, k_ring, v_ring, selected),
-                                    masked(q, k_ring, v_ring, selected)))
+        plain = masked(q, kv_ring, selected)
+        worst = max(worst, _rel_err(streamed(q, kv_ring, selected), plain))
+        if t == 1:
+            slots_of, count = jax.jit(lambda m: attention.selected_slots(
+                m[:, 0], topk))(selected)
+            gathered = jax.jit(
+                lambda *a: attention.sparse_attention_gathered(
+                    *a, sm_scale=d ** -0.5, interpret=sz.interpret))
+            _check_mosaic(
+                gathered.lower(q, kv_ring, slots_of, count).as_text(),
+                "sparse_attention_gathered")
+            worst = max(worst, _rel_err(
+                gathered(q, kv_ring, slots_of, count), plain))
     _check(worst <= KERNEL_BOUND,
-           f"the streamed sparse attention differs from its plain form by "
+           f"the sparse attention's kernels differ from the plain form by "
            f"{worst:.3g} > {KERNEL_BOUND}")
     return worst
 

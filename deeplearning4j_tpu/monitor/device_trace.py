@@ -367,6 +367,10 @@ def _hbm_arrays(text: str) -> List[float]:
     return out
 
 
+#: how the HLO line of a Pallas kernel names its target
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
 def estimate_cost(line: str, category: str = "") -> Cost:
     """What an instruction without the compiler's numbers moves through
     HBM, from its HLO line alone: every operand and every result that
@@ -374,7 +378,12 @@ def estimate_cost(line: str, category: str = "") -> Cost:
     once (a result aliased to an operand,
     ``output_to_operand_aliasing``, is that operand again).  A Pallas
     kernel's ``tpu_custom_call`` reads so: "the ring at its capacity,
-    read once".  The ``-done`` of an asynchronous pair adds nothing (the
+    read once"; one that fetches part of an operand it leaves in HBM
+    declares its bytes instead (``cost_estimate=pl.CostEstimate(...)``,
+    which the trace carries as the instruction's ``bytes_accessed``
+    beside a breakdown that still holds every operand whole:
+    :func:`instruction_costs` takes the declared count, less what the
+    breakdown says is written, as what was read).  The ``-done`` of an asynchronous pair adds nothing (the
     ``-start`` holds the fetch).  No operations are guessed.  A line
     that cannot be read is ``uncounted``."""
     parts = parse_hlo_line(line)
@@ -417,6 +426,9 @@ def instruction_costs(path: str) -> Dict[int, Dict[str, Cost]]:
     reads space 3; in ``small_trace`` each ``copy-start`` has a second
     entry for the ``Async XLA Ops`` line with the same numbers, which
     the reduction never reads (leaf events of ``XLA Ops`` alone count).
+    A ``tpu_custom_call`` whose ``bytes_accessed`` is under its
+    breakdown's HBM bytes declared that count itself, and it is taken
+    (the gathered sparse attention fetches 33.6 MB of a 537 MB ring).
     ``how``: ``counted`` from these stats; ``estimated`` where they are
     absent or all zero though the instruction has operands (custom
     calls: :func:`estimate_cost`); ``uncounted`` where there is nothing
@@ -462,6 +474,12 @@ def _instruction_costs(xspace: bytes) -> Dict[int, Dict[str, Cost]]:
                 else:
                     read += size
             flops = float(number("flops"))
+            declared = float(number("bytes_accessed"))
+            if _MOSAIC_CALL in line and 0 < declared < read + write:
+                # a Pallas kernel that said what it moves
+                # (``pl.CostEstimate``): the breakdown beside the
+                # declared count is still every operand whole
+                read = max(declared - write, 0.0)
             if category in _CONTROL_FLOW:
                 # its stats are its body's, whose own events count: as
                 # a leaf (a loop of no round) it did none of it

@@ -571,9 +571,11 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
     query ``t`` attends over the ``topk`` visible positions of largest
     ``I[t, .]`` (every visible one while they are no more; equal scores:
     the lowest position first), softmax of ``q . k / sqrt(head_dim)``
-    over them.  The carry is ``(key ring (batch, capacity, n_kv_heads x
-    head_dim), value ring, indexer-key ring (batch, capacity,
-    index_dim), cursor)``: slots-major, a slot's heads side by side.
+    over them.  The carry is ``(key/value ring (batch, capacity, 2 x
+    n_kv_heads, head_dim), indexer-key ring (batch, capacity,
+    index_dim), cursor)``: slots-major, a slot's key heads and then its
+    value heads the rows of one tile, which a token step over a long
+    ring fetches by one copy descriptor a selected slot.
     One path serves prefill chunks, single steps and ``output()`` (from
     a zero ring); the form the selection
     and the attention take is ``ops.attention.sparse_attention_path``'s,
@@ -621,8 +623,8 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
         cap = int(cache_len if cache_len is not None else self.cache_len)
         if cap < 1:
             raise ValueError("cache_len must be >= 1")
-        kv = (batch, cap, self.n_kv_heads * self.head_dim)
-        return (jnp.zeros(kv, dtype), jnp.zeros(kv, dtype),
+        return (jnp.zeros((batch, cap, 2 * self.n_kv_heads, self.head_dim),
+                          dtype),
                 jnp.zeros((batch, cap, self.index_dim), dtype),
                 jnp.zeros((), jnp.int32))
 
@@ -632,8 +634,9 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
         if cache_len < cap:
             raise ValueError(
                 f"cannot shrink the key/value ring from {cap} to {cache_len}")
-        pad = [(0, 0), (0, cache_len - cap), (0, 0)]
-        return tuple(jnp.pad(r, pad) for r in rings) + (cursor,)
+        pad = [(0, 0), (0, cache_len - cap)]
+        return tuple(jnp.pad(r, pad + [(0, 0)] * (r.ndim - 2))
+                     for r in rings) + (cursor,)
 
     # ------------------------------------------------------------ forward
     def _turn(self, positions: Array):
@@ -660,8 +663,8 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
         return q_idx, x @ params["Ww"], turn(k.astype(x.dtype))
 
     def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
-        k_ring, v_ring, i_ring, cursor = carry
-        (b, t), cap = x.shape[:2], k_ring.shape[1]
+        kv_ring, i_ring, cursor = carry
+        (b, t), cap = x.shape[:2], kv_ring.shape[1]
         if t > cap:
             raise ValueError(f"chunk of {t} timesteps exceeds the "
                              f"key/value ring's capacity {cap}")
@@ -679,25 +682,25 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
         v = x @ params["Wv"]
         with _monitor.subscope("indexer"):
             q_idx, w_idx, k_idx = self.indexer(params, x, positions)
-        k_ring, v_ring, i_ring = sparse_ring_update(
-            k_ring, v_ring, i_ring, cursor, k, v, k_idx)
+        kv_ring, i_ring = sparse_ring_update(
+            kv_ring, i_ring, cursor, k, v, k_idx)
         ctx = sparse_ring_attention(
-            q, q_idx, w_idx, k_ring, v_ring, i_ring, cursor,
+            q, q_idx, w_idx, kv_ring, i_ring, cursor,
             topk=self.topk, sm_scale=d ** -0.5, scope=_monitor.subscope)
         out = self._activate(ctx.reshape(b, t, h * d) @ params["Wo"])
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
-        return out, (k_ring, v_ring, i_ring,
-                     cursor + jnp.asarray(t, jnp.int32))
+        return out, (kv_ring, i_ring, cursor + jnp.asarray(t, jnp.int32))
 
     def attention_path(self, t: int, carry) -> str:
-        """``"streamed"`` or ``"masked"``: the form ``forward_seq`` takes for ``t`` new positions against ``carry``,
+        """``"gathered"``, ``"streamed"`` or ``"masked"``: the form
+        ``forward_seq`` takes for ``t`` new positions against ``carry``,
         by the op's own predicate (host code asks it without tracing the
         step)."""
-        k_ring = carry[0]
+        kv_ring = carry[0]
         return sparse_attention_path(t, self.n_heads, self.n_kv_heads,
-                                     self.head_dim, k_ring.shape[1],
-                                     k_ring.dtype)
+                                     self.head_dim, kv_ring.shape[1],
+                                     kv_ring.dtype, self.topk)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         out, _ = self.forward_seq(

@@ -959,18 +959,28 @@ def latent_ring_attention_dense(q_lat: Array, q_rope: Array, c_ring: Array,
 # attention of DeepSeek-V3.2): a small indexer scores every cached row for
 # every query, the ``topk`` best are selected EXACTLY (ties to the lowest
 # position), and the query heads attend over the selected rows alone.  The
-# rings are slots-major with the heads side by side: keys and values
-# (batch, capacity, kv heads x d), so that a slot's row is one contiguous
-# read and a new row is written as the projection leaves it; indexer keys
-# (batch, capacity, d_index), one head.  Two forms of one algorithm under
-# ``sparse_ring_attention``, picked by ``sparse_attention_path`` from the
-# call's shapes alone, both with the selection as a mask over the ring:
+# key/value ring is slots-major with a slot's heads as the rows of one
+# tile: (batch, capacity, 2 x kv heads, d), the key heads first and the
+# value heads after them, so that a slot's keys AND values are one
+# contiguous read that one copy descriptor names (Mosaic slices a ring in
+# HBM by whole tiles only, and bfloat16's tile is 8 rows of 128: a slot of
+# 4 + 4 heads is exactly one), and a new row is written as the
+# projections leave it; indexer keys (batch, capacity, d_index), one
+# head.  Three forms of one algorithm under ``sparse_ring_attention``,
+# picked by ``sparse_attention_path`` from the call's shapes alone:
 #
-# streamed  on a TPU, a chunk or a token step: Pallas kernels that stream
-#           the rings through VMEM in blocks, so that neither the
-#           indexer's (heads, chunk, slots) scores nor the attention's
-#           ever reach HBM; blocks beyond the newest visible slot are
-#           neither fetched nor computed;
+# gathered  on a TPU, a token step over a ring much longer than ``topk``:
+#           the selection as slot numbers, and a Pallas kernel that
+#           fetches the selected slots out of the ring (left in HBM) by
+#           copy descriptors it issues itself, one a slot, and attends
+#           over what landed: the bytes that cross the HBM bus are the
+#           selected rows', not the ring's;
+# streamed  on a TPU, a chunk, or a token step over a shorter ring: the
+#           selection as a mask, and Pallas kernels that stream the rings
+#           through VMEM in blocks, so that neither the indexer's (heads,
+#           chunk, slots) scores nor the attention's ever reach HBM;
+#           blocks beyond the newest visible slot are neither fetched nor
+#           computed;
 # masked    the same mask over dense ``jax.numpy`` attention: any dtype,
 #           any backend, ``output()`` from a zero ring.
 #
@@ -978,17 +988,21 @@ def latent_ring_attention_dense(q_lat: Array, q_rope: Array, c_ring: Array,
 # selected and no score is computed: plain causal grouped-query attention.
 # ---------------------------------------------------------------------------
 
-def sparse_ring_update(k_ring: Array, v_ring: Array, i_ring: Array, cursor,
+def sparse_ring_update(kv_ring: Array, i_ring: Array, cursor,
                        k_new: Array, v_new: Array, i_new: Array):
     """Write (batch, T, kv heads x d) keys and values and (batch, T,
-    d_index) indexer keys into their rings at the cursor.  Callers
-    guarantee ``cursor + T <= capacity``."""
+    d_index) indexer keys into the key/value ring (batch, capacity, 2 x
+    kv heads, d) and the indexer's at the cursor.  Callers guarantee
+    ``cursor + T <= capacity``."""
     zero = jnp.zeros((), jnp.int32)
-    at = (zero, jnp.asarray(cursor, jnp.int32), zero)
-    return tuple(
-        jax.lax.dynamic_update_slice(ring, new.astype(ring.dtype), at)
-        for ring, new in ((k_ring, k_new), (v_ring, v_new),
-                          (i_ring, i_new)))
+    cursor = jnp.asarray(cursor, jnp.int32)
+    by_head = lambda a: a.reshape(a.shape[:2] + (-1, kv_ring.shape[3]))
+    rows = jnp.concatenate([by_head(k_new), by_head(v_new)], axis=2)
+    return (jax.lax.dynamic_update_slice(
+                kv_ring, rows.astype(kv_ring.dtype),
+                (zero, cursor, zero, zero)),
+            jax.lax.dynamic_update_slice(
+                i_ring, i_new.astype(i_ring.dtype), (zero, cursor, zero)))
 
 
 def visible_slots(cursor, t: int, capacity: int) -> Array:
@@ -1172,10 +1186,11 @@ def _grouped(q: Array, kv_heads: int) -> Array:
     return q.reshape(b, t, kv_heads, h // kv_heads, d)
 
 
-def _by_head(ring: Array, d: int) -> Array:
-    """A (batch, slots, kv heads x d) ring as (batch, slots, kv heads,
-    d)."""
-    return ring.reshape(ring.shape[:2] + (ring.shape[2] // d, d))
+def _keys_values(kv_ring: Array):
+    """The key heads and the value heads of a (batch, slots, 2 x kv
+    heads, d) ring, (batch, slots, kv heads, d) each."""
+    kv_heads = kv_ring.shape[2] // 2
+    return kv_ring[:, :, :kv_heads], kv_ring[:, :, kv_heads:]
 
 
 def _softmax_context(s: Array, keep: Array, v: Array, spec: str, dtype):
@@ -1189,13 +1204,14 @@ def _softmax_context(s: Array, keep: Array, v: Array, spec: str, dtype):
     return _einsum_acc(spec, p.astype(v.dtype), v, s.dtype).astype(dtype)
 
 
-def sparse_attention_masked(q: Array, k_ring: Array, v_ring: Array,
-                            selected: Array, *, sm_scale: float) -> Array:
-    """The dense form: (batch, T, heads, d) queries against every slot,
-    ``selected`` (batch, T, capacity) saying which count.  Any dtype, any
-    backend; the scores are one (batch, heads, T, capacity) array."""
+def sparse_attention_masked(q: Array, kv_ring: Array, selected: Array, *,
+                            sm_scale: float) -> Array:
+    """The dense form: (batch, T, heads, d) queries against every slot
+    of the (batch, capacity, 2 x kv heads, d) ring, ``selected`` (batch,
+    T, capacity) saying which count.  Any dtype, any backend; the scores
+    are one (batch, heads, T, capacity) array."""
     acc = jnp.promote_types(q.dtype, jnp.float32)
-    k, v = _by_head(k_ring, q.shape[-1]), _by_head(v_ring, q.shape[-1])
+    k, v = _keys_values(kv_ring)
     qg = _grouped(q, k.shape[2])                             # (b, t, g, r, d)
     s = _einsum_acc("btgrd,bsgd->bgrts", qg, k, acc) \
         * jnp.asarray(sm_scale, acc)
@@ -1216,21 +1232,34 @@ def sparse_ring_block(capacity: int) -> int:
     return next((b for b in _SPARSE_BLOCKS if capacity % b == 0), 0)
 
 
+#: slots a selected one from which a token step fetches its rows by
+#: descriptor (read off ``tools/sparse_attention_sweep.py --crossover``:
+#: see ``sparse_attention_path``)
+_GATHER_RATIO = 12
+#: descriptors issued a turn of the gathered kernel's loop, most first
+_GATHER_UNROLL = (32, 16, 8)
+
+
 def sparse_attention_path(t: int, heads: int, kv_heads: int, d: int,
-                          capacity: int, dtype) -> str:
-    """``"streamed"`` or ``"masked"``: which form
+                          capacity: int, dtype, topk: int) -> str:
+    """``"gathered"``, ``"streamed"`` or ``"masked"``: which form
     :func:`sparse_ring_attention` takes for ``t`` new positions a row
-    against rings of ``capacity`` slots stored in ``dtype``.  Streamed
-    where Mosaic compiles the kernels (a TPU), the storage is bfloat16
-    or float32, a head fills the 128 lanes, the new positions are
-    fewer than the ring's slots (more than one are padded to whole
-    sublane tiles of 8), and a block divides the capacity; masked
-    elsewhere.  A token step does not gather its selected rows: on a
-    v5e XLA moves the 16,384 selected rows of one ring (8 conversations,
-    1 KB a row) in 1.11 ms out of rings of 32,768 slots and in 3.56 ms
-    out of rings of 131,072, where the streamed kernel reads BOTH rings
-    whole in 0.80 and 2.93 ms (``tools/sparse_attention_sweep.py``;
-    PERF.md, PR 37).  Also what ``sparse_attention_steps_total{path}``
+    against rings of ``capacity`` slots stored in ``dtype`` of which a
+    query selects ``topk``.  Streamed where Mosaic compiles the kernels
+    (a TPU), the storage is bfloat16 or float32, a head fills the 128
+    lanes, the new positions are fewer than the ring's slots (more than
+    one are padded to whole sublane tiles of 8), and a block divides the
+    capacity; masked elsewhere.  Gathered where the streamed form's
+    conditions hold, the call is a token step (``t == 1``: the queries
+    of a chunk together select nearly every slot), and the ring is at
+    least ``_GATHER_RATIO`` times ``topk`` long: the gathered kernel's
+    time is set by the descriptors it issues, one a selected slot, and
+    the list of slots it takes (on a v5e 0.40-0.42 ms and 0.08-0.16 ms
+    for 8 conversations of 2,048 slots, out of rings of 4,096 to
+    131,072 slots), the streamed kernel's by the ring's bytes (0.78 ms
+    for 8 rings of 32,768 slots, 24 ns a slot): they cross near 21,000
+    slots, ten times ``topk`` (``tools/sparse_attention_sweep.py``;
+    PERF.md, PR 40).  Also what ``sparse_attention_steps_total{path}``
     is labelled by."""
     streamed = (_mosaic()
                 and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
@@ -1238,7 +1267,11 @@ def sparse_attention_path(t: int, heads: int, kv_heads: int, d: int,
                 and d % 128 == 0 and t < capacity
                 and heads % kv_heads == 0
                 and sparse_ring_block(capacity) > 0)
-    return "streamed" if streamed else "masked"
+    if not streamed:
+        return "masked"
+    gathered = (t == 1 and capacity >= _GATHER_RATIO * topk
+                and topk % _GATHER_UNROLL[-1] == 0)
+    return "gathered" if gathered else "streamed"
 
 
 def _newest_block(k, cur, t: int, block: int):
@@ -1330,22 +1363,41 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
     )(cursor, q_idx, w_idx, jnp.swapaxes(i_ring, 1, 2))
 
 
-def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
-                              selected: Array, cursor, *, sm_scale: float,
+def _head_rows(kv_ref, row: int, rows: int, block: int, words: bool):
+    """The (block, d) rows that row ``row`` of every slot's tile makes
+    (a key or a value head), of a (1, block x rows, d) block of the ring
+    in VMEM, slot after slot: a read of every ``rows``-th row.
+    ``words``: the storage is 16 bits wide and Mosaic compiles this, so
+    two rows share a sublane's 32-bit words; the words of every
+    ``rows / 2``-th sublane are read and shifted (a bfloat16 is the
+    upper half of the float32 of the same value), since a strided read
+    of half-words is a shuffle a row (twice the streamed kernel's time
+    when it was tried)."""
+    if not words:
+        return kv_ref[0, pl.ds(row, block, stride=rows), :]
+    pairs = kv_ref.bitcast(jnp.uint32)[
+        0, pl.ds(row // 2, block, stride=rows // 2), :]
+    upper = pairs & jnp.uint32(0xFFFF0000) if row % 2 else pairs << 16
+    return jax.lax.bitcast_convert_type(upper, jnp.float32).astype(
+        kv_ref.dtype)
+
+
+def sparse_attention_streamed(q: Array, kv_ring: Array, selected: Array,
+                              cursor, *, sm_scale: float,
                               block: Optional[int] = None,
                               interpret: Optional[bool] = None) -> Array:
     """:func:`sparse_attention_masked` as one Pallas kernel that reads
-    each conversation's rings once: grid (batch, ring blocks); a step
-    takes one block of both rings, every key/value head of it (whole
-    rows: one contiguous read), and the block of ``selected`` (batch, T,
-    capacity), and folds it into the streaming softmax of every query
+    each conversation's ring once: grid (batch, ring blocks); a step
+    takes one block of the ring, every slot's key and value heads (whole
+    tiles: one contiguous read), and the block of ``selected`` (batch,
+    T, capacity), and folds it into the streaming softmax of every query
     head, float32 scores that never leave VMEM.  A chunk's query heads
     go one at a time, (T, block) scores each; the single position of a
     token step takes a key/value head's whole group as rows.  Blocks
     wholly beyond ``cursor + T - 1`` are neither fetched nor computed; a
     block in which a query selected nothing adds nothing to it."""
     batch, t, heads, d = q.shape
-    cap, kv_heads = k_ring.shape[1], k_ring.shape[2] // d
+    cap, kv_heads = kv_ring.shape[1], kv_ring.shape[2] // 2
     group = heads // kv_heads
     block = block or sparse_ring_block(cap)
     if not block or cap % block:
@@ -1361,8 +1413,9 @@ def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
     # they are folded ``part`` rows at a time, all of which share ``keep``
     part = group if t == 1 else t
     rows = group * t
+    words = not interpret and kv_ring.dtype == jnp.bfloat16
 
-    def kernel(cursor_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+    def kernel(cursor_ref, q_ref, kv_ref, keep_ref, o_ref,
                m_scr, l_scr, acc_scr):
         ki = pl.program_id(1)
 
@@ -1378,8 +1431,8 @@ def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
             if part != t:
                 keep = jnp.broadcast_to(keep, (part, block))
             for g in range(kv_heads):
-                k = k_ref[0, :, g * d:(g + 1) * d]          # (block, d)
-                v = v_ref[0, :, g * d:(g + 1) * d]
+                k, v = (_head_rows(kv_ref, row, 2 * kv_heads, block, words)
+                        for row in (g, kv_heads + g))       # (block, d)
                 for start in range(0, rows, part):
                     at = (g, pl.ds(start, part))
                     s = dot(q_ref[(0,) + at], k, ((1,), (1,))) * scale
@@ -1409,6 +1462,9 @@ def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
     # (batch, kv heads, group x T, d): a head's rows, query head major
     qg = jnp.transpose(_grouped(q, kv_heads), (0, 2, 3, 1, 4)).reshape(
         batch, kv_heads, rows, d)
+    # the ring as rows of d, a slot's heads after one another: the same
+    # bytes (a slot's tile is 8 of those rows), which strided reads take
+    # apart in VMEM
     out = pl.pallas_call(
         kernel,
         out_shape=_sds(qg.shape, q.dtype, q),
@@ -1417,9 +1473,7 @@ def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
             grid=(batch, num_blocks),
             in_specs=[
                 pl.BlockSpec((1, kv_heads, rows, d), whole),
-                pl.BlockSpec((1, block, kv_heads * d),
-                             lambda b, k, cur: (b, newest(k, cur), 0)),
-                pl.BlockSpec((1, block, kv_heads * d),
+                pl.BlockSpec((1, block * 2 * kv_heads, d),
                              lambda b, k, cur: (b, newest(k, cur), 0)),
                 pl.BlockSpec((1, t, block),
                              lambda b, k, cur: (b, 0, newest(k, cur))),
@@ -1434,13 +1488,190 @@ def sparse_attention_streamed(q: Array, k_ring: Array, v_ring: Array,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
         interpret=interpret,
-    )(cursor, qg, k_ring, v_ring, selected.astype(jnp.bfloat16))
+    )(cursor, qg, kv_ring.reshape(batch, cap * 2 * kv_heads, d),
+      selected.astype(jnp.bfloat16))
     out = out.reshape(batch, kv_heads, group, t, d)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape)
 
 
+#: outputs :func:`selected_slots` finds at once (rows of its products)
+_SLOT_LIST_RUN = 512
+
+
+def selected_slots(selected: Array, topk: int, *,
+                   interpret: Optional[bool] = None):
+    """The selection of a token step as numbers: ``(slots (batch, topk)
+    int32, count (batch,) int32)`` for ``selected`` (batch, capacity),
+    nonzero where a slot is selected, at most ``topk`` a row.  The first
+    ``count`` of a row's slots are its selected ones in rising order;
+    the rest name slot 0 (a row that can be fetched and weighs nothing).
+    One Pallas kernel, grid (batch,), no sort and no scatter: the ring's
+    slots are taken 128 at a time; output ``j`` finds its group by
+    comparing ``j`` with the groups' running counts, takes that group's
+    128 marks by a one-hot product, ranks them by a product with a
+    triangle, and keeps the lane whose rank is ``j``'s place in the
+    group.  Every product is of whole numbers no larger than 128 in
+    bfloat16 summed in float32: exact."""
+    batch, cap = selected.shape
+    lanes = math.gcd(cap, 128)
+    groups = cap // lanes
+    run = math.gcd(topk, _SLOT_LIST_RUN)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                        axis)
+
+    def kernel(marks_ref, turned_ref, slots_ref, count_ref):
+        marks, turned = marks_ref[0], turned_ref[0]     # (G, L), (L, G)
+        mm = lambda a, b: dot(a, b, ((1,), (0,)))
+        ones = jnp.ones((groups, lanes), bf16)
+        # a group's count along its lanes; as a row over the groups, and
+        # the running count after each group
+        held = mm(marks, jnp.ones((lanes, lanes), bf16)).astype(bf16)
+        counts = jnp.broadcast_to(jnp.sum(
+            turned.astype(f32), axis=0, keepdims=True), (8, groups))
+        rows8 = counts.astype(bf16)
+        end = mm(rows8, (iota((groups, groups), 0)
+                         <= iota((groups, groups), 1)).astype(bf16))[:1]
+        start = end - counts[:1]
+        total = mm(rows8, ones)                         # (8, L), all equal
+        earlier = (iota((lanes, lanes), 0) < iota((lanes, lanes), 1)
+                   ).astype(bf16)
+        number = iota((lanes, lanes), 0).astype(bf16)
+        # the outputs go down the sublanes, a run at a time, so that the
+        # masks are the products' left sides and what never changes (the
+        # marks, the triangle) their right: a result is (run, L) with
+        # every lane the same, turned at the end into a row
+        for at in range(0, topk, run):
+            j = (at + iota((run, 1), 0)).astype(f32)
+            # the marks of output j's group, lane by lane, and how many
+            # of them come before each
+            row = mm(((start <= j) & (j < end)).astype(bf16), marks)
+            before = mm(row.astype(bf16), earlier)
+            # the marks in, and the number of, the groups that end at or
+            # before j: its group's first place, and its group
+            ended = (end <= j).astype(bf16)
+            hit = (row > 0.5) & (before == j - mm(ended, held))
+            slot = mm(ended, ones) * lanes + mm(hit.astype(bf16), number)
+            slot = jnp.where(j < total[:1], slot, 0.0)
+            slots_ref[0, :, at:at + run] = slot.T[:8].astype(jnp.int32)
+        count_ref[0] = total.astype(jnp.int32)
+
+    marks = (selected != 0).astype(bf16).reshape(batch, groups, lanes)
+    slots, count = pl.pallas_call(
+        kernel,
+        out_shape=[_sds((batch, 8, topk), jnp.int32, selected),
+                   _sds((batch, 8, lanes), jnp.int32, selected)],
+        grid=(batch,),
+        in_specs=[pl.BlockSpec((1, groups, lanes), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((1, lanes, groups), lambda b: (b, 0, 0))],
+        out_specs=[pl.BlockSpec((1, 8, topk), lambda b: (b, 0, 0)),
+                   pl.BlockSpec((1, 8, lanes), lambda b: (b, 0, 0))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(marks, jnp.swapaxes(marks, 1, 2))
+    return slots[:, 0], count[:, 0, 0]
+
+
+def sparse_attention_gathered(q: Array, kv_ring: Array, slots: Array,
+                              count: Array, *, sm_scale: float,
+                              interpret: Optional[bool] = None) -> Array:
+    """A token step's attention over the slots its selection named,
+    fetched by the kernel's own descriptors: (batch, 1, heads, d)
+    queries, the (batch, capacity, 2 x kv heads, d) ring left in HBM,
+    ``slots`` (batch, topk) int32 of which the first ``count`` (batch,)
+    are selected (:func:`selected_slots`).  Grid (batch,); a step issues
+    one copy a listed slot, the slot's tile of keys and values (one
+    contiguous read) into its row of a (topk, 2 x kv heads, d) landing
+    buffer in VMEM, all on one DMA semaphore, and waits once, for the
+    buffer's bytes; then every key/value head's group attends over the
+    landed rows, float32 scores and softmax, the rows past ``count``
+    masked.  What bounds it is the descriptors the scalar core issues
+    (about 20 ns each on a v5e, whatever they move), so the loop that
+    issues them is unrolled as far as ``topk`` divides.  (Issuing the
+    next conversation's descriptors inside the loop that attends over
+    this one's rows, to hide the attention, was tried and was slower:
+    493 us against 425.)  Same rows, same arithmetic as the streamed
+    form; of the ring only ``batch x topk`` slots cross the HBM bus,
+    which is what the kernel declares as its cost (the trace's account
+    of HBM traffic charges an undeclared kernel every operand whole)."""
+    batch, t, heads, d = q.shape
+    kv_heads = kv_ring.shape[2] // 2
+    group, topk = heads // kv_heads, slots.shape[1]
+    if t != 1 or topk % _GATHER_UNROLL[-1]:
+        raise ValueError(f"the gathered sparse attention takes one position "
+                         f"a row and whole turns of {_GATHER_UNROLL[-1]} "
+                         f"slots (t {t}, topk {topk})")
+    unroll = next(u for u in _GATHER_UNROLL if topk % u == 0)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    scale, masked = np.float32(sm_scale), np.float32(_NEG_INF)
+
+    def kernel(slots_ref, count_ref, q_ref, ring_ref, o_ref, rows, sem):
+        b = pl.program_id(0)
+
+        def issue(turn, _):
+            for u in range(unroll):
+                n = turn * unroll + u
+                pltpu.make_async_copy(ring_ref.at[b, slots_ref[b, n]],
+                                      rows.at[n], sem).start()
+            return 0
+
+        jax.lax.fori_loop(0, topk // unroll, issue, 0)
+        # one wait for all of them: a descriptor the size of the buffer
+        pltpu.make_async_copy(rows, rows, sem).wait()
+        live = jax.lax.broadcasted_iota(jnp.int32, (group, topk), 1) \
+            < count_ref[b]
+        for g in range(kv_heads):
+            k, v = rows[:, g, :], rows[:, kv_heads + g, :]   # (topk, d)
+            s = dot(q_ref[0, g], k, ((1,), (1,))) * scale
+            s = jnp.where(live, s, masked)
+            p = jnp.where(live, jnp.exp(
+                s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+            ctx = dot(p.astype(v.dtype), v, ((1,), (0,)))
+            o_ref[0, g] = (ctx / jnp.sum(p, axis=-1, keepdims=True)
+                           ).astype(o_ref.dtype)
+
+    itemsize = jnp.dtype(kv_ring.dtype).itemsize
+    fetched = batch * topk * 2 * kv_heads * d * itemsize
+    out = pl.pallas_call(
+        kernel,
+        out_shape=_sds((batch, kv_heads, group, d), q.dtype, q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch,),
+            in_specs=[
+                pl.BlockSpec((1, kv_heads, group, d),
+                             lambda b, slots, count: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, kv_heads, group, d),
+                                   lambda b, slots, count: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((topk, 2 * kv_heads, d), kv_ring.dtype),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * batch * heads * topk * d,
+            transcendentals=batch * heads * topk,
+            bytes_accessed=fetched + 2 * q.size * q.dtype.itemsize
+            + slots.size * 4),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(slots.astype(jnp.int32), count.astype(jnp.int32),
+      q.reshape(batch, kv_heads, group, d), kv_ring)
+    return out.reshape(q.shape)
+
+
 def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
-                          k_ring: Array, v_ring: Array, i_ring: Array,
+                          kv_ring: Array, i_ring: Array,
                           cursor, *, topk: int, sm_scale: float,
                           scope=None):
     """Attention of (batch, T, heads, d) queries over the ``topk`` cached
@@ -1453,8 +1684,9 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
     ``sparse_attention``) so that a trace can tell them apart."""
     scope = scope or (lambda part: contextlib.nullcontext())
     batch, t, heads, d = q.shape
-    cap, kv_heads = k_ring.shape[1], k_ring.shape[2] // d
-    path = sparse_attention_path(t, heads, kv_heads, d, cap, k_ring.dtype)
+    cap, kv_heads = kv_ring.shape[1], kv_ring.shape[2] // 2
+    path = sparse_attention_path(t, heads, kv_heads, d, cap, kv_ring.dtype,
+                                 topk)
     cursor = jnp.asarray(cursor, jnp.int32)
     if path == "streamed" and t > 1 and t % 8:
         # the kernels take whole sublane tiles of positions: a chunk of
@@ -1463,27 +1695,32 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
         pad = lambda a: jnp.pad(
             a, [(0, 0), (0, -t % 8)] + [(0, 0)] * (a.ndim - 2))
         return sparse_ring_attention(
-            pad(q), pad(q_idx), pad(w_idx), k_ring, v_ring, i_ring, cursor,
+            pad(q), pad(q_idx), pad(w_idx), kv_ring, i_ring, cursor,
             topk=topk, sm_scale=sm_scale, scope=scope)[:, :t]
 
-    def attend(selected):
+    def attend(picked):
         with scope("sparse_attention"):
+            if path == "gathered":
+                return sparse_attention_gathered(q, kv_ring, *picked,
+                                                 sm_scale=sm_scale)
             if path == "streamed":
                 return sparse_attention_streamed(
-                    q, k_ring, v_ring, selected, cursor, sm_scale=sm_scale)
-            return sparse_attention_masked(q, k_ring, v_ring, selected,
+                    q, kv_ring, picked, cursor, sm_scale=sm_scale)
+            return sparse_attention_masked(q, kv_ring, picked,
                                            sm_scale=sm_scale)
 
     if cap <= topk:
         # every visible row is selected: no score decides anything
         return attend(jnp.broadcast_to(visible_slots(cursor, t, cap)[None],
                                        (batch, t, cap)))
+    kernels = path != "masked"
     with scope("indexer"):
         scores = (indexer_scores_streamed(q_idx, w_idx, i_ring, cursor)
-                  if path == "streamed"
-                  else indexer_scores(q_idx, w_idx, i_ring))
+                  if kernels else indexer_scores(q_idx, w_idx, i_ring))
     with scope("select"):
-        selected = (select_mask_streamed(scores, cursor, topk)
-                    if path == "streamed" else select_mask(
-                        scores, visible_slots(cursor, t, cap)[None], topk))
-    return attend(selected)
+        picked = (select_mask_streamed(scores, cursor, topk)
+                  if kernels else select_mask(
+                      scores, visible_slots(cursor, t, cap)[None], topk))
+        if path == "gathered":      # the mask as (slot numbers, count)
+            picked = selected_slots(picked[:, 0], topk)
+    return attend(picked)

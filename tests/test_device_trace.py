@@ -612,6 +612,49 @@ def test_a_loop_that_ran_no_round_counts_none_of_its_bodys_numbers(
         hand_made["unscoped_by_opcode"]
 
 
+GATHER = ("%custom-call.9 = bf16[8,4,8,128]{3,2,1,0:T(8,128)(2,1)} "
+          "custom-call(s32[8,2048]{1,0:T(8,128)S(1)} %slots, "
+          "bf16[8,32768,8,128]{3,2,1,0:T(8,128)(2,1)} %ring), "
+          "custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("declared, stats_there, expected", [
+    # the kernel declared 33.6 MB of a 537 MB ring: the declared count,
+    # less the 64 KB the breakdown says it writes, is what it read
+    (33_751_040, True, (1e6, 33_751_040.0 - 65_536, 65_536.0, 0.0,
+                        "custom-call", "counted")),
+    # a declared count no smaller than the operands changes nothing
+    (2 * 536_936_448, True, (1e6, 536_936_448.0, 65_536.0, 0.0,
+                             "custom-call", "counted")),
+    # nothing declared, all-zero stats: the operands whole, as today
+    (0, False, (0.0, 8 * 32768 * 8 * 128 * 2.0, 8 * 4 * 8 * 128 * 2.0, 0.0,
+                "custom-call", "estimated")),
+], ids=["declared", "declared_larger", "undeclared"])
+def test_a_kernel_that_declares_its_bytes_is_taken_at_its_word(
+        tmp_path, declared, stats_there, expected):
+    """``pl.CostEstimate`` reaches the trace as ``bytes_accessed`` beside
+    a breakdown that holds every operand whole (read from the traced
+    gathered sparse attention): a ``tpu_custom_call`` that fetches part
+    of an operand it left in HBM counts what it declared."""
+    breakdown = _message(
+        (1, _message((1, 1), (2, 1), (3, 536_936_448))),
+        (1, _message((1, 2), (2, 1), (3, 65_536)))) if stats_there else b""
+    stats = [_message((1, 1), (3, 7)),
+             _message((1, 2), (4, 10 ** 6 if stats_there else 0)),
+             _message((1, 3), (4, declared)),
+             _message((1, 4), (6, breakdown)),
+             _message((1, 5), (5, "custom-call"))]
+    plane = _message(
+        (2, "/device:TPU:0"),
+        (4, _message((1, 1), (2, _message(
+            (1, 1), (2, GATHER), *((5, s) for s in stats))))),
+        *((5, _message((1, i), (2, _message((1, i), (2, name)))))
+          for i, name in STATS.items()))
+    path = tmp_path / "kernel.xplane.pb"
+    path.write_bytes(_message((1, plane)))
+    assert instruction_costs(str(path))[7][GATHER] == expected
+
+
 def test_table_prints_the_costs_and_the_idle_time_by_cause(scoped):
     text = table(scoped, top=5)
     for heading in ("HBM traffic", "GB/s", "TFLOP/s", "estimated from",

@@ -56,6 +56,14 @@ def rel(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
+def joined(k, v, d):
+    """(batch, slots, kv heads x d) keys and values as the layer's ring,
+    (batch, slots, 2 x kv heads, d): the key heads, then the value
+    heads."""
+    by_head = lambda a: a.reshape(a.shape[:2] + (-1, d))
+    return jnp.concatenate([by_head(k), by_head(v)], axis=2)
+
+
 def build(cfg=CFG, **kw):
     return ComputationGraph(from_config(cfg, **{**ARGS, **kw})).init()
 
@@ -97,7 +105,8 @@ def test_within_topk_it_is_dense_grouped_query_attention(net):
     q, k, v = acts((b, t, 4, 16)), acts((b, cap, 32), 2), acts((b, cap, 32), 3)
     idx = (acts((b, t, 3, 8), 4), acts((b, t, 3), 5), acts((b, cap, 8), 6))
     got = attention.sparse_ring_attention(
-        q, idx[0], idx[1], k, v, idx[2], 4, topk=TOPK, sm_scale=0.25)
+        q, idx[0], idx[1], joined(k, v, 16), idx[2], 4, topk=TOPK,
+        sm_scale=0.25)
     by_head = lambda ring: jnp.repeat(jnp.transpose(
         ring.reshape(b, cap, 2, 16), (0, 2, 1, 3)), 2, axis=1)
     want = attention.kv_ring_attention(q, by_head(k), by_head(v), 4,
@@ -132,10 +141,119 @@ def test_the_two_forms_agree_and_pick_the_same_sets(t, cursor):
     np.testing.assert_array_equal(
         np.stack([ref.select(scores[i], visible[0], TOPK)
                   for i in range(b)]), selected)
-    masked = attention.sparse_attention_masked(q, k, v, selected,
+    ring = joined(k, v, 16)
+    masked = attention.sparse_attention_masked(q, ring, selected,
                                                sm_scale=0.25)
     assert rel(attention.sparse_attention_streamed(
-        q, k, v, selected, cursor, sm_scale=0.25, block=16), masked) < 1e-6
+        q, ring, selected, cursor, sm_scale=0.25, block=16), masked) < 1e-6
+    if t == 1:
+        # the token step's third form: the selection as slot numbers,
+        # the slots fetched by the kernel's own descriptors
+        slots, count = attention.selected_slots(selected[:, 0], TOPK)
+        for i in range(b):
+            np.testing.assert_array_equal(
+                np.asarray(slots[i, :int(count[i])]),
+                np.nonzero(np.asarray(selected[i, 0]))[0])
+        assert rel(attention.sparse_attention_gathered(
+            q, ring, slots, count, sm_scale=0.25), masked) < 1e-6
+
+
+def _gather_case(case):
+    """Scores (2, 1, 256), the cursor and the slots a row has to name,
+    for a ring of 256 slots of which 16 are selected."""
+    cap, rng = 256, np.random.RandomState(7)
+    scores = rng.randn(2, 1, cap).astype(np.float32)
+    if case == "few_visible":       # 6 visible: every one, a short list
+        return scores, 5, [np.arange(6)] * 2
+    if case == "odd_newest":
+        # the newest slot is odd and scores highest: the slot after it,
+        # its tile's neighbour, is a row nobody wrote
+        scores[:, 0, 201] = 50.0
+        cursor = 201
+    elif case == "neighbours":      # both slots of a pair, and a run of 4
+        scores[:, 0, [10, 11, 128, 129, 130, 131]] = 40.0
+        cursor = 250
+    elif case == "ties":
+        # 21 equal scores for the last 6 places: the lowest positions
+        scores[:, 0, 3:66:3] = 9.0
+        scores[:, 0, [100, 101, 102, 200, 201, 202, 203, 204, 205, 206]] = 20.0
+        cursor = 255
+    elif case == "last_group":      # every pick in the ring's last 128
+        scores[:, 0, 240:256] = 30.0
+        cursor = 255
+    visible = np.arange(cap) <= cursor
+    want = [np.sort(np.argsort(-np.where(visible, s[0], -np.inf),
+                               kind="stable")[:TOPK]) for s in scores]
+    return scores, cursor, want
+
+
+@pytest.mark.parametrize("case", ["few_visible", "odd_newest", "neighbours",
+                                  "ties", "last_group"])
+def test_the_gathered_form_fetches_the_rows_the_selection_named(case):
+    """The token step over a long ring: the mask as slot numbers (rising,
+    ``count`` of them, the rest slot 0), and the kernel that fetches the
+    named slots (through ``pltpu.InterpretParams()``, whose landing
+    buffer starts as NaN: a row that was not fetched would show)
+    against the mask over dense attention and over the streamed
+    kernel."""
+    b, cap, d = 2, 256, 128
+    scores, cursor, want = _gather_case(case)
+    rng = np.random.RandomState(11)
+    draw = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
+    q, k, v = draw(b, 1, 4, d), draw(b, cap, 2 * d), draw(b, cap, 2 * d)
+    # past the newest position: rows nobody wrote (large, to show)
+    k = k.at[:, cursor + 1:].multiply(1e4)
+    ring = joined(k, v, d)
+    selected = attention.select_mask_streamed(jnp.asarray(scores), cursor,
+                                              TOPK)
+    np.testing.assert_array_equal(
+        np.asarray(selected, np.float32) != 0,
+        attention.select_mask(jnp.asarray(scores),
+                              attention.visible_slots(cursor, 1, cap)[None],
+                              TOPK))
+    slots, count = attention.selected_slots(selected[:, 0], TOPK)
+    for i in range(b):
+        n = int(count[i])
+        np.testing.assert_array_equal(np.asarray(slots[i, :n]), want[i])
+        assert not np.asarray(slots[i, n:]).any()
+    masked = attention.sparse_attention_masked(q, ring, selected,
+                                               sm_scale=d ** -0.5)
+    gathered = attention.sparse_attention_gathered(q, ring, slots, count,
+                                                   sm_scale=d ** -0.5)
+    assert np.isfinite(np.asarray(gathered)).all()
+    assert rel(gathered, masked) < 1e-6
+    assert rel(gathered, attention.sparse_attention_streamed(
+        q, ring, selected, cursor, sm_scale=d ** -0.5, block=128)) < 1e-6
+    # bf16 rows as stored: the same rows, the same rounding of p
+    as_bf16 = lambda a: a.astype(jnp.bfloat16)
+    assert rel(attention.sparse_attention_gathered(
+        as_bf16(q), as_bf16(ring), slots, count, sm_scale=d ** -0.5),
+        attention.sparse_attention_streamed(
+            as_bf16(q), as_bf16(ring), selected, cursor, sm_scale=d ** -0.5,
+            block=128)) < 8e-3
+
+
+def test_an_unselected_neighbour_weighs_nothing():
+    """NaN in every row the selection did not name: the gathered form
+    never reads them (the masked form would spread them, 0 x NaN)."""
+    b, cap, d = 1, 256, 128
+    scores, cursor, want = _gather_case("neighbours")
+    rng = np.random.RandomState(5)
+    draw = lambda *s: rng.randn(*s).astype(np.float32)
+    q, k, v = draw(b, 1, 4, d), draw(b, cap, 2 * d), draw(b, cap, 2 * d)
+    selected = attention.select_mask(
+        jnp.asarray(scores[:1]), attention.visible_slots(cursor, 1, cap)[None],
+        TOPK)
+    clean = attention.sparse_attention_masked(
+        jnp.asarray(q), joined(jnp.asarray(k), jnp.asarray(v), d), selected,
+        sm_scale=0.1)
+    unselected = np.setdiff1d(np.arange(cap), want[0])
+    k[:, unselected], v[:, unselected] = np.nan, np.nan
+    slots, count = attention.selected_slots(selected[:, 0], TOPK)
+    got = attention.sparse_attention_gathered(
+        jnp.asarray(q), joined(jnp.asarray(k), jnp.asarray(v), d), slots,
+        count, sm_scale=0.1)
+    assert rel(got, clean) < 1e-6
 
 
 def test_equal_scores_go_to_the_lowest_positions():
@@ -166,20 +284,33 @@ def test_the_path_is_chosen_from_the_shapes(monkeypatch):
     path = attention.sparse_attention_path
     bf16 = jnp.bfloat16
     # off a TPU: the mask over dense attention, whatever the shapes
-    assert path(1, 32, 4, 128, 32768, bf16) == "masked"
-    assert path(256, 32, 4, 128, 32768, bf16) == "masked"
+    assert path(1, 32, 4, 128, 32768, bf16, 2048) == "masked"
+    assert path(256, 32, 4, 128, 32768, bf16, 2048) == "masked"
     monkeypatch.setattr(attention, "_mosaic", lambda: True)
-    # the cell's shapes: the ring streamed through the mask, both ways
-    assert path(1, 32, 4, 128, 32768, bf16) == "streamed"
-    assert path(256, 32, 4, 128, 32768, bf16) == "streamed"
-    assert path(1, 32, 4, 128, 131072, bf16) == "streamed"
+    # the cell's shapes: a token step fetches the slots it selected, a
+    # chunk (whose queries together select nearly every slot) streams
+    # the ring through the mask
+    assert path(1, 32, 4, 128, 32768, bf16, 2048) == "gathered"
+    assert path(256, 32, 4, 128, 32768, bf16, 2048) == "streamed"
+    assert path(1, 32, 4, 128, 131072, bf16, 2048) == "gathered"
+    assert path(2, 32, 4, 128, 131072, bf16, 2048) == "streamed"
+    # a token step over a ring not much longer than topk: streamed
+    ratio = attention._GATHER_RATIO
+    assert path(1, 32, 4, 128, 4096, bf16, 2048) == "streamed"
+    assert path(1, 32, 4, 128, 2048 * ratio, bf16, 2048) == "gathered"
+    assert path(1, 32, 4, 128, 2048 * ratio - 1024, bf16, 2048) == "streamed"
+    assert path(1, 32, 4, 128, 32768, bf16, 32768 // ratio + 8) == "streamed"
+    assert path(1, 32, 4, 128, 32768, jnp.float32, 2048) == "gathered"
+    # a list of whole turns of the kernel's loop
+    assert path(1, 32, 4, 128, 32768, bf16, 2047) == "streamed"
     # a chunk of any length (padded to whole sublane tiles of 8)
-    assert path(191, 32, 4, 128, 32768, bf16) == "streamed"
+    assert path(191, 32, 4, 128, 32768, bf16, 2048) == "streamed"
     # what the kernels do not take
-    assert path(256, 32, 4, 64, 32768, bf16) == "masked"
-    assert path(256, 32, 4, 128, 32768, jnp.float64) == "masked"
-    assert path(256, 32, 4, 128, 1000, bf16) == "masked"
-    assert path(256, 32, 4, 128, 256, bf16) == "masked"   # output()
+    assert path(1, 32, 4, 64, 32768, bf16, 2048) == "masked"
+    assert path(256, 32, 4, 64, 32768, bf16, 2048) == "masked"
+    assert path(256, 32, 4, 128, 32768, jnp.float64, 2048) == "masked"
+    assert path(256, 32, 4, 128, 1000, bf16, 2048) == "masked"
+    assert path(256, 32, 4, 128, 256, bf16, 2048) == "masked"   # output()
 
 
 def test_output_and_the_served_path_agree_with_the_reference(net, ids):
@@ -219,6 +350,32 @@ def test_the_streamed_form_serves_the_same(monkeypatch, ids):
         out = engine.generate("s", ids[:, 29:30], 3)
     assert monitor.counter("sparse_attention_steps_total", "").value(
         path="streamed") - before == 4 + 3
+    sequence = np.concatenate([ids[:, :30], out.ids[:, :-1]], axis=1)
+    want = np.asarray(ref.forward(wide, net.params, sequence, last=3))
+    kept = np.stack([np.asarray(k) for k in out.kept_logits], axis=1)
+    assert rel(kept, want[[0, 2]]) < 1e-5
+
+
+def test_a_token_step_over_a_long_ring_is_served_gathered(monkeypatch, ids):
+    """A ring of 256 slots, 16 selected: the prefill's chunks stream,
+    the token steps fetch by descriptor, and
+    ``sparse_attention_steps_total{path}`` says which."""
+    monkeypatch.setattr(attention, "_mosaic", lambda: True)
+    wide = {**CFG, "head_dim": 128, "num_attention_heads": 2,
+            "num_key_value_heads": 1, "vocab_size": 256}
+    net = build(wide, cache_len=256)
+    layer = net.vertices["L0_attn"].layer
+    carry = net._init_carries(3, cache_len=256)["L0_attn"]
+    assert [a.shape for a in carry] == [(3, 256, 2, 128), (3, 256, 8), ()]
+    assert (layer.attention_path(8, carry), layer.attention_path(1, carry)) \
+        == ("streamed", "gathered")
+    steps = monitor.counter("sparse_attention_steps_total", "")
+    before = {p: steps.value(path=p) for p in ("gathered", "streamed")}
+    with InferenceEngine(net, max_batch_size=4) as engine:
+        engine.prefill_session("s", ids[:, :29], chunk=8, cache_len=256)
+        out = engine.generate("s", ids[:, 29:30], 3)
+    assert steps.value(path="streamed") - before["streamed"] == 4
+    assert steps.value(path="gathered") - before["gathered"] == 3
     sequence = np.concatenate([ids[:, :30], out.ids[:, :-1]], axis=1)
     want = np.asarray(ref.forward(wide, net.params, sequence, last=3))
     kept = np.stack([np.asarray(k) for k in out.kept_logits], axis=1)
@@ -367,11 +524,10 @@ def test_the_real_file_is_the_catalog_rows_config():
 def test_fork_grow_and_chunked_prefill_of_the_three_rings(net, ids):
     layer = net.vertices["L0_attn"].layer
     carry = layer.init_carry(3, jnp.float32, 32)
-    assert [a.shape for a in carry] == [(3, 32, 32), (3, 32, 32),
-                                        (3, 32, 8), ()]
+    # keys and values share a ring: a slot's 2 + 2 heads of 16
+    assert [a.shape for a in carry] == [(3, 32, 4, 16), (3, 32, 8), ()]
     grown = layer.grow_carry(carry, 64)
-    assert [a.shape for a in grown] == [(3, 64, 32), (3, 64, 32),
-                                        (3, 64, 8), ()]
+    assert [a.shape for a in grown] == [(3, 64, 4, 16), (3, 64, 8), ()]
     with pytest.raises(ValueError, match="shrink"):
         layer.grow_carry(grown, 32)
     want = np.asarray(ref.forward(CFG, net.params, ids))
@@ -447,7 +603,7 @@ def test_both_kinds_of_ring_state_live_in_one_session_cache():
     # the fork grew past 16 slots... no: 12 positions fit; grow by hand
     grown = net.grow_decode_carries(cache.get_carries("q"), 32)
     assert grown["latent"][0].shape[1] == grown["sparse"][0].shape[1] == 32
-    assert int(cache.get_carries("p")["sparse"][3]) == 7   # untouched
+    assert int(cache.get_carries("p")["sparse"][-1]) == 7  # untouched
 
 
 # --------------------------------------------------------------- the scopes

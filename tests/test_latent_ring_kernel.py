@@ -419,7 +419,7 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
         compilation_cache.reset_cache()
 
 
-def _scoped_sparse(q, q_idx, w_idx, k_ring, v_ring, i_ring, cursor):
+def _scoped_sparse(q, q_idx, w_idx, kv_ring, i_ring, cursor):
     with monitor.scope("layer", "L0_attn"):
         with monitor.subscope("indexer"):
             scores = attention.indexer_scores_streamed(
@@ -427,9 +427,15 @@ def _scoped_sparse(q, q_idx, w_idx, k_ring, v_ring, i_ring, cursor):
         with monitor.subscope("select"):
             selected = attention.select_mask_streamed(scores, cursor, 2048,
                                                       interpret=False)
+            if q.shape[1] == 1:
+                slots, count = attention.selected_slots(selected[:, 0], 2048)
         with monitor.subscope("sparse_attention"):
+            if q.shape[1] == 1:     # the token step fetches what it selected
+                return attention.sparse_attention_gathered(
+                    q, kv_ring, slots, count, sm_scale=128 ** -0.5,
+                    interpret=False)
             return attention.sparse_attention_streamed(
-                q, k_ring, v_ring, selected, cursor, sm_scale=128 ** -0.5,
+                q, kv_ring, selected, cursor, sm_scale=128 ** -0.5,
                 interpret=False)
 
 
@@ -438,8 +444,10 @@ def test_mosaic_compiles_the_sparse_kernels_at_the_cells_shape(one_chip):
     heads over 4 key/value heads of 128, an indexer of 16 heads of 64,
     rings of 32,768 slots, bf16; the token step's single position and a
     prefill chunk's 256, compiled ahead of time for a described v5e.
-    Each of the three kernels (indexer, selection, attention) is one
-    instruction under the scope it was called under."""
+    Each of the three kernels (indexer, selection, attention: the token
+    step's fetches its selected slots out of the ring in HBM by its own
+    descriptors, the chunk's streams the ring) is one instruction under
+    the scope it was called under."""
     from jax.experimental.compilation_cache import compilation_cache
     rows, slots = 8, 32768
     jax.config.update("jax_enable_compilation_cache", False)
@@ -450,8 +458,8 @@ def test_mosaic_compiles_the_sparse_kernels_at_the_cells_shape(one_chip):
                 shapes = [
                     jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
                     for s in ((rows, t, 32, 128), (rows, t, 16, 64),
-                              (rows, t, 16), (rows, slots, 512),
-                              (rows, slots, 512), (rows, slots, 64))]
+                              (rows, t, 16), (rows, slots, 8, 128),
+                              (rows, slots, 64))]
                 cursor = jax.ShapeDtypeStruct((), jnp.int32,
                                               sharding=one_chip)
                 compiled = jax.jit(_scoped_sparse).lower(
