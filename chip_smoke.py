@@ -123,6 +123,10 @@ class Sizes:
     # (batch, query heads, key/value heads, head size, indexer heads,
     # indexer head size, ring slots, selected rows, a chunk's positions)
     sparse_shape: Tuple[int, ...] = (8, 32, 4, 128, 16, 64, 8192, 2048, 256)
+    # the dense grouped-query attention of the window-and-full cell:
+    # (batch, query heads, key/value heads, head size, window, a full
+    # ring's slots, a chunk's positions)
+    gqa_shape: Tuple[int, ...] = (8, 128, 8, 128, 4096, 8192, 256)
     interpret: bool = False         # True only where there is no Mosaic
     # four_chips: ZeRO needs a MultiLayerNetwork
     mln_conf: Callable = _lenet_conf
@@ -420,6 +424,7 @@ def phase_kernels(sz: Sizes):
     report["experts_share_grouped_max_rel_err"] = _experts_kernel(
         sz, "held_rows", *sz.experts_share_shape)
     report["sparse_streamed_max_rel_err"] = _sparse_kernels(sz)
+    report["gqa_ring_streamed_max_rel_err"] = _gqa_ring_kernels(sz)
     return report
 
 
@@ -521,6 +526,51 @@ def _sparse_kernels(sz: Sizes) -> float:
     _check(worst <= KERNEL_BOUND,
            f"the sparse attention's kernels differ from the plain form by "
            f"{worst:.3g} > {KERNEL_BOUND}")
+    return worst
+
+
+def _gqa_ring_kernels(sz: Sizes) -> float:
+    """The dense grouped-query attention's streamed kernel (bf16 rings)
+    against the masked form of the same arguments: a full ring that
+    grows, and a window's ring that has wrapped (the cursor several laps
+    on, the newest slot inside a block), each for a token step's one
+    position and a chunk's."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops import attention
+
+    batch, heads, kv_heads, d, window, slots, chunk = sz.gqa_shape
+    rng = np.random.RandomState(SEED + 9)
+    draw = lambda *shape: jnp.asarray(
+        rng.randn(*shape).astype(np.float32), jnp.bfloat16)
+    worst = 0.0
+    for win, cap in ((None, slots),
+                     (window, attention.window_ring_slots(window, chunk))):
+        ring = draw(batch, cap, 2 * kv_heads, d)
+        for t in (1, chunk):
+            # a full ring nearly full; a window's ring three laps on
+            cursor = jnp.asarray(
+                slots - t - 3 if win is None else 3 * cap + cap // 3,
+                jnp.int32)
+            q = draw(batch, t, heads, d)
+            streamed = jax.jit(
+                lambda *a: attention.gqa_ring_attention_streamed(
+                    *a, sm_scale=d ** -0.5, window=win,
+                    interpret=sz.interpret))
+            _check_mosaic(streamed.lower(q, ring, cursor).as_text(),
+                          "gqa_ring_attention_streamed")
+            masked = jax.jit(lambda *a: attention.gqa_ring_attention_masked(
+                *a, sm_scale=d ** -0.5, window=win))
+            got = streamed(q, ring, cursor)
+            # the masked form's scores are (heads, T, slots) float32 a
+            # conversation (1 GB for a chunk against 8,192 slots): the
+            # first and the last conversation, one at a time
+            for row in (slice(0, 1), slice(batch - 1, batch)):
+                worst = max(worst, _rel_err(
+                    got[row], masked(q[row], ring[row], cursor)))
+    _check(worst <= KERNEL_BOUND,
+           f"the streamed grouped-query attention differs from the masked "
+           f"form by {worst:.3g} > {KERNEL_BOUND}")
     return worst
 
 

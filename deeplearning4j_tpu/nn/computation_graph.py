@@ -237,6 +237,14 @@ class ComputationGraph(Network):
                 mask = next((m for m in in_masks if m is not None), None)
                 if isinstance(v, LayerVertex):
                     layer = v.layer
+                    own = params[name]
+                    tied = getattr(layer, "TIED_PARAMS", ())
+                    if tied:
+                        # a layer that reads another vertex's parameters
+                        # (a head tied to the embedding's table): the
+                        # same arrays, held once under that vertex
+                        own = {**own, **{k: params[layer.tied_to][k]
+                                         for k in tied}}
                     # a layer of several inputs (a hyper-connection's
                     # write) takes them all, as a tuple
                     x = (tuple(xs) if getattr(layer, "MULTI_INPUT", False)
@@ -247,7 +255,7 @@ class ComputationGraph(Network):
                             and hasattr(layer, "pre_output"):
                         if layer.dropout and train:
                             x = layer.apply_dropout(x, train, key_of[name])
-                        out = layer.pre_output(params[name], x)
+                        out = layer.pre_output(own, x)
                     elif (pol.downcasts_output and name in conf.network_outputs
                           and hasattr(layer, "pre_output")
                           and hasattr(layer, "_activate")):
@@ -263,15 +271,15 @@ class ComputationGraph(Network):
                         # drift from output() under mixed_bf16.
                         x = layer.apply_dropout(x, train, key_of[name])
                         out = layer._activate(
-                            layer.pre_output(params[name], x)
+                            layer.pre_output(own, x)
                             .astype(jnp.float32))
                     elif carries is not None and name in carries:
                         out, new_carries[name] = layer.forward_seq(
-                            params[name], x, carries[name], train=train,
+                            own, x, carries[name], train=train,
                             rng=key_of[name], mask=mask)
                     else:
                         out, new_state[name] = layer.forward(
-                            params[name], net_state[name], x, train=train,
+                            own, net_state[name], x, train=train,
                             rng=key_of[name], mask=mask)
                     acts[name] = out
                     masks[name] = mask
@@ -849,11 +857,13 @@ class ComputationGraph(Network):
                    for n in self._layer_names())
 
     def max_cache_len(self) -> int:
-        """Largest KV-ring capacity across vertices (0 without rings)."""
-        return max((int(self.vertices[n].layer.cache_len)
-                    for n in self._layer_names()
-                    if getattr(self.vertices[n].layer, "HAS_KV_RING",
-                               False)), default=0)
+        """Largest capacity of the KV rings that grow with a session (0
+        without any: no ring, or window rings alone, which are sized
+        once and wrap)."""
+        layers = (self.vertices[n].layer for n in self._layer_names())
+        return max((int(layer.cache_len) for layer in layers
+                    if getattr(layer, "HAS_KV_RING", False)
+                    and getattr(layer, "RING_GROWS", True)), default=0)
 
     # --------------------------------------------- rnn streaming state API
     def rnn_time_step(self, *features):

@@ -1,8 +1,11 @@
-"""Decoder-block layers: token embedding, RMS norm, gated feed-forward,
-latent attention over a compressed ring, grouped-query attention over
-the cached rows a learned indexer selects, a routed expert layer
-(sigmoid or softmax scores), manifold-constrained hyper-connections,
-and the language-model head.
+"""Decoder-block layers: token embedding, RMS norm and LayerNorm, gated
+feed-forward, latent attention over a compressed ring, grouped-query
+attention over the cached rows a learned indexer selects, dense
+grouped-query attention over a ring that grows or over a window's ring
+that wraps, a routed expert layer (sigmoid or softmax scores, shared
+experts summed or averaged), manifold-constrained hyper-connections,
+and the language-model head, with a table of its own or the
+embedding's.
 
 Everything here is plain ``jax.numpy`` under the container's
 ``layer.<name>`` scopes, but for the kernels that ``ops/`` puts under
@@ -11,14 +14,15 @@ a long chunk (``ops.experts``), each chosen from the call's shapes
 alone; the parts a trace has to tell apart open a sub-scope
 (``monitor.subscope``: ``layer.<name>.experts``, ``.latent_attention``,
 ``.router``, ``.shared``, ``.sinkhorn``, ``.indexer``, ``.select``,
-``.sparse_attention``).
+``.sparse_attention``, ``.window_attention``, ``.full_attention``).
 
 The equations are DeepSeek-V2/V3's for latent attention (MLA), routing
 and the expert layer, DeepSeek-V3.2's for the indexer and its
 selection, and those of "Manifold-Constrained Hyper-Connections"
 (arXiv:2512.24880) for the residual path; the plain references the
-benchmark compares with are ``benchmark/reference/mla_moe_decoder.py``
-and ``gqa_sparse_moe.py``, written apart from this file.
+benchmark compares with are ``benchmark/reference/mla_moe_decoder.py``,
+``gqa_sparse_moe.py`` and ``gqa_window_moe.py`` (Cohere2's window and
+full layers under one norm a block), written apart from this file.
 
 Activations are (batch, time, features); the residual path of a
 hyper-connected model is (batch, time, streams, features).
@@ -34,10 +38,12 @@ import jax
 import jax.numpy as jnp
 
 from ... import monitor as _monitor
-from ...ops.attention import (_einsum_acc, latent_ring_attention,
-                              latent_ring_path, latent_ring_update,
-                              sparse_attention_path, sparse_ring_attention,
-                              sparse_ring_update)
+from ...ops.attention import (_einsum_acc, gqa_attention_path,
+                              gqa_ring_attention, gqa_ring_update,
+                              latent_ring_attention, latent_ring_path,
+                              latent_ring_update, sparse_attention_path,
+                              sparse_ring_attention, sparse_ring_update,
+                              window_ring_slots)
 from ...ops.experts import (dense_experts, grouped_experts,
                             held_rows_experts, held_token_rows,
                             moe_experts_path)
@@ -102,8 +108,9 @@ class TokenEmbedding(FeedForwardLayerConfig):
 @serde.register("lm_head")
 @dataclasses.dataclass
 class LMHead(FeedForwardLayerConfig):
-    """Hidden states to float32 logits over the vocabulary; no bias, not
-    tied to the embedding."""
+    """Hidden states to float32 logits over the vocabulary through a
+    (hidden, vocabulary) matrix of its own; no bias.  A model that ties
+    its head to the embedding takes :class:`TiedLMHead`."""
 
     def param_order(self) -> tuple:
         return ("W",)
@@ -114,6 +121,36 @@ class LMHead(FeedForwardLayerConfig):
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         return _einsum_acc("btc,cv->btv", x, params["W"],
                            _acc(x.dtype)), state
+
+
+@serde.register("tied_lm_head")
+@dataclasses.dataclass
+class TiedLMHead(FeedForwardLayerConfig):
+    """Hidden states to float32 logits over the vocabulary through the
+    embedding's own table: ``logit_scale * x E^T`` with ``E`` the
+    (vocabulary, hidden) parameter ``W`` of the vertex ``tied_to``.  The
+    layer has no parameter: the container hands it the other vertex's
+    (``TIED_PARAMS``), so the table is held once, a new table set on the
+    embedding is the head's at the next step, and a gradient reaches it
+    from both uses."""
+
+    tied_to: str = "embed"
+    logit_scale: float = 1.0
+
+    #: the parameters :meth:`forward` reads of the vertex ``tied_to``
+    TIED_PARAMS = ("W",)
+
+    def param_order(self) -> tuple:
+        return ()
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        return {}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        logits = _einsum_acc("btc,vc->btv", x, params["W"], _acc(x.dtype))
+        if self.logit_scale != 1.0:
+            logits = logits * jnp.asarray(self.logit_scale, logits.dtype)
+        return logits, state
 
 
 # ------------------------------------------------------------------ norm
@@ -139,6 +176,21 @@ class RMSNorm(BaseLayerConfig):
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         return rms_normalize(x, self.eps, params["gain"]).astype(x.dtype), \
             state
+
+
+@serde.register("layer_norm")
+@dataclasses.dataclass
+class LayerNorm(RMSNorm):
+    """``(x - mean(x)) / sqrt(var(x) + eps) * gain`` over the last axis,
+    float32 inside; a gain and no bias (Cohere's)."""
+
+    eps: float = 1e-5
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        xf = x.astype(_acc(x.dtype))
+        centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        return rms_normalize(centred, self.eps,
+                             params["gain"]).astype(x.dtype), state
 
 
 # ---------------------------------------------------------- feed-forward
@@ -173,7 +225,10 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     ``y = sum_i w_i E_i(x) + E_shared(x)``.  No token is dropped.
     ``scoring="softmax"`` scores with ``g = softmax(x Wr)`` over all
     ``n_experts`` instead (the Qwen3-MoE family's router: no bias drawn,
-    no scaling, ``n_shared`` 0).
+    no scaling, ``n_shared`` 0).  ``n_shared`` shared experts lie side by
+    side in ``Sg``/``Su``/``Sd`` (``n_shared x width`` wide: their sum is
+    one gated product); ``shared_combine="average"`` adds their mean
+    instead of their sum (Cohere's), one factor on that product.
 
     ``experts_held`` (default: all) says which experts this layer holds:
     it routes over all ``n_experts`` and computes the held experts' part
@@ -207,6 +262,7 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     router_bias_std: float = 0.0
     experts_held: Optional[List[int]] = None
     scoring: str = "sigmoid"
+    shared_combine: str = "sum"
 
     def held(self) -> List[int]:
         return (list(range(self.n_experts)) if self.experts_held is None
@@ -304,7 +360,14 @@ class MixtureOfExperts(FeedForwardLayerConfig):
                 y = dense_experts(x, combine, *matrices)
         if self.n_shared:
             with _monitor.subscope("shared"):
-                y = y + _gated(x, params["Sg"], params["Su"], params["Sd"])
+                shared = _gated(x, params["Sg"], params["Su"], params["Sd"])
+                if self.shared_combine == "average":
+                    shared = shared * jnp.asarray(1.0 / self.n_shared,
+                                                  shared.dtype)
+                elif self.shared_combine != "sum":
+                    raise ValueError(f"shared experts combine by 'sum' or "
+                                     f"'average', not {self.shared_combine!r}")
+                y = y + shared
         return y.reshape(shape), {"expert_tokens": counts,
                                   "experts_spilled": spilled}
 
@@ -706,6 +769,152 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
         out, _ = self.forward_seq(
             params, x, self.init_carry(x.shape[0], x.dtype, x.shape[1]),
             train=train, rng=rng, mask=mask)
+        return out, state
+
+
+@serde.register("grouped_query_attention")
+@dataclasses.dataclass
+class GroupedQueryAttention(BaseRecurrentLayer):
+    """Dense grouped-query attention over a key/value ring, causal, with
+    a window or none and rotary or none (Cohere2's two kinds of layer).
+
+    ``q = x Wq`` (``n_heads`` of ``head_dim``), ``k = x Wk``, ``v = x Wv``
+    (``n_kv_heads`` each; query head ``h`` reads key/value head ``h //
+    (n_heads / n_kv_heads)``), no bias, no norm on ``q`` or ``k``.  With
+    ``rotary`` both are turned over the interleaved pairs ``(2i, 2i+1)``
+    of the whole head (``rope_theta``, no scaling); without, the layer
+    applies no positional embedding.  Query ``t`` sees the positions
+    ``s <= t`` and, under ``window``, ``s > t - window`` (``window`` keys
+    with its own); softmax of ``q . k / sqrt(head_dim)`` in float32.
+
+    The carry is ``(key/value ring (batch, capacity, 2 x n_kv_heads,
+    head_dim), cursor)``, the sparse layer's slots-major joined ring.
+    Without a window the ring grows with the session (``cache_len``,
+    the serving ladder, ``grow_carry``) and ``STATE_KIND`` is ``"kv"``.
+    With one the ring WRAPS: position ``p`` lives in slot ``p mod
+    capacity``, the capacity is the layer's own whatever ``cache_len`` it
+    is handed (``window + chunk - 1`` slots in whole blocks,
+    ``ops.attention.window_ring_slots``: a chunk of up to ``chunk``
+    positions is written before it is read), it never grows, a session's
+    position may pass it by any amount, and ``STATE_KIND`` is
+    ``"window_kv"``.  One path serves prefill chunks, single steps and
+    ``output()`` (from a zero ring as long as the sequence); the form the
+    attention takes is ``ops.attention.gqa_attention_path``'s, from the
+    call's shapes alone.
+    """
+
+    HAS_KV_RING = True
+
+    activation: str = "identity"
+    n_heads: int = 1
+    n_kv_heads: int = 1
+    head_dim: int = 0
+    window: Optional[int] = None
+    rotary: bool = True
+    rope_theta: float = 10000.0
+    cache_len: int = 128
+    chunk: int = 256
+
+    @property
+    def STATE_KIND(self) -> str:    # serving_session_state_bytes{kind=}
+        return "window_kv" if self.window else "kv"
+
+    @property
+    def RING_GROWS(self) -> bool:
+        """Whether the ring's capacity follows the session's length (the
+        serving ladder and its overflow checks are about such rings)."""
+        return not self.window
+
+    def param_order(self) -> tuple:
+        return ("Wq", "Wk", "Wv", "Wo")
+
+    def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
+        h, g, d, c = self.n_heads, self.n_kv_heads, self.head_dim, self.n_in
+        kq, kk, kv, ko = jax.random.split(rng, 4)
+        return {"Wq": _matrix(self, kq, (c, h * d), dtype),
+                "Wk": _matrix(self, kk, (c, g * d), dtype),
+                "Wv": _matrix(self, kv, (c, g * d), dtype),
+                "Wo": _matrix(self, ko, (h * d, self.n_out), dtype)}
+
+    # -------------------------------------------------------------- carry
+    def _ring(self, batch: int, dtype, slots: int):
+        return (jnp.zeros((batch, slots, 2 * self.n_kv_heads, self.head_dim),
+                          dtype), jnp.zeros((), jnp.int32))
+
+    def init_carry(self, batch: int, dtype, cache_len: Optional[int] = None):
+        if self.window:
+            return self._ring(batch, dtype,
+                              window_ring_slots(self.window, self.chunk))
+        cap = int(cache_len if cache_len is not None else self.cache_len)
+        if cap < 1:
+            raise ValueError("cache_len must be >= 1")
+        return self._ring(batch, dtype, cap)
+
+    def grow_carry(self, carry, cache_len: int):
+        if self.window:         # sized once, at its window
+            return carry
+        ring, cursor = carry
+        cap = ring.shape[1]
+        if cache_len < cap:
+            raise ValueError(
+                f"cannot shrink the key/value ring from {cap} to {cache_len}")
+        return jnp.pad(ring, [(0, 0), (0, cache_len - cap),
+                              (0, 0), (0, 0)]), cursor
+
+    # ------------------------------------------------------------ forward
+    def _attend(self, params, x, carry, mask=None):
+        ring, cursor = carry
+        (b, t), h, d = x.shape[:2], self.n_heads, self.head_dim
+        flat = x.reshape(b * t, -1)
+        # behind a barrier: the products leave their results as plain
+        # (tokens, features) arrays.  Without it the TPU's compiler takes
+        # the layout the attention kernel wants of its queries back
+        # through the product and turns Wq, Wk and Wv inside every step
+        # (604 MB read and written a token step at 128 heads over
+        # hidden 4,096; ``tools/step_copies.py``)
+        q, k, v = jax.lax.optimization_barrier(tuple(
+            flat @ params[w] for w in ("Wq", "Wk", "Wv")))
+        q, k, v = q.reshape(b, t, h, d), k.reshape(b, t, -1), \
+            v.reshape(b, t, -1)
+        if self.rotary:
+            positions = cursor + jnp.arange(t, dtype=jnp.int32)
+            inv_freq, _ = yarn_inv_freq(d, self.rope_theta, None)
+            q = rotate(q, positions, inv_freq)
+            k = rotate(k.reshape(b, t, self.n_kv_heads, d), positions,
+                       inv_freq).reshape(k.shape)
+        ring = gqa_ring_update(ring, cursor, k, v, wraps=bool(self.window))
+        with _monitor.subscope("window_attention" if self.window
+                               else "full_attention"):
+            ctx = gqa_ring_attention(q, ring, cursor, sm_scale=d ** -0.5,
+                                     window=self.window or None)
+        out = self._activate(ctx.reshape(b, t, h * d) @ params["Wo"])
+        if mask is not None:
+            out = out * mask[..., None].astype(out.dtype)
+        return out, (ring, cursor + jnp.asarray(t, jnp.int32))
+
+    def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
+        t, cap = x.shape[1], carry[0].shape[1]
+        if t > (cap - self.window + 1 if self.window else cap):
+            raise ValueError(
+                f"chunk of {t} timesteps exceeds what the key/value ring "
+                f"of {cap} slots takes at once"
+                + (f" under a window of {self.window}" if self.window
+                   else ""))
+        return self._attend(params, x, carry, mask)
+
+    def attention_path(self, t: int, carry) -> str:
+        """``"streamed"`` or ``"masked"``: the form ``forward_seq`` takes
+        for ``t`` new positions against ``carry``, by the op's own
+        predicate (host code asks it without tracing the step)."""
+        ring = carry[0]
+        return gqa_attention_path(t, self.n_heads, self.n_kv_heads,
+                                  self.head_dim, ring.shape[1], ring.dtype)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        # the whole sequence at once: a ring as long as it, which never
+        # wraps, under the same rule of visibility
+        out, _ = self._attend(
+            params, x, self._ring(x.shape[0], x.dtype, x.shape[1]), mask)
         return out, state
 
 

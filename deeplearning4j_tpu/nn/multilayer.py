@@ -604,10 +604,11 @@ class MultiLayerNetwork(Network):
                    for layer in self.layers)
 
     def max_cache_len(self) -> int:
-        """Largest KV-ring capacity across layers (0 without rings) —
-        the top of the serving cache-len bucket ladder."""
+        """Largest capacity of the KV rings that grow with a session (0
+        without any) — the top of the serving cache-len bucket ladder."""
         return max((int(layer.cache_len) for layer in self.layers
-                    if getattr(layer, "HAS_KV_RING", False)), default=0)
+                    if getattr(layer, "HAS_KV_RING", False)
+                    and getattr(layer, "RING_GROWS", True)), default=0)
 
     # ------------------------------------------------------------- inference
     def output(self, features, train: bool = False,

@@ -1382,6 +1382,37 @@ def _head_rows(kv_ref, row: int, rows: int, block: int, words: bool):
         kv_ref.dtype)
 
 
+def _fold_ring_block(dot, q_at, kv_ref, keep, m_scr, l_scr, acc_scr, *,
+                     kv_heads: int, rows: int, part: int, block: int,
+                     words: bool, scale):
+    """One block of a joined key/value ring folded into the streaming
+    softmax of every query head: what the streamed kernels over such a
+    ring share.  ``kv_ref`` is the (1, block x 2 x kv heads, d) block in
+    VMEM, ``q_at((head, rows))`` the queries of a key/value head (``rows``
+    of them, folded ``part`` at a time), ``keep`` (part, block) which
+    slots those rows count; the scratches are (kv heads, rows, 128 | d)
+    float32.  A row that has kept nothing yet holds its maximum at the
+    mask's value: its entries are not exp(0)."""
+    masked = np.float32(_NEG_INF)
+    for g in range(kv_heads):
+        k, v = (_head_rows(kv_ref, row, 2 * kv_heads, block, words)
+                for row in (g, kv_heads + g))               # (block, d)
+        for start in range(0, rows, part):
+            at = (g, pl.ds(start, part))
+            s = dot(q_at(at), k, ((1,), (1,))) * scale
+            s = jnp.where(keep, s, masked)
+            m_prev, l_prev = m_scr[at][:, :1], l_scr[at][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alive = m_new > masked / 2
+            p = jnp.where(alive & keep, jnp.exp(s - m_new), 0.0)
+            correction = jnp.where(alive, jnp.exp(m_prev - m_new), 0.0)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[at] = acc_scr[at] * correction + dot(
+                p.astype(v.dtype), v, ((1,), (0,)))
+            m_scr[at] = jnp.broadcast_to(m_new, (part, 128))
+            l_scr[at] = jnp.broadcast_to(l_new, (part, 128))
+
+
 def sparse_attention_streamed(q: Array, kv_ring: Array, selected: Array,
                               cursor, *, sm_scale: float,
                               block: Optional[int] = None,
@@ -1430,28 +1461,10 @@ def sparse_attention_streamed(q: Array, kv_ring: Array, selected: Array,
             keep = keep_ref[0].astype(jnp.float32) != 0.0   # (t, block)
             if part != t:
                 keep = jnp.broadcast_to(keep, (part, block))
-            for g in range(kv_heads):
-                k, v = (_head_rows(kv_ref, row, 2 * kv_heads, block, words)
-                        for row in (g, kv_heads + g))       # (block, d)
-                for start in range(0, rows, part):
-                    at = (g, pl.ds(start, part))
-                    s = dot(q_ref[(0,) + at], k, ((1,), (1,))) * scale
-                    s = jnp.where(keep, s, masked)
-                    m_prev, l_prev = m_scr[at][:, :1], l_scr[at][:, :1]
-                    m_new = jnp.maximum(
-                        m_prev, jnp.max(s, axis=-1, keepdims=True))
-                    # a query that has selected nothing yet keeps m at
-                    # the mask's value: its entries are not exp(0)
-                    alive = m_new > masked / 2
-                    p = jnp.where(alive & keep, jnp.exp(s - m_new), 0.0)
-                    correction = jnp.where(alive, jnp.exp(m_prev - m_new),
-                                           0.0)
-                    l_new = l_prev * correction + jnp.sum(
-                        p, axis=-1, keepdims=True)
-                    acc_scr[at] = acc_scr[at] * correction + dot(
-                        p.astype(v.dtype), v, ((1,), (0,)))
-                    m_scr[at] = jnp.broadcast_to(m_new, (part, 128))
-                    l_scr[at] = jnp.broadcast_to(l_new, (part, 128))
+            _fold_ring_block(dot, lambda at: q_ref[(0,) + at], kv_ref, keep,
+                             m_scr, l_scr, acc_scr, kv_heads=kv_heads,
+                             rows=rows, part=part, block=block, words=words,
+                             scale=scale)
 
         @pl.when(ki == num_blocks - 1)
         def _finalize():
@@ -1724,3 +1737,283 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
         if path == "gathered":      # the mask as (slot numbers, count)
             picked = selected_slots(picked[:, 0], topk)
     return attend(picked)
+
+
+# ---------------------------------------------------------------------------
+# Dense grouped-query attention over a joined key/value ring (the layout
+# above: (batch, capacity, 2 x kv heads, d)), causal over a ring that grows
+# or over the last ``window`` positions of a ring that WRAPS: position
+# ``p`` lives in slot ``p mod capacity``, which for a ring that has not
+# wrapped (a growing ring never does: ``cursor + T <= capacity``) is slot
+# ``p``, so one rule of visibility serves both.  With ``last = cursor + T -
+# 1`` the newest position written, slot ``c`` holds position ``base + c``
+# where ``c <= last mod capacity`` and ``base + c - capacity`` elsewhere
+# (``base = last - last mod capacity``, the position of slot 0 in the
+# current lap; a negative position is a slot never written).  Query ``i``
+# of the call stands at ``cursor + i`` and sees the positions ``p`` with
+# ``0 <= p <= cursor + i`` and, under a window, ``p > cursor + i -
+# window``.  A chunk is written before it is read, so a window ring holds
+# ``window + chunk - 1`` slots or more (``window_ring_slots``): the oldest
+# position the chunk's first query sees is not yet overwritten by its last.
+# Two forms under ``gqa_ring_attention``, picked by ``gqa_attention_path``
+# from the call's shapes alone: *streamed*, one Pallas kernel that brings
+# the blocks holding a visible position through VMEM once (a TPU, the token
+# step and prefill chunks), and *masked*, the rule as a mask over
+# ``sparse_attention_masked`` (any dtype, any backend; the kernel's oracle).
+# ---------------------------------------------------------------------------
+
+#: query rows of a key/value head a grid step of the streamed form folds
+#: (a chunk's positions are taken in tiles of this many rows over the
+#: group's heads: each tile streams the ring once)
+_GQA_QUERY_ROWS = 1024
+
+
+def window_ring_slots(window: int, chunk: int) -> int:
+    """Slots of a ring that serves a ``window`` under chunks of up to
+    ``chunk`` positions written before they are read: ``window + chunk -
+    1``, rounded up to whole blocks of the streamed form, the block the
+    largest of which eight still span the ring (4,608 slots in blocks of
+    512 for a window of 4,096 under chunks of 256: on a v5e a token
+    step's grid step costs about 2.4 us whatever it folds, which 256
+    slots, 1.3 us of bytes, do not hide; PERF.md, PR 41), and 128 at the
+    least."""
+    need = int(window) + max(int(chunk), 1) - 1
+    block = next((b for b in _SPARSE_BLOCKS if 8 * b <= need),
+                 _SPARSE_BLOCKS[-1])
+    return -(-need // block) * block
+
+
+def gqa_ring_update(kv_ring: Array, cursor, k_new: Array, v_new: Array, *,
+                    wraps: bool = False) -> Array:
+    """Write (batch, T, kv heads x d) keys and values into the ring
+    (batch, capacity, 2 x kv heads, d) at positions ``cursor .. cursor + T
+    - 1``.  ``wraps``: position ``p`` goes to slot ``p mod capacity`` (a
+    chunk may straddle the end: its rows are scattered; a single position
+    is one slice); otherwise callers guarantee ``cursor + T <=
+    capacity``."""
+    cap = kv_ring.shape[1]
+    zero = jnp.zeros((), jnp.int32)
+    cursor = jnp.asarray(cursor, jnp.int32)
+    by_head = lambda a: a.reshape(a.shape[:2] + (-1, kv_ring.shape[3]))
+    rows = jnp.concatenate([by_head(k_new), by_head(v_new)],
+                           axis=2).astype(kv_ring.dtype)
+    t = rows.shape[1]
+    if wraps and t > 1:
+        slots = (cursor + jnp.arange(t, dtype=jnp.int32)) % cap
+        return kv_ring.at[:, slots].set(rows, unique_indices=True)
+    start = cursor % cap if wraps else cursor
+    return jax.lax.dynamic_update_slice(kv_ring, rows,
+                                        (zero, start, zero, zero))
+
+
+def _lap(cursor, t: int, capacity: int):
+    """``(base, newest slot)`` of a ring whose newest position is
+    ``cursor + t - 1``: slot ``c`` holds ``base + c`` up to the newest
+    slot and ``base + c - capacity`` beyond it."""
+    last = jnp.asarray(cursor, jnp.int32) + jnp.int32(t - 1)
+    newest = last % jnp.int32(capacity)
+    return last - newest, newest
+
+
+def ring_visible(cursor, t: int, capacity: int,
+                 window: Optional[int] = None) -> Array:
+    """(T, capacity) bool: which slots of a ring written up to position
+    ``cursor + T - 1`` query ``i`` (at ``cursor + i``) sees: the rule at
+    the head of this section."""
+    base, newest = _lap(cursor, t, capacity)
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    held = base + slot - jnp.where(slot > newest, jnp.int32(capacity), 0)
+    at = (jnp.asarray(cursor, jnp.int32)
+          + jnp.arange(t, dtype=jnp.int32))[:, None]
+    keep = (held >= 0)[None, :] & (held[None, :] <= at)
+    if window is not None:
+        keep = keep & (held[None, :] > at - jnp.int32(window))
+    return keep
+
+
+def gqa_ring_attention_masked(q: Array, kv_ring: Array, cursor, *,
+                              sm_scale: float,
+                              window: Optional[int] = None) -> Array:
+    """The plain form: (batch, T, heads, d) queries against every slot of
+    the ring, the rule of visibility as a mask.  Any dtype, any backend;
+    the scores are one (batch, heads, T, capacity) array."""
+    keep = ring_visible(cursor, q.shape[1], kv_ring.shape[1], window)
+    return sparse_attention_masked(
+        q, kv_ring, jnp.broadcast_to(keep[None], (q.shape[0],) + keep.shape),
+        sm_scale=sm_scale)
+
+
+def _gqa_query_tile(t: int, group: int) -> int:
+    """Positions a grid step of the streamed form takes: all of a token
+    step's one, else the most whole sublane tiles of 8 that divide ``t``
+    within ``_GQA_QUERY_ROWS`` rows of a key/value head."""
+    if t == 1:
+        return 1
+    fits = [n for n in range(8, t + 1, 8)
+            if t % n == 0 and n * group <= _GQA_QUERY_ROWS]
+    return max(fits, default=8)
+
+
+def gqa_attention_path(t: int, heads: int, kv_heads: int, d: int,
+                       capacity: int, dtype) -> str:
+    """``"streamed"`` or ``"masked"``: which form :func:`gqa_ring_attention`
+    takes for ``t`` new positions a row against a ring of ``capacity``
+    slots stored in ``dtype``.  Streamed where Mosaic compiles the kernel
+    (a TPU), the storage is bfloat16 or float32, a head fills the 128
+    lanes, the new positions are fewer than the ring's slots (``output()``
+    from a zero ring is plain attention and stays masked; more than one
+    are padded to whole sublane tiles of 8) and a block divides the
+    capacity.  A window does not enter: the kernel serves both rules.
+    Also what ``gqa_attention_steps_total{path}`` is labelled by."""
+    streamed = (_mosaic()
+                and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                         jnp.dtype(jnp.float32))
+                and d % 128 == 0 and t < capacity
+                and heads % kv_heads == 0
+                and sparse_ring_block(capacity) > 0)
+    return "streamed" if streamed else "masked"
+
+
+def gqa_ring_attention_streamed(q: Array, kv_ring: Array, cursor, *,
+                                sm_scale: float,
+                                window: Optional[int] = None,
+                                written: Optional[int] = None,
+                                block: Optional[int] = None,
+                                interpret: Optional[bool] = None) -> Array:
+    """:func:`gqa_ring_attention_masked` as one Pallas kernel that reads
+    each conversation's visible blocks once: grid (batch, query tiles,
+    ring blocks).  A step takes one block of the ring, every slot's key
+    and value heads (whole tiles: one contiguous read), works out which
+    of its slots each query sees from the cursor alone (no mask is read)
+    and folds it into the streaming softmax of every query head
+    (``_fold_ring_block``, ``sparse_attention_streamed``'s).  The blocks
+    are taken in the order of the positions they hold, from the one with
+    the oldest position any query of the call sees (block 0 without a
+    window; under a window the ring may have wrapped, and the walk wraps
+    with it) to the one with the newest; the blocks before and beyond are
+    neither fetched nor computed.  ``T`` is 1 or a multiple of 8;
+    ``written`` (default ``T``) says how many of the queries' positions
+    the ring holds, where the last queries are padding."""
+    batch, t, heads, d = q.shape
+    written = t if written is None else int(written)
+    cap, kv_heads = kv_ring.shape[1], kv_ring.shape[2] // 2
+    group = heads // kv_heads
+    block = block or sparse_ring_block(cap)
+    if not block or cap % block:
+        raise ValueError(f"no block of the streamed attention divides a "
+                         f"ring of {cap} slots (block {block})")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    num_blocks = cap // block
+    scale, masked = np.float32(sm_scale), np.float32(_NEG_INF)
+    tile = _gqa_query_tile(t, group)
+    tiles = t // tile
+    part = group if t == 1 else tile
+    rows = group * tile
+    words = not interpret and kv_ring.dtype == jnp.bfloat16
+
+    cursor = jnp.asarray(cursor, jnp.int32)
+    base, newest = _lap(cursor, written, cap)
+    # the oldest position any query sees, the slots from there to the
+    # newest one, and so the first block and how many the walk takes
+    oldest = (jnp.zeros((), jnp.int32) if window is None
+              else jnp.maximum(cursor - jnp.int32(window - 1), 0))
+    first_slot = oldest % jnp.int32(cap)
+    first = first_slot // jnp.int32(block)
+    span = (first_slot - first * jnp.int32(block)
+            + (cursor + written - oldest))
+    walk = jnp.minimum((span - 1) // jnp.int32(block), num_blocks - 1)
+    scalars = jnp.stack([cursor, base, newest, first, walk])
+
+    def ring_block(k, s):
+        """The ring block of grid step ``k``: the walk's, or its last
+        one again (a repeat is not fetched)."""
+        return (s[3] + jnp.minimum(k, s[4])) % jnp.int32(num_blocks)
+
+    def kernel(s_ref, q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr):
+        qi, ki = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr[:], masked)
+            l_scr[:] = jnp.zeros_like(l_scr[:])
+            acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+        @pl.when(ki <= s_ref[4])
+        def _fold():
+            slot = (ring_block(ki, s_ref) * block
+                    + jax.lax.broadcasted_iota(jnp.int32, (part, block), 1))
+            held = s_ref[1] + slot - jnp.where(slot > s_ref[2],
+                                               jnp.int32(cap), 0)
+            at = s_ref[0] + qi * tile
+            if t > 1:
+                at = at + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (part, block), 0)
+            keep = (held >= 0) & (held <= at)
+            if window is not None:
+                keep = keep & (held > at - jnp.int32(window))
+            _fold_ring_block(dot, lambda at_: q_ref[(0, 0) + at_], kv_ref,
+                             keep, m_scr, l_scr, acc_scr, kv_heads=kv_heads,
+                             rows=rows, part=part, block=block, words=words,
+                             scale=scale)
+
+        @pl.when(ki == num_blocks - 1)
+        def _finalize():
+            o_ref[0, 0] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
+
+    whole = lambda b, i, k, s: (b, i, 0, 0, 0)
+    # (batch, tiles, kv heads, group x tile, d): a head's rows, query head
+    # major within a tile of positions
+    qg = jnp.transpose(
+        _grouped(q, kv_heads).reshape(batch, tiles, tile, kv_heads, group, d),
+        (0, 1, 3, 4, 2, 5)).reshape(batch, tiles, kv_heads, rows, d)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=_sds(qg.shape, q.dtype, q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, tiles, num_blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, kv_heads, rows, d), whole),
+                pl.BlockSpec((1, block * 2 * kv_heads, d),
+                             lambda b, i, k, s: (b, ring_block(k, s), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, kv_heads, rows, d), whole),
+            scratch_shapes=[
+                pltpu.VMEM((kv_heads, rows, 128), jnp.float32),  # max
+                pltpu.VMEM((kv_heads, rows, 128), jnp.float32),  # denom
+                pltpu.VMEM((kv_heads, rows, d), jnp.float32),    # sum
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(scalars, qg, kv_ring.reshape(batch, cap * 2 * kv_heads, d))
+    out = out.reshape(batch, tiles, kv_heads, group, tile, d)
+    return jnp.transpose(out, (0, 1, 4, 2, 3, 5)).reshape(q.shape)
+
+
+def gqa_ring_attention(q: Array, kv_ring: Array, cursor, *, sm_scale: float,
+                       window: Optional[int] = None) -> Array:
+    """Attention of (batch, T, heads, d) queries at positions ``cursor ..
+    cursor + T - 1`` over a joined key/value ring that already holds
+    them: causal, and within the last ``window`` positions (the query's
+    own counted) where one is given; the context, (batch, T, heads, d).
+    Two forms of one rule, chosen by :func:`gqa_attention_path` from the
+    arguments alone."""
+    batch, t, heads, d = q.shape
+    path = gqa_attention_path(t, heads, kv_ring.shape[2] // 2, d,
+                              kv_ring.shape[1], kv_ring.dtype)
+    if path == "masked":
+        return gqa_ring_attention_masked(q, kv_ring, cursor,
+                                         sm_scale=sm_scale, window=window)
+    if t > 1 and t % 8:
+        # whole sublane tiles of positions: a chunk of another length (a
+        # prompt's remainder) is padded with queries whose rows are cut
+        # off again; the ring holds ``t`` positions, and the padded
+        # queries stand beyond its newest and are nobody's
+        q = jnp.pad(q, [(0, 0), (0, -t % 8), (0, 0), (0, 0)])
+    return gqa_ring_attention_streamed(
+        q, kv_ring, cursor, sm_scale=sm_scale, window=window,
+        written=t)[:, :t]

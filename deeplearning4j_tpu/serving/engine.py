@@ -702,7 +702,10 @@ class InferenceEngine:
             raise ValueError(f"expected {self._n_inputs} example shapes, "
                              f"got {len(shapes)}")
         from .bucketing import batch_ladder
-        ladder = batch_ladder(model.max_cache_len())
+        # the ladder of the rings that grow; one bucket, the layers' own
+        # sizes, where none does (window rings alone)
+        longest = model.max_cache_len()
+        ladder = batch_ladder(longest) if longest else (None,)
         prefix = "cg" if self._is_graph else "mln"
         fns = ((prefix + ".decode_step_int8",) if self._qdecode is not None
                else (prefix + ".decode_step",)) + (prefix + ".decode_grow",)
@@ -718,7 +721,7 @@ class InferenceEngine:
                 feats = tuple(np.zeros((bb, t) + shp, self._dtype)
                               for shp in shapes)
                 for i, cap in enumerate(ladder):
-                    if t > cap:
+                    if cap is not None and t > cap:
                         continue
                     carries = model._init_carries(bb, cache_len=cap)
                     if self._qdecode is not None:

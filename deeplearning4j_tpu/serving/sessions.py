@@ -29,6 +29,15 @@ whatever the model's carry contract says it is:
   compile-free.  The host never reads the device cursor — position
   accounting is pure host arithmetic, so no sync point enters the hot
   path.
+- **Capacity is about the rings that grow.**  A session's ``capacity``,
+  the ladder, ``prefill(cache_len=)`` and the "does not fit" checks
+  speak of the rings whose length follows the session's (latent rings,
+  key/value rings of full attention, ``sparse_kv``).  A window layer's
+  ring (``GroupedQueryAttention(window=...)``, kind ``window_kv``) is
+  sized once by the layer at its window, wraps, and is neither grown
+  by a hop nor asked whether a prompt fits: a session's position
+  passes it by any amount.  A model whose rings are all windows has an
+  empty ladder and no limit on a session's length.
 
 Eviction (both counted in ``serving_session_evictions_total``):
 
@@ -185,7 +194,9 @@ class SessionCache:
                                     lambda: False)())
         # ring state by kind, as the layers name theirs (``STATE_KIND``:
         # ``latent`` rings of compressed rows, ``sparse_kv`` key/value
-        # rings with an indexer's beside them): ``{kind: [vertices]}``
+        # rings with an indexer's beside them, ``kv`` key/value rings of
+        # full attention, ``window_kv`` a window's rings, which wrap):
+        # ``{kind: [vertices]}``
         self._ring_kinds: dict = {}
         if self._decode and self._is_graph:
             for n in model._layer_names():
@@ -194,8 +205,9 @@ class SessionCache:
                     self._ring_kinds.setdefault(kind, []).append(n)
         self._scenario = ("serving.decode_step" if self._decode
                           else "serving.rnn_step")
-        self._cache_ladder = (batch_ladder(model.max_cache_len())
-                              if self._decode else ())
+        # the ladder of the rings that grow; empty where none does
+        longest = model.max_cache_len() if self._decode else 0
+        self._cache_ladder = batch_ladder(longest) if longest else ()
 
     # ------------------------------------------------------------- metrics
     # Refreshed when the session SET changes (create/evict/clear), not
@@ -402,7 +414,7 @@ class SessionCache:
         """The ladder bucket this chunk needs, or 0 when the current
         ring already fits.  Raises past the top of the ladder."""
         need = sess.position + steps
-        if need <= sess.capacity:
+        if need <= sess.capacity or not self._cache_ladder:
             return 0
         for cap in self._cache_ladder:
             if cap >= need and cap > sess.capacity:
@@ -446,11 +458,10 @@ class SessionCache:
         batch, total = int(ids.shape[0]), int(ids.shape[1])
         chunk = int(chunk or total or 1)
         sess = self._acquire(session_id, batch, total,
-                             capacity=int(cache_len
-                                          or self._cache_ladder[-1]))
+                             capacity=int(cache_len or self._top()))
         with sess.lock:
             self._check_state(session_id, sess, batch)
-            if sess.position + total > sess.capacity:
+            if self._overflows(sess, total):
                 raise SessionError(
                     f"session {session_id!r} holds {sess.position} tokens "
                     f"in a ring of {sess.capacity}; {total} more do not fit")
@@ -468,8 +479,9 @@ class SessionCache:
             chunks = Counter(hi - lo for lo, hi in zip(bounds, bounds[1:]))
             self._count_expert_steps(batch, chunks)
             sess.position += total
-            self._count_attention_steps(sess, chunks, ("sparse_kv",),
-                                        pinned=bool(kw))
+            self._count_attention_steps(
+                sess, chunks, ("sparse_kv", "kv", "window_kv"),
+                pinned=bool(kw))
             sess.steps += 1
             sess.last_used = time.monotonic()
             return sess.position
@@ -519,11 +531,11 @@ class SessionCache:
             raise SessionError("max_new_tokens must be at least 1")
         batch, fed = int(ids.shape[0]), int(ids.shape[1])
         sess = self._acquire(session_id, batch, fed + n - 1,
-                             capacity=self._cache_ladder[-1])
+                             capacity=self._top())
         model = self._model
         with sess.lock, _monitor.span("serve/generate", tokens=n):
             self._check_state(session_id, sess, batch)
-            if sess.position + fed + n - 1 > sess.capacity:
+            if self._overflows(sess, fed + n - 1):
                 raise SessionError(
                     f"session {session_id!r} holds {sess.position} tokens "
                     f"in a ring of {sess.capacity}; {fed + n - 1} more do "
@@ -556,9 +568,9 @@ class SessionCache:
             n, model=self._name)
         # the first step takes the ``fed`` ids, every later one its own
         by_length = Counter([fed] + [1] * (n - 1))
-        self._count_attention_steps(sess, by_length,
-                                    ("latent", "sparse_kv"),
-                                    pinned=bool(kw))
+        self._count_attention_steps(
+            sess, by_length, ("latent", "sparse_kv", "kv", "window_kv"),
+            pinned=bool(kw))
         self._count_expert_steps(batch, by_length)
         tokens = _monitor.counter(
             "moe_expert_tokens_total",
@@ -606,7 +618,14 @@ class SessionCache:
         session's position (already advanced by the caller) reads."""
         model = self._model
         laid = () if pinned else model.laid_vertices()
+        dense = _monitor.counter(
+            "gqa_attention_steps_total",
+            "launched token steps and prefill chunks, by the kind of ring "
+            "their dense grouped-query attention reads (kv: one that "
+            "grows; window_kv: a window's, which wraps) and the form it "
+            "took")
         counters = {
+            "kv": dense, "window_kv": dense,
             "latent": _monitor.counter(
                 "latent_attention_steps_total",
                 "launched token steps, by the form their latent attention "
@@ -627,6 +646,8 @@ class SessionCache:
                         t, sess.carries[v])}
                     if kind == "latent":
                         form["weights"] = "laid" if v in laid else "stored"
+                    elif launched is dense:
+                        form["kind"] = kind
                     forms.add(tuple(form.items()))
                 for form in forms:
                     launched.inc(steps, **dict(form))
@@ -661,6 +682,17 @@ class SessionCache:
                          for layer in experts}:
                 launched.inc(steps, path=path)
 
+    def _top(self) -> int:
+        """The longest the rings that grow may get (0: none grows)."""
+        return self._cache_ladder[-1] if self._cache_ladder else 0
+
+    def _overflows(self, sess: _Session, more: int) -> bool:
+        """Whether ``more`` positions would pass the capacity of the
+        session's rings that grow (never, where none does: a window's
+        ring wraps)."""
+        return bool(self._cache_ladder) \
+            and sess.position + more > sess.capacity
+
     def _acquire(self, session_id: str, batch: int,
                  steps: int = 1, capacity: int = 0) -> _Session:
         now = time.monotonic()
@@ -672,7 +704,7 @@ class SessionCache:
                 while len(self._sessions) >= self._max_sessions:
                     self._sessions.popitem(last=False)   # LRU out
                     self._count_eviction("capacity")
-                if self._decode and not capacity:
+                if self._cache_ladder and not capacity:
                     capacity = self._cache_ladder[0]
                     for cap in self._cache_ladder:
                         if cap >= steps:

@@ -54,7 +54,8 @@ TOY = chip_smoke.Sizes(
     latent_shape=(2, 8, 128, 16, 256, 200),
     experts_shape=(40, 8, 32, 64, 2),
     experts_share_shape=(32, 24, 4, 32, 64, 3),
-    sparse_shape=(2, 4, 2, 16, 3, 8, 256, 16, 8), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
+    sparse_shape=(2, 4, 2, 16, 3, 8, 256, 16, 8),
+    gqa_shape=(2, 4, 2, 16, 8, 256, 8), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
 
 
 def test_train_then_serve():
