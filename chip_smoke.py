@@ -465,9 +465,10 @@ def _sparse_kernels(sz: Sizes) -> float:
     """The indexed sparse attention's kernels (bf16 rings) against the
     plain forms of the same arguments, for a token step's one position
     and a chunk's: the indexer's scores (float32 both ways), the
-    selection (the same rows), then the attention over them, streamed
-    through the mask and, for the token step, with the selected slots
-    fetched by the kernel's own descriptors.  All attentions round ``p``
+    selection (the same rows; for the token step also as the slot list
+    one kernel makes from the scores), then the attention over them,
+    streamed through the mask and, for the token step, with the
+    selected slots fetched by the kernel's own descriptors.  All attentions round ``p``
     to bf16 for the context product, the plain form after the division
     and the kernels before it."""
     import jax
@@ -496,6 +497,7 @@ def _sparse_kernels(sz: Sizes) -> float:
             jnp.where(visible, scored(q_idx, w_idx, i_ring), 0.0),
             jnp.where(visible, plain, 0.0)))
         select = jax.jit(lambda s: attention.select_mask(s, visible, topk))
+        plain_scores = plain
         selected = select(plain)
         _check(bool(jnp.all(jnp.sum(selected, axis=-1) == topk)),
                f"the selection of {topk} rows picked another number")
@@ -513,8 +515,18 @@ def _sparse_kernels(sz: Sizes) -> float:
         plain = masked(q, kv_ring, selected)
         worst = max(worst, _rel_err(streamed(q, kv_ring, selected), plain))
         if t == 1:
-            slots_of, count = jax.jit(lambda m: attention.selected_slots(
-                m[:, 0], topk))(selected)
+            # the token step's selection, the scores to the slot list in
+            # one kernel: the mask's slots, in rising order
+            listing = jax.jit(lambda s: attention.selected_slots_streamed(
+                s[:, 0], cursor, topk, interpret=sz.interpret))
+            _check_mosaic(listing.lower(plain_scores).as_text(),
+                          "selected_slots_streamed")
+            slots_of, count, _ = listing(plain_scores)
+            _check(bool(jnp.all(count == topk)) and all(
+                np.array_equal(np.asarray(slots_of[i]),
+                               np.nonzero(np.asarray(selected[i, 0]))[0])
+                for i in range(batch)),
+                "the slot list named other slots than the plain selection")
             gathered = jax.jit(
                 lambda *a: attention.sparse_attention_gathered(
                     *a, sm_scale=d ** -0.5, interpret=sz.interpret))

@@ -274,9 +274,15 @@ class ComputationGraph(Network):
                             layer.pre_output(own, x)
                             .astype(jnp.float32))
                     elif carries is not None and name in carries:
-                        out, new_carries[name] = layer.forward_seq(
+                        # a layer whose steps count what they did
+                        # returns its state too
+                        step = getattr(layer, "forward_seq_counted",
+                                       layer.forward_seq)
+                        out, new_carries[name], *counted = step(
                             own, x, carries[name], train=train,
                             rng=key_of[name], mask=mask)
+                        if counted:
+                            new_state[name] = counted[0]
                     else:
                         out, new_state[name] = layer.forward(
                             own, net_state[name], x, train=train,
@@ -508,6 +514,12 @@ class ComputationGraph(Network):
         return [n for n in self._layer_names()
                 if "expert_tokens" in self.net_state.get(n, {})]
 
+    def _selection_vertices(self) -> List[str]:
+        """Layer vertices whose state says whether a step's sparse
+        selection settled ties (``selection_tied``)."""
+        return [n for n in self._layer_names()
+                if "selection_tied" in self.net_state.get(n, {})]
+
     @functools.cached_property
     def _token_step_fn(self):
         """One step of token generation, sampling included: integer ids
@@ -519,7 +531,9 @@ class ComputationGraph(Network):
         a comparison with a reference reads), and ``counts`` plus this
         step's tokens by expert and, last in the row, whether its
         grouped experts spilled, one row a vertex of
-        ``_expert_vertices``.  The carries and the counts are donated:
+        ``_expert_vertices``; then a row a vertex of
+        ``_selection_vertices`` whose last column says whether its
+        selection settled ties.  The carries and the counts are donated:
         the rings are updated in place.  Like ``cg.prefill_step`` and
         ``cg.fork_state`` it says what it closes over, so the executable
         store serves a warm start with it."""
@@ -535,6 +549,10 @@ class ComputationGraph(Network):
             picks = [jnp.append(new_state[n]["expert_tokens"],
                                 new_state[n]["experts_spilled"])
                      for n in self._expert_vertices()]
+            # the rare branch of each step, in the last column
+            picks += [jnp.zeros(counts.shape[1], jnp.int32).at[-1].set(
+                new_state[n]["selection_tied"])
+                for n in self._selection_vertices()]
             if picks:
                 counts = counts + jnp.stack(picks)
             return next_ids, kept, counts, new_carries
@@ -964,8 +982,9 @@ class ComputationGraph(Network):
         vocabulary) float32 of rows 0 and batch-1, tokens by expert,
         new carries)``, all device arrays, ONE dispatch.  ``ids`` is
         (batch, time); ``carries`` and ``counts`` are consumed (donated).
-        ``counts`` is ``(len(_expert_vertices()), n_experts + 1)`` int32 or
-        None to start from zero."""
+        ``counts`` is ``(len(_expert_vertices()) +
+        len(_selection_vertices()), n_experts + 1)`` int32 or None to
+        start from zero."""
         self.init()
         if counts is None:
             counts = self.zero_expert_counts()
@@ -977,6 +996,7 @@ class ComputationGraph(Network):
     def zero_expert_counts(self):
         rows = [self.net_state[n]["expert_tokens"].shape[0] + 1
                 for n in self._expert_vertices()]
+        rows += [1] * len(self._selection_vertices())
         return jnp.zeros((len(rows), max(rows, default=0)), jnp.int32)
 
     def prefill_step(self, carries, ids, params=None, net_state=None):

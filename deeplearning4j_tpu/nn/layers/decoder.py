@@ -725,7 +725,21 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
              + params["kI_bias"].astype(k.dtype))
         return q_idx, x @ params["Ww"], turn(k.astype(x.dtype))
 
+    def init_state(self, dtype=jnp.float32):
+        # whether the last step's selection settled equal scores at the
+        # ``topk``-th place (its rare branch), for
+        # ``sparse_select_tied_total``
+        return {"selection_tied": jnp.zeros((), jnp.int32)}
+
     def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
+        out, carry, _ = self.forward_seq_counted(
+            params, x, carry, train=train, rng=rng, mask=mask)
+        return out, carry
+
+    def forward_seq_counted(self, params, x, carry, *, train, rng=None,
+                            mask=None):
+        """``forward_seq`` that also returns the step's state
+        (``init_state``'s keys): what a served step counts."""
         kv_ring, i_ring, cursor = carry
         (b, t), cap = x.shape[:2], kv_ring.shape[1]
         if t > cap:
@@ -747,13 +761,14 @@ class SparseGroupedQueryAttention(BaseRecurrentLayer):
             q_idx, w_idx, k_idx = self.indexer(params, x, positions)
         kv_ring, i_ring = sparse_ring_update(
             kv_ring, i_ring, cursor, k, v, k_idx)
-        ctx = sparse_ring_attention(
+        ctx, tied = sparse_ring_attention(
             q, q_idx, w_idx, kv_ring, i_ring, cursor,
             topk=self.topk, sm_scale=d ** -0.5, scope=_monitor.subscope)
         out = self._activate(ctx.reshape(b, t, h * d) @ params["Wo"])
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
-        return out, (kv_ring, i_ring, cursor + jnp.asarray(t, jnp.int32))
+        return (out, (kv_ring, i_ring, cursor + jnp.asarray(t, jnp.int32)),
+                {"selection_tied": tied})
 
     def attention_path(self, t: int, carry) -> str:
         """``"gathered"``, ``"streamed"`` or ``"masked"``: the form

@@ -1295,13 +1295,17 @@ def _kernel_dot(widen: bool):
 def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
                             cursor, *, block: Optional[int] = None,
                             interpret: Optional[bool] = None) -> Array:
-    """:func:`indexer_scores` as one Pallas kernel: grid (batch, ring
-    blocks), a block of indexer keys a step, every head's product
-    weighted and summed in VMEM, the (batch, T, capacity) float32 sum
-    the only thing written.  A chunk's heads go one at a time, (T,
-    block) each; the single position of a token step takes its heads as
-    the rows of one product.  A block wholly beyond ``cursor + T - 1``
-    is not fetched and reads 0."""
+    """:func:`indexer_scores` as one Pallas kernel: a block of indexer
+    keys a grid step, every head's product weighted and summed in VMEM,
+    the (batch, T, capacity) float32 sum the only thing written.  A
+    chunk's heads go one at a time, (T, block) each, grid (batch, ring
+    blocks); the single position of a token step takes its heads as the
+    rows of one product, grid (ring blocks, batch), and writes its sum
+    as row ``b`` of a (batch, block) block that holds every
+    conversation: the scores lie as (batch, capacity), a conversation a
+    sublane, which is how :func:`selected_slots_streamed` reads them
+    (the (batch, 1, capacity) returned is the same array).  A block
+    wholly beyond ``cursor + T - 1`` is not fetched and reads 0."""
     batch, t, heads, d = q_idx.shape
     cap = i_ring.shape[1]
     # a token step's product is (heads, block): long blocks, or the grid
@@ -1319,9 +1323,16 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
     if t > 1:       # (batch, heads, T, .): a product a head, rows = T
         q_idx, w_idx = jnp.swapaxes(q_idx, 1, 2), jnp.swapaxes(w_idx, 1, 2)
     products, rows = q_idx.shape[1], q_idx.shape[2]
+    chunk = t > 1
+    # a chunk: grid (batch, ring blocks); a token step: (ring blocks,
+    # batch), so that the block of sums stays while every conversation
+    # writes its row
+    conversation = lambda i, j: i if chunk else j
+    ring_block = lambda i, j: j if chunk else i
 
     def kernel(cursor_ref, q_ref, w_ref, ring_ref, o_ref):
-        ki = pl.program_id(1)
+        ki = ring_block(pl.program_id(0), pl.program_id(1))
+        out = o_ref.at[0] if chunk else o_ref.at[pl.ds(pl.program_id(1), 1)]
 
         @pl.when(ki * block <= cursor_ref[0] + (t - 1))
         def _score():
@@ -1330,37 +1341,44 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
             for j in range(products):
                 s = dot(q_ref[0, j], ring, ((1,), (0,)))
                 total = total + jnp.maximum(s, 0.0) * w_ref[0, j]
-            if t == 1:                                  # rows are heads
+            if not chunk:                               # rows are heads
                 total = jnp.sum(total, axis=0, keepdims=True)
-            o_ref[0] = jnp.where(total == 0.0, 0.0, total)
+            out[...] = jnp.where(total == 0.0, 0.0, total)
 
         @pl.when(ki * block > cursor_ref[0] + (t - 1))
         def _beyond():
-            o_ref[0] = jnp.zeros((t, block), jnp.float32)
+            out[...] = jnp.zeros((t, block), jnp.float32)
 
-    whole = lambda b, k, cur: (b, 0, 0, 0)
+    whole = lambda i, j, cur: (conversation(i, j), 0, 0, 0)
+    if chunk:
+        out_shape = (batch, t, cap)
+        out_spec = pl.BlockSpec((1, t, block), lambda b, k, cur: (b, 0, k))
+    else:
+        out_shape = (batch, cap)
+        out_spec = pl.BlockSpec((batch, block), lambda k, b, cur: (0, k))
     # the ring with its slots minor, as ``latent_ring_attention_streamed``
     # takes its narrow ring: a bitcast where XLA:TPU keeps it so, and a
     # (d, block) tile fills its lanes wherever it does not
-    return pl.pallas_call(
+    scores = pl.pallas_call(
         kernel,
-        out_shape=_sds((batch, t, cap), jnp.float32, q_idx),
+        out_shape=_sds(out_shape, jnp.float32, q_idx),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(batch, cap // block),
+            grid=(batch, cap // block) if chunk else (cap // block, batch),
             in_specs=[
                 pl.BlockSpec((1, products, rows, d), whole),
                 pl.BlockSpec((1, products, rows, 1), whole),
-                pl.BlockSpec((1, d, block), lambda b, k, cur: (
-                    b, 0, _newest_block(k, cur, t, block))),
+                pl.BlockSpec((1, d, block), lambda i, j, cur: (
+                    conversation(i, j), 0,
+                    _newest_block(ring_block(i, j), cur, t, block))),
             ],
-            out_specs=pl.BlockSpec((1, t, block),
-                                   lambda b, k, cur: (b, 0, k))),
+            out_specs=out_spec),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
         interpret=interpret,
     )(cursor, q_idx, w_idx, jnp.swapaxes(i_ring, 1, 2))
+    return scores.reshape(batch, t, cap)
 
 
 def _head_rows(kv_ref, row: int, rows: int, block: int, words: bool):
@@ -1507,87 +1525,203 @@ def sparse_attention_streamed(q: Array, kv_ring: Array, selected: Array,
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape)
 
 
-#: outputs :func:`selected_slots` finds at once (rows of its products)
+#: outputs :func:`_slot_list` finds at once (rows of its products)
 _SLOT_LIST_RUN = 512
 
 
-def selected_slots(selected: Array, topk: int, *,
-                   interpret: Optional[bool] = None):
-    """The selection of a token step as numbers: ``(slots (batch, topk)
-    int32, count (batch,) int32)`` for ``selected`` (batch, capacity),
-    nonzero where a slot is selected, at most ``topk`` a row.  The first
-    ``count`` of a row's slots are its selected ones in rising order;
-    the rest name slot 0 (a row that can be fetched and weighs nothing).
-    One Pallas kernel, grid (batch,), no sort and no scatter: the ring's
-    slots are taken 128 at a time; output ``j`` finds its group by
-    comparing ``j`` with the groups' running counts, takes that group's
-    128 marks by a one-hot product, ranks them by a product with a
-    triangle, and keeps the lane whose rank is ``j``'s place in the
-    group.  Every product is of whole numbers no larger than 128 in
-    bfloat16 summed in float32: exact."""
-    batch, cap = selected.shape
-    lanes = math.gcd(cap, 128)
-    groups = cap // lanes
+def _slot_list(dot, marks: Array, topk: int, write) -> None:
+    """The slots ``marks`` (groups, lanes) bfloat16 names (row ``g``:
+    the 0/1 marks of slots ``lanes x g`` to ``lanes x (g + 1) - 1``, at
+    most ``topk`` of them) in rising order, handed to ``write(at,
+    slots)`` a run of outputs at a time as a (1, run) int32 row (outputs
+    ``at`` to ``at + run - 1``; past the marks' count, slot 0).  No sort
+    and no scatter: output ``j`` finds its group by comparing ``j`` with
+    the groups' running counts, takes that group's marks by a one-hot
+    product, ranks them by a product with a triangle, and keeps the lane
+    whose rank is ``j``'s place in the group.  Every
+    product is of whole numbers no larger than 128 in bfloat16 summed in
+    float32: exact."""
+    groups, lanes = marks.shape
     run = math.gcd(topk, _SLOT_LIST_RUN)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    dot = _kernel_dot(bool(interpret))
     f32, bf16 = jnp.float32, jnp.bfloat16
     iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape,
                                                         axis)
+    mm = lambda a, b: dot(a, b, ((1,), (0,)))
+    ones = jnp.ones((groups, lanes), bf16)
+    # a group's count along its lanes; as a row over the groups (a
+    # product with the marks turned), and the running count after each
+    held = mm(marks, jnp.ones((lanes, lanes), bf16)).astype(bf16)
+    counts = dot(jnp.ones((8, lanes), bf16), marks, ((1,), (1,)))
+    rows8 = counts.astype(bf16)
+    end = mm(rows8, (iota((groups, groups), 0)
+                     <= iota((groups, groups), 1)).astype(bf16))[:1]
+    start = end - counts[:1]
+    total = mm(rows8, ones)                             # (8, L), all equal
+    earlier = (iota((lanes, lanes), 0) < iota((lanes, lanes), 1)
+               ).astype(bf16)
+    number = iota((lanes, lanes), 0).astype(bf16)
+    # the outputs go down the sublanes, a run at a time, so that the
+    # masks are the products' left sides and what never changes (the
+    # marks, the triangle) their right: a result is (run, L) with every
+    # lane the same, turned at the end into a row
+    for at in range(0, topk, run):
+        j = (at + iota((run, 1), 0)).astype(f32)
+        # the marks of output j's group, lane by lane, and how many of
+        # them come before each
+        row = mm(((start <= j) & (j < end)).astype(bf16), marks)
+        before = mm(row.astype(bf16), earlier)
+        # the marks in, and the number of, the groups that end at or
+        # before j: its group's first place, and its group
+        ended = (end <= j).astype(bf16)
+        hit = (row > 0.5) & (before == j - mm(ended, held))
+        slot = mm(ended, ones) * lanes + mm(hit.astype(bf16), number)
+        slot = jnp.where(j < total[:1], slot, 0.0)
+        write(at, slot.T[:1].astype(jnp.int32))
 
-    def kernel(marks_ref, turned_ref, slots_ref, count_ref):
-        marks, turned = marks_ref[0], turned_ref[0]     # (G, L), (L, G)
-        mm = lambda a, b: dot(a, b, ((1,), (0,)))
-        ones = jnp.ones((groups, lanes), bf16)
-        # a group's count along its lanes; as a row over the groups, and
-        # the running count after each group
-        held = mm(marks, jnp.ones((lanes, lanes), bf16)).astype(bf16)
-        counts = jnp.broadcast_to(jnp.sum(
-            turned.astype(f32), axis=0, keepdims=True), (8, groups))
-        rows8 = counts.astype(bf16)
-        end = mm(rows8, (iota((groups, groups), 0)
-                         <= iota((groups, groups), 1)).astype(bf16))[:1]
-        start = end - counts[:1]
-        total = mm(rows8, ones)                         # (8, L), all equal
-        earlier = (iota((lanes, lanes), 0) < iota((lanes, lanes), 1)
-                   ).astype(bf16)
-        number = iota((lanes, lanes), 0).astype(bf16)
-        # the outputs go down the sublanes, a run at a time, so that the
-        # masks are the products' left sides and what never changes (the
-        # marks, the triangle) their right: a result is (run, L) with
-        # every lane the same, turned at the end into a row
-        for at in range(0, topk, run):
-            j = (at + iota((run, 1), 0)).astype(f32)
-            # the marks of output j's group, lane by lane, and how many
-            # of them come before each
-            row = mm(((start <= j) & (j < end)).astype(bf16), marks)
-            before = mm(row.astype(bf16), earlier)
-            # the marks in, and the number of, the groups that end at or
-            # before j: its group's first place, and its group
-            ended = (end <= j).astype(bf16)
-            hit = (row > 0.5) & (before == j - mm(ended, held))
-            slot = mm(ended, ones) * lanes + mm(hit.astype(bf16), number)
-            slot = jnp.where(j < total[:1], slot, 0.0)
-            slots_ref[0, :, at:at + run] = slot.T[:8].astype(jnp.int32)
-        count_ref[0] = total.astype(jnp.int32)
 
-    marks = (selected != 0).astype(bf16).reshape(batch, groups, lanes)
-    slots, count = pl.pallas_call(
+def _settle_ties(dot, codes: Array, topk: int) -> Array:
+    """The marks of one conversation where equal keys straddle the
+    ``topk``-th place: ``codes`` (groups, lanes) float32 is 2 for a slot
+    above the ``topk``-th key, 1 for a visible slot at it, 0 elsewhere;
+    every slot above is kept and then the tied ones at the lowest
+    positions, as :func:`select_mask` keeps them.  A tied slot's place
+    among the tied is its group's running start (a product with a
+    triangle over the groups) plus the tied before it in its group (a
+    product with a triangle over the lanes): whole numbers, exact."""
+    groups, lanes = codes.shape
+    bf16 = jnp.bfloat16
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                        axis)
+    mm = lambda a, b: dot(a, b, ((1,), (0,)))
+    above, tied = codes > 1.5, (codes > 0.5) & (codes < 1.5)
+    room = topk - jnp.sum(above.astype(jnp.float32))
+    marks = tied.astype(bf16)
+    held = mm(marks, jnp.ones((lanes, lanes), bf16)).astype(bf16)
+    start = mm((iota((groups, groups), 1) < iota((groups, groups), 0)
+                ).astype(bf16), held)
+    before = start + mm(marks, (iota((lanes, lanes), 0)
+                                < iota((lanes, lanes), 1)).astype(bf16))
+    return above | (tied & (before < room))
+
+
+def selected_slots_streamed(scores: Array, cursor, topk: int, *,
+                            interpret: Optional[bool] = None):
+    """A token step's selection as numbers, from the indexer's float32
+    scores (batch, capacity) with slot ``c`` visible iff ``c <=
+    cursor``: ``(slots (batch, topk) int32, count (batch,) int32, tied
+    (1,) int32)``.  The first ``count`` of a row's slots are the
+    ``topk`` visible slots of largest score (every visible one where
+    they are no more; of equal scores at the ``topk``-th place the
+    lowest positions) in rising order, the rest name slot 0 (a row that
+    can be fetched and weighs nothing): what :func:`select_mask`
+    selects.  ``tied`` is 1 where some row's equal scores straddled the
+    place.
+
+    One Pallas kernel, grid (batch / 8,): a step holds 8 conversations'
+    whole score rows in VMEM, a conversation a sublane ((8, capacity)
+    float32, 1 MB at 32,768 slots; the whole batch where it is not a
+    multiple of 8), and finds every row's ``topk``-th key at once by a
+    binary search over its 32 bits: 32 turns of a compare and a count
+    over the block, each row's candidate carried through the same turn.
+    The marks (2 above the key, 1 at it) are laid in VMEM a group of 128
+    slots by a group, every conversation's group one tile, so that a
+    conversation's (groups, 128) marks are one strided read; a row with
+    more than ``topk`` at or above its key settles its ties there
+    (:func:`_settle_ties`, under ``pl.when``), and :func:`_slot_list`
+    makes its list.  Nothing leaves VMEM but the slots and the
+    counts."""
+    batch, cap = scores.shape
+    if topk % 8:
+        raise ValueError(f"the slot list is written in whole tiles of 8 "
+                         f"(topk {topk})")
+    rows = 8 if batch % 8 == 0 else batch
+    stride = -(-rows // 8) * 8          # scratch rows a group takes
+    lanes = math.gcd(cap, 128)          # 128 wherever Mosaic compiles it
+    groups = cap // lanes
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dot = _kernel_dot(bool(interpret))
+    cursor = jnp.asarray(cursor, jnp.int32).reshape(1)
+    lowest = np.int32(-2 ** 31)
+
+    def kernel(cursor_ref, s_ref, slots_ref, count_ref, tied_ref,
+               keys_scr, codes_scr, marks_scr):
+        step, newest = pl.program_id(0), cursor_ref[0]
+
+        @pl.when(step == 0)
+        def _():
+            tied_ref[0] = jnp.int32(0)
+
+        bits = jax.lax.bitcast_convert_type(s_ref[...], jnp.int32)
+        # int32 whose SIGNED order is the floats' order; what may not be
+        # selected sorts below every number
+        keys = jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits)
+        slot = jax.lax.broadcasted_iota(jnp.int32, (rows, cap), 1)
+        keys_scr[...] = jnp.where(slot <= newest, keys, lowest)
+
+        def narrow(i, prefix):
+            # ``prefix`` (rows, 1) holds the bits found so far in offset
+            # binary (unsigned order); flipping the top bit gives the
+            # signed number the keys compare with
+            candidate = prefix | jnp.left_shift(np.int32(1), 31 - i)
+            n = jnp.sum((keys_scr[...] >= (candidate ^ lowest)).astype(
+                jnp.int32), axis=-1, keepdims=True)
+            return jnp.where(n >= topk, candidate, prefix)
+
+        kth = jax.lax.fori_loop(0, 32, narrow,
+                                jnp.zeros((rows, 1), jnp.int32)) ^ lowest
+
+        def lay(g, _):
+            first = pl.multiple_of(g * lanes, lanes)
+            key = keys_scr[:, pl.ds(first, lanes)]
+            visible = first + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, lanes), 1) <= newest
+            codes_scr[pl.ds(pl.multiple_of(g * stride, 8), rows), :] = \
+                jnp.where(key > kth, 2.0,
+                          jnp.where(visible & (key == kth), 1.0, 0.0))
+            return 0
+
+        jax.lax.fori_loop(0, groups, lay, 0)
+        for b in range(rows):
+            codes = codes_scr[pl.ds(b, groups, stride=stride), :]
+            marks_scr[...] = (codes > 0.5).astype(jnp.float32)
+            n = jnp.sum(marks_scr[...])
+
+            @pl.when(n > topk)          # equal keys straddle the place
+            def _ties():
+                marks_scr[...] = _settle_ties(dot, codes, topk).astype(
+                    jnp.float32)
+                tied_ref[0] = jnp.int32(1)
+
+            count_ref[step * rows + b] = jnp.minimum(n, topk).astype(
+                jnp.int32)
+
+            def write(at, listed, b=b):
+                slots_ref[pl.ds(b, 1), at:at + listed.shape[1]] = listed
+
+            _slot_list(dot, marks_scr[...].astype(jnp.bfloat16), topk, write)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
         kernel,
-        out_shape=[_sds((batch, 8, topk), jnp.int32, selected),
-                   _sds((batch, 8, lanes), jnp.int32, selected)],
-        grid=(batch,),
-        in_specs=[pl.BlockSpec((1, groups, lanes), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, lanes, groups), lambda b: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, 8, topk), lambda b: (b, 0, 0)),
-                   pl.BlockSpec((1, 8, lanes), lambda b: (b, 0, 0))],
+        out_shape=[_sds((batch, topk), jnp.int32, scores),
+                   _sds((batch,), jnp.int32, scores),
+                   _sds((1,), jnp.int32, scores)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch // rows,),
+            in_specs=[pl.BlockSpec((rows, cap), lambda i, cur: (i, 0))],
+            out_specs=[pl.BlockSpec((rows, topk), lambda i, cur: (i, 0)),
+                       smem, smem],
+            scratch_shapes=[pltpu.VMEM((rows, cap), jnp.int32),
+                            pltpu.VMEM((groups * stride, lanes),
+                                       jnp.float32),
+                            pltpu.VMEM((groups, lanes), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
         interpret=interpret,
-    )(marks, jnp.swapaxes(marks, 1, 2))
-    return slots[:, 0], count[:, 0, 0]
+    )(cursor, scores.astype(jnp.float32))
 
 
 def sparse_attention_gathered(q: Array, kv_ring: Array, slots: Array,
@@ -1597,7 +1731,7 @@ def sparse_attention_gathered(q: Array, kv_ring: Array, slots: Array,
     fetched by the kernel's own descriptors: (batch, 1, heads, d)
     queries, the (batch, capacity, 2 x kv heads, d) ring left in HBM,
     ``slots`` (batch, topk) int32 of which the first ``count`` (batch,)
-    are selected (:func:`selected_slots`).  Grid (batch,); a step issues
+    are selected (:func:`selected_slots_streamed`).  Grid (batch,); a step issues
     one copy a listed slot, the slot's tile of keys and values (one
     contiguous read) into its row of a (topk, 2 x kv heads, d) landing
     buffer in VMEM, all on one DMA semaphore, and waits once, for the
@@ -1688,7 +1822,10 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
                           cursor, *, topk: int, sm_scale: float,
                           scope=None):
     """Attention of (batch, T, heads, d) queries over the ``topk`` cached
-    rows their indexer picks; the context, (batch, T, heads, d).
+    rows their indexer picks: ``(context (batch, T, heads, d), tied)``,
+    ``tied`` an int32 that is 1 where the gathered form's selection
+    found equal scores straddling the ``topk``-th place in some row (its
+    rare branch ran) and 0 otherwise, and in the other forms.
     ``q_idx`` (batch, T, index heads, d_index) and
     ``w_idx`` (batch, T, index heads) are the indexer's queries and head
     weights; slot ``c`` is visible to query ``t`` iff ``c <= cursor +
@@ -1707,9 +1844,10 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
         # whose rows are cut off again; what they read is nobody's
         pad = lambda a: jnp.pad(
             a, [(0, 0), (0, -t % 8)] + [(0, 0)] * (a.ndim - 2))
-        return sparse_ring_attention(
+        ctx, tied = sparse_ring_attention(
             pad(q), pad(q_idx), pad(w_idx), kv_ring, i_ring, cursor,
-            topk=topk, sm_scale=sm_scale, scope=scope)[:, :t]
+            topk=topk, sm_scale=sm_scale, scope=scope)
+        return ctx[:, :t], tied
 
     def attend(picked):
         with scope("sparse_attention"):
@@ -1722,21 +1860,28 @@ def sparse_ring_attention(q: Array, q_idx: Array, w_idx: Array,
             return sparse_attention_masked(q, kv_ring, picked,
                                            sm_scale=sm_scale)
 
+    tied = jnp.zeros((), jnp.int32)
     if cap <= topk:
         # every visible row is selected: no score decides anything
         return attend(jnp.broadcast_to(visible_slots(cursor, t, cap)[None],
-                                       (batch, t, cap)))
+                                       (batch, t, cap))), tied
     kernels = path != "masked"
     with scope("indexer"):
         scores = (indexer_scores_streamed(q_idx, w_idx, i_ring, cursor)
                   if kernels else indexer_scores(q_idx, w_idx, i_ring))
     with scope("select"):
-        picked = (select_mask_streamed(scores, cursor, topk)
-                  if kernels else select_mask(
-                      scores, visible_slots(cursor, t, cap)[None], topk))
-        if path == "gathered":      # the mask as (slot numbers, count)
-            picked = selected_slots(picked[:, 0], topk)
-    return attend(picked)
+        if path == "gathered":      # (slot numbers, count), in one kernel
+            slots, count, tie = selected_slots_streamed(
+                scores.reshape(batch, cap), cursor, topk)
+            picked = (slots, count)
+        elif kernels:
+            picked = select_mask_streamed(scores, cursor, topk)
+        else:
+            picked = select_mask(scores, visible_slots(cursor, t, cap)[None],
+                                 topk)
+    if path == "gathered":
+        tied = tie.reshape(())
+    return attend(picked), tied
 
 
 # ---------------------------------------------------------------------------

@@ -122,12 +122,15 @@ class Generation:
     whoever compares them with a reference afterwards;
     ``expert_tokens`` is ``{vertex: (n_experts,) picks}`` over the
     call's steps, ``experts_spilled`` ``{vertex: steps}`` whose held
-    pairs outgrew the grouped form's rows (more rounds, the same sum)."""
+    pairs outgrew the grouped form's rows (more rounds, the same sum),
+    ``selection_tied`` ``{vertex: steps}`` whose sparse selection found
+    equal scores straddling its ``topk``-th place."""
 
     ids: np.ndarray
     kept_logits: list
     expert_tokens: dict
     experts_spilled: dict
+    selection_tied: dict
 
 
 class _Session:
@@ -599,8 +602,19 @@ class SessionCache:
             held_picks.inc(int(row[held].sum()), model=self._name,
                            layer=vertex)
             experts_held.set(len(held), model=self._name, layer=vertex)
+        # the selections' rows follow the experts' (``cg.token_step``)
+        selections = model._selection_vertices()
+        ties = dict(zip(selections, counts[len(by_vertex):, -1].tolist()
+                        if selections else []))
+        tied = _monitor.counter(
+            "sparse_select_tied_total",
+            "token steps whose sparse selection found equal scores "
+            "straddling its topk-th place and settled them by position, "
+            "by layer")
+        for vertex, steps in ties.items():
+            tied.inc(steps, model=self._name, layer=vertex)
         return Generation(np.concatenate(host_ids, axis=1), kept, by_vertex,
-                          spills)
+                          spills, ties)
 
     def _count_attention_steps(self, sess: _Session, by_length,
                                kinds, pinned: bool = False) -> None:

@@ -104,9 +104,10 @@ def test_within_topk_it_is_dense_grouped_query_attention(net):
     b, t, cap = 2, 12, 16                     # the ring no longer than topk
     q, k, v = acts((b, t, 4, 16)), acts((b, cap, 32), 2), acts((b, cap, 32), 3)
     idx = (acts((b, t, 3, 8), 4), acts((b, t, 3), 5), acts((b, cap, 8), 6))
-    got = attention.sparse_ring_attention(
+    got, tied = attention.sparse_ring_attention(
         q, idx[0], idx[1], joined(k, v, 16), idx[2], 4, topk=TOPK,
         sm_scale=0.25)
+    assert int(tied) == 0
     by_head = lambda ring: jnp.repeat(jnp.transpose(
         ring.reshape(b, cap, 2, 16), (0, 2, 1, 3)), 2, axis=1)
     want = attention.kv_ring_attention(q, by_head(k), by_head(v), 4,
@@ -148,8 +149,14 @@ def test_the_two_forms_agree_and_pick_the_same_sets(t, cursor):
         q, ring, selected, cursor, sm_scale=0.25, block=16), masked) < 1e-6
     if t == 1:
         # the token step's third form: the selection as slot numbers,
-        # the slots fetched by the kernel's own descriptors
-        slots, count = attention.selected_slots(selected[:, 0], TOPK)
+        # made from the scores in one kernel, the slots fetched by the
+        # kernel's own descriptors
+        slots, count, tied = attention.selected_slots_streamed(
+            scores[:, 0], cursor, TOPK)
+        # the indexer's exact zeros (every relu 0) may straddle the place
+        keys = -np.sort(-np.asarray(scores[:, 0, :cursor + 1]), axis=1)
+        assert int(tied[0]) == (cursor + 1 > TOPK and bool(
+            (keys[:, TOPK - 1] == keys[:, TOPK]).any()))
         for i in range(b):
             np.testing.assert_array_equal(
                 np.asarray(slots[i, :int(count[i])]),
@@ -211,7 +218,9 @@ def test_the_gathered_form_fetches_the_rows_the_selection_named(case):
         attention.select_mask(jnp.asarray(scores),
                               attention.visible_slots(cursor, 1, cap)[None],
                               TOPK))
-    slots, count = attention.selected_slots(selected[:, 0], TOPK)
+    slots, count, tied = attention.selected_slots_streamed(
+        jnp.asarray(scores[:, 0]), cursor, TOPK)
+    assert int(tied[0]) == (case == "ties")
     for i in range(b):
         n = int(count[i])
         np.testing.assert_array_equal(np.asarray(slots[i, :n]), want[i])
@@ -249,11 +258,101 @@ def test_an_unselected_neighbour_weighs_nothing():
         sm_scale=0.1)
     unselected = np.setdiff1d(np.arange(cap), want[0])
     k[:, unselected], v[:, unselected] = np.nan, np.nan
-    slots, count = attention.selected_slots(selected[:, 0], TOPK)
+    slots, count, _ = attention.selected_slots_streamed(
+        jnp.asarray(scores[:1, 0]), cursor, TOPK)
     got = attention.sparse_attention_gathered(
         jnp.asarray(q), joined(jnp.asarray(k), jnp.asarray(v), d), slots,
         count, sm_scale=0.1)
     assert rel(got, clean) < 1e-6
+
+
+def _selection_case(case, cap, rng):
+    """Scores (3, capacity) float32 of a token step for ``case``."""
+    scores = rng.randn(3, cap).astype(np.float32)
+    if case == "ties":
+        # 40 equal scores straddle the 16th place in every row, among
+        # a few above them: the lowest positions go first
+        scores[:, 1:120:3] = 25.0
+        scores[:, [7, 50, 99]] = 30.0
+    elif case == "zeros":
+        # the indexer's exact zeros (every relu 0): all tied, in one row
+        # beside a row of random scores and one that is mostly zeros
+        scores[0] = 0.0
+        scores[2, 40:] = 0.0
+    return scores
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "zeros"])
+@pytest.mark.parametrize("cap", [256, 1024])
+@pytest.mark.parametrize("cursor", [4, 15, 130, 255])
+def test_one_kernel_lists_what_the_mask_selects(cursor, cap, case):
+    """``selected_slots_streamed``: from the scores to the slot list in
+    one kernel, with fewer than ``topk`` visible (cursor 4), exactly
+    ``topk`` (15) and more; its slots and counts are those of
+    ``select_mask`` (and the reference's selection) in rising order,
+    slot 0 after them, and ``tied`` reads 1 where equal scores
+    straddled the ``topk``-th place."""
+    rng = np.random.RandomState(cursor + cap)
+    scores = _selection_case(case, cap, rng)
+    visible = attention.visible_slots(cursor, 1, cap)[0]
+    slots, count, tied = attention.selected_slots_streamed(
+        jnp.asarray(scores), cursor, TOPK)
+    mask = np.asarray(attention.select_mask(
+        jnp.asarray(scores)[:, None], visible[None, None], TOPK))[:, 0]
+    straddled = False
+    for i in range(3):
+        want = np.nonzero(mask[i])[0]
+        np.testing.assert_array_equal(
+            np.asarray(ref.select(jnp.asarray(scores[i:i + 1]), visible,
+                                  TOPK))[0], mask[i])
+        n = int(count[i])
+        assert n == len(want) == min(TOPK, cursor + 1)
+        np.testing.assert_array_equal(np.asarray(slots[i, :n]), want)
+        assert not np.asarray(slots[i, n:]).any()
+        keys = np.sort(scores[i, :cursor + 1])[::-1]
+        straddled |= bool(cursor + 1 > TOPK and keys[TOPK - 1] == keys[TOPK])
+    assert int(tied[0]) == straddled
+    assert straddled == (case != "random" and cursor + 1 > TOPK)
+
+
+def test_the_token_step_selects_in_one_kernel(monkeypatch):
+    """The gathered path's selection is ``selected_slots_streamed``
+    alone: neither ``select_mask_streamed`` nor ``select_mask`` runs,
+    nothing is conditional, and the step is three kernels (indexer,
+    selection, attention) with no XLA operation between the scores and
+    the list."""
+    monkeypatch.setattr(attention, "_mosaic", lambda: True)
+
+    def refused(*a, **k):
+        raise AssertionError("the token step called the mask's search")
+
+    monkeypatch.setattr(attention, "select_mask_streamed", refused)
+    monkeypatch.setattr(attention, "select_mask", refused)
+    b, cap, d = 2, 256, 128
+    rng = np.random.RandomState(3)
+    draw = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
+    args = (draw(b, 1, 4, d), draw(b, 1, 3, 8), draw(b, 1, 3),
+            draw(b, cap, 2, d), draw(b, cap, 8))
+    assert attention.sparse_attention_path(1, 4, 1, d, cap, jnp.float32,
+                                           TOPK) == "gathered"
+    run = lambda *a: attention.sparse_ring_attention(
+        *a, 200, topk=TOPK, sm_scale=0.1)
+    jaxpr = jax.make_jaxpr(run)(*args)
+    prims = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert prims.count("pallas_call") == 3 and "cond" not in prims
+    indexer, listing, attending = [e for e in jaxpr.jaxpr.eqns
+                                   if e.primitive.name == "pallas_call"]
+    # the list's kernel reads the indexer's scores through reshapes
+    # alone (bitcasts: the indexer writes them (batch, capacity)), and
+    # the attention's takes the slots and counts as the list made them
+    made = {v: e for e in jaxpr.jaxpr.eqns for v in e.outvars}
+    scores = listing.invars[1]
+    while made[scores] is not indexer:
+        assert made[scores].primitive.name == "reshape"
+        scores = made[scores].invars[0]
+    assert attending.invars[:2] == listing.outvars[:2]
+    ctx, tied = run(*args)
+    assert np.isfinite(np.asarray(ctx)).all() and int(tied) == 0
 
 
 def test_equal_scores_go_to_the_lowest_positions():
@@ -376,6 +475,15 @@ def test_a_token_step_over_a_long_ring_is_served_gathered(monkeypatch, ids):
         out = engine.generate("s", ids[:, 29:30], 3)
     assert steps.value(path="streamed") - before["streamed"] == 4
     assert steps.value(path="gathered") - before["gathered"] == 3
+    # the steps whose selection settled ties (the indexer's exact zeros
+    # can straddle the 16th place of 30-32 rows), a layer, beside the
+    # experts' counts and in the counter
+    assert set(out.selection_tied) == {"L0_attn", "L1_attn"}
+    assert set(out.selection_tied.values()) <= {0, 1, 2, 3}
+    assert set(out.experts_spilled) == {"L0_moe", "L1_moe"}
+    tied = monitor.counter("sparse_select_tied_total", "")
+    for layer, steps in out.selection_tied.items():
+        assert tied.value(model=engine._name, layer=layer) >= steps
     sequence = np.concatenate([ids[:, :30], out.ids[:, :-1]], axis=1)
     want = np.asarray(ref.forward(wide, net.params, sequence, last=3))
     kept = np.stack([np.asarray(k) for k in out.kept_logits], axis=1)

@@ -16,6 +16,7 @@ import contextlib
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -425,10 +426,13 @@ def _scoped_sparse(q, q_idx, w_idx, kv_ring, i_ring, cursor):
             scores = attention.indexer_scores_streamed(
                 q_idx, w_idx, i_ring, cursor, interpret=False)
         with monitor.subscope("select"):
-            selected = attention.select_mask_streamed(scores, cursor, 2048,
-                                                      interpret=False)
-            if q.shape[1] == 1:
-                slots, count = attention.selected_slots(selected[:, 0], 2048)
+            if q.shape[1] == 1:     # the scores to the slot list, one kernel
+                slots, count, _ = attention.selected_slots_streamed(
+                    scores.reshape(q.shape[0], -1), cursor, 2048,
+                    interpret=False)
+            else:
+                selected = attention.select_mask_streamed(
+                    scores, cursor, 2048, interpret=False)
         with monitor.subscope("sparse_attention"):
             if q.shape[1] == 1:     # the token step fetches what it selected
                 return attention.sparse_attention_gathered(
@@ -471,6 +475,15 @@ def test_mosaic_compiles_the_sparse_kernels_at_the_cells_shape(one_chip):
                     for c in calls] == ["layer.L0_attn.indexer",
                                         "layer.L0_attn.select",
                                         "layer.L0_attn.sparse_attention"]
+                if t == 1:
+                    # the token step's selection is its kernel alone: no
+                    # copy, fusion or conditional lays the scores or the
+                    # slots between the three kernels
+                    laid = [line for line in compiled.as_text().splitlines()
+                            if "L0_attn.select" in line and re.search(
+                                r" (copy|fusion|conditional|copy-start)\(",
+                                line)]
+                    assert not laid, laid
                 # float32 scores, the bfloat16 mask, and what the rare
                 # tie's fallback keeps (keys, counts, a bool): nothing
                 # is as large as a (heads, T, slots) array would be

@@ -1,7 +1,8 @@
 """The parts of the indexed sparse attention alone, on the chip, at a
 cell's shapes: microseconds a call of the indexer's scores, of the
 selection as a mask (the radix search), as row numbers by ``lax.top_k``
-and as the slot list the gathered form takes, and of the attention in
+and as the slot list the gathered form takes (PR 42: made from the
+scores in one kernel), and of the attention in
 its three forms, for the token step's one position and for a prefill
 chunk.
 
@@ -19,7 +20,11 @@ quote.  The token step's rows, in the order the gathered form was built:
   their own, 1 KB each) beside it, which is why the rings were joined;
 - ``attention, gathered``: the same copies and the attention over what
   landed (``sparse_attention_gathered``);
-- ``slot list from the mask``: ``selected_slots``;
+- ``slot list from the scores``: ``selected_slots_streamed``, the
+  search for every conversation's ``topk``-th key and the list in one
+  kernel; ``select as a mask, streamed`` beside it is the search alone
+  as PR 40's token step ran it (its list's kernel, ``selected_slots``,
+  was folded into the new one in PR 42: the parent's sweep times it);
 - ``attention, streamed``: the ring streamed whole through the mask;
 - ``--crossover``: gathered (list included) against streamed over rings
   of 4,096 to 131,072 slots, which is where ``_GATHER_RATIO`` is read.
@@ -174,13 +179,15 @@ def main(argv=None) -> int:
         visible = A.visible_slots(cursor, 1, s)[None]
         as_mask = jax.jit(lambda x: A.select_mask(x, visible, k))
         selected = as_mask(scores)
-        as_list = jax.jit(lambda m: A.selected_slots(m[:, 0], k))
-        slots, count = as_list(selected)
+        as_list = jax.jit(lambda x: A.selected_slots_streamed(
+            x[:, 0], cursor, k)[:2])
+        slots, count = as_list(scores)
         row = {}
-        row["slot list from the mask"] = run(
-            f"slots={s} t=1 slot list from the mask (selected_slots)",
-            lambda m: A.selected_slots(m[:, 0] > 0.5, k),
-            selected.astype(jnp.bfloat16))
+        row["slot list from the scores"] = run(
+            f"slots={s} t=1 slot list from the scores, one kernel "
+            f"(selected_slots_streamed)",
+            lambda x: A.selected_slots_streamed(x[:, 0], cursor, k),
+            scores)
         row["attention, gathered"] = run(
             f"slots={s} t=1 attention, gathered kernel",
             lambda a, ring, at, n: A.sparse_attention_gathered(
