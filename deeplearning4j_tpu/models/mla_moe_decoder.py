@@ -5,7 +5,13 @@ One builder for the published ``config.json`` files it can read, the
 sublayers picked from the file's own keys:
 
 - attention: latent attention (``LatentAttention``) from DeepSeek-V3's
-  ``kv_lora_rank`` and its companions; grouped-query attention over the
+  ``kv_lora_rank`` and its companions, by ``layer_types`` where the file
+  gives them: a ``full_attention`` layer at those keys, with DeepSeek-
+  V3.2's indexer from ``index_n_heads`` / ``index_head_dim`` /
+  ``index_topk``, a ``sliding_attention`` layer at the ``swa_*`` keys
+  over a window of ``sliding_window_size``, each gated headwise where
+  ``attention_gate_type`` / ``swa_attention_gate_type`` says so;
+  grouped-query attention over the
   rows a learned indexer selects (``SparseGroupedQueryAttention``) from
   ``sa_config`` with ``num_key_value_heads`` and ``head_dim``; dense
   grouped-query attention, a window's layers beside full ones
@@ -36,10 +42,13 @@ sublayers picked from the file's own keys:
 The benchmark's ``xing4_29b_a4b`` configuration is a latent-attention
 file with the hyper-connection keys, ``ax_k1`` one without,
 ``keye_vl2_30b_a3b`` a ``sa_config`` file, ``command_a_plus_05_2026`` a
-``layer_types`` file with the parallel block; the CPU tests run a small
-one of each.  A key the builder would have to understand and does not
-(``use_qk_norm``, ``attention_bias``, a rotary it has no form for, a
-kind of layer it does not make) raises and names the key.
+``layer_types`` file with the parallel block, ``dots3_note_prev`` a
+latent-attention file with ``layer_types``, ``swa_*``, ``index_*`` and
+gates; the CPU tests run a small one of each.  A key the builder would
+have to understand and does not (``use_qk_norm``, ``attention_bias``, a
+rotary it has no form for, a kind of layer it does not make, a window's
+or an indexer's key it does not know, a gate other than headwise,
+experts picked from groups) raises and names the key.
 
 With ``hc_mult``, per token: ``embed`` -> ``streams`` (``hc_mult``
 copies) -> for each layer ``L<i>`` two sublayers, attention then
@@ -85,6 +94,69 @@ from ..nn.layers.decoder import (GatedFeedForward, GroupedQueryAttention,
 from ..nn.weights import Distribution
 
 
+#: the keys of a latent-attention file that describe its window layers
+#: (``sliding_attention``: a latent attention of their own sizes) and its
+#: full layers' indexer
+_SWA_KEYS = ("swa_q_lora_rank", "swa_kv_lora_rank", "swa_num_attention_heads",
+             "swa_num_key_value_heads", "swa_qk_nope_head_dim",
+             "swa_qk_rope_head_dim", "swa_v_head_dim", "swa_rope_theta",
+             "swa_attention_gate_type")
+_INDEX_KEYS = ("index_n_heads", "index_head_dim", "index_topk")
+
+
+def _check_latent_keys(cfg: Dict) -> None:
+    """Raise, naming the key, where a latent-attention file asks for what
+    the builder does not make, instead of building another model: a kind
+    of layer other than ``full_attention`` and ``sliding_attention``; a
+    window's or an indexer's keys where the file says no layer is which;
+    a gate other than ``headwise``; a window or an indexer without all of
+    its keys, or with one the builder does not know; key/value heads that
+    are not the query heads; an indexer under a scaled rotary; experts
+    picked from groups."""
+    kinds = cfg.get("layer_types")
+    for i, kind in enumerate((kinds or [])[:int(cfg["num_hidden_layers"])]):
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"layer_types[{i}] is {kind!r}: the latent "
+                             "attention makes full_attention and "
+                             "sliding_attention layers")
+    window = [k for k in cfg if k.startswith("swa_")
+              or k == "sliding_window_size"]
+    index = [k for k in cfg if k.startswith("index_")]
+    for key in window + index:
+        if key not in _SWA_KEYS + _INDEX_KEYS + ("sliding_window_size",):
+            raise ValueError(f"{key}: the builder does not know this key of "
+                             "a window's latent attention or of an indexer")
+    if window and kinds is None:
+        raise ValueError(f"{window[0]} is given without layer_types: no "
+                         "layer is said to be a window's")
+    for key in ("attention_gate_type", "swa_attention_gate_type"):
+        if cfg.get(key) not in (None, "none", "headwise"):
+            raise ValueError(f"{key} is {cfg[key]!r}: the latent attention "
+                             "gates headwise or not at all")
+    need = list(_INDEX_KEYS) if index else []
+    if kinds and "sliding_attention" in kinds:
+        need += ["sliding_window_size"] + [
+            k for k in _SWA_KEYS if k != "swa_attention_gate_type"]
+    for key in need:
+        if key not in cfg:
+            raise ValueError(f"{key} is missing: the file's "
+                             + ("indexer" if key in _INDEX_KEYS
+                                else "window layers") + " need it")
+    for heads, kv in (("num_attention_heads", "num_key_value_heads"),
+                      ("swa_num_attention_heads", "swa_num_key_value_heads")):
+        if kv in cfg and cfg[kv] != cfg[heads]:
+            raise ValueError(f"{kv} is {cfg[kv]}, not {heads}: latent "
+                             "attention has a key and a value a head")
+    if index and cfg.get("rope_scaling"):
+        raise ValueError("rope_scaling is set: the indexer's rotary has no "
+                         "scaled form")
+    groups = int(cfg.get("n_group") or 1)
+    if cfg.get("topk_method") == "noaux_tc" and groups > 1 and int(
+            cfg.get("topk_group") or groups) < groups:
+        raise ValueError(f"n_group is {groups} under noaux_tc: the router "
+                         "picks from every expert, not the best groups")
+
+
 def from_config(cfg: Dict, *, cache_len: int = 4096,
                 experts_held: Optional[List[int]] = None,
                 init_std: float = 0.02, hc_alpha_init: float = 0.01,
@@ -94,7 +166,8 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
     """The graph configuration for the published keys in ``cfg``.
     ``cache_len`` is the default capacity of the attention rings that
     grow (a window layer's ring is sized by its window and
-    ``max_chunk``, the most positions one call brings it);
+    ``max_chunk``, the most positions one call brings it; a file that
+    asks for what the builder does not make raises and names the key);
     ``experts_held`` the experts every expert layer holds (default:
     all); ``init_std`` the normal deviation of every matrix;
     ``hc_alpha_init``, ``hc_bias_std`` and ``router_bias_std`` the
@@ -187,6 +260,38 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
     if streams:
         g.add_vertex("streams", StreamExpandVertex(n_streams=n), x)
         x = "streams"
+    if "kv_lora_rank" in cfg:
+        _check_latent_keys(cfg)
+
+    def latent(i: int, common: Dict):
+        """Latent attention of the kind ``layer_types[i]`` names (every
+        layer a full one where the file gives no ``layer_types``): a
+        ``full_attention`` layer at the plain keys, with the indexer of
+        ``index_*`` where the file gives one; a ``sliding_attention``
+        layer at the ``swa_*`` keys over a window of
+        ``sliding_window_size``; either gated as its gate key says."""
+        sliding = cfg.get("layer_types", ["full_attention"] * (i + 1))[i] \
+            == "sliding_attention"
+        pre = "swa_" if sliding else ""
+        size = lambda key: int(cfg[pre + key])
+        options = dict(gate=cfg.get(pre + "attention_gate_type") == "headwise")
+        if sliding:
+            options.update(window=int(cfg["sliding_window_size"]),
+                           chunk=int(max_chunk))
+            common = {**common, "n_heads": size("num_attention_heads"),
+                      "rope_theta": float(cfg["swa_rope_theta"])}
+        elif "index_n_heads" in cfg:
+            options.update(index_heads=int(cfg["index_n_heads"]),
+                           index_dim=int(cfg["index_head_dim"]),
+                           index_rope=size("qk_rope_head_dim"),
+                           topk=int(cfg["index_topk"]))
+        return LatentAttention(
+            eps=float(cfg["rms_norm_eps"]), q_rank=size("q_lora_rank"),
+            kv_rank=size("kv_lora_rank"), d_nope=size("qk_nope_head_dim"),
+            d_rope=size("qk_rope_head_dim"), d_v=size("v_head_dim"),
+            rope_scaling=None if sliding else cfg.get("rope_scaling"),
+            **options, **common)
+
     def attention(i: int):
         common = dict(n_in=c, n_out=c,
                       n_heads=int(cfg["num_attention_heads"]),
@@ -212,14 +317,7 @@ def from_config(cfg: Dict, *, cache_len: int = 4096,
                 window=int(cfg["sliding_window"]) if sliding else None,
                 rotary=sliding, chunk=int(max_chunk), **common)
         if "kv_lora_rank" in cfg:
-            return LatentAttention(
-                eps=float(cfg["rms_norm_eps"]),
-                q_rank=int(cfg["q_lora_rank"]),
-                kv_rank=int(cfg["kv_lora_rank"]),
-                d_nope=int(cfg["qk_nope_head_dim"]),
-                d_rope=int(cfg["qk_rope_head_dim"]),
-                d_v=int(cfg["v_head_dim"]),
-                rope_scaling=cfg.get("rope_scaling"), **common)
+            return latent(i, common)
         if "sa_config" in cfg:
             sa = cfg["sa_config"]
             if int(sa.get("indexer_num_kv_heads", 1)) != 1:

@@ -1,5 +1,7 @@
 """Decoder-block layers: token embedding, RMS norm and LayerNorm, gated
-feed-forward, latent attention over a compressed ring, grouped-query
+feed-forward, latent attention over a compressed ring (over every
+position, over the rows a learned indexer selects, or over a window's
+ring that wraps, gated headwise or not), grouped-query
 attention over the cached rows a learned indexer selects, dense
 grouped-query attention over a ring that grows or over a window's ring
 that wraps, a routed expert layer (sigmoid or softmax scores, shared
@@ -14,15 +16,18 @@ a long chunk (``ops.experts``), each chosen from the call's shapes
 alone; the parts a trace has to tell apart open a sub-scope
 (``monitor.subscope``: ``layer.<name>.experts``, ``.latent_attention``,
 ``.router``, ``.shared``, ``.sinkhorn``, ``.indexer``, ``.select``,
-``.sparse_attention``, ``.window_attention``, ``.full_attention``).
+``.sparse_attention``, ``.window_attention``, ``.full_attention``,
+``.sparse_latent_attention``, ``.window_latent_attention``).
 
 The equations are DeepSeek-V2/V3's for latent attention (MLA), routing
 and the expert layer, DeepSeek-V3.2's for the indexer and its
 selection, and those of "Manifold-Constrained Hyper-Connections"
 (arXiv:2512.24880) for the residual path; the plain references the
 benchmark compares with are ``benchmark/reference/mla_moe_decoder.py``,
-``gqa_sparse_moe.py`` and ``gqa_window_moe.py`` (Cohere2's window and
-full layers under one norm a block), written apart from this file.
+``gqa_sparse_moe.py``, ``gqa_window_moe.py`` (Cohere2's window and
+full layers under one norm a block) and ``mla_sparse_window_moe.py``
+(dots3's latent attention over selected rows beside latent window
+layers), written apart from this file.
 
 Activations are (batch, time, features); the residual path of a
 hyper-connected model is (batch, time, streams, features).
@@ -41,8 +46,11 @@ from ... import monitor as _monitor
 from ...ops.attention import (_einsum_acc, gqa_attention_path,
                               gqa_ring_attention, gqa_ring_update,
                               latent_ring_attention, latent_ring_path,
-                              latent_ring_update, sparse_attention_path,
-                              sparse_ring_attention, sparse_ring_update,
+                              joined_width, latent_ring_update,
+                              latent_walk_path, ring_write,
+                              sparse_attention_path,
+                              sparse_latent_attention, sparse_ring_attention,
+                              sparse_ring_update, window_latent_attention,
                               window_ring_slots)
 from ...ops.experts import (dense_experts, grouped_experts,
                             held_rows_experts, held_token_rows,
@@ -458,10 +466,39 @@ class LatentAttention(BaseRecurrentLayer):
     multiplied in the forms :meth:`lay` gives them, laid once by a
     served net or inside the step from the stored parameters: the same
     products either way.
+
+    Three options, each off by default (dots3's two kinds of layer):
+
+    - ``window``: query ``t`` sees ``t - window < s <= t`` (the query's
+      own position counted), over a ring that WRAPS: position ``p`` in
+      slot ``p mod capacity``, the capacity the layer's own whatever
+      ``cache_len`` it is handed (``ops.attention.window_ring_slots(
+      window, chunk)``: a chunk of up to ``chunk`` positions is written
+      before it is read), never grown; ``STATE_KIND`` ``"window_latent"``.
+    - an indexer (``index_heads`` > 0; DeepSeek-V3.2's, its queries
+      from the query latent): ``qI = c_q WqI`` (``index_heads`` of
+      ``index_dim``), ``kI = LayerNorm(x WkI)`` (one head, gain and
+      bias, cached), both turned over the half-split pairs of their
+      first ``index_rope`` numbers, ``w = x Ww``; ``I[t, s] = sum_j
+      w[t, j] relu(qI[t, j] . kI[s])`` in float32; query ``t`` attends
+      over the ``topk`` visible positions of largest ``I[t, .]`` (every
+      visible one while they are no more; equal scores: the lowest
+      position first); ``STATE_KIND`` ``"sparse_latent"``.
+    - ``gate``: each head's output times ``sigmoid(x Wg)[head]``
+      (``Wg`` hidden -> heads; headwise), before ``Wo``.
+
+    With a window or an indexer the latent ring is JOINED, ``(batch,
+    capacity, joined_width(kv_rank, d_rope))``: a slot's ``c_kv`` and
+    ``k_r`` one row of whole lanes, which one copy reads
+    (``ops.attention``'s joined latent ring); an indexer adds its key
+    ring ``(batch, capacity, index_dim)`` before the cursor.  Without
+    either the layer keeps its two rings: its kernel reads them within
+    a tenth of the HBM roof on a TPU v5e (the narrow ``k_r`` ring taken
+    slots-minor, as XLA lays it), and a joined row's padding to whole
+    lanes would add a ninth to those bytes (576 numbers to 640).
     """
 
     HAS_KV_RING = True
-    STATE_KIND = "latent"       # serving_session_state_bytes{kind=}
 
     activation: str = "identity"
     n_heads: int = 1
@@ -474,14 +511,42 @@ class LatentAttention(BaseRecurrentLayer):
     rope_theta: float = 10000.0
     rope_scaling: Optional[dict] = None
     cache_len: int = 128
+    window: Optional[int] = None
+    chunk: int = 256
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope: int = 0
+    topk: int = 2048
+    gate: bool = False
+
+    @property
+    def STATE_KIND(self) -> str:    # serving_session_state_bytes{kind=}
+        if self.window:
+            return "window_latent"
+        return "sparse_latent" if self.index_heads else "latent"
+
+    @property
+    def RING_GROWS(self) -> bool:
+        """Whether the ring's capacity follows the session's length."""
+        return not self.window
+
+    @property
+    def _joined(self) -> bool:
+        return bool(self.window or self.index_heads)
 
     def param_order(self) -> tuple:
-        return ("Wqa", "q_gain", "Wqb", "Wkva", "kv_gain", "Wkvb", "Wo")
+        return (("Wqa", "q_gain", "Wqb", "Wkva", "kv_gain", "Wkvb", "Wo")
+                + (("Wg",) if self.gate else ())
+                + (("WqI", "WkI", "kI_gain", "kI_bias", "Ww")
+                   if self.index_heads else ()))
 
     def init_params(self, rng, dtype=jnp.float32) -> ParamTree:
         h, c = self.n_heads, self.n_in
         ka, kb, kc, kd, ko = jax.random.split(rng, 5)
-        return {
+        # the options' keys apart, so that a layer without them draws
+        # what it always drew
+        kg, ki = jax.random.fold_in(rng, 5), jax.random.fold_in(rng, 6)
+        p = {
             "Wqa": _matrix(self, ka, (c, self.q_rank), dtype),
             "q_gain": jnp.ones((self.q_rank,), dtype),
             "Wqb": _matrix(self, kb, (self.q_rank,
@@ -492,6 +557,19 @@ class LatentAttention(BaseRecurrentLayer):
                                        h * (self.d_nope + self.d_v)), dtype),
             "Wo": _matrix(self, ko, (h * self.d_v, self.n_out), dtype),
         }
+        if self.gate:
+            p["Wg"] = _matrix(self, kg, (c, h), dtype)
+        if self.index_heads:
+            kq, kk, kw = jax.random.split(ki, 3)
+            p.update(
+                WqI=_matrix(self, kq, (self.q_rank,
+                                       self.index_heads * self.index_dim),
+                            dtype),
+                WkI=_matrix(self, kk, (c, self.index_dim), dtype),
+                kI_gain=jnp.ones((self.index_dim,), dtype),
+                kI_bias=jnp.zeros((self.index_dim,), dtype),
+                Ww=_matrix(self, kw, (c, self.index_heads), dtype))
+        return p
 
     def sm_scale(self) -> float:
         scale = (self.d_nope + self.d_rope) ** -0.5
@@ -503,25 +581,41 @@ class LatentAttention(BaseRecurrentLayer):
     # -------------------------------------------------------------- carry
     def init_carry(self, batch: int, dtype, cache_len: Optional[int] = None):
         cap = int(cache_len if cache_len is not None else self.cache_len)
+        if self.window:         # sized by its window, whatever it is handed
+            cap = window_ring_slots(self.window, self.chunk)
         if cap < 1:
             raise ValueError("cache_len must be >= 1")
-        return (jnp.zeros((batch, cap, self.kv_rank), dtype),
-                jnp.zeros((batch, cap, self.d_rope), dtype),
-                jnp.zeros((), jnp.int32))
+        return self._rings(batch, dtype, cap)
+
+    def _rings(self, batch: int, dtype, cap: int):
+        cursor = jnp.zeros((), jnp.int32)
+        if not self._joined:
+            return (jnp.zeros((batch, cap, self.kv_rank), dtype),
+                    jnp.zeros((batch, cap, self.d_rope), dtype), cursor)
+        ring = jnp.zeros((batch, cap, joined_width(self.kv_rank,
+                                                   self.d_rope)), dtype)
+        if self.window:
+            return ring, cursor
+        return ring, jnp.zeros((batch, cap, self.index_dim), dtype), cursor
 
     def grow_carry(self, carry, cache_len: int):
-        c_ring, r_ring, cursor = carry
-        cap = c_ring.shape[1]
+        if self.window:         # sized once, at its window
+            return carry
+        *rings, cursor = carry
+        cap = rings[0].shape[1]
         if cache_len < cap:
             raise ValueError(
                 f"cannot shrink the latent ring from {cap} to {cache_len}")
         pad = [(0, 0), (0, cache_len - cap), (0, 0)]
-        return jnp.pad(c_ring, pad), jnp.pad(r_ring, pad), cursor
+        return tuple(jnp.pad(r, pad) for r in rings) + (cursor,)
 
     # ------------------------------------------------------------ forward
-    #: the stored matrices :meth:`lay` turns into the forms the step
-    #: multiplies; every other parameter is multiplied as it is stored
-    LAID_FROM = ("Wqb", "Wkvb")
+    @property
+    def LAID_FROM(self) -> tuple:
+        """The stored matrices :meth:`lay` turns into the forms the step
+        multiplies; every other parameter is multiplied as it is
+        stored."""
+        return ("Wqb", "Wkvb") + (("WqI",) if self.index_heads else ())
 
     def lay(self, params: ParamTree) -> ParamTree:
         """The forms ``forward_seq`` multiplies in place of ``Wqb`` and
@@ -536,7 +630,9 @@ class LatentAttention(BaseRecurrentLayer):
           columns, the two members of a rotated pair apart;
         - ``Wk_absorbed`` (heads, d_nope, kv_rank): the key half of
           ``Wkvb``, which the query absorbs;
-        - ``Wv`` (heads, kv_rank, d_v): its value half.
+        - ``Wv`` (heads, kv_rank, d_v): its value half;
+        - ``Wq_index`` (index_heads, index_dim, q_rank): an indexer's
+          ``WqI``, turned likewise.
 
         Transposes and column splits: every number is kept as it is.
         A served net runs this once when its weights are set
@@ -546,13 +642,18 @@ class LatentAttention(BaseRecurrentLayer):
         wqb = params["Wqb"].reshape(self.q_rank, h, self.d_nope + self.d_rope)
         wkvb = params["Wkvb"].reshape(self.kv_rank, h,
                                       self.d_nope + self.d_v)
-        return {
+        laid = {
             "Wq_nope": wqb[..., :self.d_nope].transpose(1, 2, 0),
             "Wq_rope": wqb[..., self.d_nope:].reshape(
                 self.q_rank, h, self.d_rope // 2, 2).transpose(2, 3, 1, 0),
             "Wk_absorbed": wkvb[..., :self.d_nope].transpose(1, 2, 0),
             "Wv": wkvb[..., self.d_nope:].transpose(1, 0, 2),
         }
+        if self.index_heads:
+            laid["Wq_index"] = params["WqI"].reshape(
+                self.q_rank, self.index_heads, self.index_dim).transpose(
+                    1, 2, 0)
+        return laid
 
     def compress(self, params: ParamTree, x: Array, turn):
         """What the cache holds of ``x``: (normed ``c_kv``, ``k_r``
@@ -562,21 +663,71 @@ class LatentAttention(BaseRecurrentLayer):
                              params["kv_gain"]).astype(x.dtype)
         return c_kv, turn(kv[..., self.kv_rank:])
 
-    def queries(self, laid: ParamTree, x: Array, turn):
+    def query_latent(self, laid: ParamTree, x: Array) -> Array:
+        """``c_q = norm(x Wqa)``: what the queries (and an indexer's)
+        are projected from."""
+        return rms_normalize(x @ laid["Wqa"], self.eps,
+                             laid["q_gain"]).astype(x.dtype)
+
+    def queries(self, laid: ParamTree, c_q: Array, turn):
         """((batch, time, heads, d_nope), (.., d_rope) rotated by
-        ``turn``), from the laid forms."""
-        c_q = rms_normalize(x @ laid["Wqa"], self.eps,
-                            laid["q_gain"]).astype(x.dtype)
+        ``turn``) of the query latent ``c_q``, from the laid forms."""
         q_nope = jnp.einsum("btq,hdq->bthd", c_q, laid["Wq_nope"])
         q_rope = jnp.einsum("btq,pjhq->bthpj", c_q, laid["Wq_rope"])
         return q_nope, turn(q_rope.reshape(q_rope.shape[:3] + (self.d_rope,)))
 
+    def indexer(self, laid: ParamTree, x: Array, c_q: Array,
+                positions: Array):
+        """The indexer's view of ``x`` (batch, time, hidden) at
+        ``positions``, from the laid forms: ``(queries (batch, time,
+        index_heads, index_dim) from the query latent ``c_q``, head
+        weights (batch, time, index_heads), keys (batch, time,
+        index_dim): LayerNorm(x WkI))``, queries and keys turned over the
+        half-split pairs of their first ``index_rope`` numbers, the rest
+        as they are."""
+        r = self.index_rope
+        inv_freq, _ = yarn_inv_freq(r, self.rope_theta, None)
+
+        def turn(a):
+            return jnp.concatenate(
+                [rotate_half(a[..., :r], positions, inv_freq), a[..., r:]],
+                axis=-1)
+
+        q_idx = turn(jnp.einsum("btq,jdq->btjd", c_q, laid["Wq_index"]))
+        k = (x @ laid["WkI"]).astype(_acc(x.dtype))
+        k = rms_normalize(k - jnp.mean(k, axis=-1, keepdims=True), self.eps,
+                          laid["kI_gain"])
+        k = k + laid["kI_bias"].astype(k.dtype)
+        return q_idx, x @ laid["Ww"], turn(k.astype(x.dtype))
+
+    def init_state(self, dtype=jnp.float32):
+        # an indexer's step says whether its selection settled equal
+        # scores at the ``topk``-th place, for ``sparse_select_tied_total``
+        if self.index_heads:
+            return {"selection_tied": jnp.zeros((), jnp.int32)}
+        return {}
+
     def forward_seq(self, params, x, carry, *, train, rng=None, mask=None):
-        c_ring, r_ring, cursor = carry
-        t, cap = x.shape[1], c_ring.shape[1]
-        if t > cap:
-            raise ValueError(f"chunk of {t} timesteps exceeds the latent "
-                             f"ring's capacity {cap}")
+        return self.forward_seq_counted(params, x, carry, train=train,
+                                        rng=rng, mask=mask)[:2]
+
+    def forward_seq_counted(self, params, x, carry, *, train, rng=None,
+                            mask=None):
+        """``forward_seq``, and after the carry, where the layer has an
+        indexer, the step's state (``init_state``'s keys): what a served
+        step counts."""
+        t, cap = x.shape[1], carry[0].shape[1]
+        if t > (cap - self.window + 1 if self.window else cap):
+            raise ValueError(
+                f"chunk of {t} timesteps exceeds what the latent ring of "
+                f"{cap} slots takes at once"
+                + (f" under a window of {self.window}" if self.window
+                   else ""))
+        return self._attend(params, x, carry, mask)
+
+    def _attend(self, params, x, carry, mask=None):
+        *rings, cursor = carry
+        t = x.shape[1]
         # stored parameters (``fit``, ``output()``, a net not prepared,
         # a pinned version) are laid here, inside the step; behind a
         # barrier, so that the compiler multiplies the forms as made
@@ -587,34 +738,74 @@ class LatentAttention(BaseRecurrentLayer):
         inv_freq, factor = yarn_inv_freq(self.d_rope, self.rope_theta,
                                          self.rope_scaling)
         turn = lambda a: rotate(a, positions, inv_freq, factor)
-        q_nope, q_rope = self.queries(laid, x, turn)
-        c_ring, r_ring = latent_ring_update(
-            c_ring, r_ring, cursor, *self.compress(laid, x, turn))
-        with _monitor.subscope("latent_attention"):
-            q_lat = jnp.einsum("bthd,hdr->bthr", q_nope, laid["Wk_absorbed"])
-            ctx = latent_ring_attention(q_lat, q_rope, c_ring, r_ring,
-                                        cursor, sm_scale=self.sm_scale())
+        c_q = self.query_latent(laid, x)
+        q_nope, q_rope = self.queries(laid, c_q, turn)
+        compressed = self.compress(laid, x, turn)
+        counted = ()
+        if not self._joined:
+            rings = latent_ring_update(*rings, cursor, *compressed)
+            with _monitor.subscope("latent_attention"):
+                q_lat = jnp.einsum("bthd,hdr->bthr", q_nope,
+                                   laid["Wk_absorbed"])
+                ctx = latent_ring_attention(q_lat, q_rope, *rings, cursor,
+                                            sm_scale=self.sm_scale())
+        else:
+            # the query joined as the ring is: the absorbed no-position
+            # half beside the rotary one and zeros to whole lanes, one
+            # product a score
+            fill = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(
+                0, rings[0].shape[2] - self.kv_rank - self.d_rope)])
+            q = fill(jnp.concatenate(
+                [jnp.einsum("bthd,hdr->bthr", q_nope, laid["Wk_absorbed"]),
+                 q_rope.astype(q_nope.dtype)], axis=-1))
+            rings[0] = ring_write(rings[0], cursor,
+                                  fill(jnp.concatenate(compressed, axis=-1)),
+                                  wraps=bool(self.window))
+            kw = dict(rank=self.kv_rank, sm_scale=self.sm_scale())
+            if self.window:
+                with _monitor.subscope("window_latent_attention"):
+                    ctx = window_latent_attention(q, rings[0], cursor,
+                                                  window=self.window, **kw)
+            else:
+                with _monitor.subscope("indexer"):
+                    q_idx, w_idx, k_idx = self.indexer(laid, x, c_q,
+                                                       positions)
+                rings[1] = ring_write(rings[1], cursor, k_idx)
+                ctx, tied = sparse_latent_attention(
+                    q, q_idx, w_idx, *rings, cursor, topk=self.topk,
+                    scope=_monitor.subscope, **kw)
+                counted = ({"selection_tied": tied},)
         out = jnp.einsum("bthr,hrd->bthd", ctx, laid["Wv"])
+        if self.gate:
+            g = jax.nn.sigmoid((x @ laid["Wg"]).astype(_acc(x.dtype)))
+            out = out * g[..., None].astype(out.dtype)
         out = self._activate(
             out.reshape(x.shape[:2] + (-1,)) @ laid["Wo"])
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
-        return out, (c_ring, r_ring, cursor + jnp.asarray(t, jnp.int32))
+        return (out, (*rings, cursor + jnp.asarray(t, jnp.int32))) + counted
 
     def attention_path(self, t: int, carry) -> str:
-        """``"streamed"`` or ``"dense"``: the form ``forward_seq`` takes
-        for ``t`` new positions against ``carry``, by the op's own
-        predicate (host code asks it without tracing the step)."""
+        """The form ``forward_seq`` takes for ``t`` new positions against
+        ``carry``, by the op's own predicate (host code asks it without
+        tracing the step): ``"streamed"`` or ``"dense"`` over the two
+        rings of the plain layer, ``"gathered"``, ``"streamed"`` or
+        ``"masked"`` over a joined ring."""
+        if self._joined:
+            ring = carry[0]
+            return latent_walk_path(t, self.n_heads, ring.shape[2],
+                                    self.kv_rank, ring.shape[1], ring.dtype,
+                                    None if self.window else self.topk)
         c_ring, r_ring = carry[0], carry[1]
         return latent_ring_path(t, self.n_heads, c_ring.shape[2],
                                 r_ring.shape[2], c_ring.shape[1],
                                 c_ring.dtype)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
-        out, _ = self.forward_seq(
-            params, x, self.init_carry(x.shape[0], x.dtype, x.shape[1]),
-            train=train, rng=rng, mask=mask)
-        return out, state
+        # the whole sequence at once: rings as long as it, which never
+        # wrap, under the same rule of visibility
+        return self._attend(params, x, self._rings(
+            x.shape[0], x.dtype, x.shape[1]), mask)[0], state
 
 
 @serde.register("sparse_grouped_query_attention")

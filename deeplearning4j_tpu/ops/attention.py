@@ -1329,6 +1329,12 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
     # writes its row
     conversation = lambda i, j: i if chunk else j
     ring_block = lambda i, j: j if chunk else i
+    # the ring as XLA:TPU keeps it: a key that fills whole lanes slot
+    # after slot, in (block, d) blocks; a narrower one with its slots
+    # minor, as ``latent_ring_attention_streamed`` takes its narrow ring
+    # (a bitcast there, and a (d, block) tile fills its lanes wherever it
+    # is not)
+    rows_major = d % 128 == 0
 
     def kernel(cursor_ref, q_ref, w_ref, ring_ref, o_ref):
         ki = ring_block(pl.program_id(0), pl.program_id(1))
@@ -1336,10 +1342,10 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
 
         @pl.when(ki * block <= cursor_ref[0] + (t - 1))
         def _score():
-            ring = ring_ref[0]                          # (d, block)
+            ring = ring_ref[0]                  # (block, d) or (d, block)
             total = jnp.zeros((rows, block), jnp.float32)
             for j in range(products):
-                s = dot(q_ref[0, j], ring, ((1,), (0,)))
+                s = dot(q_ref[0, j], ring, ((1,), (1 if rows_major else 0,)))
                 total = total + jnp.maximum(s, 0.0) * w_ref[0, j]
             if not chunk:                               # rows are heads
                 total = jnp.sum(total, axis=0, keepdims=True)
@@ -1356,9 +1362,15 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
     else:
         out_shape = (batch, cap)
         out_spec = pl.BlockSpec((batch, block), lambda k, b, cur: (0, k))
-    # the ring with its slots minor, as ``latent_ring_attention_streamed``
-    # takes its narrow ring: a bitcast where XLA:TPU keeps it so, and a
-    # (d, block) tile fills its lanes wherever it does not
+    at_block = lambda i, j, cur: _newest_block(ring_block(i, j), cur, t,
+                                               block)
+    if rows_major:
+        ring_spec = pl.BlockSpec((1, block, d), lambda i, j, cur: (
+            conversation(i, j), at_block(i, j, cur), 0))
+    else:
+        ring_spec = pl.BlockSpec((1, d, block), lambda i, j, cur: (
+            conversation(i, j), 0, at_block(i, j, cur)))
+        i_ring = jnp.swapaxes(i_ring, 1, 2)
     scores = pl.pallas_call(
         kernel,
         out_shape=_sds(out_shape, jnp.float32, q_idx),
@@ -1368,16 +1380,14 @@ def indexer_scores_streamed(q_idx: Array, w_idx: Array, i_ring: Array,
             in_specs=[
                 pl.BlockSpec((1, products, rows, d), whole),
                 pl.BlockSpec((1, products, rows, 1), whole),
-                pl.BlockSpec((1, d, block), lambda i, j, cur: (
-                    conversation(i, j), 0,
-                    _newest_block(ring_block(i, j), cur, t, block))),
+                ring_spec,
             ],
             out_specs=out_spec),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
         interpret=interpret,
-    )(cursor, q_idx, w_idx, jnp.swapaxes(i_ring, 1, 2))
+    )(cursor, q_idx, w_idx, i_ring)
     return scores.reshape(batch, t, cap)
 
 
@@ -1928,27 +1938,36 @@ def window_ring_slots(window: int, chunk: int) -> int:
     return -(-need // block) * block
 
 
+def ring_write(ring: Array, cursor, rows: Array, *,
+               wraps: bool = False) -> Array:
+    """Write (batch, T, ...) ``rows`` into the ring (batch, capacity, ...)
+    at positions ``cursor .. cursor + T - 1``.  ``wraps``: position ``p``
+    goes to slot ``p mod capacity`` (a chunk may straddle the end: its
+    rows are scattered; a single position is one slice); otherwise
+    callers guarantee ``cursor + T <= capacity``."""
+    cap = ring.shape[1]
+    zero = jnp.zeros((), jnp.int32)
+    cursor = jnp.asarray(cursor, jnp.int32)
+    rows = rows.astype(ring.dtype)
+    t = rows.shape[1]
+    if wraps and t > 1:
+        slots = (cursor + jnp.arange(t, dtype=jnp.int32)) % cap
+        return ring.at[:, slots].set(rows, unique_indices=True)
+    start = cursor % cap if wraps else cursor
+    return jax.lax.dynamic_update_slice(
+        ring, rows, (zero, start) + (zero,) * (ring.ndim - 2))
+
+
 def gqa_ring_update(kv_ring: Array, cursor, k_new: Array, v_new: Array, *,
                     wraps: bool = False) -> Array:
     """Write (batch, T, kv heads x d) keys and values into the ring
     (batch, capacity, 2 x kv heads, d) at positions ``cursor .. cursor + T
-    - 1``.  ``wraps``: position ``p`` goes to slot ``p mod capacity`` (a
-    chunk may straddle the end: its rows are scattered; a single position
-    is one slice); otherwise callers guarantee ``cursor + T <=
-    capacity``."""
-    cap = kv_ring.shape[1]
-    zero = jnp.zeros((), jnp.int32)
-    cursor = jnp.asarray(cursor, jnp.int32)
+    - 1`` (:func:`ring_write`, a slot's key heads then its value
+    heads)."""
     by_head = lambda a: a.reshape(a.shape[:2] + (-1, kv_ring.shape[3]))
     rows = jnp.concatenate([by_head(k_new), by_head(v_new)],
                            axis=2).astype(kv_ring.dtype)
-    t = rows.shape[1]
-    if wraps and t > 1:
-        slots = (cursor + jnp.arange(t, dtype=jnp.int32)) % cap
-        return kv_ring.at[:, slots].set(rows, unique_indices=True)
-    start = cursor % cap if wraps else cursor
-    return jax.lax.dynamic_update_slice(kv_ring, rows,
-                                        (zero, start, zero, zero))
+    return ring_write(kv_ring, cursor, rows, wraps=wraps)
 
 
 def _lap(cursor, t: int, capacity: int):
@@ -1958,6 +1977,44 @@ def _lap(cursor, t: int, capacity: int):
     last = jnp.asarray(cursor, jnp.int32) + jnp.int32(t - 1)
     newest = last % jnp.int32(capacity)
     return last - newest, newest
+
+
+def _ring_walk(cursor, written: int, capacity: int, block: int,
+               window: Optional[int]) -> Array:
+    """The int32 scalars a streamed kernel walks a ring by, for queries
+    at ``cursor ..`` of which the ring holds the first ``written``:
+    ``[cursor, base, newest slot, first block, last step of the walk]``
+    (``_lap``'s two, then the blocks from the one that holds the oldest
+    position any query sees to the one that holds the newest, wrapping
+    with the ring)."""
+    num_blocks = capacity // block
+    cursor = jnp.asarray(cursor, jnp.int32)
+    base, newest = _lap(cursor, written, capacity)
+    oldest = (jnp.zeros((), jnp.int32) if window is None
+              else jnp.maximum(cursor - jnp.int32(window - 1), 0))
+    first_slot = oldest % jnp.int32(capacity)
+    first = first_slot // jnp.int32(block)
+    span = (first_slot - first * jnp.int32(block)
+            + (cursor + written - oldest))
+    walk = jnp.minimum((span - 1) // jnp.int32(block), num_blocks - 1)
+    return jnp.stack([cursor, base, newest, first, walk])
+
+
+def _walk_block(k, s, num_blocks: int):
+    """The ring block of grid step ``k`` of a walk by the scalars ``s``
+    (:func:`_ring_walk`): the walk's, or its last one again (a repeat is
+    not fetched)."""
+    return (s[3] + jnp.minimum(k, s[4])) % jnp.int32(num_blocks)
+
+
+def _walk_keep(s, slot, at, capacity: int, window: Optional[int]):
+    """Which of the slots ``slot`` the queries at positions ``at`` see,
+    by the rule of visibility from the walk's scalars ``s``."""
+    held = s[1] + slot - jnp.where(slot > s[2], jnp.int32(capacity), 0)
+    keep = (held >= 0) & (held <= at)
+    if window is not None:
+        keep = keep & (held > at - jnp.int32(window))
+    return keep
 
 
 def ring_visible(cursor, t: int, capacity: int,
@@ -2058,23 +2115,8 @@ def gqa_ring_attention_streamed(q: Array, kv_ring: Array, cursor, *,
     rows = group * tile
     words = not interpret and kv_ring.dtype == jnp.bfloat16
 
-    cursor = jnp.asarray(cursor, jnp.int32)
-    base, newest = _lap(cursor, written, cap)
-    # the oldest position any query sees, the slots from there to the
-    # newest one, and so the first block and how many the walk takes
-    oldest = (jnp.zeros((), jnp.int32) if window is None
-              else jnp.maximum(cursor - jnp.int32(window - 1), 0))
-    first_slot = oldest % jnp.int32(cap)
-    first = first_slot // jnp.int32(block)
-    span = (first_slot - first * jnp.int32(block)
-            + (cursor + written - oldest))
-    walk = jnp.minimum((span - 1) // jnp.int32(block), num_blocks - 1)
-    scalars = jnp.stack([cursor, base, newest, first, walk])
-
-    def ring_block(k, s):
-        """The ring block of grid step ``k``: the walk's, or its last
-        one again (a repeat is not fetched)."""
-        return (s[3] + jnp.minimum(k, s[4])) % jnp.int32(num_blocks)
+    scalars = _ring_walk(cursor, written, cap, block, window)
+    ring_block = lambda k, s: _walk_block(k, s, num_blocks)
 
     def kernel(s_ref, q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr):
         qi, ki = pl.program_id(1), pl.program_id(2)
@@ -2089,15 +2131,11 @@ def gqa_ring_attention_streamed(q: Array, kv_ring: Array, cursor, *,
         def _fold():
             slot = (ring_block(ki, s_ref) * block
                     + jax.lax.broadcasted_iota(jnp.int32, (part, block), 1))
-            held = s_ref[1] + slot - jnp.where(slot > s_ref[2],
-                                               jnp.int32(cap), 0)
             at = s_ref[0] + qi * tile
             if t > 1:
                 at = at + jax.lax.broadcasted_iota(jnp.int32,
                                                    (part, block), 0)
-            keep = (held >= 0) & (held <= at)
-            if window is not None:
-                keep = keep & (held > at - jnp.int32(window))
+            keep = _walk_keep(s_ref, slot, at, cap, window)
             _fold_ring_block(dot, lambda at_: q_ref[(0, 0) + at_], kv_ref,
                              keep, m_scr, l_scr, acc_scr, kv_heads=kv_heads,
                              rows=rows, part=part, block=block, words=words,
@@ -2162,3 +2200,362 @@ def gqa_ring_attention(q: Array, kv_ring: Array, cursor, *, sm_scale: float,
     return gqa_ring_attention_streamed(
         q, kv_ring, cursor, sm_scale=sm_scale, window=window,
         written=t)[:, :t]
+
+
+# ---------------------------------------------------------------------------
+# Latent attention over a JOINED latent ring: one row a slot, the compressed
+# key/value ``c_kv`` (rank) and the rotary key ``k_r`` (d_rope) side by side
+# and zeros to whole lanes of 128, (batch, slots, ``joined_width(rank,
+# d_rope)``), written by ``ring_write``: XLA:TPU keeps an array whose rows
+# are not whole lanes with its slots minor, and a kernel that reads rows
+# would then cost a transpose of the whole ring in and out of every step.
+# The queries are joined the same way, the absorbed ``q_nope`` (rank)
+# beside the rotary ``q_rope`` and zeros, so that a score is one product
+# over a row and the latent context is the product of the weights with the
+# row's first ``rank`` numbers.  Two layers read such a ring: a window's,
+# whose ring WRAPS under the rule of visibility above (``ring_visible``,
+# ``window_latent_attention``), and a full layer's, whose ring grows and
+# whose queries attend over the ``topk`` rows an indexer selects
+# (``sparse_latent_attention``: the indexer's scores and the exact
+# selection of the indexed sparse attention above).  Three forms, picked by
+# ``latent_walk_path`` from the call's shapes alone:
+#
+# gathered  on a TPU, a full layer's token step over a ring much longer
+#           than ``topk``: the selection as slot numbers
+#           (``selected_slots_streamed``), the selected rows taken out of
+#           the ring by XLA's gather, and the streamed kernel over them;
+# streamed  on a TPU, a chunk, a window's token step, or a token step over
+#           a shorter ring: ONE Pallas kernel that walks the blocks that
+#           hold a visible position, as ``gqa_ring_attention_streamed``
+#           does (the rule from the cursor; a full layer's selection as a
+#           mask besides), and folds them into the streaming softmax of
+#           every query row, so that no score leaves VMEM;
+# masked    the rule (and the selection) as a mask over dense ``jax.numpy``
+#           attention: any dtype, any backend, ``output()``; the kernels'
+#           oracle.
+# ---------------------------------------------------------------------------
+
+def joined_width(rank: int, d_rope: int) -> int:
+    """Numbers a slot of a joined latent ring: ``rank + d_rope`` rounded
+    up to whole lanes of 128."""
+    return -(-(rank + d_rope) // 128) * 128
+
+
+#: ring slots a grid step of the streamed latent kernel, largest first;
+#: the first that divides the ring and fits ``_LATENT_VMEM_BUDGET`` serves
+_LATENT_WALK_BLOCKS = (2048, 1024, 512, 256, 128)
+#: query rows (positions x heads) a grid step of a chunk folds at most
+_LATENT_QUERY_ROWS = 1024
+
+
+def _latent_walk_tile(t: int, heads: int) -> int:
+    """Positions a grid step of the streamed latent kernel takes: a token
+    step's one, else the most whole sublane tiles of 8 that divide ``t``
+    within ``_LATENT_QUERY_ROWS`` rows."""
+    if t == 1:
+        return 1
+    fits = [n for n in range(8, t + 1, 8)
+            if t % n == 0 and n * heads <= _LATENT_QUERY_ROWS]
+    return max(fits, default=8)
+
+
+def _latent_walk_vmem(rows: int, width: int, rank: int, block: int,
+                      itemsize: int) -> int:
+    """VMEM of one grid step, reckoned high: the ring's block, the
+    queries and the context double-buffered at whole lanes, the float32
+    accumulator and both statistics, four float32 (rows, block) tiles for
+    the scores on their way to ``p``."""
+    lanes = lambda d: -(-d // 128) * 128
+    return (2 * (block + rows) * lanes(width) * itemsize
+            + 2 * rows * lanes(rank) * itemsize
+            + rows * (lanes(rank) + 2 * 128) * 4 + 4 * rows * block * 4)
+
+
+def latent_walk_block(rows: int, width: int, rank: int, capacity: int,
+                      dtype) -> int:
+    """Ring slots a grid step of the streamed latent kernel for ``rows``
+    query rows, or 0 where no block both divides ``capacity`` and
+    fits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return next((b for b in _LATENT_WALK_BLOCKS if capacity % b == 0
+                 and _latent_walk_vmem(rows, width, rank, b, itemsize)
+                 <= _LATENT_VMEM_BUDGET), 0)
+
+
+def latent_walk_path(t: int, heads: int, width: int, rank: int,
+                     capacity: int, dtype, topk: Optional[int] = None) -> str:
+    """``"gathered"``, ``"streamed"`` or ``"masked"``: which form the
+    attention over a joined latent ring of ``capacity`` slots ``width``
+    wide stored in ``dtype`` takes for ``t`` new positions a row, of
+    which a full layer's query selects ``topk`` (``None``: a window's,
+    which selects nothing).  Streamed where Mosaic compiles the kernel (a
+    TPU), the storage is bfloat16 or float32, the latent rank fills whole
+    lanes, the new positions are fewer than the ring's slots (``output()``
+    from a zero ring is plain attention and stays masked; more than one
+    are padded to whole sublane tiles of 8) and a block divides the ring
+    with the rows in VMEM; masked elsewhere.  Gathered where the streamed
+    form's conditions hold, the call is a full layer's token step and the
+    ring is at least ``_GATHER_RATIO`` times ``topk`` long (the sparse
+    attention's crossing: a selected row is fetched at the cost of about
+    ten streamed ones).  Also what ``latent_attention_steps_total{path}``
+    is labelled by."""
+    padded = t if t == 1 else -(-t // 8) * 8
+    rows = _latent_walk_tile(padded, heads) * heads
+    streamed = (_mosaic()
+                and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                         jnp.dtype(jnp.float32))
+                and rank % 128 == 0 and t < capacity
+                and latent_walk_block(rows, width, rank, capacity, dtype) > 0)
+    if not streamed:
+        return "masked"
+    gathered = (topk is not None and t == 1
+                and capacity >= _GATHER_RATIO * topk and topk % 8 == 0
+                and latent_walk_block(heads, width, rank, topk, dtype) > 0)
+    return "gathered" if gathered else "streamed"
+
+
+def latent_attention_masked(q: Array, ring: Array, keep: Array, *,
+                            rank: int, sm_scale: float) -> Array:
+    """The dense form: (batch, T, heads, width) joined queries
+    against every slot of the (batch, capacity, width) ring,
+    ``keep`` (batch, T, capacity) saying which count; the latent context
+    (batch, T, heads, rank).  Any dtype, any backend; the scores are one
+    (batch, heads, T, capacity) array."""
+    acc = jnp.promote_types(q.dtype, jnp.float32)
+    s = _einsum_acc("bthw,bcw->bhtc", q, ring, acc) \
+        * jnp.asarray(sm_scale, acc)
+    return _softmax_context(s, keep[:, None], ring[..., :rank],
+                            "bhtc,bcr->bthr", q.dtype)
+
+
+def _make_latent_walk_kernel(*, sm_scale: float, rank: int, heads: int,
+                             tile: int, t: int, block: int, num_blocks: int,
+                             capacity: int, window: Optional[int],
+                             selected: bool, widen: bool):
+    """The streaming softmax of one conversation's query rows (``tile``
+    positions x ``heads``, position major) over the ring blocks of a walk: a
+    block's slots are kept by the rule of visibility (and, ``selected``,
+    by the selection's block), a score is one product over the joined
+    row, the context the product of the weights with the row's latent
+    part.  A row that has kept nothing yet holds its maximum at the
+    mask's value: its entries are not exp(0)."""
+    scale, masked = np.float32(sm_scale), np.float32(_NEG_INF)
+    dot = _kernel_dot(widen)
+    rows = heads * tile
+
+    def kernel(s_ref, q_ref, ring_ref, *refs):
+        keep_ref = refs[0] if selected else None
+        o_ref, m_scr, l_scr, acc_scr = refs[1:] if selected else refs
+        qi, ki = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr[:], masked)
+            l_scr[:] = jnp.zeros_like(l_scr[:])
+            acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+        @pl.when(ki <= s_ref[4])
+        def _fold():
+            slot = (_walk_block(ki, s_ref, num_blocks) * block
+                    + jax.lax.broadcasted_iota(jnp.int32, (tile, block), 1))
+            at = s_ref[0] + qi * tile
+            if t > 1:
+                at = at + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (tile, block), 0)
+            keep = _walk_keep(s_ref, slot, at, capacity, window)
+            if selected:
+                keep = keep & (keep_ref[0, 0].astype(jnp.float32) != 0.0)
+            # a position's marks for each of its heads (rows run position
+            # after position), laid as float32 rows of whole tiles
+            keep = jnp.broadcast_to(keep.astype(jnp.float32)[:, None],
+                                    (tile, heads, block)).reshape(
+                                        rows, block) > 0.5
+            blk = ring_ref[0]                           # (block, width)
+            s = dot(q_ref[0, 0], blk, ((1,), (1,))) * scale
+            s = jnp.where(keep, s, masked)
+            m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alive = m_new > masked / 2
+            p = jnp.where(alive & keep, jnp.exp(s - m_new), 0.0)
+            correction = jnp.where(alive, jnp.exp(m_prev - m_new), 0.0)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[:] = acc_scr[:] * correction + dot(
+                p.astype(blk.dtype), blk[:, :rank], ((1,), (0,)))
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when(ki == num_blocks - 1)
+        def _finalize():
+            # a padded query may have seen nothing: its row is cut off
+            o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+                           ).astype(o_ref.dtype)
+
+    return kernel
+
+
+def latent_attention_streamed(q: Array, ring: Array, cursor, *, rank: int,
+                              sm_scale: float, window: Optional[int] = None,
+                              selected: Optional[Array] = None,
+                              written: Optional[int] = None,
+                              block: Optional[int] = None,
+                              interpret: Optional[bool] = None) -> Array:
+    """:func:`latent_attention_masked` as one Pallas kernel that reads
+    each conversation's visible blocks once: grid (batch, query tiles,
+    ring blocks).  A step takes one block of the joined ring, works out
+    which of its slots each query sees from the cursor alone (the rule of
+    ``ring_visible``: causal, within ``window`` where one is given, the
+    walk wrapping with the ring), and where ``selected`` (batch, T,
+    capacity) is given keeps only the slots it marks too; the blocks
+    before the oldest and beyond the newest visible position are neither
+    fetched nor computed.  The latent context (batch, T, heads, rank).
+
+    A chunk whose length is not whole sublane tiles of 8 (a prompt's
+    remainder) is padded with queries whose rows are cut off again
+    (``written``, default ``T``, says how many positions the ring holds;
+    the padded queries stand beyond its newest and are nobody's)."""
+    t = q.shape[1]
+    if t > 1 and t % 8:
+        grow = lambda a: jnp.pad(
+            a, [(0, 0), (0, -t % 8)] + [(0, 0)] * (a.ndim - 2))
+        return latent_attention_streamed(
+            grow(q), ring, cursor, rank=rank, sm_scale=sm_scale,
+            window=window,
+            selected=None if selected is None else grow(selected),
+            written=t if written is None else written, block=block,
+            interpret=interpret)[:, :t]
+    batch, t, heads, width = q.shape
+    written = t if written is None else int(written)
+    cap = ring.shape[1]
+    tile = _latent_walk_tile(t, heads)
+    tiles, rows = t // tile, heads * tile
+    block = block or latent_walk_block(rows, width, rank, cap, ring.dtype)
+    if not block or cap % block:
+        raise ValueError(f"no block of the streamed latent attention "
+                         f"divides a ring of {cap} slots (block {block})")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    num_blocks = cap // block
+    scalars = _ring_walk(cursor, written, cap, block, window)
+    # (batch, tiles, tile x heads, width): a tile's rows, position major
+    # (the queries as they lie: no copy)
+    qt = q.reshape(batch, tiles, rows, width)
+    whole = lambda b, i, k, s: (b, i, 0, 0)
+    in_specs = [pl.BlockSpec((1, 1, rows, width), whole),
+                pl.BlockSpec((1, block, width), lambda b, i, k, s: (
+                    b, _walk_block(k, s, num_blocks), 0))]
+    operands = [scalars, qt, ring]
+    if selected is not None:
+        in_specs.append(pl.BlockSpec((1, 1, tile, block), lambda b, i, k, s:
+                                     (b, i, 0, _walk_block(k, s, num_blocks))))
+        operands.append(selected.astype(jnp.bfloat16).reshape(
+            batch, tiles, tile, cap))
+    out = pl.pallas_call(
+        _make_latent_walk_kernel(
+            sm_scale=float(sm_scale), rank=rank, heads=heads, tile=tile,
+            t=t, block=block, num_blocks=num_blocks, capacity=cap,
+            window=window, selected=selected is not None,
+            widen=bool(interpret)),
+        out_shape=_sds((batch, tiles, rows, rank), q.dtype, q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, tiles, num_blocks),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, rows, rank), whole),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 128), jnp.float32),    # running max
+                pltpu.VMEM((rows, 128), jnp.float32),    # running denom
+                pltpu.VMEM((rows, rank), jnp.float32),   # weighted sum
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )(*operands)
+    return out.reshape(batch, t, heads, rank)
+
+
+def window_latent_attention(q: Array, ring: Array, cursor, *, rank: int,
+                            window: int, sm_scale: float) -> Array:
+    """Attention of (batch, T, heads, width) joined queries at
+    positions ``cursor .. cursor + T - 1`` over a window's joined latent
+    ring that WRAPS and already holds them (the query's own position and
+    the ``window - 1`` before it): the latent context, (batch, T, heads,
+    rank).  Two forms of one rule, picked by :func:`latent_walk_path`."""
+    batch, t, heads, width = q.shape
+    cap = ring.shape[1]
+    if latent_walk_path(t, heads, width, rank, cap, ring.dtype) == "masked":
+        keep = ring_visible(cursor, t, cap, window)
+        return latent_attention_masked(
+            q, ring, jnp.broadcast_to(keep[None], (batch,) + keep.shape),
+            rank=rank, sm_scale=sm_scale)
+    return latent_attention_streamed(q, ring, cursor, rank=rank,
+                                     sm_scale=sm_scale, window=window)
+
+
+def sparse_latent_attention(q: Array, q_idx: Array, w_idx: Array,
+                            ring: Array, i_ring: Array, cursor, *, rank: int,
+                            topk: int, sm_scale: float, scope=None):
+    """Attention of (batch, T, heads, width) joined queries over
+    the ``topk`` rows of a full layer's joined latent ring (a ring that
+    grows) that their indexer picks: ``(latent context (batch, T, heads,
+    rank), tied)``, ``tied`` an int32 that is 1 where the gathered form's
+    selection found equal scores straddling the ``topk``-th place in
+    some row, 0 otherwise and in the other forms.  ``q_idx`` (batch, T,
+    index heads, d_index), ``w_idx`` (batch, T, index heads) and the
+    indexer-key ring ``i_ring`` (batch, capacity, d_index) are what
+    :func:`sparse_ring_attention` takes; slot ``c`` is visible to query
+    ``t`` iff ``c <= cursor + t``; the rings already hold the new
+    positions.  ``scope(part)``, where given, is entered around each part
+    (``indexer``, ``select``, ``sparse_latent_attention``)."""
+    scope = scope or (lambda part: contextlib.nullcontext())
+    batch, t, heads, width = q.shape
+    cap = ring.shape[1]
+    path = latent_walk_path(t, heads, width, rank, cap, ring.dtype, topk)
+    cursor = jnp.asarray(cursor, jnp.int32)
+    kw = dict(rank=rank, sm_scale=sm_scale)
+    if path == "streamed" and t > 1 and t % 8:
+        # the kernels take whole sublane tiles of positions: a chunk of
+        # another length (a prompt's remainder) is padded with queries
+        # whose rows are cut off again; the ring holds the ``t`` real
+        # positions, and what the padded queries read is nobody's
+        grow = lambda a: jnp.pad(
+            a, [(0, 0), (0, -t % 8)] + [(0, 0)] * (a.ndim - 2))
+        q, q_idx, w_idx = grow(q), grow(q_idx), grow(w_idx)
+
+    def attend(picked):
+        with scope("sparse_latent_attention"):
+            if path == "gathered":
+                slots, _ = picked
+                rows = jnp.take_along_axis(ring, slots[..., None], axis=1)
+                # the listed rows are a ring of ``topk`` slots whose first
+                # ``min(cursor, topk - 1) + 1`` every row selected (the
+                # rest name slot 0 and are not seen)
+                return latent_attention_streamed(
+                    q, rows, jnp.minimum(cursor, topk - 1), **kw)
+            if path == "streamed":
+                return latent_attention_streamed(
+                    q, ring, cursor, selected=picked, written=t, **kw)[:, :t]
+            keep = visible_slots(cursor, t, cap)[None]
+            if picked is not None:
+                keep = keep & picked
+            return latent_attention_masked(
+                q, ring, jnp.broadcast_to(keep, (batch, t, cap)), **kw)
+
+    tied = jnp.zeros((), jnp.int32)
+    if cap <= topk:
+        # every visible row is selected: no score decides anything
+        return attend(None), tied
+    with scope("indexer"):
+        scores = (indexer_scores(q_idx, w_idx, i_ring) if path == "masked"
+                  else indexer_scores_streamed(q_idx, w_idx, i_ring, cursor))
+    with scope("select"):
+        if path == "gathered":
+            slots, count, tie = selected_slots_streamed(
+                scores.reshape(batch, cap), cursor, topk)
+            picked, tied = (slots, count), tie.reshape(())
+        elif path == "streamed":
+            picked = select_mask_streamed(scores, cursor, topk)
+        else:
+            picked = select_mask(scores, visible_slots(cursor, t, cap)[None],
+                                 topk)
+    return attend(picked), tied

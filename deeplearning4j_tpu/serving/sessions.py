@@ -32,8 +32,9 @@ whatever the model's carry contract says it is:
 - **Capacity is about the rings that grow.**  A session's ``capacity``,
   the ladder, ``prefill(cache_len=)`` and the "does not fit" checks
   speak of the rings whose length follows the session's (latent rings,
-  key/value rings of full attention, ``sparse_kv``).  A window layer's
-  ring (``GroupedQueryAttention(window=...)``, kind ``window_kv``) is
+  key/value rings of full attention, ``sparse_kv``, ``sparse_latent``).
+  A window layer's ring (``GroupedQueryAttention(window=...)``, kind
+  ``window_kv``; ``LatentAttention(window=...)``, ``window_latent``) is
   sized once by the layer at its window, wraps, and is neither grown
   by a hop nor asked whether a prompt fits: a session's position
   passes it by any amount.  A model whose rings are all windows has an
@@ -196,9 +197,11 @@ class SessionCache:
         self._decode = bool(getattr(model, "has_kv_ring",
                                     lambda: False)())
         # ring state by kind, as the layers name theirs (``STATE_KIND``:
-        # ``latent`` rings of compressed rows, ``sparse_kv`` key/value
-        # rings with an indexer's beside them, ``kv`` key/value rings of
-        # full attention, ``window_kv`` a window's rings, which wrap):
+        # ``latent`` rings of compressed rows, ``sparse_latent`` a joined
+        # latent ring with an indexer's beside it, ``window_latent`` a
+        # window's joined latent ring, which wraps, ``sparse_kv``
+        # key/value rings with an indexer's beside them, ``kv`` key/value
+        # rings of full attention, ``window_kv`` a window's rings):
         # ``{kind: [vertices]}``
         self._ring_kinds: dict = {}
         if self._decode and self._is_graph:
@@ -572,7 +575,8 @@ class SessionCache:
         # the first step takes the ``fed`` ids, every later one its own
         by_length = Counter([fed] + [1] * (n - 1))
         self._count_attention_steps(
-            sess, by_length, ("latent", "sparse_kv", "kv", "window_kv"),
+            sess, by_length, ("latent", "sparse_latent", "window_latent",
+                              "sparse_kv", "kv", "window_kv"),
             pinned=bool(kw))
         self._count_expert_steps(batch, by_length)
         tokens = _monitor.counter(
@@ -638,16 +642,21 @@ class SessionCache:
             "their dense grouped-query attention reads (kv: one that "
             "grows; window_kv: a window's, which wraps) and the form it "
             "took")
+        latent = _monitor.counter(
+            "latent_attention_steps_total",
+            "launched token steps, by the kind of latent attention (full: "
+            "over every position; sparse: over the "
+            "rows an indexer selects; window: over a window's ring, which "
+            "wraps), the form it took and the weights it multiplied")
         counters = {
-            "kv": dense, "window_kv": dense,
-            "latent": _monitor.counter(
-                "latent_attention_steps_total",
-                "launched token steps, by the form their latent attention "
-                "took and the weights it multiplied"),
+            "kv": dense, "window_kv": dense, "latent": latent,
+            "sparse_latent": latent, "window_latent": latent,
             "sparse_kv": _monitor.counter(
                 "sparse_attention_steps_total",
                 "launched token steps and prefill chunks, by the form "
                 "their indexed sparse attention took")}
+        latent_kinds = {"latent": "full", "sparse_latent": "sparse",
+                        "window_latent": "window"}
         for kind in kinds:
             vertices = self._ring_kinds.get(kind, ())
             if not vertices:
@@ -658,14 +667,15 @@ class SessionCache:
                 for v in vertices:
                     form = {"path": model.vertices[v].layer.attention_path(
                         t, sess.carries[v])}
-                    if kind == "latent":
+                    if launched is latent:
+                        form["kind"] = latent_kinds[kind]
                         form["weights"] = "laid" if v in laid else "stored"
                     elif launched is dense:
                         form["kind"] = kind
                     forms.add(tuple(form.items()))
                 for form in forms:
                     launched.inc(steps, **dict(form))
-            if kind == "sparse_kv":
+            if kind in ("sparse_kv", "sparse_latent"):
                 selected = _monitor.gauge(
                     "sparse_attention_selected",
                     "cached rows a query of the newest position reads, "

@@ -156,7 +156,7 @@ def streamed_net(monkeypatch):
 
 def _steps(path, weights="stored"):
     return monitor.counter("latent_attention_steps_total", "").value(
-        path=path, weights=weights)
+        kind="full", path=path, weights=weights)
 
 
 def test_streamed_prefill_in_chunks_then_decode_agrees_with_the_full_forward(

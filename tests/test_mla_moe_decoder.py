@@ -585,7 +585,7 @@ def test_a_share_counts_the_picks_that_named_its_experts(nets, ids):
     before = {v: held_picks(v) for v in ("L1_moe", "L2_moe")}
     launched = (steps("moe_experts_steps_total", "dense"),
                 steps("latent_attention_steps_total", "dense",
-                      weights="stored"))
+                      kind="full", weights="stored"))
     with InferenceEngine(flat, max_batch_size=4) as engine:
         engine.prefill_session("s", ids[:, :-1], chunk=8, cache_len=32)
         out = engine.generate("s", ids[:, -1:], 5)
@@ -599,7 +599,7 @@ def test_a_share_counts_the_picks_that_named_its_experts(nets, ids):
     # three prefill chunks (8, 8 and 3 tokens a row) and five token steps
     assert steps("moe_experts_steps_total", "dense") - launched[0] == 8
     # a net not prepared for serving lays its weights inside the step
-    assert steps("latent_attention_steps_total", "dense",
+    assert steps("latent_attention_steps_total", "dense", kind="full",
                  weights="stored") - launched[1] == 5
     # a net that holds every expert counts every pick
     _, whole, _ = nets["streams"]
@@ -883,7 +883,7 @@ def test_a_served_session_counts_its_steps_as_laid(ids):
     tree (handed to the step as stored) says so."""
     def steps(weights):
         return monitor.counter("latent_attention_steps_total", "").value(
-            path="dense", weights=weights)
+            kind="full", path="dense", weights=weights)
 
     served = build(for_inference=True, kind="plain")
     before = steps("laid"), steps("stored")
