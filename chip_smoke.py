@@ -119,6 +119,9 @@ class Sizes:
     # and of one layer of the share's cell under its token step: (tokens,
     # the router's width, experts held, width, hidden, picks a token)
     experts_share_shape: Tuple[int, ...] = (256, 192, 12, 2048, 7168, 8)
+    # and of one chip of eight's token step (command_a's), whose picks
+    # leave most held experts without one: the same order
+    experts_step_shape: Tuple[int, ...] = (8, 128, 16, 4096, 4096, 8)
     # the indexed sparse attention of one layer of the sparse cell:
     # (batch, query heads, key/value heads, head size, indexer heads,
     # indexer head size, ring slots, selected rows, a chunk's positions)
@@ -423,6 +426,8 @@ def phase_kernels(sz: Sizes):
         sz, "grouped", tokens, n_experts, n_experts, *widths)
     report["experts_share_grouped_max_rel_err"] = _experts_kernel(
         sz, "held_rows", *sz.experts_share_shape)
+    report["experts_step_grouped_max_rel_err"] = _experts_kernel(
+        sz, "grouped", *sz.experts_step_shape)
     report["sparse_streamed_max_rel_err"] = _sparse_kernels(sz)
     report["gqa_ring_streamed_max_rel_err"] = _gqa_ring_kernels(sz)
     return report

@@ -529,8 +529,9 @@ class ComputationGraph(Network):
         with no fetch between.  Also out: the float32 logits of the
         first and the last row at that position (computed anyway; what
         a comparison with a reference reads), and ``counts`` plus this
-        step's tokens by expert and, last in the row, whether its
-        grouped experts spilled, one row a vertex of
+        step's tokens by expert, then the held experts whose blocks it
+        read and, last in the row, whether its grouped experts spilled,
+        one row a vertex of
         ``_expert_vertices``; then a row a vertex of
         ``_selection_vertices`` whose last column says whether its
         selection settled ties.  The carries and the counts are donated:
@@ -546,8 +547,9 @@ class ComputationGraph(Network):
             last = acts[out_name][:, -1]
             next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)[:, None]
             kept = jnp.stack([last[0], last[-1]])
-            picks = [jnp.append(new_state[n]["expert_tokens"],
-                                new_state[n]["experts_spilled"])
+            picks = [jnp.concatenate([new_state[n]["expert_tokens"],
+                                      new_state[n]["experts_read"][None],
+                                      new_state[n]["experts_spilled"][None]])
                      for n in self._expert_vertices()]
             # the rare branch of each step, in the last column
             picks += [jnp.zeros(counts.shape[1], jnp.int32).at[-1].set(
@@ -983,7 +985,7 @@ class ComputationGraph(Network):
         new carries)``, all device arrays, ONE dispatch.  ``ids`` is
         (batch, time); ``carries`` and ``counts`` are consumed (donated).
         ``counts`` is ``(len(_expert_vertices()) +
-        len(_selection_vertices()), n_experts + 1)`` int32 or None to
+        len(_selection_vertices()), n_experts + 2)`` int32 or None to
         start from zero."""
         self.init()
         if counts is None:
@@ -994,7 +996,7 @@ class ComputationGraph(Network):
             carries, ids, counts)
 
     def zero_expert_counts(self):
-        rows = [self.net_state[n]["expert_tokens"].shape[0] + 1
+        rows = [self.net_state[n]["expert_tokens"].shape[0] + 2
                 for n in self._expert_vertices()]
         rows += [1] * len(self._selection_vertices())
         return jnp.zeros((len(rows), max(rows, default=0)), jnp.int32)

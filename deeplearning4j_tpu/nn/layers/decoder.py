@@ -253,12 +253,16 @@ class MixtureOfExperts(FeedForwardLayerConfig):
     TPU takes the grouped form, ``ops.experts.grouped_experts``, which
     puts each (token, pick) pair through its own expert only, over the
     same matrices where they lie, and so does the token step of a share
-    whose tokens are many for the few picks that land on it
+    whose tokens are many for the few picks that land on it, or so few
+    that most held experts get no pick and are not read
     (``experts_path``: from the call's shapes alone).  State
     ``expert_tokens`` (``n_experts`` int32) counts the picks of the last
-    call, for ``moe_expert_tokens_total``, and ``experts_spilled``
+    call, for ``moe_expert_tokens_total``, ``experts_spilled``
     (int32) says whether its held pairs outgrew the grouped form's rows
-    and took further rounds, for ``moe_experts_spilled_total``.
+    and took further rounds, for ``moe_experts_spilled_total``, and
+    ``experts_read`` (int32) how many held experts' blocks it read (every
+    held one in the dense forms, the picked ones in the grouped form),
+    for ``moe_experts_read_total``.
     """
 
     n_experts: int = 8
@@ -309,7 +313,8 @@ class MixtureOfExperts(FeedForwardLayerConfig):
 
     def init_state(self, dtype=jnp.float32):
         return {"expert_tokens": jnp.zeros((self.n_experts,), jnp.int32),
-                "experts_spilled": jnp.zeros((), jnp.int32)}
+                "experts_spilled": jnp.zeros((), jnp.int32),
+                "experts_read": jnp.zeros((), jnp.int32)}
 
     def route(self, params: ParamTree, x: Array):
         """(tokens, top_k) expert indices and weights, in float32."""
@@ -352,15 +357,17 @@ class MixtureOfExperts(FeedForwardLayerConfig):
             counts = jnp.sum(
                 idx[:, :, None] == jnp.arange(self.n_experts)[None, None],
                 axis=(0, 1), dtype=jnp.int32)
-        spilled = jnp.zeros((), jnp.int32)
+        # the dense form reads every held expert
+        spilled, read = (jnp.zeros((), jnp.int32),
+                         jnp.asarray(len(self.held()), jnp.int32))
         matrices = params["Wg"], params["Wu"], params["Wd"]
         with _monitor.subscope("experts"):
             if path == "grouped":
-                y, spilled = grouped_experts(
+                y, spilled, read = grouped_experts(
                     x, idx, w, *matrices, held=self.held(),
                     n_experts=self.n_experts)
             elif path == "held_rows":
-                y, spilled = held_rows_experts(
+                y, spilled, read = held_rows_experts(
                     x, jnp.any(hit, axis=(1, 2)), combine, *matrices,
                     rows=held_token_rows(x.shape[0], self.top_k,
                                          len(self.held()), self.n_experts))
@@ -377,7 +384,8 @@ class MixtureOfExperts(FeedForwardLayerConfig):
                                      f"'average', not {self.shared_combine!r}")
                 y = y + shared
         return y.reshape(shape), {"expert_tokens": counts,
-                                  "experts_spilled": spilled}
+                                  "experts_spilled": spilled,
+                                  "experts_read": read}
 
 
 # -------------------------------------------------------------- attention

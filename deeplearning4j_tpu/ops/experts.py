@@ -17,7 +17,12 @@ no pair is dropped.  Under a share (the layer holds some of the experts
 its router picks from) most pairs name an expert that lies on another
 chip: the held pairs alone are laid in rows, a number of rows reckoned
 from the shapes (:func:`grouped_rows`), and a step whose held pairs
-outgrow them takes further rounds of as many.
+outgrow them takes further rounds of as many.  A share's token step
+sends few pairs to its chip (8 of 64 at 8 tokens of 8 picks, 16 of 128
+experts held), and most held experts get none: the grouped form then
+lays them in one tile of whole sublane tiles of 16 rows and fetches the
+blocks of the held experts its picks name alone, where the dense form
+reads every held expert.
 
 The matrices are read where they lie: ``Wg``/``Wu`` (hidden, held x
 width) and ``Wd`` (held x width, hidden), expert after expert, so an
@@ -58,6 +63,35 @@ _TILE_ROWS = 128
 #: at which the grouped form wins, and the token step's 64 rows keep the
 #: program they had
 _GROUPED_MIN_TOKENS = 512
+#: rows of a bfloat16 sublane tile: rows few enough to be one tile of the
+#: grouped product are rounded to whole such tiles, not to ``_TILE_ROWS``
+#: (a token step's 16 rows at 128 would multiply 8 times the rows, as
+#: long at the peak as an expert's bytes at the command_a cell's widths)
+_SUBLANE_ROWS = 16
+#: a share's token step whose picks leave held experts without one: the
+#: dense form reads every held expert, the grouped form the blocks of the
+#: picked ones (:func:`grouped_step_wins`).  Swept on a v5e,
+#: ``tools/moe_experts_sweep.py --step``, milliseconds a layer, dense /
+#: grouped, by :func:`picked_share`: 16 of 128 held of (4,096, 4,096),
+#: 2.149 / 0.970 at 0.403, 2.152 / 1.779 at 0.873, 2.157 / 1.926 at
+#: 0.924, 2.162 / 2.179 at 0.955; of (2,048, 768), 0.214 / 0.119 at
+#: 0.403, 0.212 / 0.230 at 0.644, 0.225 / 0.296 at 0.984; 8 of 256 of
+#: (5,120, 1,536), 0.521 / 0.296 at 0.638, 0.524 / 0.443 at 0.782, 0.535
+#: / 0.501 at 0.953, 0.533 / 0.568 at 0.983.  The crossing is 0.92-0.96,
+#: under 0.64 and 0.95-0.98: no one share serves every width.  Read
+#: whole, the grouped form takes 1.03, 1.31 and 1.07 times the dense
+#: one: a grid step (a column tile of one expert's block) costs about
+#: 1.3 us beside its bytes, which weighs on blocks of 3.1 MB and not on
+#: blocks of 16 MB; laying the rows, the group tables and the sum of a
+#: token's rows cost about 40 us a layer (the profiled pieces in PERF.md
+#: section 5); XLA's product reads an expert matrix at about 750 GB/s
+#: (467-481 us for 352 MB).  So reckoned, the crossing at 16 rows is
+#: 0.927, 0.612 and 0.867 at those three widths (never the grouped form
+#: where the sweep found it the slower), and 0.911 at (7,168, 2,048), where A.X-K1's 64 tokens (0.934)
+#: stay dense
+_GROUPED_STEP_S = 1.3e-6
+_GROUPED_FIXED_S = 40e-6
+_EXPERT_BYTES_PER_S = 750e9
 #: rows at which the dense form's operations show beside the experts'
 #: bytes.  Under a share (fewer experts held than the router is wide) it
 #: is laid over the tokens that picked a held expert alone from as many
@@ -124,7 +158,35 @@ def held_token_rows(tokens: int, top_k: int, held: int,
             / math.comb(n_experts, top_k))
     rows = math.ceil(tokens * (1 - miss) + _HELD_ROWS_SIGMAS
                      * math.sqrt(tokens * miss * (1 - miss)))
-    return -(-min(rows, tokens) // 16) * 16
+    return -(-min(rows, tokens) // _SUBLANE_ROWS) * _SUBLANE_ROWS
+
+
+def picked_share(tokens: int, top_k: int, n_experts: int) -> float:
+    """The share of a router's ``n_experts`` experts that an even router
+    gives at least one of ``tokens`` tokens' ``top_k`` picks: each token
+    picks a given expert with probability ``top_k / n_experts``."""
+    return 1.0 - (1.0 - top_k / n_experts) ** tokens
+
+
+def grouped_step_wins(tokens: int, top_k: int, held: int, n_experts: int,
+                      hidden: int, width: int, itemsize: int,
+                      tile: int) -> bool:
+    """Whether the grouped form, reading the blocks of the held experts
+    an even router gives a pick (:func:`picked_share`) in column tiles
+    for ``tile`` rows, a grid step's cost each, and laying its rows
+    besides, takes less time than the dense form reading every held
+    expert: reckoned from the shapes and the sweep above.  It holds for
+    8 tokens of 8 picks over 16 of 128 experts at both widths of those
+    shares (0.403 picked) and for 32 over 8 of 256 (0.638); not for
+    A.X-K1's 64 tokens over 12 of 192 (0.934)."""
+    expert = 3 * hidden * width * itemsize
+    steps = (2 * width // grouped_tile_columns(hidden, width, itemsize, tile)
+             + hidden // grouped_tile_columns(width, hidden, itemsize, tile))
+    dense = held * expert / _EXPERT_BYTES_PER_S
+    grouped = (picked_share(tokens, top_k, n_experts) * held
+               * (expert / _EXPERT_BYTES_PER_S + steps * _GROUPED_STEP_S)
+               + _GROUPED_FIXED_S)
+    return grouped < dense
 
 
 def moe_experts_path(tokens: int, held: int, n_experts: int, top_k: int,
@@ -133,32 +195,43 @@ def moe_experts_path(tokens: int, held: int, n_experts: int, top_k: int,
     routed experts' products take for ``tokens`` tokens through ``held``
     held experts of (``hidden``, ``width``) with ``top_k`` picks a token
     of a router ``n_experts`` wide.  Dense off a TPU (where the other
-    forms were measured), for float64 (Mosaic has none), in training
-    (the kernel has no VJP) and where a token chooses every held expert.
-    Under a share (``held < n_experts``), from the tokens at which the
-    dense form's operations no longer hide under the experts' bytes, the
-    dense form is laid over the tokens that picked a held expert alone
-    (:func:`held_rows_experts`) while those rows are few enough that its
-    operations hide again.  Grouped from the tokens at which that form
-    wins, where an expert's block tiles: both sizes whole lanes, the
-    block within the kernel's VMEM.  Also what
-    ``moe_experts_steps_total{path}`` is labelled by."""
+    forms were measured), for float64 (Mosaic has none) and in training
+    (the kernel has no VJP).  Grouped under a share (``held <
+    n_experts``) wherever an even router leaves enough held experts
+    without a pick that the blocks of the picked ones alone cost less
+    than every held expert's (:func:`grouped_step_wins`).  ``tokens`` are those the layer is handed:
+    a token step of a few conversations whose picks reach this share
+    alone (a lightly loaded pool, a decode tier at low concurrency).  A
+    chip of a loaded pool is handed the pool's picks for its experts,
+    several an expert, and stays dense.
+    Otherwise dense where a token may choose every held expert; under a
+    share, from the tokens at which the dense form's operations no
+    longer hide under the experts' bytes, the dense form laid over the
+    tokens that picked a held expert alone (:func:`held_rows_experts`)
+    while those rows are few enough that its operations hide again; and
+    grouped from the tokens at which that form wins.  Grouped only where
+    an expert's block tiles: both sizes whole lanes, the block within
+    the kernel's VMEM.  Also what ``moe_experts_steps_total{path}`` is
+    labelled by."""
     itemsize = jnp.dtype(dtype).itemsize
-    if not (_mosaic() and not train and top_k < held
+    if not (_mosaic() and not train
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32))):
+        return "dense"
+    _, tile = grouped_rows(tokens, top_k, held, n_experts)
+    tiles = (hidden % 128 == 0 and width % 128 == 0
+             and grouped_tile_columns(hidden, width, itemsize, tile) > 0
+             and grouped_tile_columns(width, hidden, itemsize, tile) > 0)
+    if tiles and held < n_experts and grouped_step_wins(
+            tokens, top_k, held, n_experts, hidden, width, itemsize, tile):
+        return "grouped"
+    if top_k >= held:
         return "dense"
     if held < n_experts and tokens >= _DENSE_TURN_ROWS and held_token_rows(
             tokens, top_k, held, n_experts) <= min(tokens - 1,
                                                    _DENSE_TURN_ROWS):
         return "held_rows"
-    _, tile = grouped_rows(tokens, top_k, held, n_experts)
-    if (tokens >= _GROUPED_MIN_TOKENS
-            and hidden % 128 == 0 and width % 128 == 0
-            and grouped_tile_columns(hidden, width, itemsize, tile) > 0
-            and grouped_tile_columns(width, hidden, itemsize, tile) > 0):
-        return "grouped"
-    return "dense"
+    return "grouped" if tiles and tokens >= _GROUPED_MIN_TOKENS else "dense"
 
 
 def group_tiles(sizes: Array, rows: int, tm: int):
@@ -213,7 +286,7 @@ def _make_grouped_kernel(tm: int, widen: bool):
 
 
 def grouped_matmul(lhs: Array, rhs: Array, tiles, *, groups_along: int,
-                   tm: int = _TILE_ROWS,
+                   fetched: float, tm: int = _TILE_ROWS,
                    interpret: Optional[bool] = None) -> Array:
     """``lhs[offsets[g]:offsets[g + 1]] @ rhs_g`` for every group ``g``:
     ``lhs`` (rows, k) holds the groups' rows one after another (``tiles``
@@ -224,7 +297,11 @@ def grouped_matmul(lhs: Array, rhs: Array, tiles, *, groups_along: int,
     out; rows beyond the last group are left unwritten.
     Grid (column tiles, visits): a row tile's visits are consecutive, so
     its output block stays in VMEM from the first to the last of them,
-    and an expert's block is fetched once a column tile."""
+    and an expert's block is fetched once a column tile; a group with no
+    row is not fetched.  The kernel declares its bytes
+    (``pl.CostEstimate``): ``fetched`` groups' blocks (the caller's
+    expectation of the groups that receive a row: the trace takes it for
+    what a call read), its rows once a column tile and its output."""
     rows, k = lhs.shape
     group, tile, live, offsets = tiles
     groups = offsets.shape[0] - 1
@@ -244,6 +321,12 @@ def grouped_matmul(lhs: Array, rhs: Array, tiles, *, groups_along: int,
         raise ValueError(f"no column tile of a ({k}, {n}) expert block "
                          "fits the grouped product's VMEM")
     across = n // tn
+    itemsize = jnp.dtype(lhs.dtype).itemsize
+    cost = pl.CostEstimate(
+        flops=int(2 * tm * k * n * max(rows // tm + fetched - 1, 1)),
+        transcendentals=0,
+        bytes_accessed=int(fetched * k * n * jnp.dtype(rhs.dtype).itemsize
+                           + across * rows * k * itemsize + rows * n * 4))
     if groups_along:
         rhs_block = lambda j, v, group, tile, live, offsets: (
             0, group[v] * across + j)
@@ -265,6 +348,7 @@ def grouped_matmul(lhs: Array, rhs: Array, tiles, *, groups_along: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_GROUPED_VMEM_LIMIT),
+        cost_estimate=cost,
         interpret=interpret,
     )(group, tile, live, offsets, lhs, rhs)
 
@@ -273,19 +357,22 @@ def grouped_rows(tokens: int, top_k: int, held: int, n_experts: int,
                  tm: int = _TILE_ROWS) -> Tuple[int, int]:
     """``(rows, tile)`` of the grouped form for ``tokens`` tokens of
     ``top_k`` picks through ``held`` of ``n_experts`` experts: the rows
-    the pairs are laid in, a multiple of ``tm``, and the rows a tile.
+    the pairs are laid in, a multiple of the tile, and the rows a tile.
     Every pair has a row where every expert is held.  Under a share the
     rows are a bound from these shapes alone: twice the held pairs an
     even router sends (``tokens x top_k x held / n_experts``), never
     more than the pairs; a step whose held pairs exceed it takes further
-    rounds of as many rows.  Rows few enough are one tile, so that no
-    group straddles two."""
-    pairs = tokens * top_k
-    rows = -(-pairs // tm) * tm
+    rounds of as many rows.  Rows few enough (up to two tiles of ``tm``)
+    are one tile of whole sublane tiles of 16, so that no group straddles
+    two and a token step multiplies no more rows than it lays; more are
+    tiles of ``tm``."""
+    bound = tokens * top_k
     if held < n_experts:
-        expected = -(-2 * pairs * held // n_experts)
-        rows = min(rows, -(-expected // tm) * tm)
-    return rows, (rows if rows <= 2 * tm else tm)
+        bound = min(bound, -(-2 * bound * held // n_experts))
+    rows = -(-bound // _SUBLANE_ROWS) * _SUBLANE_ROWS
+    if rows <= 2 * tm:
+        return rows, rows
+    return -(-bound // tm) * tm, tm
 
 
 def held_pair_rows(slot: Array, n_held: int):
@@ -351,9 +438,10 @@ def dense_experts(x: Array, combine: Array, wg: Array, wu: Array,
 def held_rows_experts(x: Array, picked: Array, combine: Array, wg: Array,
                       wu: Array, wd: Array, *, rows: int):
     """:func:`dense_experts` over the tokens that picked a held expert
-    (``picked`` (tokens,) bool) alone, gathered into ``rows`` rows, and
-    whether that spilled (int32).  Under a share most tokens pick none,
-    and the dense form's operations, which at a few hundred tokens no
+    (``picked`` (tokens,) bool) alone, gathered into ``rows`` rows,
+    whether that spilled (int32) and how many held experts' blocks it
+    read (int32: all of them a round).  Under a share most tokens pick
+    none, and the dense form's operations, which at a few hundred tokens no
     longer hide under the experts' bytes, shrink with its rows.  The
     other tokens' result is 0; a step with more such tokens than
     ``rows`` takes further rounds of as many: no token is left out."""
@@ -370,7 +458,8 @@ def held_rows_experts(x: Array, picked: Array, combine: Array, wg: Array,
         return _einsum_acc("tr,rh->th", back.astype(x.dtype), out,
                            jnp.float32).astype(x.dtype)
 
-    return _rounds(round_from, rows, total)
+    y, rounds = _rounds(round_from, rows, total)
+    return y, (rounds > 1).astype(jnp.int32), rounds * combine.shape[1]
 
 
 def _way_back(token_of_row: Array, base, total: Array, tokens: int):
@@ -385,14 +474,14 @@ def _way_back(token_of_row: Array, base, total: Array, tokens: int):
 
 def _rounds(round_from, rows: int, total: Array):
     """``round_from(base)`` summed over ``base`` = 0, ``rows``, ... below
-    ``total``, and whether there was more than one (int32).  The first
-    round is not in the loop: it is the only one unless a router is
-    skewed, and so starts from no array of zeros."""
+    ``total``, and how many rounds that took (int32, at least 1).  The
+    first round is not in the loop: it is the only one unless a router
+    is skewed, and so starts from no array of zeros."""
     _, y = jax.lax.while_loop(
         lambda carry: carry[0] < total,
         lambda carry: (carry[0] + rows, carry[1] + round_from(carry[0])),
         (jnp.full((), rows, jnp.int32), round_from(0)))
-    return y, (total > rows).astype(jnp.int32)
+    return y, jnp.maximum(-(-total // rows), 1)
 
 
 def grouped_experts(x: Array, idx: Array, w: Array, wg: Array, wu: Array,
@@ -400,9 +489,12 @@ def grouped_experts(x: Array, idx: Array, w: Array, wg: Array, wu: Array,
                     tm: int = _TILE_ROWS,
                     interpret: Optional[bool] = None):
     """``sum_k w[t, k] * E_idx[t, k](x[t])`` over the picks whose expert
-    is in ``held``, with ``E(x) = (silu(x Wg) * x Wu) Wd``, and whether
+    is in ``held``, with ``E(x) = (silu(x Wg) * x Wu) Wd``, whether
     that spilled (int32: 1 where the held pairs exceeded the rows of
-    :func:`grouped_rows`, a skewed router): ``x`` (tokens, hidden),
+    :func:`grouped_rows`, a skewed router) and how many held experts'
+    blocks the products fetched (int32: the groups that received a row,
+    a round each whose rows they meet; an expert with no pick is not
+    fetched): ``x`` (tokens, hidden),
     ``idx``/``w`` (tokens, top_k) from the router, the matrices as
     :class:`MixtureOfExperts` stores them (the blocks in the order of
     ``held``).  The pairs are laid in rows by their expert's place in
@@ -439,6 +531,7 @@ def grouped_experts(x: Array, idx: Array, w: Array, wg: Array, wu: Array,
         tiles = group_tiles(sizes, rows, tile)
         product = lambda lhs, rhs, along: grouped_matmul(
             lhs, rhs, tiles, groups_along=along, tm=tile,
+            fetched=n_held * picked_share(tokens, top_k, n_experts),
             interpret=interpret)
         xs = jnp.take(x, jnp.minimum(order // top_k, tokens - 1), axis=0)
         ws = jnp.take(jnp.pad(w.reshape(-1), (0, max(rows - pairs, 0))),
@@ -458,7 +551,8 @@ def grouped_experts(x: Array, idx: Array, w: Array, wg: Array, wu: Array,
         picked = jnp.where((slot < n_held)[:, :, None],
                            jnp.take(out, row, axis=0), 0.0)
         return (jnp.sum(picked, axis=1).astype(x.dtype),
-                jnp.zeros((), jnp.int32))
+                jnp.zeros((), jnp.int32),
+                jnp.sum(sizes > 0, dtype=jnp.int32))
 
     at, starts, ends = held_pair_rows(slot.reshape(-1), n_held)
     total = ends[-1]
@@ -471,5 +565,8 @@ def grouped_experts(x: Array, idx: Array, w: Array, wg: Array, wu: Array,
         # the kernel leaves the rows beyond the last pair unwritten
         return sum_rows(back, jnp.where(live[:, None], out, 0.0))
 
-    y, spilled = _rounds(round_from, rows, total)
-    return y.astype(x.dtype), spilled
+    y, rounds = _rounds(round_from, rows, total)
+    # an expert's rows lie in each round from its first row's to its last's
+    read = jnp.sum(jnp.where(ends > starts, (ends - 1) // rows
+                             - starts // rows + 1, 0), dtype=jnp.int32)
+    return y.astype(x.dtype), (rounds > 1).astype(jnp.int32), read

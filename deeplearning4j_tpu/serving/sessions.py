@@ -594,10 +594,19 @@ class SessionCache:
             "moe_experts_spilled_total",
             "token steps whose held pairs outgrew the grouped form's rows "
             "and took further rounds, by layer")
-        by_vertex = dict(zip(model._expert_vertices(), counts[:, :-1]))
-        spills = dict(zip(by_vertex, counts[:, -1:].ravel().tolist()))
+        read = _monitor.counter(
+            "moe_experts_read_total",
+            "held experts whose blocks a token step read, summed over its "
+            "rounds and the steps, by layer")
+        # a vertex's row: its picks by expert, then what it read and
+        # whether it spilled
+        rows = dict(zip(model._expert_vertices(), counts))
+        by_vertex = {vertex: row[:-2] for vertex, row in rows.items()}
+        reads = {vertex: int(row[-2]) for vertex, row in rows.items()}
+        spills = {vertex: int(row[-1]) for vertex, row in rows.items()}
         for vertex, row in by_vertex.items():
             spilled.inc(spills[vertex], model=self._name, layer=vertex)
+            read.inc(reads[vertex], model=self._name, layer=vertex)
             for expert, picks in enumerate(row):
                 if picks:
                     tokens.inc(int(picks), model=self._name, layer=vertex,

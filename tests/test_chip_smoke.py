@@ -54,6 +54,7 @@ TOY = chip_smoke.Sizes(
     latent_shape=(2, 8, 128, 16, 256, 200),
     experts_shape=(40, 8, 32, 64, 2),
     experts_share_shape=(32, 24, 4, 32, 64, 3),
+    experts_step_shape=(24, 64, 8, 32, 64, 2),
     sparse_shape=(2, 4, 2, 16, 3, 8, 256, 16, 8),
     gqa_shape=(2, 4, 2, 16, 8, 256, 8), interpret=True, mln_conf=_tiny_mln, mln_features=6, mln_classes=3)
 
@@ -84,6 +85,7 @@ def test_kernels():
             "latent_streamed_max_rel_err",
             "experts_grouped_max_rel_err",
             "experts_share_grouped_max_rel_err",
+            "experts_step_grouped_max_rel_err",
             "sparse_streamed_max_rel_err"} <= set(info)
 
 

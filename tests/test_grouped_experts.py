@@ -186,6 +186,71 @@ def test_group_tiles_visit_every_meeting_of_a_tile_and_a_group():
     assert int(live[0]) == 0 and set(tile.tolist()) == {0}
 
 
+#: a share's token step at toy widths: 24 tokens through 8 held experts
+#: (in no order) of a 64-wide router, the picks a token and the
+#: selection bias (expert -> bias) that shapes the groups; last, whether
+#: the held pairs outgrow the one tile of 16 rows and take further rounds
+STEP_HELD = [5, 2, 7, 0, 9, 11, 3, 60]
+STEP_CASES = {
+    "even_routing": (2, {}, 0),
+    "no_held_pick": (2, {e: -100.0 for e in STEP_HELD}, 0),
+    # 24 pairs on one expert: its group spans two rounds of 16 rows
+    "every_pick_on_one_held_expert": (1, {7: 100.0}, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_a_token_step_of_a_share_reads_only_the_picked_experts(monkeypatch,
+                                                               case):
+    """The grouped form as the token step of a share takes it: the rows
+    and the tile from the shapes alone, 16 rows in one sublane tile, the
+    kernel interpreted, against ``dense_experts`` over every held expert
+    with the unchosen weighted 0.  What it says it read is the held
+    experts that received a pick, once for every round their rows meet;
+    with no held pick nothing is live and the routed part is 0."""
+    top_k, bias, spilled = STEP_CASES[case]
+    tokens, n_experts = 24, 64
+    assert experts.grouped_rows(tokens, top_k, len(STEP_HELD),
+                                n_experts) == (16, 16)
+    layer = decoder.MixtureOfExperts(
+        n_in=HIDDEN, n_out=HIDDEN, n_experts=n_experts, top_k=top_k,
+        width=WIDTH, n_shared=0, experts_held=STEP_HELD,
+        weight_init="distribution",
+        dist=decoder.Distribution(kind="normal", std=0.3))
+    params = layer.init_params(jax.random.PRNGKey(5), jnp.float32)
+    params["router_bias"] = jnp.asarray(
+        [bias.get(e, 0.0) for e in range(n_experts)], jnp.float32)
+    x = jnp.asarray(np.random.RandomState(3).randn(tokens, HIDDEN),
+                    jnp.float32)
+    idx, w = layer.route(params, x)
+    matrices = params["Wg"], params["Wu"], params["Wd"]
+    kernel, live = experts.grouped_matmul, []
+
+    def spy(lhs, rhs, tiles, **kw):
+        live.append(tiles[2])
+        return kernel(lhs, rhs, tiles, **kw)
+
+    monkeypatch.setattr(experts, "grouped_matmul", spy)
+    got, spills, read = experts.grouped_experts(
+        x, idx, w, *matrices, held=STEP_HELD, n_experts=n_experts)
+    hit = np.asarray(idx)[:, :, None] == np.asarray(STEP_HELD)
+    combine = jnp.asarray(np.sum(np.where(hit, np.asarray(w)[:, :, None],
+                                          0.0), axis=1), jnp.float32)
+    want = experts.dense_experts(x, combine, *matrices)
+    picked = int(hit.any(axis=(0, 1)).sum())
+    assert int(spills) == spilled
+    assert int(read) == picked + spilled
+    if case == "no_held_pick":
+        assert picked == 0 and not np.asarray(want).any()
+        # the first round's three products (the loop of further rounds is
+        # traced after them): nothing live, nothing written
+        assert [int(v[0]) for v in live[:3]] == [0, 0, 0]
+        np.testing.assert_array_equal(np.asarray(got), 0.0)
+    else:
+        assert 0 < picked < len(STEP_HELD)
+        assert rel(got, want) < 1e-5
+
+
 # ------------------------------------------------------------ the predicate
 def test_the_path_is_chosen_from_the_arguments(monkeypatch):
     path = experts.moe_experts_path
@@ -239,6 +304,47 @@ def test_the_path_is_chosen_from_the_arguments(monkeypatch):
     # the held rows need no kernel: widths that do not tile keep them
     assert path(256, 12, 192, 8, 7168, 2000, jnp.bfloat16,
                 False) == "held_rows"
+    # the five decode cells' token steps: where an even router leaves
+    # most held experts without a pick, the grouped form reads the picked
+    # ones alone (command_a and keye: 16 of a 128-wide router, 8 tokens
+    # of 8 picks, 0.403 of them picked; dots3: 8 of 256, 32 tokens,
+    # 0.638); Xing4.0 holds every expert, A.X-K1's 256 tokens pick 1.0
+    command_a, keye = (16, 128, 8, 4096, 4096), (16, 128, 8, 2048, 768)
+    dots3 = (8, 256, 8, 5120, 1536)
+    assert path(8, *command_a, jnp.bfloat16, False) == "grouped"
+    assert path(8, *keye, jnp.bfloat16, False) == "grouped"
+    assert path(32, *dots3, jnp.bfloat16, False) == "grouped"
+    assert path(64, *cell, jnp.bfloat16, False) == "dense"
+    assert path(256, *share, jnp.bfloat16, False) == "held_rows"
+    assert [round(experts.picked_share(t, 8, n), 3) for t, n in (
+        (8, 128), (32, 256), (64, 192), (128, 192))] == [
+            0.403, 0.638, 0.934, 0.996]
+    # the reckoned crossing takes the side the sweep measured (tokens,
+    # the share's shape, whether the grouped form was the faster)
+    for tokens, shape, faster in (
+            (8, command_a, True), (40, command_a, True),
+            (48, command_a, False), (64, command_a, False),
+            (8, keye, True), (16, keye, False), (64, keye, False),
+            (32, dots3, True), (48, dots3, True), (128, dots3, False)):
+        held, n_experts, top_k, hidden, width = shape
+        _, tile = experts.grouped_rows(tokens, top_k, held, n_experts)
+        assert experts.grouped_step_wins(tokens, top_k, held, n_experts,
+                                         hidden, width, 2, tile) is faster
+    # and leaves A.X-K1's share dense at 64 tokens
+    assert not experts.grouped_step_wins(64, 8, 12, 192, 7168, 2048, 2, 64)
+    # chunks of those cells keep the grouped form; training, float64
+    # and a share whose picks reach every held expert stay dense
+    assert path(2048, *command_a, jnp.bfloat16, False) == "grouped"
+    assert path(8, *command_a, jnp.bfloat16, True) == "dense"
+    assert path(8, *command_a, jnp.float64, False) == "dense"
+    assert path(64, *command_a, jnp.bfloat16, False) == "dense"
+    # one sublane tile of 16 rows, and whole expert blocks or halves of
+    # them a column tile at those rows
+    for shape in ((8, 8, 16, 128), (32, 8, 8, 256)):
+        assert experts.grouped_rows(*shape) == (16, 16)
+    assert [experts.grouped_tile_columns(k, n, 2, 16) for k, n in (
+        (4096, 4096), (2048, 768), (768, 2048), (5120, 1536),
+        (1536, 5120))] == [2048, 768, 2048, 1536, 5120]
     monkeypatch.undo()
     assert path(256, *share, jnp.bfloat16, False) == "dense"
 
@@ -250,8 +356,12 @@ def test_the_path_is_chosen_from_the_arguments(monkeypatch):
     ((2048, 8, 12, 192), (2048, 128, 928)),     # its prefill chunk
     ((1984, 8, 12, 192), (1984 + 64, 128, 912)),
     ((2048, 4, 64, 64), (8192, 128, 2048)),     # Xing4.0's: every pair
-    ((64, 8, 12, 192), (128, 128, 48)),
-    ((8, 2, 3, 4), (128, 128, 16)),         # never more than there are
+    # rows few enough for one tile, in whole sublane tiles of 16
+    ((64, 8, 12, 192), (64, 64, 48)),
+    ((8, 2, 3, 4), (16, 16, 16)),           # never more than there are
+    ((8, 8, 16, 128), (16, 16, 16)),        # command_a's and keye's steps
+    ((32, 8, 8, 256), (16, 16, 32)),        # dots3's
+    ((1, 8, 16, 128), (16, 16, 16)),
     ((512, 4, 48, 64), (2048, 128, 512)),
     ((256, 4, 16, 64), (512, 128, 208)),
 ])
@@ -373,3 +483,43 @@ def test_a_share_serves_its_token_steps_by_either_form_and_counts_the_spills(
             experts_held=held))
         assert rel(np.stack(got.kept_logits), np.swapaxes(
             want[[0, -1], ids.shape[1] - 1:], 0, 1)) < 1e-5
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+def test_the_read_counter_is_the_held_experts_a_step_picked(monkeypatch,
+                                                            form):
+    """``moe_experts_read_total``: 4 of a 24-wide router's experts held,
+    12 rows of 2 picks a step (24 pairs, 16 rows of the held ones: the
+    share's rows).  A grouped step reads the held experts that received
+    a pick, counted here from the router's picks of that step alone (a
+    call of one step: ``expert_tokens`` is that step's); a dense step
+    reads every held expert."""
+    held = [6, 1, 20, 13]
+    share = {**{k: v for k, v in CFG.items()
+                if not k.startswith(("hc_", "mhc_"))},
+             "n_routed_experts": 4, "published": {"n_routed_experts": 24}}
+    force(monkeypatch, lambda tokens: form if tokens == 12 else "dense")
+    ids = np.random.RandomState(4).randint(0, 256, (12, 6)).astype(np.int32)
+    net = ComputationGraph(from_config(
+        share, dtype="float32", cache_len=16, init_std=0.1,
+        seed={"grouped": 5, "dense": 6}[form], experts_held=held)).init()
+    read = monitor.counter("moe_experts_read_total", "")
+    reads = []
+    with InferenceEngine(net, max_batch_size=16) as engine:
+        engine.prefill_session("s", ids[:, :-1], chunk=5, cache_len=16)
+        step = ids[:, -1:]
+        for _ in range(4):
+            before, steps = read.value(model="default",
+                                       layer="L1_moe"), _steps(form)
+            got = engine.generate("s", step, 1)
+            step = got.ids[:, -1:]
+            assert _steps(form) - steps == 1
+            assert got.experts_spilled == {"L1_moe": 0}
+            row = got.expert_tokens["L1_moe"]
+            assert row.shape == (24,) and int(row.sum()) == 12 * 2
+            picked = int((row[held] > 0).sum())
+            reads.append(read.value(model="default", layer="L1_moe")
+                         - before)
+            assert reads[-1] == (picked if form == "grouped" else len(held))
+    if form == "grouped":
+        assert min(reads) < len(held)       # some held expert went unread

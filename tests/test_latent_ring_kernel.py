@@ -420,6 +420,64 @@ def test_mosaic_compiles_the_grouped_experts_at_the_cells_widths(one_chip,
         compilation_cache.reset_cache()
 
 
+#: a share's token step that takes the grouped form: tokens, hidden,
+#: width, experts held, the router's width, picks a token
+STEPS = {"command_a_plus_05_2026": (8, 4096, 4096, 16, 128, 8),
+         "keye_vl2_30b_a3b": (8, 2048, 768, 16, 128, 8),
+         "dots3_note_prev": (32, 5120, 1536, 8, 256, 8)}
+
+
+@pytest.mark.parametrize("cell", sorted(STEPS))
+def test_mosaic_compiles_a_shares_token_step_grouped(one_chip, monkeypatch,
+                                                     cell):
+    """The token step of each share whose picks leave most held experts
+    without one, bf16, compiled for a described v5e: the predicate gives
+    the grouped form, 16 rows in one tile; three kernels in the first
+    round and three in the loop of further rounds, under the experts'
+    scope; each declares the blocks of the held experts an even router
+    picks (not every held expert's) and its rows, which the trace takes
+    over its operands; nothing weight-sized is made but a prefetch of a
+    whole matrix into VMEM that XLA may schedule ahead of a kernel
+    (keye's ``Wg``, 50 MB: its dense form's step holds such prefetches
+    too)."""
+    import re
+    tokens, hidden, width, held, n_experts, top_k = STEPS[cell]
+    monkeypatch.setattr(experts, "_mosaic", lambda: True)
+    assert experts.moe_experts_path(tokens, held, n_experts, top_k, hidden,
+                                    width, jnp.bfloat16, False) == "grouped"
+    assert experts.grouped_rows(tokens, top_k, held, n_experts) == (16, 16)
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in (((tokens, hidden), jnp.bfloat16),
+                           ((tokens, top_k), jnp.int32),
+                           ((tokens, top_k), jnp.float32),
+                           ((hidden, held * width), jnp.bfloat16),
+                           ((hidden, held * width), jnp.bfloat16),
+                           ((held * width, hidden), jnp.bfloat16))]
+    with _without_compile_cache(), jax.enable_x64(False):
+        compiled = jax.jit(functools.partial(
+            _scoped_experts, held, n_experts)).lower(*shapes).compile()
+    lines = compiled.as_text().splitlines()
+    calls = [line for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 6
+    assert {monitor.parse_op_name(line.split('op_name="')[1].split('"')[0])
+            for line in calls} == {("layer.L1_moe.experts", "forward")}
+    declared = [int(re.search(r'"bytes_accessed":"(\d+)"', line).group(1))
+                for line in calls]
+    picked = held * experts.picked_share(tokens, top_k, n_experts)
+    assert sum(declared[:3]) == pytest.approx(
+        3 * picked * hidden * width * 2, rel=0.02)
+    assert picked < 0.65 * held
+    matrix = (f"bf16[{hidden},{held * width}]",
+              f"bf16[{held * width},{hidden}]")
+    assert not [line for line in lines if " = " in line
+                and line.split(" = ")[1].startswith(matrix)
+                and " parameter(" not in line
+                and " get-tuple-element(" not in line
+                and not (" copy-done(" in line and "S(1)}" in line)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
 def _scoped_sparse(q, q_idx, w_idx, kv_ring, i_ring, cursor):
     with monitor.scope("layer", "L0_attn"):
         with monitor.subscope("indexer"):

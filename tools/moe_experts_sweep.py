@@ -5,9 +5,13 @@ with the form forced, over token counts, row tiles and two routings:
     python tools/moe_experts_sweep.py [--tokens 64 128 ...] [--tiles 128]
     python tools/moe_experts_sweep.py --hidden 7168 --width 2048 \
         --experts 192 --held 12 --top-k 8 [--pieces]   # one chip of sixteen
+    python tools/moe_experts_sweep.py --hidden 4096 --width 4096 \
+        --experts 128 --held 16 --top-k 8 --step --tokens 8 16 32 64 \
+        --routings even
 
-What ``ops.experts._GROUPED_MIN_TOKENS``, ``_DENSE_TURN_ROWS`` and
-``_TILE_ROWS`` were set from (PERF.md, PRs 34 and 36).  Widths default
+What ``ops.experts._GROUPED_MIN_TOKENS``, ``_DENSE_TURN_ROWS``,
+``_TILE_ROWS``, ``_GROUPED_STEP_S`` and ``_GROUPED_FIXED_S`` were set
+from (PERF.md, sections 5 and 6).  Widths default
 to the decode cell's (hidden 3,584, 64 experts of 1,024, 4 picks; one
 layer, 1.41 GB in bfloat16).  ``even`` draws the router at random;
 ``one_expert`` biases expert 0 so that every token picks it (a quarter
@@ -20,7 +24,15 @@ PR 35); the grouped form then lays the held pairs alone in rows
 ``grouped_all_pairs_ms``, times it with a row for every pair (the form
 before PR 36: ``grouped_rows`` patched to the pair count), and a fourth,
 ``held_rows_ms``, the dense form over the tokens that picked a held
-expert alone (``ops.experts.held_rows_experts``).  ``--pieces``
+expert alone (``ops.experts.held_rows_experts``).  ``--step`` times a
+share's token step instead: the dense form against the grouped form as
+the program lays its rows (one tile of 16 rows at a few tokens), beside
+the share of held experts an even router picks (``picked_share``) and
+the share the grouped form read (``read_share``, its ``experts_read``
+over the held experts); there a call is ``--chain`` layers of their
+own weights one after another in one program, each fed the last one's
+output, so that the host's launch of a call (~0.2 ms) does not hide
+a layer's tenth of a millisecond.  ``--pieces``
 adds, at ``--pieces-tokens``, each form's device operations of one call
 in time order, from the profiler.  Each timing is the wall of
 ``--calls`` back-to-back dispatches of one jitted call after three warm
@@ -56,6 +68,14 @@ def main() -> int:
     ap.add_argument("--width", type=int, default=1024)
     ap.add_argument("--top-k", type=int, default=4)
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--step", action="store_true",
+                    help="a share's token step: dense against grouped "
+                         "as the program lays its rows")
+    ap.add_argument("--chain", type=int, default=4,
+                    help="with --step: layers a timed call runs, each "
+                         "with its own weights")
+    ap.add_argument("--routings", nargs="+", default=["even", "one_expert"],
+                    choices=["even", "one_expert"])
     ap.add_argument("--pieces", action="store_true",
                     help="each form's device operations of one call, "
                          "from the profiler")
@@ -86,6 +106,9 @@ def main() -> int:
         dist=decoder.Distribution(kind="normal", std=0.02))
     params = layer.init_params(jax.random.PRNGKey(0), jnp.bfloat16)
     state = layer.init_state()
+    # --step: further layers' weights, each drawn from a key of its own
+    chained = [layer.init_params(jax.random.PRNGKey(i), jnp.bfloat16)
+               for i in range(1, args.chain)] if args.step else []
 
     def clock(call, *inputs) -> tuple:
         for _ in range(3):
@@ -110,8 +133,20 @@ def main() -> int:
             experts, "grouped_rows",
             every_pair if all_pairs else experts.grouped_rows)
         with path, product, rows:
-            return clock(jax.jit(lambda p, x: layer.forward(
+            result = clock(jax.jit(lambda p, x: layer.forward(
                 p, state, x, train=False)), params, x)
+            if not args.step:
+                return result
+            ps = [params] + [dict(q, router_bias=params["router_bias"])
+                             for q in chained]
+
+            def chain(ps, x):
+                for q in ps:
+                    x = x + 1e-3 * layer.forward(q, state, x, train=False)[0]
+                return x
+
+            ms, _ = clock(jax.jit(chain), ps, x)
+            return ms / len(ps), result[1]
 
     def rel_l2(got, want) -> float:
         return round(float(
@@ -162,7 +197,7 @@ def main() -> int:
                 if lo <= s <= hi and e - s >= 1e-6]
 
     rows = []
-    for routing in ("even", "one_expert"):
+    for routing in args.routings:
         bias = np.zeros((args.experts,), np.float32)
         if routing == "one_expert":
             bias[0] = 10.0
@@ -173,12 +208,23 @@ def main() -> int:
             dense_ms, (want, _) = timed("dense", x, p)
             row = {"routing": routing, "tokens": tokens,
                    "dense_ms": round(dense_ms, 4)}
-            for tm in args.tiles:
+            if args.step:
+                held = args.held or args.experts
+                ms, (got, counts) = timed("grouped", x, p)
+                row.update(
+                    grouped_ms=round(ms, 4), grouped_rel_l2=rel_l2(got, want),
+                    rows=experts.grouped_rows(tokens, args.top_k, held,
+                                              args.experts),
+                    picked_share=round(experts.picked_share(
+                        tokens, args.top_k, args.experts), 4),
+                    read_share=round(int(counts["experts_read"]) / held, 4),
+                    spilled=int(counts["experts_spilled"]))
+            for tm in () if args.step else args.tiles:
                 ms, (got, counts) = timed("grouped", x, p, tm)
                 row[f"grouped_tm{tm}_ms"] = round(ms, 4)
                 row[f"grouped_tm{tm}_rel_l2"] = rel_l2(got, want)
                 row["spilled"] = int(counts["experts_spilled"])
-            if args.held is not None:
+            if args.held is not None and not args.step:
                 ms, (got, _) = timed("grouped", x, p, all_pairs=True)
                 row["grouped_all_pairs_ms"] = round(ms, 4)
                 row["grouped_all_pairs_rel_l2"] = rel_l2(got, want)
@@ -193,7 +239,8 @@ def main() -> int:
             if args.pieces and tokens in args.pieces_tokens:
                 row["pieces"] = {
                     form: pieces(form, x, p)
-                    for form in (("dense", "grouped") if args.held is None
+                    for form in (("dense", "grouped")
+                                 if args.held is None or args.step
                                  else ("dense", "held_rows", "grouped"))}
                 for form, ops in row["pieces"].items():
                     print(json.dumps({
